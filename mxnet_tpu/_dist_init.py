@@ -59,18 +59,21 @@ def _raw_init(coordinator, num_processes, process_id):
     ``multihost_utils`` see a normal distributed world."""
     import jax
     from jax._src import distributed as _dist
-    from jax._src.lib import xla_extension as _xe
+    from jax._src.lib import _jax
 
     gs = _dist.global_state
     if gs.client is not None:       # operator initialized it already
         return
     interval, max_missing = _heartbeat_knobs()
+    # jaxlib takes one timeout where it used to take an interval and a
+    # miss budget; their product is the same silence before a peer
+    # counts as missing
+    heartbeat_timeout = interval * max_missing
     port = str(coordinator).rsplit(":", 1)[1]
     if int(process_id) == 0 and gs.service is None:
-        gs.service = _xe.get_distributed_runtime_service(
+        gs.service = _jax.get_distributed_runtime_service(
             "[::]:" + port, int(num_processes),
-            heartbeat_interval=interval,
-            max_missing_heartbeats=max_missing)
+            heartbeat_timeout=heartbeat_timeout)
 
     def _on_missed(status):
         # a silent peer is the launcher's membership problem; log +
@@ -83,12 +86,11 @@ def _raw_init(coordinator, num_processes, process_id):
         except Exception:  # noqa: BLE001 — never raise into the cb
             pass
 
-    gs.client = _xe.get_distributed_runtime_client(
+    gs.client = _jax.get_distributed_runtime_client(
         str(coordinator), int(process_id),
         init_timeout=int(os.environ.get("MXTPU_COORD_INIT_TIMEOUT_S",
                                         "120") or 120),
-        heartbeat_interval=interval,
-        max_missing_heartbeats=max_missing,
+        heartbeat_timeout=heartbeat_timeout,
         missed_heartbeat_callback=_on_missed,
         shutdown_on_destruction=False,
         use_compression=True)

@@ -9,6 +9,7 @@ SURVEY.md §1) owns scheduling.
 """
 from __future__ import annotations
 
+import os
 import threading
 
 import jax
@@ -21,19 +22,11 @@ __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context",
 _DEVTYPE_ALIASES = {
     "cpu": "cpu",
     "cpu_pinned": "cpu",
-    # ``gpu`` kept for one-line porting of reference scripts: on this stack the
-    # accelerator is whatever jax exposes as the default backend.
-    "gpu": None,
-    "tpu": None,
+    # ``gpu`` kept for one-line porting of reference scripts: on this stack
+    # the accelerator is the TPU, and nothing else answers for it.
+    "gpu": "tpu",
+    "tpu": "tpu",
 }
-
-
-def _default_accelerator_platform():
-    """Best accelerator platform name known to jax, else 'cpu'."""
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - jax init failure
-        return "cpu"
 
 
 class Context:
@@ -53,18 +46,18 @@ class Context:
     # -- jax interop ------------------------------------------------------
     @property
     def jax_device(self):
-        """Resolve to a concrete jax.Device."""
+        """Resolve to a concrete jax.Device.  ``tpu``/``gpu`` mean the TPU
+        backend and nothing else: when JAX has none, this raises rather
+        than hand back a CPU device under the name ``tpu(0)``."""
         platform = _DEVTYPE_ALIASES[self.device_type]
-        if platform is None:
-            platform = _default_accelerator_platform()
         try:
             devices = jax.devices(platform)
-        except RuntimeError:
-            if self.device_type in ("tpu", "gpu"):
-                # graceful degradation mirroring mx.gpu() on a CPU build
-                devices = jax.devices("cpu")
-            else:
-                raise
+        except RuntimeError as e:
+            found = sorted({d.platform for d in jax.devices()})
+            raise MXNetError(
+                f"{self}: JAX has no {platform!r} backend in this process "
+                f"(found {found}; JAX_PLATFORMS="
+                f"{os.environ.get('JAX_PLATFORMS', '')!r}): {e}") from e
         if self.device_id >= len(devices):
             raise MXNetError(
                 f"{self} out of range: only {len(devices)} {self.device_type} "
@@ -116,11 +109,10 @@ def tpu(device_id=0):
 
 
 def num_tpus():
+    """TPU devices JAX can see in this process; 0 when it has no TPU
+    backend (for example under ``JAX_PLATFORMS=cpu``)."""
     try:
-        backend = _default_accelerator_platform()
-        if backend == "cpu":
-            return 0
-        return len(jax.devices(backend))
+        return len(jax.devices("tpu"))
     except RuntimeError:
         return 0
 

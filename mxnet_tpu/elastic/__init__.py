@@ -18,8 +18,8 @@ Three layers stitched through the existing stack:
   a ring against departed peers.
 
 ``estimator.fit(elastic_controller=...)`` wires the pause/resume hook
-into the high-level loop; ``testing/chaos.py`` (``tools/
-tpu_queue_runner.py --chaos elastic``) is the end-to-end kill-at-K /
+into the high-level loop; ``testing/chaos.py`` (``python -m
+mxnet_tpu.testing.chaos elastic``) is the end-to-end kill-at-K /
 join-at-K' smoke with bitwise continuation parity.  docs/
 FAULT_TOLERANCE.md §Elastic membership has the state diagram.
 
@@ -31,7 +31,7 @@ timeout; lapsed grace raises the typed ``DrainDeadline``) and
 serving replicas ON LOAD through hysteresis windows + cooldown, and a
 ``DegradationLadder``: shed serving admissions -> run shrunken ->
 checkpoint-and-stop).  Chaos gate:
-``tools/tpu_queue_runner.py --chaos autoscale``.
+``python -m mxnet_tpu.testing.chaos autoscale``.
 
 Env knobs: ``MXTPU_ELASTIC=0`` (kill switch),
 ``MXTPU_ELASTIC_RENDEZVOUS_S`` (join window, default 30),

@@ -17,6 +17,7 @@ import re
 import warnings
 
 import numpy as _np
+import jax
 import jax.numpy as jnp
 
 from ..base import MXNetError
@@ -122,6 +123,13 @@ class Parameter:
         if isinstance(initializer, str):
             initializer = init_mod.create(initializer)
         initializer(init_mod.InitDesc(self.name), data)
+        # the initializer leaves an uncommitted array (the result of a
+        # jax.random op); every later value of the parameter is a jit
+        # output committed to its device, and jit keys its cache on that
+        # difference — uncommitted here means the forward, the backward
+        # and the update all compile twice (47 s of a ResNet-50's second
+        # step on a v5e, PR 21)
+        data._set_data(jax.device_put(data.data, ctx.jax_device))
         self._data = data
         self._deferred_init = None
         if self._grad_req != "null":

@@ -639,7 +639,7 @@ class AsyncDecodeIter:
         self._pending = []
         # JOIN the pool threads, but with a DEADLINE: the old
         # wait=True shutdown blocked close() (and test teardown) for as
-        # long as one wedged sample decode — the known test_real_data
+        # long as one stuck sample decode — the known test_real_data
         # teardown flake on a loaded host.  Pending work was cancelled
         # above, so the join normally returns within one in-flight
         # decode; a straggler past the deadline is left to finish on

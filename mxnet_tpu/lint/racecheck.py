@@ -25,7 +25,7 @@ Findings are recorded in-process (:func:`findings`), emitted as
 ``racecheck.*`` telemetry events, and dumped through the PR 9 flight
 recorder (``reason="racecheck:<kind>"``) so a chaos run that races
 leaves the same post-mortem a kill does.  The chaos suites
-(``testing/chaos.py``, ``tools/tpu_queue_runner.py --chaos``) run under
+(``testing/chaos.py``, ``python -m mxnet_tpu.testing.chaos``) run under
 the detector and assert an empty findings list after every scenario.
 
 Zero overhead when off (the default): :func:`make_lock` returns a plain

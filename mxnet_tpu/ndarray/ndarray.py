@@ -265,12 +265,7 @@ class NDArray:
             other._set_data(jax.device_put(self._data, other._ctx.jax_device))
             return other
         ctx = Context(other) if not isinstance(other, Context) else other
-        try:
-            dev = ctx.jax_device
-            data = jax.device_put(self._data, dev)
-        except Exception:
-            data = self._data
-        out = NDArray(data, ctx)
+        out = NDArray(jax.device_put(self._data, ctx.jax_device), ctx)
         # copies stay differentiable (CopyFromTo registers identity grad)
         if _tape.is_recording() and _tape and (self._node is not None
                                                or self._grad_req != "null"):
@@ -761,11 +756,7 @@ def _convert_index(key):
 def _put(data, ctx):
     ctx = Context(ctx) if ctx is not None and not isinstance(ctx, Context) else ctx
     ctx = ctx or current_context()
-    try:
-        data = jax.device_put(data, ctx.jax_device)
-    except Exception:
-        pass
-    return NDArray(data, ctx)
+    return NDArray(jax.device_put(data, ctx.jax_device), ctx)
 
 
 def array(source_array, ctx=None, dtype=None):

@@ -28,8 +28,20 @@ __all__ = ["seed", "uniform", "normal", "randn", "randint", "gamma",
 
 class _RandState(threading.local):
     def __init__(self):
-        self.key = jax.random.key(0)
+        # the key is built on first use: making one initializes the JAX
+        # backend, and ``import mxnet_tpu`` must not take the chip
+        self._key = None
         self.trace_stack = []   # [(key, counter-box)] while tracing CachedOps
+
+    @property
+    def key(self):
+        if self._key is None:
+            self._key = jax.random.key(0)
+        return self._key
+
+    @key.setter
+    def key(self, value):
+        self._key = value
 
 
 _STATE = _RandState()

@@ -24,6 +24,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
+
+from .kernel_mode import kernel_mode
 
 __all__ = ["flash_attention"]
 
@@ -48,7 +52,7 @@ def _pick_block(n, preferred=512):
 # Pallas TPU forward
 # ---------------------------------------------------------------------------
 
-def _pallas_forward(q, k, v, causal, sm_scale, bq, bk):
+def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -123,6 +127,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="mxtpu_flash_fwd",
+        interpret=interpret,
     )(q, k, v)
     return out, lse[:, 0, :]
 
@@ -209,7 +215,7 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
 # ---------------------------------------------------------------------------
 
 def _use_pallas(lq, lk, d):
-    if jax.default_backend() != "tpu":
+    if kernel_mode() is None:
         return None
     import os
 
@@ -242,6 +248,31 @@ def _use_pallas(lq, lk, d):
     return bq, bk
 
 
+def _per_batch_shard(kernel, q):
+    """``kernel``, wrapped in a shard_map over the data axis when this call
+    is being traced into a step that XLA partitions over that axis by itself
+    (the trainer's plain dp path: a jit over batch-sharded inputs).
+
+    XLA refuses to partition a Mosaic call ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so
+    there each chip runs the kernel on its own (batch x heads) rows.  The
+    step says how it is split through the ambient mesh
+    (``parallel.mesh_scope``); a step that is already inside a shard_map
+    (ZeRO-1) masks it with ``mesh_scope(None)``.  A mesh with a second axis
+    of size > 1 is left to fail as before: only the leading dimension is
+    known to be splittable."""
+    from ..parallel.mesh import AXIS_DP, current_mesh
+    mesh = current_mesh()
+    if mesh is None or not isinstance(q, jax.core.Tracer):
+        return kernel
+    n = mesh.shape.get(AXIS_DP, 1)
+    if n == 1 or n != mesh.size or q.shape[0] % n:
+        return kernel
+    rows = P(AXIS_DP)
+    return shard_map(kernel, mesh=mesh, in_specs=(rows,) * 3,
+                     out_specs=(rows, rows), check_vma=False)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash(q, k, v, causal, sm_scale):
     return _flash_fwd(q, k, v, causal, sm_scale)[0]
@@ -250,7 +281,10 @@ def _flash(q, k, v, causal, sm_scale):
 def _flash_fwd(q, k, v, causal, sm_scale):
     blocks = _use_pallas(q.shape[1], k.shape[1], q.shape[2])
     if blocks is not None:
-        out, lse = _pallas_forward(q, k, v, causal, sm_scale, *blocks)
+        kernel = functools.partial(
+            _pallas_forward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
+            bk=blocks[1], interpret=kernel_mode() == "interpret")
+        out, lse = _per_batch_shard(kernel, q)(q, k, v)
     else:
         bk = _pick_block(k.shape[1], 256) or k.shape[1]
         out, lse = _scan_forward(q, k, v, causal, sm_scale, bk)

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .kernel_mode import kernel_mode
+
 __all__ = ["fused_layer_norm"]
 
 _LANE = 128
@@ -55,7 +57,7 @@ def _pick_rows(rows, sublane=_SUBLANE, preferred=256):
 
 def _use_pallas(rows, d, dtype=jnp.float32):
     import os
-    if jax.default_backend() != "tpu":
+    if kernel_mode() is None:
         return None
     if os.environ.get("MXTPU_FUSED_LN", "1") == "0":
         return None
@@ -144,6 +146,7 @@ def _pallas_forward(x2, res2, gamma, beta, eps, br, interpret=False):
         + [vec_spec, vec_spec],
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((rows, d), x2.dtype),
+        name="mxtpu_fused_ln_fwd",
         interpret=interpret,
     )(*ins)
 
@@ -165,6 +168,7 @@ def _pallas_backward(x2, res2, gamma, dy2, eps, br, interpret=False):
         out_shape=[jax.ShapeDtypeStruct((rows, d), x2.dtype),
                    jax.ShapeDtypeStruct((1, d), jnp.float32),
                    jax.ShapeDtypeStruct((1, d), jnp.float32)],
+        name="mxtpu_fused_ln_bwd",
         interpret=interpret,
     )(*ins)
     return dx, dg[0], db[0]
@@ -220,8 +224,9 @@ def _fused_ln_fwd(x, res, gamma, beta, eps):
     if br is not None:
         x2 = x.reshape(rows, d)
         res2 = None if res is None else res.reshape(rows, d)
-        y = _pallas_forward(x2, res2, gamma, beta, eps, br) \
-            .reshape(x.shape)
+        y = _pallas_forward(
+            x2, res2, gamma, beta, eps, br,
+            interpret=kernel_mode() == "interpret").reshape(x.shape)
     else:
         y = _fallback_forward(x, res, gamma, beta, eps)
     return y, (x, res, gamma)
@@ -236,7 +241,8 @@ def _fused_ln_bwd(eps, saved, dy):
         x2 = x.reshape(rows, d)
         res2 = None if res is None else res.reshape(rows, d)
         dx2, dgamma, dbeta = _pallas_backward(
-            x2, res2, gamma, dy.reshape(rows, d), eps, br)
+            x2, res2, gamma, dy.reshape(rows, d), eps, br,
+            interpret=kernel_mode() == "interpret")
         dx = dx2.reshape(x.shape)
     else:
         dx, dgamma, dbeta = _fallback_backward(x, res, gamma, dy, eps)

@@ -19,8 +19,8 @@ Entry points:
     function object), so CPU numerics are bitwise-unchanged.
 
 Eligibility: rule in {sgd, nag, adam, adamw}, f32 payload, TPU backend,
-``MXTPU_PALLAS_UPDATE`` not ``0``.  Everything else silently takes the
-XLA fallback — the kernel is an optimization, never a correctness gate.
+``MXTPU_PALLAS_UPDATE`` not ``0``.  Everything else takes the XLA rule;
+an eligible bucket whose kernel fails to lower raises.
 
 The gluon ``Trainer`` fused group update concatenates its whole
 parameter group into one flat bucket per state-layout (trainer.py
@@ -34,6 +34,8 @@ import os
 
 import jax
 import jax.numpy as jnp
+
+from .kernel_mode import kernel_mode
 
 __all__ = ["fused_bucket_rule", "pallas_update_enabled", "PALLAS_RULES"]
 
@@ -116,11 +118,11 @@ def _sgd_kernel(momentum, nesterov, clip):
 
 
 def _adam_kernel(beta1, beta2, epsilon, decoupled_wd, clip):
-    def kernel(lr_ref, wd_ref, tf_ref, p_ref, g_ref, m_ref, v_ref,
+    def kernel(lr_ref, wd_ref, lr_t_ref, p_ref, g_ref, m_ref, v_ref,
                out_p, out_m, out_v):
         lr = lr_ref[0, 0]
         wd = wd_ref[0, 0]
-        tf = tf_ref[0, 0]
+        lr_t = lr_t_ref[0, 0]
         p = p_ref[:]
         g = g_ref[:]
         if clip is not None:
@@ -129,7 +131,6 @@ def _adam_kernel(beta1, beta2, epsilon, decoupled_wd, clip):
             g = g + wd * p
         m = beta1 * m_ref[:] + (1 - beta1) * g
         v = beta2 * v_ref[:] + (1 - beta2) * jnp.square(g)
-        lr_t = lr * jnp.sqrt(1 - beta2 ** tf) / (1 - beta1 ** tf)
         new_p = p - lr_t * m / (jnp.sqrt(v) + epsilon)
         if decoupled_wd:
             new_p = new_p - lr * wd * p
@@ -139,7 +140,7 @@ def _adam_kernel(beta1, beta2, epsilon, decoupled_wd, clip):
     return kernel
 
 
-def _run_pallas(kernel, scalars, tensors, n_out, br, rows,
+def _run_pallas(kernel, name, scalars, tensors, n_out, br, rows,
                 interpret=False):
     from jax.experimental import pallas as pl
     out = pl.pallas_call(
@@ -150,6 +151,7 @@ def _run_pallas(kernel, scalars, tensors, n_out, br, rows,
         out_specs=[_vec_spec(br) for _ in range(n_out)],
         out_shape=[jax.ShapeDtypeStruct((rows, _LANE), jnp.float32)
                    for _ in range(n_out)],
+        name=name,
         interpret=interpret,
     )(*[jnp.asarray(s, jnp.float32).reshape(1, 1) for s in scalars],
       *tensors)
@@ -164,12 +166,12 @@ def _pallas_sgd(p, g, s, lr, wd, momentum, nesterov, clip,
     kernel = _sgd_kernel(momentum, nesterov, clip)
     if momentum:
         m2 = _pad_to_grid(s["mom"])[0]
-        new_p, new_m = _run_pallas(kernel, (lr, wd), (p2, g2, m2), 2,
-                                   br, rows, interpret)
+        new_p, new_m = _run_pallas(kernel, "mxtpu_bucket_sgd", (lr, wd),
+                                   (p2, g2, m2), 2, br, rows, interpret)
         return (new_p.reshape(-1)[:n],
                 {"mom": new_m.reshape(-1)[:n]})
-    (new_p,) = _run_pallas(kernel, (lr, wd), (p2, g2), 1, br, rows,
-                           interpret)
+    (new_p,) = _run_pallas(kernel, "mxtpu_bucket_sgd", (lr, wd),
+                           (p2, g2), 1, br, rows, interpret)
     return new_p.reshape(-1)[:n], dict(s)
 
 
@@ -181,10 +183,15 @@ def _pallas_adam(p, g, s, lr, wd, beta1, beta2, epsilon, decoupled_wd,
     m2 = _pad_to_grid(s["m"])[0]
     v2 = _pad_to_grid(s["v"])[0]
     t = s["t"] + 1
-    tf = t.astype(jnp.float32) if hasattr(t, "astype") else float(t)
+    # the bias-corrected rate is scalar math on the step count; it stays
+    # outside the kernel because Mosaic has no scalar powf ("failed to
+    # legalize operation 'math.powf'", libtpu 0.0.34)
+    tf = jnp.asarray(t, jnp.float32)
+    lr_t = lr * jnp.sqrt(1 - beta2 ** tf) / (1 - beta1 ** tf)
     kernel = _adam_kernel(beta1, beta2, epsilon, decoupled_wd, clip)
     new_p, new_m, new_v = _run_pallas(
-        kernel, (lr, wd, tf), (p2, g2, m2, v2), 3, br, rows, interpret)
+        kernel, "mxtpu_bucket_adam", (lr, wd, lr_t), (p2, g2, m2, v2), 3,
+        br, rows, interpret)
     return (new_p.reshape(-1)[:n],
             {"m": new_m.reshape(-1)[:n], "v": new_v.reshape(-1)[:n],
              "t": t})
@@ -206,7 +213,7 @@ def _pallas_apply(name, hyper, clip, p, g, s, lr, wd, interpret=False):
 def _eligible(name, p):
     return (name in PALLAS_RULES
             and pallas_update_enabled()
-            and jax.default_backend() == "tpu"
+            and kernel_mode() is not None
             and getattr(p, "ndim", 0) == 1
             and p.dtype == jnp.float32)
 
@@ -223,12 +230,11 @@ def fused_bucket_rule(name, clip_gradient=None, **hyper):
     @functools.wraps(base_apply)
     def apply(p, g, s, lr, wd):
         if _eligible(name, p):
-            try:
-                return _pallas_apply(name, hyper, clip_gradient,
-                                     p, g, s, lr, wd)
-            except Exception:  # noqa: BLE001 — kernel lowering is an
-                # optimization; the XLA chain is always valid
-                pass
+            # an eligible bucket that cannot lower is an error, not a
+            # reason to take the XLA chain quietly
+            return _pallas_apply(name, hyper, clip_gradient,
+                                 p, g, s, lr, wd,
+                                 interpret=kernel_mode() == "interpret")
         return base_apply(p, g, s, lr, wd)
 
     return init, apply

@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .kernel_mode import kernel_mode
+
 __all__ = ["paged_decode_attention"]
 
 _NEG_INF = -1e30
@@ -52,7 +54,7 @@ def _use_pallas(block_size, kv_heads, head_dim):
     """Pallas only on TPU backends, and only for geometries Mosaic
     tiles well (lane dim = head_dim multiple of 64, sublane = block
     rows multiple of 8).  Anything else: the bitwise fallback."""
-    if jax.default_backend() != "tpu":
+    if kernel_mode() is None:
         return False
     return head_dim % 64 == 0 and block_size % 8 == 0
 
@@ -87,7 +89,7 @@ def _fallback(q, k_pool, v_pool, block_tables, pos, scale,
 
 
 def _pallas_paged(q, k_pool, v_pool, block_tables, pos, scale,
-                  k_scale=None, v_scale=None):
+                  k_scale=None, v_scale=None, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -185,6 +187,8 @@ def _pallas_paged(q, k_pool, v_pool, block_tables, pos, scale,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, h, d), q.dtype),
+        name="mxtpu_paged_decode",
+        interpret=interpret,
     )(block_tables, pos, *operands)
     return out.reshape(B, h * d)
 
@@ -213,6 +217,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, scale,
     bs, kvh, d = k_pool.shape[1:]
     if _use_pallas(bs, kvh, d):
         return _pallas_paged(q, k_pool, v_pool, block_tables, pos,
-                             scale, k_scale=k_scale, v_scale=v_scale)
+                             scale, k_scale=k_scale, v_scale=v_scale,
+                             interpret=kernel_mode() == "interpret")
     return _fallback(q, k_pool, v_pool, block_tables, pos, scale,
                      k_scale=k_scale, v_scale=v_scale)

@@ -40,8 +40,8 @@ from ..telemetry import tracing as _trace
 from ..telemetry import watchdog as _watchdog
 from ..telemetry import costmodel as _costmodel
 from ..gluon.parameter import _bind_params
-from ._compat import shard_map
-from .mesh import (current_mesh, make_mesh, MeshConfig,
+from jax import shard_map
+from .mesh import (current_mesh, make_mesh, mesh_scope, MeshConfig,
                    AXIS_DP, AXIS_TP, AXIS_PP)
 from . import zero as _zero
 
@@ -244,19 +244,26 @@ class DataParallelTrainer:
             out.append(jax.device_put(stacked, sharding))
         return out
 
-    def _make_loss_of(self):
+    def _make_loss_of(self, manual=False):
         """The traced fwd+loss closure — ONE source for every step
-        variant (plain, indexed, accumulating), replicated or sharded."""
+        variant (plain, indexed, accumulating), replicated or sharded.
+
+        While it traces, the ambient mesh says how the step is split:
+        this trainer's mesh when XLA partitions the step itself (the
+        psum path), none when the trace already runs per chip inside a
+        shard_map (``manual``, the ZeRO-1 path).  A Pallas kernel reads
+        it to wrap itself in the shard_map XLA cannot add for it."""
         block = self.block
         loss_fn = self.loss_fn
         params = self._param_objs
+        ambient = None if manual else self.mesh
 
         def loss_of(pv, key, inputs, label):
             prev = _tape.set_training(True)
             binding = {p: NDArray(v) for p, v in zip(params, pv)}
             try:
                 with _tape.trace_scope(), _bind_params(binding), \
-                        _rnd.trace_key_scope(key):
+                        _rnd.trace_key_scope(key), mesh_scope(ambient):
                     out = block.forward(*[NDArray(b) for b in inputs])
                     loss = loss_fn(out, NDArray(label))
             finally:
@@ -631,7 +638,7 @@ class DataParallelTrainer:
             return jitted
         mesh = self.mesh
         n_in = len(inputs)
-        grad_fn = self._grad_fn(self._make_loss_of(),
+        grad_fn = self._grad_fn(self._make_loss_of(manual=True),
                                 n_micro if kind in ("accum", "multi")
                                 and n_micro else 1)
 
@@ -1035,8 +1042,7 @@ class DataParallelTrainer:
         crosses host->device; the batch select is an in-graph
         ``dynamic_index``. This is the TPU analog of the reference's
         PrefetcherIter keeping decoded batches pinned
-        (src/io/iter_prefetcher.h) — and on remote-tunneled hosts it
-        avoids the per-step H2D dispatch stall entirely.
+        (src/io/iter_prefetcher.h).
         """
         if self._pp_active():
             raise MXNetError(
@@ -1376,6 +1382,25 @@ class DataParallelTrainer:
             for p in params]
 
     # -- observability ---------------------------------------------------
+    def compiled_step_text(self, *batch):
+        """The optimized HLO of the program :meth:`step` runs for this
+        batch signature, as the compiler left it: what a check reads to
+        see that a kernel (a Mosaic ``tpu_custom_call`` and its name)
+        or a collective really is in the step.  Call after a step; it
+        compiles once more, or hits the persistent compile cache.
+        Trainer state is untouched."""
+        inputs = [b.data if isinstance(b, NDArray) else jnp.asarray(b)
+                  for b in batch]
+        if self._param_vals is None:
+            raise MXNetError("compiled_step_text: run one step() first")
+        jitted = self._get_zero1_jit("plain", inputs) \
+            if self._zero1_active() else self._jitted
+        return jitted.lower(
+            self._param_vals, self._opt_state,
+            jnp.asarray(self.learning_rate, jnp.float32),
+            jax.random.key(0), *self._put_batch(inputs)
+        ).compile().as_text()
+
     def overlap_probe(self, *batch, iters=5):
         """The with-vs-without-overlap probe (ISSUE 5): time three
         structurally different builds of THIS trainer's sharded step on

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 from ..base import MXNetError
 from .mesh import AXIS_DP, AXIS_PP

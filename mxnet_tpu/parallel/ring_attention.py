@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..base import MXNetError

@@ -14,7 +14,25 @@ import os
 import jax
 
 __all__ = ["Feature", "Features", "feature_list", "lhs_flags",
-           "apply_lhs_flags", "steps_per_call"]
+           "apply_lhs_flags", "steps_per_call", "enable_compile_cache"]
+
+
+def enable_compile_cache():
+    """Point JAX's persistent compilation cache at one fixed place and
+    return that directory.  ``JAX_COMPILATION_CACHE_DIR``, when the
+    caller set it, already is that place — JAX reads it itself and this
+    touches no setting; otherwise the cache goes to ``.jax_cache`` beside
+    the package (the checkout root).  The path never carries a temporary
+    name, a pid or a time: a directory that moves is a cache that never
+    hits.  Call before the first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def steps_per_call():

@@ -16,7 +16,7 @@ Three pieces turn PR 7's single-replica engine into a servable fleet:
   shared warmup compile cache for the whole fleet.
 
 See docs/SERVING.md §Front-end; the chaos gate is
-``tools/tpu_queue_runner.py --chaos serving``.
+``python -m mxnet_tpu.testing.chaos serving``.
 """
 from __future__ import annotations
 

@@ -641,7 +641,7 @@ class Router:
                 boundaries += 1
                 if boundaries > max_boundaries:
                     raise MXNetError("router drive exceeded "
-                                     "max_boundaries — fleet wedged")
+                                     "max_boundaries — fleet stuck")
             if not progressed and not self.all_done():
                 raise MXNetError(
                     "router: no replica can make progress but "

@@ -43,7 +43,7 @@ row), and a boundary where no row drafts runs the plain decode graph.
 Disaggregated prefill/decode (ISSUE 18): a batcher can be built with a
 ``role`` — ``"prefill"`` admits prompts and parks the finished-prefill
 requests in a ``handoff_ready`` outbox instead of decoding them;
-``"decode"`` never admits from its queue and instead ``adopt_handoff``\ s
+``"decode"`` never admits from its queue and instead calls ``adopt_handoff`` on
 requests whose KV blocks were filled by a prefill-role peer over the
 SAME :class:`~.kv_cache.PagedKVCache`.  The handoff rides the CoW
 refcount machinery: the decode side refs every block FIRST (adopt), the
@@ -628,7 +628,7 @@ class ContinuousBatcher(_BatcherBase):
             steps += 1
             if steps > max_steps:
                 raise MXNetError("run() exceeded max_steps — scheduler "
-                                 "wedged (pool too small for any "
+                                 "stuck (pool too small for any "
                                  "queued request?)")
             if moved == 0 and not self.active and \
                     (self.queue or self.prefilling):

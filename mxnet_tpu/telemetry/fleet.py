@@ -103,8 +103,8 @@ def local_transport():
 def ps_transport(host, port, retries=3, policy=None):
     """Scrape a remote rank over its PS server's ``_OP_TELEMETRY`` RPC
     (fmt=2: snapshot + finished-span ring — the fleet payload).  A
-    fresh connection per scrape: a wedged worker must fail THIS scrape,
-    not wedge the collector's socket forever.  ``policy`` (a
+    fresh connection per scrape: a hung worker must fail THIS scrape,
+    not block the collector's socket forever.  ``policy`` (a
     ``kvstore.rpc.RetryPolicy``) bounds the connect/read deadlines and
     retries (ISSUE 19); the default reads the ``MXTPU_RPC_*`` env, so a
     dead rank fails TYPED within the deadline instead of hanging the

@@ -10,7 +10,7 @@ package owns that machinery:
   via the :func:`~mxnet_tpu.testing.faults.inject` context manager or
   the ``MXTPU_FAULT_INJECT`` env hook.
 - :mod:`mxnet_tpu.testing.chaos` — the self-contained kill-and-resume
-  smoke scenario ``tools/tpu_queue_runner.py --chaos`` runs.
+  smoke scenario ``python -m mxnet_tpu.testing.chaos`` runs.
 """
 from . import faults
 

@@ -1,8 +1,7 @@
 """Kill-and-resume chaos smoke: the fault-tolerance layer end to end.
 
-``python -m mxnet_tpu.testing.chaos`` (or ``tools/tpu_queue_runner.py
---chaos``) runs, on the simulated CPU mesh, the exact scenario the
-acceptance bar demands — in one process, deterministically:
+``python -m mxnet_tpu.testing.chaos`` runs, on the simulated CPU mesh,
+the exact scenario the acceptance bar demands — in one process, deterministically:
 
 1. **Reference run**: N training steps, uninterrupted; final params +
    optimizer state recorded.
@@ -19,8 +18,7 @@ Runs the scenario twice: plain ``gluon.Trainer`` and
 ``DataParallelTrainer(shard_updates=True)``.  Prints one JSON verdict
 line; exit code 0 only if every check passed.
 
-``python -m mxnet_tpu.testing.chaos elastic`` (or ``tools/
-tpu_queue_runner.py --chaos elastic``) runs the ELASTIC MEMBERSHIP
+``python -m mxnet_tpu.testing.chaos elastic`` runs the ELASTIC MEMBERSHIP
 scenarios instead (ISSUE 8) — kill/join workers mid-run and demand
 bitwise continuation parity, all on the simulated 8-device CPU mesh
 with a ``FakeClock`` (zero sleeps):
@@ -39,8 +37,7 @@ with a ``FakeClock`` (zero sleeps):
   rewinds to its step and replays at dp=4 — parity against a fresh
   process restored from that same checkpoint.
 
-``python -m mxnet_tpu.testing.chaos serving`` (or ``tools/
-tpu_queue_runner.py --chaos serving``) runs the SERVING FRONT-END
+``python -m mxnet_tpu.testing.chaos serving`` runs the SERVING FRONT-END
 scenario instead (ISSUE 12), deterministic on CPU with a FakeClock and
 zero sleeps: a 2-replica ``serving.frontend.Router`` (prefix cache +
 chunked prefill on, shared warmup compile cache) serves a
@@ -65,8 +62,7 @@ killed at a scheduling boundary.  Every request must finish exactly
 once with the solo combined-role token stream, zero compiles after
 warmup, and the shared pool must pass the leak sweep on the survivors.
 
-``python -m mxnet_tpu.testing.chaos autoscale`` (or ``tools/
-tpu_queue_runner.py --chaos autoscale``) runs the PRODUCTION-ELASTICITY
+``python -m mxnet_tpu.testing.chaos autoscale`` runs the PRODUCTION-ELASTICITY
 scenario (ISSUE 13), deterministic on the CPU mesh with a FakeClock and
 zero sleeps: a preemption NOTICE for training worker 1 drains it at a
 step boundary AHEAD of the heartbeat timeout (checkpoint-then-reshard
@@ -81,14 +77,12 @@ adds a replacement replica from the shared compile cache (zero new
 compiles).  Every injected notice leaves a parseable flight dump;
 racecheck is armed; the KV pools pass the leak sweep.
 
-``python -m mxnet_tpu.testing.chaos watchdog`` (or ``tools/
-tpu_queue_runner.py --chaos watchdog``) runs the RUN-HEALTH scenario
+``python -m mxnet_tpu.testing.chaos watchdog`` runs the RUN-HEALTH scenario
 (ISSUE 14): a NaN loss injected through the ``watchdog.loss`` fault
 point and a FakeClock step stall must each emit a typed ``watchdog.*``
 event and dump the flight recorder with ``reason="watchdog:<rule>"``.
 
-``python -m mxnet_tpu.testing.chaos fleet`` (or ``tools/
-tpu_queue_runner.py --chaos fleet``) runs the FLEET-OBSERVABILITY
+``python -m mxnet_tpu.testing.chaos fleet`` runs the FLEET-OBSERVABILITY
 scenario (ISSUE 15): N simulated workers (per-rank metric registries —
 exactly what a remote ``PSClient.telemetry()`` scrape returns) stepped
 under ONE FakeClock with zero sleeps, one injected straggler (its
@@ -100,8 +94,7 @@ the rule, the merged histograms must equal the element-wise per-rank
 bucket sums bitwise, and racecheck must report zero findings on the
 collector locks.
 
-``python -m mxnet_tpu.testing.chaos procs`` (or ``tools/
-tpu_queue_runner.py --chaos procs``) runs the MULTI-PROCESS scenario
+``python -m mxnet_tpu.testing.chaos procs`` runs the MULTI-PROCESS scenario
 (ISSUE 19) — the only suite with real processes instead of threads
 under FakeClock: a 4-process pod over ``jax.distributed`` (the
 ``mxnet_tpu.pod.PodLauncher`` runtime), one worker SIGKILLed while the
@@ -1132,7 +1125,7 @@ def run_watchdog_scenario(total_steps=6, nan_at=3, workdir=None):
     then starve the step clock (FakeClock, zero sleeps) past
     ``stall_s``.  Each incident must emit its typed ``watchdog.*``
     event and dump the flight recorder with ``reason="watchdog:<rule>"``
-    — the same gates ``tools/tpu_queue_runner.py --chaos watchdog``
+    — the same gates ``python -m mxnet_tpu.testing.chaos watchdog``
     applies in a child process."""
     import mxnet_tpu as mx
     from mxnet_tpu import telemetry

@@ -2,8 +2,8 @@
 
 The recovery code paths (torn-checkpoint skip, writer-failure surfacing,
 heartbeat death, preemption save) are exactly the paths a normal run
-never exercises.  This module lets tests — and the ``--chaos`` smoke
-mode of ``tools/tpu_queue_runner.py`` — provoke each failure on purpose
+never exercises.  This module lets tests — and the chaos smoke
+(``python -m mxnet_tpu.testing.chaos``) — provoke each failure on purpose
 and deterministically (no wall-clock races, no real SIGKILL needed).
 
 Instrumented code calls :func:`fault_point` at named sites::

@@ -17,30 +17,58 @@ _LIB = None
 _TRIED = False
 
 
+def _repo_root():
+    return os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "..", "..")
+
+
+def _newest_source_mtime():
+    """Newest mtime among the files the library is built from (``src/``
+    without its build directory), or None when the sources are not
+    there (an installed package)."""
+    src = os.path.join(_repo_root(), "src")
+    newest = None
+    for dirpath, dirnames, filenames in os.walk(src):
+        if dirpath == src and "build" in dirnames:
+            dirnames.remove("build")
+        for name in filenames:
+            if name == ".build.lock":
+                continue
+            m = os.path.getmtime(os.path.join(dirpath, name))
+            newest = m if newest is None else max(newest, m)
+    return newest
+
+
 def _find_lib():
+    """A built ``libmxtpu.so`` that is no older than the sources.  A
+    binary left behind by another checkout or an earlier commit (both
+    build locations are git-ignored, so it survives) is not accepted:
+    the caller rebuilds."""
     here = os.path.dirname(os.path.abspath(__file__))
     candidates = [
         os.path.join(here, "libmxtpu.so"),
         os.path.join(here, "..", "..", "src", "build", "libmxtpu.so"),
         os.path.join(here, "..", "..", "build", "libmxtpu.so"),
     ]
+    newest = _newest_source_mtime()
     for c in candidates:
-        if os.path.exists(c):
+        if os.path.exists(c) and (newest is None
+                                  or os.path.getmtime(c) >= newest):
             return c
     return None
 
 
 def _try_build():
     """Attempt a one-shot cmake build of src/ (first use on a fresh
-    checkout). Logged, serialized via a file lock so concurrent processes
-    (e.g. a distributed launch) don't race the build directory; failures
-    leave the pure-Python path in charge."""
+    checkout, or the sources changed). Logged, serialized via a file lock
+    so concurrent processes (e.g. a distributed launch) don't race the
+    build directory; a failure is a WARNING and leaves the pure-Python
+    path in charge."""
     import fcntl
     import logging
+    import shutil
     import subprocess
-    root = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "..", "..")
-    src = os.path.join(root, "src")
+    src = os.path.join(_repo_root(), "src")
     if not os.path.isfile(os.path.join(src, "CMakeLists.txt")):
         return
     build = os.path.join(src, "build")
@@ -53,13 +81,20 @@ def _try_build():
             logging.getLogger("mxnet_tpu").info(
                 "building native library (src/ -> libmxtpu.so); "
                 "set MXTPU_NO_NATIVE_BUILD=1 to skip")
+            # always configure from nothing: a build directory copied
+            # from another checkout pins that checkout's absolute paths
+            # in its CMakeCache.txt and cmake refuses it
+            shutil.rmtree(build, ignore_errors=True)
             subprocess.run(["cmake", "-S", src, "-B", build],
                            capture_output=True, timeout=120, check=True)
             subprocess.run(["cmake", "--build", build],
                            capture_output=True, timeout=300, check=True)
-    except Exception as exc:
-        logging.getLogger("mxnet_tpu").info(
-            "native library build failed (%s); using pure-Python IO", exc)
+    except (OSError, subprocess.SubprocessError) as exc:
+        detail = getattr(exc, "stderr", b"") or b""
+        logging.getLogger("mxnet_tpu").warning(
+            "native library build failed (%s)%s; using pure-Python IO",
+            exc, ": " + detail.decode(errors="replace")[-400:]
+            if detail else "")
 
 
 def _load():

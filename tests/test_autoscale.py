@@ -470,8 +470,8 @@ def test_estimator_drain_deadline_takes_emergency_exit(tmp_path):
 
 
 # ----------------------------------------------------------------------
-# the chaos acceptance scenario (also tools/tpu_queue_runner.py
-# --chaos autoscale)
+# the chaos acceptance scenario (also python -m
+# mxnet_tpu.testing.chaos autoscale)
 # ----------------------------------------------------------------------
 
 @pytest.mark.slow   # the queue runner re-runs this exact scenario

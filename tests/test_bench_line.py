@@ -1,12 +1,9 @@
-"""The driver parses only a ~2KB tail window of bench.py stdout.
+"""The last line bench.py prints is the compact headline.
 
-Round-4 post-mortem: the final JSON line grew to ~3.5KB on the fallback
-path and the driver recorded `parsed: null` — zero machine-readable
-metrics for the round.  These tests pin the new contract: whatever the
-payload (success, fallback, or adversarially bloated), the FINAL line
-bench.py prints is valid JSON under 1800 bytes with the headline metric
-intact.  (Upstream analogue: the perf scripts' one-line summary contract,
-SURVEY.md §6.)
+These tests pin its contract: whatever the payload (a full run, or one
+adversarially bloated), the FINAL line is valid JSON under 1800 bytes
+with the headline metric intact.  (Upstream analogue: the perf scripts'
+one-line summary contract, SURVEY.md §6.)
 """
 import json
 import os
@@ -76,28 +73,6 @@ def _success_payload():
     }
 
 
-def _fallback_payload():
-    """The r04 failure shape: cpu-FALLBACK + cached TPU run + trail."""
-    cached_result = _success_payload()
-    return {
-        "metric": "resnet50_train_images_per_sec", "value": 3.1,
-        "unit": "img/s", "vs_baseline": 0.002, "platform": "cpu-FALLBACK",
-        "batch": 4, "dtype": "fp32", "data": "synthetic", "s2d_stem": True,
-        "error": ("backend probe failed after 6 attempts (120s timeout "
-                  "each); falling back to CPU" + " detail" * 30),
-        "last_known_tpu": {"cached_at": "2026-07-29 21:11:04",
-                           "result": cached_result},
-        "extra": {
-            "note": "cpu smoke mode: bert/rec/bandwidth skipped",
-            "queued_tpu_experiments": "q" * 300,
-            "tunnel_probe_trail": [f"probe {i} failed: timeout 120s"
-                                   for i in range(8)],
-            "scaling_projection": cached_result["extra"][
-                "scaling_projection"],
-        },
-    }
-
-
 def test_success_line_parses_and_fits():
     obj = _assert_headline(bench._compact_line(_success_payload()))
     assert obj["value"] == 2068.4
@@ -118,33 +93,12 @@ def test_success_line_parses_and_fits():
     assert obj["memory_levers.zero1_hbm_savings_mb"] == 150.1
 
 
-def test_fallback_line_parses_and_fits():
-    obj = _assert_headline(bench._compact_line(_fallback_payload()))
-    assert obj["platform"] == "cpu-FALLBACK"
-    assert "error" in obj and len(obj["error"]) <= 160
-    lk = obj["last_known_tpu"]
-    assert lk["value"] == 2068.4 and lk["mfu"] == 0.235
-    assert lk["bert_samples_s"] == 1162.0
-
-
 def test_adversarially_bloated_payload_still_fits():
     p = _success_payload()
     # hundreds of scalar extras: budget must hold regardless
     p["extra"]["sweep"] = {f"k{i}": i * 1.5 for i in range(500)}
     p["error"] = "e" * 5000
     _assert_headline(bench._compact_line(p))
-
-
-def test_committed_tpu_cache_round_trips():
-    """The REAL cached payload (what the next fallback will attach)."""
-    path = bench._TPU_CACHE
-    if not os.path.exists(path):
-        return
-    with open(path) as f:
-        cached = json.load(f)
-    payload = _fallback_payload()
-    payload["last_known_tpu"] = cached
-    _assert_headline(bench._compact_line(payload))
 
 
 def test_minimal_error_payload():
@@ -288,22 +242,26 @@ def test_dispatch_probe_schema_and_monotone_shrink():
     assert rows[1]["step_ms"] >= rows[16]["step_ms"]
 
 
-def test_require_tpu_fail_fast_refuses_cpu(monkeypatch, capsys):
-    """MXTPU_BENCH_REQUIRE_TPU=1 on a non-TPU host: error exit, no CPU
-    fallback numbers, platform stamps in the JSON."""
-    monkeypatch.setenv("MXTPU_BENCH_REQUIRE_TPU", "1")
-    monkeypatch.setenv("MXTPU_BENCH_PROBE_TIMEOUT", "30")
-    monkeypatch.setenv("MXTPU_PROBE_RETRIES", "1")
+def test_main_refuses_a_platform_nobody_asked_for(monkeypatch, capsys):
+    """JAX fell back to the CPU and the caller did not ask for it: the
+    run fails before it measures anything and prints no result."""
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.setattr(bench, "_probe_backend", lambda t: "cpu")
-    rc = bench.main()
-    assert rc == 2
-    lines = [l for l in capsys.readouterr().out.splitlines() if l]
-    obj = json.loads(lines[0])
-    assert obj["platform_requested"] == "tpu"
-    assert obj["platform_actual"] == "cpu"
-    assert "REQUIRE_TPU" in obj["error"]
-    _assert_headline(lines[-1])
+    monkeypatch.setattr(bench, "_run_bench", lambda: pytest.fail("ran"))
+    assert bench.main() != 0
+    out = capsys.readouterr()
+    assert out.out == "" and "not a TPU" in out.err
+
+
+def test_main_does_not_swallow_a_failed_run(monkeypatch, capsys):
+    """An exception ends the run (non-zero exit through the traceback):
+    no JSON line, no fallback child."""
+    def boom():
+        raise RuntimeError("step failed")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setattr(bench, "_run_bench", boom)
+    with pytest.raises(RuntimeError, match="step failed"):
+        bench.main()
+    assert capsys.readouterr().out == ""
 
 
 # ----------------------------------------------------------------------
@@ -864,12 +822,12 @@ def test_bench_multiproc_single_process_is_nulls_not_zeros(monkeypatch):
     """bench.py's multiproc block in one process: nothing was killed and
     nothing re-initialized, so the recovery costs are null — the
     correctness evidence lives in the real-process chaos suite
-    (tools/tpu_queue_runner.py --chaos procs)."""
+    (python -m mxnet_tpu.testing.chaos procs)."""
     monkeypatch.delenv("MXTPU_NUM_PROCESSES", raising=False)
     blk = bench._bench_multiproc()
     assert blk["coordinator_reinit_ms"] is None
     assert blk["sigkill_recover_ms"] is None
-    assert "note" in blk and "--chaos procs" in blk["note"]
+    assert "note" in blk and "testing.chaos procs" in blk["note"]
 
 
 def test_multiproc_compact_keys_surface_when_measured():

@@ -81,7 +81,6 @@ def test_export_import_fresh_process(tmp_path):
     script = tmp_path / "reload.py"
     script.write_text(
         f"import sys; sys.path.insert(0, {REPO!r})\n"
-        "from _cpu_defense import force_cpu; force_cpu()\n"
         "import numpy as np\n"
         "import mxnet_tpu as mx\n"
         "from mxnet_tpu import gluon\n"

@@ -103,31 +103,17 @@ def test_mha_use_flash_matches_einsum_path():
 
 def test_pallas_kernel_structure_compiles_in_interpret_mode():
     """Exercise the Pallas kernel itself (interpret=True on CPU)."""
-    try:
-        from jax.experimental import pallas as pl
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    except (ImportError, NotImplementedError) as exc:
-        pytest.skip(f"pallas-tpu unavailable in CPU test env: {exc}")
     rng = np.random.RandomState(5)
     q = jnp.asarray(rng.randn(2, 128, 128), jnp.float32)
     k = jnp.asarray(rng.randn(2, 128, 128), jnp.float32)
     v = jnp.asarray(rng.randn(2, 128, 128), jnp.float32)
-    import mxnet_tpu.ops.flash_attention as mod
-    orig = mod._pallas_forward
-
-    import functools
-    from unittest import mock
+    # the package re-exports the op under the submodule's own name
+    import importlib
+    mod = importlib.import_module("mxnet_tpu.ops.flash_attention")
 
     def interp_forward(q, k, v, causal, sm_scale, bq, bk):
-        with jax.disable_jit(False):
-            return _interp(q, k, v, causal, sm_scale, bq, bk)
-
-    def _interp(q, k, v, causal, sm_scale, bq, bk):
-        # re-run the real builder but with interpret=True
-        with mock.patch.object(pl, "pallas_call",
-                               functools.partial(pl.pallas_call,
-                                                 interpret=True)):
-            return orig(q, k, v, causal, sm_scale, bq, bk)
+        return mod._pallas_forward(q, k, v, causal, sm_scale, bq, bk,
+                                   interpret=True)
 
     for causal in (False, True):
         out, lse = interp_forward(q, k, v, causal, 1.0 / np.sqrt(128.0),
@@ -138,3 +124,38 @@ def test_pallas_kernel_structure_compiles_in_interpret_mode():
                                    rtol=2e-5, atol=2e-5)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                    rtol=2e-5, atol=2e-5)
+
+
+def test_kernel_is_wrapped_in_shard_map_under_a_partitioned_step():
+    """XLA cannot partition a Mosaic call by itself (on the chip: "Mosaic
+    kernels cannot be automatically partitioned"), so under an ambient dp
+    mesh — what DataParallelTrainer's psum path sets while it traces —
+    the kernel goes inside a shard_map over 'dp'; with no ambient mesh
+    (one chip, or a trace that is already per chip inside ZeRO-1's
+    shard_map) it is called bare.  Results agree either way."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu.ops.kernel_mode import interpret_kernels
+    from mxnet_tpu.parallel import make_mesh, mesh_scope
+    mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    rng = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(rng.randn(8, 2, 128, 64), jnp.float32)
+               for _ in range(3))
+
+    def fresh():
+        # the ambient mesh is read while tracing and is no part of any
+        # jax cache key: every trace below gets a function of its own
+        return lambda q, k, v: flash_attention(q, k, v, causal=True)
+
+    with interpret_kernels():
+        with mesh_scope(mesh):
+            wrapped = str(jax.make_jaxpr(fresh())(q, k, v))
+            sharded = [jax.device_put(a, NamedSharding(mesh, P("dp")))
+                       for a in (q, k, v)]
+            out = jax.jit(fresh())(*sharded)
+        with mesh_scope(None):
+            bare = str(jax.make_jaxpr(fresh())(q, k, v))
+    assert "shard_map" in wrapped and "pallas_call" in wrapped
+    assert "shard_map" not in bare and "pallas_call" in bare
+    assert out.sharding.spec[0] == "dp"
+    np.testing.assert_allclose(np.asarray(out), np.asarray(fresh()(q, k, v)),
+                               rtol=2e-5, atol=2e-5)

@@ -402,7 +402,7 @@ def test_kv_cache_block_nbytes_is_exact():
 # ----------------------------------------------------------------------
 
 def test_chaos_fleet_scenario(tmp_path, monkeypatch):
-    """The tier-1 wiring of ``tools/tpu_queue_runner.py --chaos fleet``:
+    """The tier-1 wiring of ``python -m mxnet_tpu.testing.chaos fleet``:
     straggler + scrape-dead ranks named, histograms merged bitwise,
     racecheck clean."""
     monkeypatch.setenv("MXTPU_FLIGHT_DIR", str(tmp_path))

@@ -158,7 +158,7 @@ def test_tpu_sync_traced_push_lowers_to_psum():
     on executed numerics (every shard sees the cross-device sum)."""
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.ndarray.ndarray import NDArray
 
@@ -190,7 +190,7 @@ def test_dist_tpu_sync_traced_push_stays_in_graph():
     raises); also assert the collective is in the lowered jaxpr."""
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.ndarray.ndarray import NDArray
 
@@ -212,7 +212,7 @@ def test_dist_tpu_sync_traced_push_stays_in_graph():
 
 
 def test_tpu_sync_traced_push_rejects_updater():
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     import jax.numpy as jnp
     from mxnet_tpu.ndarray.ndarray import NDArray
@@ -238,7 +238,7 @@ def test_tpu_sync_traced_mixed_pull_and_stale_scrub():
     tracers from an aborted trace never leak into eager pulls."""
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.ndarray.ndarray import NDArray
 
@@ -277,7 +277,7 @@ def test_tpu_sync_traced_push_guards():
     """Uninitialized keys and unbound axis names fail fast with guidance."""
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.ndarray.ndarray import NDArray
 

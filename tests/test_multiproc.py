@@ -1,7 +1,7 @@
 """Process-level pod runtime (ISSUE 19): mxnet_tpu.pod + chaos procs.
 
 The full SIGKILL scenario (4 real processes, coordinator re-init,
-bitwise resume) is ``slow`` — it belongs to ``--chaos procs``.  Tier-1
+bitwise resume) is ``slow`` — it belongs to the ``procs`` chaos suite.  Tier-1
 keeps one tiny real-process smoke (2 CPU workers, 2 steps, clean exit)
 plus the pure-file control-plane unit tests, so the launcher protocol
 is exercised on every run without paying the full scenario.

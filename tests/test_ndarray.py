@@ -261,7 +261,9 @@ def test_where_clip_misc():
 
 def test_context_api():
     assert mx.cpu(0) == mx.cpu(0)
-    assert mx.cpu(0) != mx.tpu(0) or mx.context.num_tpus() == 0
+    assert mx.cpu(0) != mx.tpu(0)
+    assert mx.gpu(0) != mx.tpu(0)     # an alias for the device, not the name
+    assert mx.context.num_tpus() == 0   # the suite runs on the CPU backend
     with mx.Context("cpu", 0):
         a = nd.zeros((2,))
         assert a.context.device_type == "cpu"

@@ -30,7 +30,7 @@ def test_make_mesh_shapes():
 def test_psum_over_mesh():
     mesh = make_mesh({"dp": 8})
     from jax.sharding import PartitionSpec as P
-    from mxnet_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     def f(x):
         return jax.lax.psum(x, "dp")

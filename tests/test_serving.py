@@ -384,7 +384,7 @@ def test_pool_exhaustion_keeps_requests_queued():
 def test_request_finishing_inside_prefill_is_progress():
     """max_new_tokens=1 (or EOS on the prefill-sampled token) completes
     the request inside the prefill boundary; the scheduler must count
-    that as progress, not a wedged queue (regression: run() raised
+    that as progress, not a stuck queue (regression: run() raised
     'cannot be admitted' when an admitted request never reached the
     decode batch)."""
     net = _net(tie=True)

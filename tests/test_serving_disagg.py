@@ -238,7 +238,7 @@ def test_autoscaler_scales_pools_independently():
 
 
 @pytest.mark.slow   # composition gate; the chaos serving scenario
-# (tpu_queue_runner --chaos serving) drives spec-decode fleets per run
+# (python -m mxnet_tpu.testing.chaos serving) drives spec-decode fleets per run
 def test_disagg_composes_with_spec_decode():
     """MXTPU_SPEC_DECODE on the disaggregated fleet: the decode pool
     drafts+verifies, outputs stay bitwise the PLAIN solo streams."""
@@ -259,7 +259,7 @@ def test_disagg_composes_with_spec_decode():
     router._shared_cache.check_leaks(holders=0)
 
 
-@pytest.mark.slow   # also tpu_queue_runner --chaos disagg
+@pytest.mark.slow   # also python -m mxnet_tpu.testing.chaos disagg
 def test_chaos_prefill_replica_killed_mid_handoff():
     """The ISSUE 18 acceptance gate: a prefill replica killed BETWEEN
     "prefill finished" and "decode adopted" — zero lost, zero
@@ -270,7 +270,7 @@ def test_chaos_prefill_replica_killed_mid_handoff():
     assert r["requeues"] >= 1 and r["handoffs"] >= 1
 
 
-@pytest.mark.slow   # also tpu_queue_runner --chaos disagg
+@pytest.mark.slow   # also python -m mxnet_tpu.testing.chaos disagg
 def test_chaos_decode_replica_killed_at_boundary():
     """Decode-pool death: adopted requests requeue through a fresh
     prefill, still exactly once and bitwise solo."""

@@ -12,7 +12,7 @@ THE acceptance gates:
   one-prompt-per-boundary, with zero compiles after warmup;
 - a replica kill mid-traffic requeues with zero lost/duplicated
   requests and solo-reference outputs (the chaos scenario, also wired
-  as ``tools/tpu_queue_runner.py --chaos serving``).
+  as ``python -m mxnet_tpu.testing.chaos serving``).
 
 Every engine in this module shares ONE compile cache (the Router's
 fleet discipline), so the file pays the graph compiles once.
@@ -435,7 +435,7 @@ def test_router_threaded_mode_racecheck_clean(net):
 
 @pytest.mark.slow
 def test_serving_chaos_scenario(tmp_path):
-    """The tier-1 wiring of ``--chaos serving`` (like the elastic
+    """The tier-1 wiring of the ``serving`` chaos suite (like the elastic
     scenarios): replica kill mid-traffic, requeue, solo-exact outputs,
     flight dump, racecheck, KV leak sweep — one verdict dict."""
     from mxnet_tpu.testing.chaos import run_serving_scenario

@@ -19,7 +19,7 @@ from jax.sharding import PartitionSpec as P
 import mxnet_tpu as mx
 from mxnet_tpu import autograd, gluon
 from mxnet_tpu.parallel import make_mesh, mesh_scope
-from mxnet_tpu.parallel._compat import shard_map
+from jax import shard_map
 from mxnet_tpu.parallel import zero
 from mxnet_tpu.parallel.data_parallel import DataParallelTrainer
 

@@ -52,7 +52,7 @@ _MAX_NEW = 6
 def net():
     # one layer keeps the verify-family compiles inside the tier-1 time
     # budget; multi-layer speculative decode runs in the slow chaos
-    # scenarios (2-layer nets, MXTPU_SPEC_DECODE=1 in tpu_queue_runner)
+    # scenarios (2-layer nets, under MXTPU_SPEC_DECODE=1)
     cfg = LlamaConfig(vocab_size=_VOCAB, hidden_size=32, num_layers=1,
                       num_heads=4, num_kv_heads=2, intermediate_size=64,
                       max_seq_len=64, tie_embeddings=True)

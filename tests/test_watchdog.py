@@ -241,7 +241,7 @@ def test_estimator_ticks_loss_rules(tmp_path, monkeypatch):
 
 
 def test_watchdog_chaos_scenario(tmp_path, monkeypatch):
-    """The tier-1 wiring of ``--chaos watchdog``: NaN-loss injection
+    """The tier-1 wiring of the ``watchdog`` chaos suite: NaN-loss injection
     through the fault point + FakeClock step stall, each leaving the
     typed event and a flight dump whose reason names the rule."""
     monkeypatch.setenv("MXTPU_FLIGHT_DIR", str(tmp_path))

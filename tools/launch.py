@@ -51,7 +51,7 @@ def _free_port():
 
 def supervise(args):
     """The pod mode: spawn + supervise through mxnet_tpu.pod, print one
-    JSON summary line (what tools/tpu_queue_runner.py parses)."""
+    JSON summary line."""
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     from mxnet_tpu.pod import PodLauncher

@@ -458,6 +458,8 @@ def main(argv=None):
             ).strip()
     n = args.requests if args.requests is not None else (12 if smoke
                                                          else 64)
+    from mxnet_tpu.runtime import enable_compile_cache
+    enable_compile_cache()
     payload = run_loadgen(
         n_requests=n, max_batch=args.max_batch,
         block_size=args.block_size or (8 if smoke else 16),
