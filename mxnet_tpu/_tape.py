@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from .base import MXNetError
+from .telemetry import tracing as _trace
 
 __all__ = ["is_recording", "is_training", "set_recording", "set_training",
            "apply_op", "backward", "mark_variable", "Node",
@@ -276,10 +277,19 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
     """Run the reverse pass from ``heads``.
 
     Reference: ``Imperative::Backward`` (src/imperative/imperative.cc) invoked
-    from ``python/mxnet/autograd.py`` ``backward()``.
+    from ``python/mxnet/autograd.py`` ``backward()``.  One
+    ``autograd.backward`` span covers the walk (``nodes``: tape nodes
+    reached from the heads; ``heads``).
     """
     if not isinstance(heads, (list, tuple)):
         heads = [heads]
+    with _trace.span("autograd.backward", heads=len(heads)) as sp:
+        nodes = _backward(heads, head_grads, retain_graph)
+        _trace.annotate(sp, nodes=nodes)
+
+
+def _backward(heads, head_grads, retain_graph):
+    """The walk itself; returns the number of tape nodes it reached."""
     if head_grads is None:
         head_grads = [None] * len(heads)
     elif not isinstance(head_grads, (list, tuple)):
@@ -311,7 +321,7 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
     if not live:
         for arr, g in leaf_grads.values():
             _finalize_leaf(arr, g)
-        return
+        return 0
 
     # Collect the subgraph reachable from the heads (the tape holds no
     # global node list: the graph lives in NDArray._node / Node.inputs
@@ -407,6 +417,7 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True):
 
     for arr, g in leaf_grads.values():
         _finalize_leaf(arr, g)
+    return len(reachable)
 
 
 def replay_function(heads, variables):

@@ -41,10 +41,39 @@ from ..ndarray.ndarray import NDArray
 from ..ndarray import utils as nd_utils
 from .. import _tape
 from ..ndarray import random as _rnd
+from ..telemetry import tracing as _trace
 from .parameter import (Parameter, ParameterDict, Constant,
                         DeferredInitializationError, _bind_params)
 
 __all__ = ["Block", "HybridBlock", "SymbolBlock", "nn_block_scope"]
+
+
+# ----------------------------------------------------------------------
+# the forward's span: one per outermost call of the Gluon loop
+# ----------------------------------------------------------------------
+
+class _ForwardDepth(threading.local):
+    depth = 0       # >0 while this thread is inside some block's __call__
+
+
+_FORWARD = _ForwardDepth()
+
+
+def _traced_call(block, call, args, kwargs):
+    """``call(*args, **kwargs)`` under a ``gluon.forward`` root span when
+    this is the outermost block call of the thread: children add nothing,
+    nor does a call that an enclosing jit trace inlines (a span per block
+    or per operator would be the overhead it measures)."""
+    if _FORWARD.depth or _tape._STATE.trace_depth or not _trace.enabled():
+        return call(*args, **kwargs)
+    _FORWARD.depth = 1
+    try:
+        with _trace.span("gluon.forward", block=block.name,
+                         hybridized=bool(getattr(block, "_active", False)),
+                         retrace=False):
+            return call(*args, **kwargs)
+    finally:
+        _FORWARD.depth = 0
 
 
 # ----------------------------------------------------------------------
@@ -298,6 +327,9 @@ class Block:
         return out
 
     def __call__(self, *args, **kwargs):
+        return _traced_call(self, self._call, args, kwargs)
+
+    def _call(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
         out = self.forward(*args, **kwargs)
@@ -457,10 +489,15 @@ class CachedOp:
             # one distinct (mode, shapes, dtypes) signature == one jit
             # cache miss == one full retrace + XLA compile; the raw path
             # inlines into an enclosing trace and has no cache of its own
-            self._retrace.record(
-                (train, self._none_pos,
-                 tuple((tuple(a.data.shape), str(a.data.dtype))
-                       for a in args)))
+            if self._retrace.record(
+                    (train, self._none_pos,
+                     tuple((tuple(a.data.shape), str(a.data.dtype))
+                           for a in args))):
+                # a new signature: this call compiles, and the ambient
+                # gluon.forward span says so
+                sp = _trace.current()
+                if sp is not None and sp.name == "gluon.forward":
+                    sp.args["retrace"] = True
         jfn = self._get_jitted(train, raw=raw)
         key = _rnd.next_key()
         n_params = len(params)
@@ -573,7 +610,7 @@ class HybridBlock(Block):
             "automatically; run a forward pass first or set in_units/"
             "in_channels explicitly.")
 
-    def __call__(self, *args, **kwargs):
+    def _call(self, *args, **kwargs):
         # inside an enclosing trace (outer CachedOp / fused trainer step)
         # blocks normally inline as plain ops — EXCEPT remat blocks, which
         # must still route through their jax.checkpoint-wrapped CachedOp so
@@ -591,7 +628,7 @@ class HybridBlock(Block):
                     if k in ("static_alloc", "static_shape", "inline_limit",
                              "remat")})
             return self._cached_op(*args)
-        return super().__call__(*args, **kwargs)
+        return super()._call(*args, **kwargs)
 
     def forward(self, *args, **kwargs):
         """Gather this block's own params and call hybrid_forward.

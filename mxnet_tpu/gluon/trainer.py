@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from ..base import MXNetError
 from ..ndarray.ndarray import NDArray
 from .. import optimizer as opt
+from ..telemetry import tracing as _trace
 from .parameter import ParameterDict, Parameter
 
 __all__ = ["Trainer"]
@@ -170,22 +171,28 @@ class Trainer:
         self._optimizer.set_learning_rate(lr)
 
     def _all_reduce_grads(self):
-        if self._overlap is not None:
-            # buckets whose grads finished during backward already went
-            # out (async); this launches stragglers and waits ONLY on
-            # the tail bucket.  Reduced grads carry _grad_reduced, so
-            # the batched pass below cannot double-count them.
-            self._overlap.finish()
-        if self._kvstore is None or self._kvstore.num_workers <= 1 and \
-                type(self._kvstore).__name__ == "KVStoreLocal":
+        reduces = not (self._kvstore is None or
+                       self._kvstore.num_workers <= 1 and
+                       type(self._kvstore).__name__ == "KVStoreLocal")
+        if self._overlap is None and not reduces:
             return
-        # ONE implementation shared with parallel.all_reduce_gradients
-        # (they used to be drifting copies): one batched pushpull, the
-        # dist store coalesces into BIGARRAY_BOUND buckets, and each
-        # accumulated gradient (grad_req='add') is reduced exactly once
-        # per cycle — allreduce_grads() then step() can't double-count.
-        from ..parallel.data_parallel import all_reduce_gradients
-        all_reduce_gradients(self._params, kvstore=self._kvstore)
+        with _trace.span("gluon.update.allreduce"):
+            if self._overlap is not None:
+                # buckets whose grads finished during backward already
+                # went out (async); this launches stragglers and waits
+                # ONLY on the tail bucket.  Reduced grads carry
+                # _grad_reduced, so the batched pass below cannot
+                # double-count them.
+                self._overlap.finish()
+            if reduces:
+                # ONE implementation shared with
+                # parallel.all_reduce_gradients (they used to be drifting
+                # copies): one batched pushpull, the dist store coalesces
+                # into BIGARRAY_BOUND buckets, and each accumulated
+                # gradient (grad_req='add') is reduced exactly once per
+                # cycle — allreduce_grads() then step() can't double-count.
+                from ..parallel.data_parallel import all_reduce_gradients
+                all_reduce_gradients(self._params, kvstore=self._kvstore)
 
     def allreduce_grads(self):
         if not self._kv_initialized:
@@ -198,15 +205,17 @@ class Trainer:
         if not self._kv_initialized:
             self._init_kvstore()
         self._optimizer.rescale_grad = self._scale / batch_size
-        self._all_reduce_grads()
-        self._update(ignore_stale_grad)
+        with _trace.span("gluon.update") as sp:
+            self._all_reduce_grads()
+            self._update(ignore_stale_grad, sp)
 
     def update(self, batch_size, ignore_stale_grad=False):
         """update only (user did allreduce manually)."""
         if not self._kv_initialized:
             self._init_kvstore()
         self._optimizer.rescale_grad = self._scale / batch_size
-        self._update(ignore_stale_grad)
+        with _trace.span("gluon.update") as sp:
+            self._update(ignore_stale_grad, sp)
 
     def _sharded_update_mesh(self):
         """Ambient dp mesh for weight-update sharding of the fused step
@@ -339,10 +348,11 @@ class Trainer:
         """Fused, jitted, donated update for the whole parameter group
         (the Trainer-side half of the overlapped-pipeline tentpole; the
         fully fused fwd/bwd/update lives in parallel.DataParallelTrainer).
-        Falls back (returns False) for optimizers without a functional
-        kernel, sparse/accumulating grads, multi-precision, or
-        unexpected loaded state layouts — the exact eager path then
-        runs.  Disable with MXTPU_FUSED_STEP=0.
+        Returns the number of parameters it updated, or False where it
+        falls back: optimizers without a functional kernel,
+        sparse/accumulating grads, multi-precision, or unexpected loaded
+        state layouts — the exact eager path then runs.  Disable with
+        MXTPU_FUSED_STEP=0.
 
         When the whole group is uniform (same lr/wd/step count, all f32,
         a flat-able rule) the group collapses further into ONE
@@ -376,7 +386,7 @@ class Trainer:
             idxs.append(i)
             params.append(param)
         if not idxs:
-            return True
+            return 0
         # phase 2: commit — counters/lr/wd evaluated once per param
         # (identical bookkeeping to the eager loop), then one jit call
         for i in idxs:
@@ -476,13 +486,14 @@ class Trainer:
             param._data._set_data(np_)
             unpack(i, self._states[i], ns)
             param._data._grad_fresh = False
-        return True
+        return len(idxs)
 
     def _fused_group_update(self, ignore_stale_grad):
         """ONE multi-tensor op for the whole parameter group (reference
         multi_sgd_mom_update, src/operator/optimizer_op.cc): collapses N
         eager dispatches per step into one XLA program. Only the plain
-        dense-SGD case qualifies; anything else falls back per-param."""
+        dense-SGD case qualifies; anything else falls back per-param
+        (False; else the number of parameters updated)."""
         from .. import optimizer as opt_mod
         from ..ndarray import sparse as _sp
         from ..ndarray import ops as _ops
@@ -505,7 +516,7 @@ class Trainer:
             idxs.append(i)
             arrays.append((param, param.data(), param.grad()))
         if not arrays:
-            return True
+            return 0
         # phase 2: commit — counters/lr/wd evaluated once per param
         lrs, wds = [], []
         for i in idxs:
@@ -532,13 +543,22 @@ class Trainer:
                 clip_gradient=opt.clip_gradient)
         for param, _, _ in arrays:
             param._data._grad_fresh = False
-        return True
+        return len(arrays)
 
-    def _update(self, ignore_stale_grad=False):
-        if self._fused_jit_update(ignore_stale_grad):
-            return
-        if self._fused_group_update(ignore_stale_grad):
-            return
+    def _update(self, ignore_stale_grad=False, sp=None):
+        """One of three paths, named on the ``gluon.update`` span ``sp``
+        with the jitted calls it dispatched (``programs``: one for a
+        fused path however many parameters, one optimizer call per
+        parameter on the eager path, each of them several un-jitted
+        operations) and the parameters it updated."""
+        for path, fused in (("fused_jit", self._fused_jit_update),
+                            ("fused_group", self._fused_group_update)):
+            done = fused(ignore_stale_grad)
+            if done is not False:
+                _trace.annotate(sp, path=path, programs=min(done, 1),
+                                params=done)
+                return
+        updated = 0
         for i, param in enumerate(self._params):
             if param.grad_req == "null" or param._data is None:
                 continue
@@ -555,8 +575,10 @@ class Trainer:
             self._optimizer.update_multi_precision(
                 i, param.data(), param.grad(), self._states[i])
             param._data._grad_fresh = False
+            updated += 1
             if param.grad_req == "add":
                 param.zero_grad()
+        _trace.annotate(sp, path="eager", programs=updated, params=updated)
 
     # -- checkpoint protocol (mx.checkpoint.CheckpointManager) ----------
     def _counters(self):

@@ -120,11 +120,6 @@ class PipelineStats:
         return out
 
 
-def _profiler_span(name, t0, t1):
-    from .. import profiler
-    profiler.record_span(name, t0, t1)
-
-
 # ---------------------------------------------------------------------------
 # DevicePrefetcher
 # ---------------------------------------------------------------------------
@@ -339,11 +334,8 @@ class DevicePrefetcher:
             t2 = time.perf_counter()
             self.stats.add("decode", t1 - t0)
             self.stats.add("h2d", t2 - t1, nbytes)
-            _profiler_span("pipeline:decode", t0, t1)
-            _profiler_span("pipeline:h2d", t1, t2)
-            if _tracing.enabled():
-                _tracing.record("io.decode", t0, t1)
-                _tracing.record("io.h2d", t1, t2, bytes=nbytes)
+            _tracing.record("io.decode", t0, t1)
+            _tracing.record("io.h2d", t1, t2, bytes=nbytes)
             if not self._enqueue((dev_item,)):
                 return
 
@@ -377,9 +369,7 @@ class DevicePrefetcher:
                 f"(worker stalled or source hung)")
         t_got = time.perf_counter()
         self.stats.add("stall", t_got - now)
-        _profiler_span("pipeline:stall", now, t_got)
-        if _tracing.enabled():
-            _tracing.record("io.wait", now, t_got)
+        _tracing.record("io.wait", now, t_got)
         if _telem.enabled():
             # read-ahead occupancy AFTER this get: depth batches queued
             # = the worker is fully ahead; 0 = the consumer is about to
@@ -622,7 +612,7 @@ class AsyncDecodeIter:
             raise
         t1 = time.perf_counter()
         self.stats.add("decode", t1 - t0)
-        _profiler_span("pipeline:decode-wait", t0, t1)
+        _tracing.record("io.decode_wait", t0, t1)
         self._fill()       # keep the pool primed while consumer computes
         return results
 

@@ -22,6 +22,7 @@ axis. Two gradient-sync pipelines exist:
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as _np
@@ -38,7 +39,6 @@ from .. import _tape
 from .. import telemetry as _telem
 from ..telemetry import tracing as _trace
 from ..telemetry import watchdog as _watchdog
-from ..telemetry import costmodel as _costmodel
 from ..gluon.parameter import _bind_params
 from jax import shard_map
 from .mesh import (current_mesh, make_mesh, mesh_scope, MeshConfig,
@@ -55,6 +55,17 @@ __all__ = ["DataParallelTrainer", "all_reduce_gradients"]
 from ..optimizer.optimizer import fused_rule, _FUSED_KERNELS
 
 _RULES = _FUSED_KERNELS  # names the fused path accepts
+
+
+def _step_span(step):
+    """A step entry point under its scoped ``train.step`` root span
+    (ambient, and a ``TraceAnnotation`` in any profile that runs); the
+    body commits the phases as its children."""
+    @functools.wraps(step)
+    def traced(self, *args, **kwargs):
+        with _trace.span("train.step"):
+            return step(self, *args, **kwargs)
+    return traced
 
 
 class DataParallelTrainer:
@@ -153,12 +164,7 @@ class DataParallelTrainer:
         self._jit_zero1_cache = {}
         self._num_update = 0
         self._donate = donate
-        # live MFU accounting (ISSUE 14): per-compiled-step XLA FLOP
-        # cost, computed at most once per jitted object and only when
-        # the chip peak is known (costmodel.live_cost_enabled)
-        self._live_cost = {}         # id(jitted) -> (jitted, flops)
-        self._last_step_flops = None
-        self._live_peak = ()         # () = not yet resolved
+        self._last_entry = None      # clock at the last step's entry
         # memory honesty (ISSUE 15): exact byte gauges for the flight
         # recorder's memory block, published once per build
         self._mem_gauges_stale = True
@@ -262,8 +268,11 @@ class DataParallelTrainer:
             prev = _tape.set_training(True)
             binding = {p: NDArray(v) for p, v in zip(params, pv)}
             try:
+                # train.loss names the forward's operations in a profile;
+                # the backward's carry transpose(jvp(train.loss))
                 with _tape.trace_scope(), _bind_params(binding), \
-                        _rnd.trace_key_scope(key), mesh_scope(ambient):
+                        _rnd.trace_key_scope(key), mesh_scope(ambient), \
+                        jax.named_scope("train.loss"):
                     out = block.forward(*[NDArray(b) for b in inputs])
                     loss = loss_fn(out, NDArray(label))
             finally:
@@ -278,10 +287,11 @@ class DataParallelTrainer:
         its own single source, :meth:`_zero1_sync_update`."""
         rule_apply = self._rule_apply
         new_params, new_state = [], []
-        for p, g, s in zip(param_vals, grads, opt_state):
-            np_, ns = rule_apply(p, g.astype(p.dtype), s, lr)
-            new_params.append(np_)
-            new_state.append(ns)
+        with jax.named_scope("train.update"):
+            for p, g, s in zip(param_vals, grads, opt_state):
+                np_, ns = rule_apply(p, g.astype(p.dtype), s, lr)
+                new_params.append(np_)
+                new_state.append(ns)
         return new_params, new_state
 
     def _step_body(self):
@@ -406,6 +416,7 @@ class DataParallelTrainer:
             return jnp.moveaxis(b, bax, 0)
         return split_micro
 
+    @_step_span
     def step_accum(self, *batch, n_micro):
         """One fused update from ``n_micro`` microbatches: batch arrays
         carry n_micro * B elements on ``batch_axis`` and are consumed
@@ -415,9 +426,8 @@ class DataParallelTrainer:
             raise MXNetError("step_accum: n_micro must be >= 1")
         if self._pp_active():
             return self._pp_step(batch, n_micro=n_micro)
-        t_step = _telem.clock() if _telem.enabled() else None
+        t_step = self._step_entry()
         trc = _trace.enabled()
-        tt0 = _trace.clock() if trc else None
         inputs = [b.data if isinstance(b, NDArray) else jnp.asarray(b)
                   for b in batch]
         bax = self._eff_bax(inputs[-1].ndim, is_label=True)
@@ -469,7 +479,7 @@ class DataParallelTrainer:
             p._data._set_data(v)
         self._record_step(1, t_step)
         if trc:
-            self._trace_step_phases(tt0, tt1, tt2, tt3)
+            self._trace_step_phases(tt1, tt2, tt3)
         return NDArray(loss)
 
     def _build_indexed(self):
@@ -610,17 +620,22 @@ class DataParallelTrainer:
             if comm_mode == "none":
                 gshard = lax.dynamic_slice(gflat, (idx * ls,), (ls,))
             else:
-                gshard = _zero.reduce_scatter_bucket(
-                    gflat, jax.random.fold_in(key, b), dp, mode)
+                with jax.named_scope("train.allreduce"):
+                    gshard = _zero.reduce_scatter_bucket(
+                        gflat, jax.random.fold_in(key, b), dp, mode)
             prev_shard = gshard
             pshard = lax.dynamic_slice(pflats[b], (idx * ls,), (ls,))
             # flat 1/N shard update: ONE fused kernel walks the bucket
             # (Pallas on TPU, the identical fused_rule chain elsewhere)
-            np_, ns = self._bucket_apply(pshard, gshard, opt_local[b], lr)
+            with jax.named_scope("train.update"):
+                np_, ns = self._bucket_apply(pshard, gshard, opt_local[b],
+                                             lr)
             if comm_mode == "none":
                 new_pflats.append(jnp.tile(np_, dp))
             else:
-                new_pflats.append(lax.all_gather(np_, AXIS_DP, tiled=True))
+                with jax.named_scope("train.allreduce"):
+                    new_pflats.append(
+                        lax.all_gather(np_, AXIS_DP, tiled=True))
             new_state.append(ns)
         return plan.unflatten(new_pflats, param_vals), new_state
 
@@ -746,7 +761,7 @@ class DataParallelTrainer:
         microbatches through the 1F1B schedule.  Loss semantics match
         the flat step: the mean of equal-size microbatch means IS the
         full-batch mean."""
-        t_step = _telem.clock() if _telem.enabled() else None
+        t_step = self._step_entry()
         if self.batch_axis != 0 or self._label_bax != 0:
             raise MXNetError(
                 "pipeline parallelism supports batch_axis=0 only")
@@ -767,11 +782,11 @@ class DataParallelTrainer:
         self._record_step(1, t_step)
         if trc:
             # host-driven 1F1B: the stage executor owns the inner
-            # schedule, so the step is one dispatch-phase span
-            root = _trace.record("train.step", tt0, _trace.clock(),
-                                 step=self._num_update, pp=True)
-            _trace.record("train.phase.dispatch", tt0, root.t1,
-                          parent=root)
+            # schedule, so the step is one dispatch-phase child of the
+            # ambient train.step root
+            _trace.annotate(_trace.current(), step=self._num_update,
+                            pp=True)
+            _trace.record("train.phase.dispatch", tt0, _trace.clock())
         return NDArray(loss)
 
     # -- telemetry (ISSUE 9) --------------------------------------------
@@ -782,12 +797,6 @@ class DataParallelTrainer:
         profiler/XLA trace, not here).  An unhandled dispatch exception
         dumps the flight recorder before re-raising."""
         t0 = _telem.clock() if _telem.enabled() else None
-        if t0 is not None:
-            # live MFU (ISSUE 14): resolve this compiled step's XLA FLOP
-            # cost BEFORE dispatch (the args are donated by the call) —
-            # at most once per jitted object, and only when the chip
-            # peak is known (never on a plain CPU host)
-            self._maybe_live_cost(jitted, args)
         try:
             out = jitted(*args)
         except Exception as e:  # noqa: BLE001 — record, then re-raise
@@ -804,25 +813,25 @@ class DataParallelTrainer:
                            (_telem.clock() - t0) * 1e3)
         return out
 
-    def _maybe_live_cost(self, jitted, args):
-        """Cache the compiled step's XLA FLOP estimate (once per jitted
-        — the dict keeps the jitted alive so ids can't be reused) and
-        remember it as the cost of the step being dispatched."""
-        key = id(jitted)
-        hit = self._live_cost.get(key)
-        if hit is None:
-            flops = (_costmodel.compiled_flops(jitted, *args)
-                     if _costmodel.live_cost_enabled() else None)
-            hit = (jitted, flops)
-            self._live_cost[key] = hit
-        self._last_step_flops = hit[1]
+    def _step_entry(self):
+        """The telemetry clock at a step's entry (None when telemetry is
+        off), after observing ``train.step_interval_ms``: the time since
+        the previous entry, which in a device-bound loop is the step
+        time, whereas ``train.step_ms`` is the host's time inside the
+        call."""
+        if not _telem.enabled():
+            return None
+        t = _telem.clock()
+        if self._last_entry is not None:
+            _telem.observe("train.step_interval_ms",
+                           (t - self._last_entry) * 1e3)
+        self._last_entry = t
+        return t
 
     def _record_step(self, k, t_step0):
         """Publish per-step metrics after ``k`` steps committed; the
         ambient telemetry step context feeds event records and profiler
-        span tags.  When the compiled step's FLOP cost is known, the
-        live ``train.mfu`` / ``train.tflops_delivered`` gauges are O(1)
-        arithmetic on top; the health watchdog ticks at the same seam."""
+        span tags; the health watchdog ticks at the same seam."""
         if t_step0 is None:
             return
         dt_s = _telem.clock() - t_step0
@@ -830,16 +839,6 @@ class DataParallelTrainer:
         _telem.inc("train.steps", k)
         _telem.observe("train.step_ms", dt_s * 1e3 / max(k, 1))
         _telem.set_gauge("train.num_update", self._num_update)
-        flops = self._last_step_flops
-        if flops and dt_s > 0:
-            if self._live_peak == ():
-                self._live_peak = _costmodel.chip_peak_flops()
-            _telem.set_gauge("train.step_flops", flops / max(k, 1))
-            _telem.set_gauge("train.tflops_delivered",
-                             round(flops / dt_s / 1e12, 4))
-            if self._live_peak:
-                _telem.set_gauge("train.mfu",
-                                 round(flops / dt_s / self._live_peak, 4))
         _watchdog.on_step(self._num_update,
                           step_ms=dt_s * 1e3 / max(k, 1))
         if self._mem_gauges_stale:
@@ -874,18 +873,18 @@ class DataParallelTrainer:
         except Exception:  # noqa: BLE001 — observability never takes
             pass           # a training step down
 
-    def _trace_step_phases(self, t0, t1, t2, t3):
-        """Commit the per-step phase span tree (ISSUE 14): one
-        ``train.step`` root whose children tile it exactly —
+    def _trace_step_phases(self, t1, t2, t3):
+        """Commit the four pre-timed children that tile the ambient
+        ``train.step`` root (:func:`_step_span`) from its start to now —
         prepare (param collect / plan / device state), h2d (batch
         placement), dispatch (the compiled call), commit (host-side
         param bookkeeping + metric publication)."""
-        t4 = _trace.clock()
-        root = _trace.record("train.step", t0, t4, step=self._num_update)
-        _trace.record("train.phase.prepare", t0, t1, parent=root)
-        _trace.record("train.phase.h2d", t1, t2, parent=root)
-        _trace.record("train.phase.dispatch", t2, t3, parent=root)
-        _trace.record("train.phase.commit", t3, t4, parent=root)
+        root = _trace.current()
+        _trace.annotate(root, step=self._num_update)
+        _trace.record("train.phase.prepare", root.t0, t1)
+        _trace.record("train.phase.h2d", t1, t2)
+        _trace.record("train.phase.dispatch", t2, t3)
+        _trace.record("train.phase.commit", t3, _trace.clock())
 
     # -- public API -----------------------------------------------------
     @property
@@ -897,14 +896,14 @@ class DataParallelTrainer:
     def set_learning_rate(self, lr):
         self._lr = lr
 
+    @_step_span
     def step(self, *batch):
         """batch = (*inputs, label) NDArrays. Returns the scalar loss
         NDArray."""
         if self._pp_active():
             return self._pp_step(batch)
-        t_step = _telem.clock() if _telem.enabled() else None
+        t_step = self._step_entry()
         trc = _trace.enabled()
-        tt0 = _trace.clock() if trc else None
         inputs = [b.data if isinstance(b, NDArray) else jnp.asarray(b)
                   for b in batch]
         params = self._collect(*[NDArray(b) for b in inputs[:-1]])
@@ -934,9 +933,10 @@ class DataParallelTrainer:
             p._data._set_data(v)
         self._record_step(1, t_step)
         if trc:
-            self._trace_step_phases(tt0, tt1, tt2, tt3)
+            self._trace_step_phases(tt1, tt2, tt3)
         return NDArray(loss)
 
+    @_step_span
     def step_multi(self, batches, n_micro=1):
         """K training steps in ONE compiled dispatch (ISSUE 6 tentpole).
 
@@ -956,9 +956,8 @@ class DataParallelTrainer:
         default) keeps K-aware loops (estimator/bench) on the per-step
         entry points, restoring today's graphs exactly.
         """
-        t_step = _telem.clock() if _telem.enabled() else None
+        t_step = self._step_entry()
         trc = _trace.enabled()
-        tt0 = _trace.clock() if trc else None
         batches = list(batches)
         k = len(batches)
         if k < 1:
@@ -1030,7 +1029,7 @@ class DataParallelTrainer:
             p._data._set_data(v)
         self._record_step(k, t_step)
         if trc:
-            self._trace_step_phases(tt0, tt1, tt2, tt3)
+            self._trace_step_phases(tt1, tt2, tt3)
         return NDArray(losses)
 
     def put_epoch(self, superdata, superlabel):
@@ -1121,7 +1120,7 @@ class DataParallelTrainer:
     def step_indexed(self, epoch_handle, i):
         """One fused train step on batch ``i`` of a resident epoch
         (see :meth:`put_epoch`)."""
-        t_step = _telem.clock() if _telem.enabled() else None
+        t_step = self._step_entry()
         superdata, superlabel = epoch_handle[0], epoch_handle[1]
         if self._param_objs is None:
             # probe batch only for deferred-shape resolution on first call
@@ -1422,7 +1421,6 @@ class DataParallelTrainer:
         state is untouched.  Zeros when the sharded pipeline is off
         (CPU / dp=1 / kill switch)."""
         import time
-        from .. import profiler
         # None = NOT measured (pipeline off) — a 0.0 here would read as
         # "measured: comm is free", which the r04/r05 CPU-fallback rounds
         # showed gets mistaken for evidence
@@ -1467,7 +1465,7 @@ class DataParallelTrainer:
         finally:
             for arr, raw in snapshot:
                 arr._data = raw
-        profiler.record_span("overlap.probe", t_all0, time.perf_counter())
+        _trace.record("overlap.probe", t_all0, time.perf_counter())
         comp = out["compute_only_step_ms"]
         exposed = max(0.0, out["overlapped_step_ms"] - comp)
         serial = max(exposed, out["monolithic_step_ms"] - comp)
@@ -1554,7 +1552,6 @@ class DataParallelTrainer:
         per-step collectives (bucketed RS + param AG) — the measured
         ``collective_ms`` evidence for the comm block."""
         import time
-        from .. import profiler
         plan = self._plan
         dp = self.mesh.shape[AXIS_DP]
         mode = self._comm_dtype
@@ -1579,7 +1576,7 @@ class DataParallelTrainer:
             out = f(flats, key)
         jax.block_until_ready(out)
         t1 = time.perf_counter()
-        profiler.record_span("comm.collectives", t0, t1)
+        _trace.record("comm.collectives", t0, t1)
         return (t1 - t0) / iters * 1e3
 
 

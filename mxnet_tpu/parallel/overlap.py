@@ -235,11 +235,8 @@ class OverlapScheduler:
 
 
 def _span(name, t0, t1):
-    from .. import profiler
+    # the comm spans nest under whatever step/backward span is ambient
+    # on the dispatching thread (ISSUE 14): bucket launches that fire
+    # during backward show up INSIDE the step timeline
     from ..telemetry import tracing
-    profiler.record_span(name, t0, t1)
-    if tracing.enabled():
-        # the comm spans nest under whatever step/backward span is
-        # ambient on the dispatching thread (ISSUE 14): bucket launches
-        # that fire during backward show up INSIDE the step timeline
-        tracing.record(name, t0, t1)
+    tracing.record(name, t0, t1)

@@ -15,7 +15,7 @@ import warnings
 
 __all__ = ["set_config", "set_state", "start", "stop", "pause", "resume",
            "dump", "dumps", "reset", "Task", "Frame", "Counter", "Marker",
-           "Domain", "scope", "record_span"]
+           "Domain", "scope"]
 
 _CONFIG = {"filename": "profile.json", "profile_all": False,
            "aggregate_stats": False}
@@ -125,22 +125,6 @@ def _emit(name, ph, **extra):
     if not extra:
         extra = _span_context()
     _STATE["events"].append((name, ph, time.time(), extra))
-
-
-def record_span(name, t0, t1):
-    """Record an already-completed [t0, t1] span (perf_counter or epoch
-    seconds) when a profile is running; no-op otherwise.
-
-    Used by the input-pipeline stages (``io.DevicePrefetcher`` /
-    ``io.AsyncDecodeIter`` worker threads) so decode/H2D/stall show up
-    in ``dumps()`` next to the step — list.append is atomic under the
-    GIL, so cross-thread emission needs no lock.  Spans are tagged with
-    the current telemetry step/epoch for trace correlation."""
-    if not _STATE["running"]:
-        return
-    extra = _span_context()
-    _STATE["events"].append((name, "B", t0, extra))
-    _STATE["events"].append((name, "E", t1, extra))
 
 
 class Domain:

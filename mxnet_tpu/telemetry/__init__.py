@@ -23,7 +23,7 @@ now all publish to:
 Exposure, three ways: :func:`snapshot` (the API), a Prometheus-style
 text dump (:func:`prom_text`, ``tools/telemetry_dump.py``, and the PS
 server's ``_OP_TELEMETRY`` RPC for live pod scraping), and perfetto
-correlation — ``profiler.record_span`` tags spans with the current
+correlation — ``mx.profiler``'s scoped events carry the current
 step/epoch from :func:`context`.
 
 Zero overhead when ``MXTPU_TELEMETRY=0``: every helper below is a single
@@ -76,6 +76,7 @@ _EVENTS = EventLog(ring_size=_env_ring(),
                    path=os.environ.get("MXTPU_EVENT_LOG") or None,
                    now=time.time)
 _FLIGHT = FlightRecorder(_REGISTRY, _EVENTS)
+tracing.install_compile_listener()     # jit.compile spans, jit.compiles
 
 
 def configure(enabled=None, ring_size=None, event_log=None, now=None):
@@ -174,9 +175,8 @@ def value(name):
 
 def set_context(step=None, epoch=None):
     """Update the ambient (step, membership-epoch) every event record —
-    and every ``profiler.record_span`` while a profile runs — is stamped
-    with.  The trainer sets ``step``; the elastic layer sets
-    ``epoch``."""
+    and every ``mx.profiler`` Task/Frame event — is stamped with.  The
+    trainer sets ``step``; the elastic layer sets ``epoch``."""
     if not _ENABLED:
         return
     _EVENTS.set_context(step=step, epoch=epoch)

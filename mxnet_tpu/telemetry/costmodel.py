@@ -1,18 +1,11 @@
-"""Shared FLOP/MFU accounting: the one cost model bench.py AND the live
-trainer gauges read (ISSUE 14).
+"""FLOP/MFU accounting for ``bench.py`` and ``chip_smoke.py``: the chip
+peak table, XLA's cost analysis of a compiled step, the analytic 2*MAC
+counts, and :func:`attach_mfu`, which stamps a bench payload with them.
 
-bench.py computed MFU offline only — chip peak table, XLA cost
-analysis, analytic 2*MAC fallbacks all private to the script — so a
-running job could never see its own delivered FLOP/s.  This module is
-those helpers lifted verbatim (bench.py now imports them; its output
-for the same inputs is byte-identical — gated in test_bench_line.py),
-plus the LIVE half: :func:`live_cost_enabled` decides once whether the
-trainer should pay the one-per-compile ``cost_analysis`` (only when
-the chip peak is actually known — a real TPU device kind or the
-``MXTPU_CHIP_PEAK_TFLOPS`` override; a CPU run stamps nothing rather
-than a fake number, the PR 6 honesty rule), and the trainer then
-publishes ``train.mfu`` / ``train.tflops_delivered`` /
-``train.step_flops`` gauges at O(1) arithmetic per step.
+Nothing on the trainer's path reads this module: a running job's
+utilization is measured by the benchmark from a device trace
+(``device.mfu_pct``, benchmark/readers/device.py), and the trainer
+publishes ``train.step_interval_ms`` for the step time.
 """
 from __future__ import annotations
 
@@ -20,7 +13,7 @@ import os
 
 __all__ = ["PEAK_BF16", "chip_peak_flops", "compiled_flops",
            "resnet_train_flops_per_img", "bert_train_flops_per_sample",
-           "attach_mfu", "live_cost_enabled"]
+           "attach_mfu"]
 
 #: Advertised per-chip bf16 peak FLOP/s by device_kind substring (google
 #: cloud TPU docs); lowercase match, first hit wins.
@@ -36,8 +29,7 @@ PEAK_BF16 = [
 
 def _env_peak():
     """``MXTPU_CHIP_PEAK_TFLOPS`` override (TFLOP/s): unknown device
-    kinds, and the CPU-hosted live-MFU parity gate, set the peak
-    explicitly.  None when unset/unparseable."""
+    kinds set the peak explicitly.  None when unset/unparseable."""
     raw = os.environ.get("MXTPU_CHIP_PEAK_TFLOPS", "").strip()
     if not raw:
         return None
@@ -118,18 +110,3 @@ def attach_mfu(result, flops_per_sample, samples_per_sec, jitted=None,
             flops_per_step / batch * samples_per_sec / peak, 4)
         result["chip_peak_tflops_bf16"] = peak / 1e12
     return result
-
-
-def live_cost_enabled():
-    """Whether the trainer should pay the once-per-compile cost
-    analysis for live MFU gauges: only when the peak is KNOWN (real
-    TPU device kind, or the env override) — on a plain CPU host the
-    answer is no, the gauges stay unset (null-when-unmeasured), and no
-    extra compile is ever paid."""
-    if _env_peak() is not None:
-        return True
-    try:
-        import jax
-        return chip_peak_flops(jax.devices()[0]) is not None
-    except Exception:  # noqa: BLE001 — no backend yet: no live cost
-        return False

@@ -13,7 +13,10 @@ gap or a p99 tail you cannot decompose.  This module is that timeline:
   two identical runs produce identical trees, the twin-request gate in
   tests/test_tracing.py), a ``trace`` id (the root span's id), a
   ``parent`` id, ``[t0, t1]`` stamps from an injectable clock, and
-  JSON-able ``args``;
+  JSON-able ``args``; beside them ``t0_ns`` / ``t1_ns``, the same two
+  moments on the clock the JAX profiler stamps host and device events
+  with (``time.time_ns``), read at the span and not derived from one
+  offset per process, so a reader can lay a span over a device trace;
 - **ambient context** per thread (:func:`span` nests automatically)
   with EXPLICIT cross-thread propagation — :func:`capture` on the
   owning thread, :func:`activate` on the worker (``DevicePrefetcher``,
@@ -24,9 +27,15 @@ gap or a p99 tail you cannot decompose.  This module is that timeline:
   for lifecycles that cross call boundaries — a serving request's root
   span lives on the ``Request`` object from admission to finish,
   surviving a drain-and-requeue hop across replicas;
+- **profiler annotations**: a scoped :func:`span` also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so a profile anyone
+  takes shows the program's spans beside the device lines;
+- **compiles** (:func:`install_compile_listener`): every backend compile
+  JAX reports becomes a pre-timed ``jit.compile`` child of whatever span
+  is ambient, and bumps the counter ``jit.compiles``;
 - **Chrome-trace/perfetto export** (:func:`chrome_trace`): finished
-  spans as complete ``"X"`` events merged with the existing
-  ``profiler.record_span`` B/E stream — one timeline for both
+  spans as complete ``"X"`` events merged with ``mx.profiler``'s
+  Task/Frame/Counter/Marker events — one timeline for both
   (``tools/telemetry_dump.py --trace out.json``).
 
 ``MXTPU_TRACE=0`` is a bitwise-inert kill switch in the PR 9 style:
@@ -42,12 +51,16 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from ..lint import racecheck as _racecheck
 
 __all__ = ["Span", "enabled", "configure", "configure_from_env",
            "reset", "clock", "span", "start", "finish", "record",
-           "current", "capture", "activate", "spans", "dropped",
-           "chrome_trace"]
+           "annotate", "current", "capture", "activate", "spans",
+           "dropped", "chrome_trace", "install_compile_listener"]
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _env_enabled():
@@ -67,23 +80,24 @@ class Span:
     span is open (open spans never export)."""
 
     __slots__ = ("name", "trace", "span", "parent", "t0", "t1",
-                 "thread", "args")
+                 "t0_ns", "t1_ns", "thread", "args")
 
-    def __init__(self, name, trace, span_id, parent, t0, args):
+    def __init__(self, name, trace, span_id, parent, stamp, args):
         self.name = name
         self.trace = trace
         self.span = span_id
         self.parent = parent
-        self.t0 = t0
-        self.t1 = None
+        self.t0, self.t0_ns = stamp
+        self.t1 = self.t1_ns = None
         self.thread = threading.current_thread().name
         self.args = args
 
     def to_record(self):
         return {"name": self.name, "trace": self.trace,
                 "span": self.span, "parent": self.parent,
-                "t0": self.t0, "t1": self.t1, "thread": self.thread,
-                "args": dict(self.args)}
+                "t0": self.t0, "t1": self.t1,
+                "t0_ns": self.t0_ns, "t1_ns": self.t1_ns,
+                "thread": self.thread, "args": dict(self.args)}
 
 
 class _NullSpan:
@@ -91,7 +105,7 @@ class _NullSpan:
     no-op, usable as a context manager and as a ``parent=``."""
 
     __slots__ = ()
-    name = trace = span = parent = t0 = t1 = None
+    name = trace = span = parent = t0 = t1 = t0_ns = t1_ns = None
     args = {}
 
     def __enter__(self):
@@ -111,6 +125,9 @@ class Tracer:
     def __init__(self, ring_size=4096, now=None):
         self.ring_size = int(ring_size)
         self._now = now if now is not None else time.perf_counter
+        # an injected clock (FakeClock) also gives the ns stamps, so twin
+        # runs stay identical; the real one reads both clocks at the span
+        self._injected = now is not None
         self._lock = _racecheck.make_lock("telemetry.Tracer._lock")
         self._ring = deque(maxlen=self.ring_size)   # guarded-by: _lock
         self._next_id = 0                           # guarded-by: _lock
@@ -133,6 +150,22 @@ class Tracer:
         st = self._stack()
         return st[-1] if st else None
 
+    # -- the two clocks --------------------------------------------------
+    def _stamp(self):
+        """``(seconds on the span clock, ns on the profiler's clock)``,
+        read back to back."""
+        t = self._now()
+        return t, (int(round(t * 1e9)) if self._injected
+                   else time.time_ns())
+
+    def _to_ns(self, t0, t1):
+        """A pre-timed ``[t0, t1]`` on the profiler's clock, through an
+        offset between the two clocks sampled now (they drift, so never
+        one offset per process)."""
+        off = 0 if self._injected \
+            else time.time_ns() - int(round(self._now() * 1e9))
+        return int(round(t0 * 1e9)) + off, int(round(t1 * 1e9)) + off
+
     # -- span lifecycle --------------------------------------------------
     def start(self, name, parent=None, **args):
         """Open a span (NOT pushed as ambient — the manual API for
@@ -142,8 +175,8 @@ class Tracer:
             parent = self.current()
         sid = self._new_id()
         if parent is None or parent is NULL_SPAN:
-            return Span(name, sid, sid, None, self._now(), args)
-        return Span(name, parent.trace, sid, parent.span, self._now(),
+            return Span(name, sid, sid, None, self._stamp(), args)
+        return Span(name, parent.trace, sid, parent.span, self._stamp(),
                     args)
 
     def finish(self, sp, **args):
@@ -151,7 +184,7 @@ class Tracer:
         the null span and on already-finished spans."""
         if sp is None or sp is NULL_SPAN or sp.t1 is not None:
             return sp
-        sp.t1 = self._now()
+        sp.t1, sp.t1_ns = self._stamp()
         if args:
             sp.args.update(args)
         self._commit(sp.to_record())
@@ -171,17 +204,21 @@ class Tracer:
             from . import inc       # outside _lock; one counter bump
             inc("telemetry.trace.dropped_spans")
 
-    def record(self, name, t0, t1, parent=None, **args):
+    def record(self, name, t0, t1, parent=None, ns=None, **args):
         """Commit an already-timed ``[t0, t1]`` span in one call (the
-        pre-timed form: decode boundaries, prefetcher stage times)."""
+        pre-timed form: decode boundaries, prefetcher stage times).
+        ``ns`` is the caller's own ``(t0_ns, t1_ns)`` pair where it
+        stamped both clocks; without it the pair is converted."""
         if parent is None:
             parent = self.current()
+        t0_ns, t1_ns = ns if ns is not None else self._to_ns(t0, t1)
         sid = self._new_id()
         if parent is None or parent is NULL_SPAN:
-            sp = Span(name, sid, sid, None, t0, args)
+            sp = Span(name, sid, sid, None, (t0, t0_ns), args)
         else:
-            sp = Span(name, parent.trace, sid, parent.span, t0, args)
-        sp.t1 = t1
+            sp = Span(name, parent.trace, sid, parent.span, (t0, t0_ns),
+                      args)
+        sp.t1, sp.t1_ns = t1, t1_ns
         self._commit(sp.to_record())
         return sp
 
@@ -225,10 +262,11 @@ def configure(enabled=None, ring_size=None, now=None):
     if enabled is not None:
         _ENABLED = bool(enabled)
     if ring_size is not None or now is not None:
+        if now is None and _TRACER._injected:
+            now = _TRACER._now
         _TRACER = Tracer(
             ring_size=ring_size if ring_size is not None
-            else _TRACER.ring_size,
-            now=now if now is not None else _TRACER._now)
+            else _TRACER.ring_size, now=now)
     return _ENABLED
 
 
@@ -250,20 +288,24 @@ def clock():
 
 class _Scope:
     """The ambient context-manager span: child of the current ambient
-    span, itself ambient for the scope's duration."""
+    span, itself ambient for the scope's duration, and for that time a
+    ``TraceAnnotation`` of the same name in any profile that runs."""
 
-    __slots__ = ("_sp",)
+    __slots__ = ("_sp", "_annotation")
 
     def __init__(self, name, args):
+        self._annotation = TraceAnnotation(name)
         self._sp = _TRACER.start(name, **args)
 
     def __enter__(self):
+        self._annotation.__enter__()
         _TRACER.push(self._sp)
         return self._sp
 
     def __exit__(self, *exc):
         _TRACER.pop(self._sp)
         _TRACER.finish(self._sp)
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -289,11 +331,18 @@ def finish(sp, **args):
     return _TRACER.finish(sp, **args)
 
 
-def record(name, t0, t1, parent=None, **args):
+def record(name, t0, t1, parent=None, ns=None, **args):
     """Commit a pre-timed span (no-op when disabled)."""
     if not _ENABLED:
         return NULL_SPAN
-    return _TRACER.record(name, t0, t1, parent=parent, **args)
+    return _TRACER.record(name, t0, t1, parent=parent, ns=ns, **args)
+
+
+def annotate(sp, **args):
+    """Add ``args`` to an open span; nothing on the null span, which is
+    what a disabled :func:`span` hands out."""
+    if sp is not None and sp is not NULL_SPAN:
+        sp.args.update(args)
 
 
 def current():
@@ -364,6 +413,36 @@ def reset():
     _TRACER = Tracer(ring_size=_env_ring())
 
 
+# -- compiles -----------------------------------------------------------
+
+def _on_duration(event, secs, **_kw):
+    """Which step compiled, and inside what: each backend compile JAX
+    reports lands as a pre-timed ``jit.compile`` child of the span that
+    is ambient on the compiling thread (a root when none is)."""
+    if event != COMPILE_EVENT:
+        return
+    from . import inc
+    inc("jit.compiles")
+    if _ENABLED:
+        t1, t1_ns = _TRACER._stamp()
+        _TRACER.record("jit.compile", t1 - secs, t1,
+                       ns=(t1_ns - int(round(secs * 1e9)), t1_ns))
+
+
+_LISTENING = False
+
+
+def install_compile_listener():
+    """Register the one ``jax.monitoring`` listener behind
+    ``jit.compile`` (``telemetry`` does, on import; JAX keeps listeners
+    for the life of the process, so once)."""
+    global _LISTENING
+    if not _LISTENING:
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        _LISTENING = True
+
+
 # -- export -------------------------------------------------------------
 
 def _span_event(r, pid, tid):
@@ -421,9 +500,9 @@ def chrome_trace(include_profiler=True, fleet=None):
     """The merged Chrome-trace JSON object: every finished tracing span
     as a complete ``"X"`` event (ts/dur in microseconds, ``args``
     carrying trace/span/parent ids for perfetto correlation) plus —
-    when ``include_profiler`` — the ``profiler.record_span`` B/E event
-    stream, so XLA-adjacent pipeline spans and causal request/step
-    spans land on ONE timeline.  With ``fleet`` (a
+    when ``include_profiler`` — ``mx.profiler``'s Task / Frame /
+    Counter / Marker events, so the user's own annotations and the
+    causal request/step spans land on ONE timeline.  With ``fleet`` (a
     :meth:`~.fleet.FleetCollector.collect` snapshot) the export is the
     STITCHED multi-worker timeline instead: one process lane per rank,
     clock offsets disclosed, never applied.  ``otherData`` stamps the

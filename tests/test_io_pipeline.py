@@ -453,8 +453,12 @@ def test_pipeline_end_to_end_trains(tmp_path, monkeypatch):
     assert _wait_threads_gone()
 
 
-def test_profiler_records_pipeline_spans(tmp_path):
+def test_pipeline_spans_go_to_the_one_span_stream(tmp_path):
+    """The stages write to telemetry.tracing only: a running profile's
+    own B/E list (``profiler.dumps()``) no longer holds a second copy
+    of the same intervals under another name."""
     from mxnet_tpu import profiler
+    from mxnet_tpu.telemetry import tracing
     profiler.set_config(filename=str(tmp_path / "p.json"))
     profiler.start()
     try:
@@ -463,9 +467,11 @@ def test_profiler_records_pipeline_spans(tmp_path):
         list(pf)
     finally:
         profiler.stop()
+    names = [s["name"] for s in tracing.spans()]
+    assert names.count("io.decode") == 3 and names.count("io.h2d") == 3
+    assert "io.wait" in names
     table = profiler.dumps(reset=True)
-    assert "pipeline:decode" in table
-    assert "pipeline:h2d" in table
+    assert "pipeline:" not in table and "io.decode" not in table
 
 
 def test_ndarray_iter_shuffle_cursor_restores_standalone():

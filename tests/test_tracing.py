@@ -159,7 +159,8 @@ def test_train_step_phase_spans_tile_the_step():
     phases = ("train.phase.prepare", "train.phase.h2d",
               "train.phase.dispatch", "train.phase.commit")
     for root in roots:
-        kids = [s for s in spans if s["parent"] == root["span"]]
+        kids = [s for s in spans if s["parent"] == root["span"]
+                and s["name"] != "jit.compile"]    # the first step's
         assert [k["name"] for k in kids] == list(phases)
         wall = root["t1"] - root["t0"]
         covered = sum(k["t1"] - k["t0"] for k in kids)
@@ -175,7 +176,8 @@ def test_train_step_phase_spans_tile_the_step():
     spans = tracing.spans()
     roots = [s for s in spans if s["name"] == "train.step"]
     assert len(roots) == 1
-    kids = [s for s in spans if s["parent"] == roots[0]["span"]]
+    kids = [s for s in spans if s["parent"] == roots[0]["span"]
+            and s["name"] != "jit.compile"]
     assert [k["name"] for k in kids] == list(phases)
 
 
@@ -365,13 +367,10 @@ def test_chrome_trace_merges_tracing_and_profiler_streams():
     from mxnet_tpu import profiler
     with tracing.span("step", step=1):
         pass
-    # a profiler record_span only lands while a profile "runs"; drive
-    # the span store directly (jax trace start is out of scope here)
-    profiler._STATE["running"] = True
-    try:
-        profiler.record_span("pipeline:decode", 1.0, 2.0)
-    finally:
-        profiler._STATE["running"] = False
+    # mx.profiler's MXNet-compatible scoped events are the other
+    # stream chrome_trace() merges (the program itself writes spans only)
+    with profiler.Task(profiler.Domain("user"), "user:decode"):
+        pass
     payload = tracing.chrome_trace()
     evs = payload["traceEvents"]
     assert isinstance(evs, list)
@@ -380,7 +379,7 @@ def test_chrome_trace_merges_tracing_and_profiler_streams():
     assert len(xs) == 1 and xs[0]["name"] == "step"
     assert xs[0]["args"]["trace"] == xs[0]["args"]["span"]
     assert xs[0]["dur"] >= 0
-    assert {e["name"] for e in bes} == {"pipeline:decode"}
+    assert {e["name"] for e in bes} == {"user:decode"}
     for e in xs + bes:
         assert {"name", "ph", "ts", "pid", "tid"} <= set(e)
     # valid JSON end to end (the chrome://tracing contract)

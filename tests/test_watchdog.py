@@ -1,12 +1,10 @@
-"""Health watchdog + live MFU accounting (ISSUE 14).
+"""Health watchdog + the bench cost model (ISSUE 14).
 
 Covers the rule catalog (non-finite loss/grad, loss spike vs trailing
 window, FakeClock step stall, serving queue saturation, KV-block leak
 trend), the typed ``watchdog.*`` event + ``reason="watchdog:<rule>"``
 flight-dump contract, the bitwise-inert ``MXTPU_WATCHDOG=0`` kill
-switch, and the ``train.mfu`` live gauge's agreement with the shared
-``telemetry.costmodel`` (the bench.py cost model) on the same compiled
-step.
+switch, and that ``telemetry.costmodel`` is bench.py's cost model.
 """
 import json
 import math
@@ -254,7 +252,7 @@ def test_watchdog_chaos_scenario(tmp_path, monkeypatch):
 
 
 # ----------------------------------------------------------------------
-# live MFU accounting (telemetry/costmodel.py)
+# the bench cost model (telemetry/costmodel.py)
 # ----------------------------------------------------------------------
 
 def test_costmodel_is_the_bench_cost_model():
@@ -274,76 +272,7 @@ def test_costmodel_is_the_bench_cost_model():
 
 def test_chip_peak_env_override(monkeypatch):
     assert costmodel.chip_peak_flops() is None          # CPU host
-    assert not costmodel.live_cost_enabled()
     monkeypatch.setenv("MXTPU_CHIP_PEAK_TFLOPS", "197")
     assert costmodel.chip_peak_flops() == 197e12
-    assert costmodel.live_cost_enabled()
     monkeypatch.setenv("MXTPU_CHIP_PEAK_TFLOPS", "bogus")
     assert costmodel.chip_peak_flops() is None
-
-
-def test_live_mfu_gauges_agree_with_offline_cost(monkeypatch):
-    """Acceptance: the live ``train.mfu`` gauge agrees with the offline
-    cost model on the SAME compiled step.  peak=1 TFLOP/s makes
-    mfu == tflops_delivered exactly (same expression, same rounding);
-    ``train.step_flops`` must be exactly what the shared
-    ``costmodel.compiled_flops`` (bench.py's XLA cost analysis) returned
-    for that executable — computed ONCE per compile, and identical
-    across two trainers compiling the same step."""
-    monkeypatch.setenv("MXTPU_CHIP_PEAK_TFLOPS", "1")
-    calls = []
-    real = costmodel.compiled_flops
-
-    def spy(jitted, *args):
-        out = real(jitted, *args)
-        calls.append(out)
-        return out
-    monkeypatch.setattr(costmodel, "compiled_flops", spy)
-
-    def run(seed):
-        mx.random.seed(seed)
-        np.random.seed(seed)
-        net = gluon.nn.Dense(4)
-        net.initialize()
-        tr = parallel.DataParallelTrainer(
-            net, gluon.loss.L2Loss(), "adam", {"learning_rate": 0.05})
-        rng = np.random.RandomState(1)
-        x = nd.array(rng.randn(16, 8).astype(np.float32))
-        y = nd.array(rng.randn(16, 4).astype(np.float32))
-        for _ in range(2):
-            tr.step(x, y)
-
-    run(11)
-    flops = telemetry.value("train.step_flops")
-    tflops = telemetry.value("train.tflops_delivered")
-    mfu = telemetry.value("train.mfu")
-    assert flops and flops > 0
-    assert tflops is not None and mfu is not None
-    assert mfu == tflops                   # peak = 1 TFLOP/s: the mfu
-    #                                        and tflops expressions are
-    #                                        identical incl. rounding
-    # once per compile across 2 steps; the gauge IS the cost model's
-    # number for this executable (bench's offline path calls the same
-    # function on the same compiled step)
-    assert calls == [flops]
-    run(12)                                # same model, fresh compile
-    assert calls == [flops, flops]         # identical program, same cost
-
-
-def test_live_mfu_null_when_unmeasured_on_cpu():
-    """No chip peak known (plain CPU): the gauges never materialize —
-    null-when-unmeasured, not a fake zero — and no cost analysis (no
-    second compile) is ever paid."""
-    mx.random.seed(12)
-    np.random.seed(12)
-    net = gluon.nn.Dense(4)
-    net.initialize()
-    tr = parallel.DataParallelTrainer(
-        net, gluon.loss.L2Loss(), "sgd", {"learning_rate": 0.1})
-    x = nd.array(np.zeros((8, 4), np.float32))
-    y = nd.array(np.zeros((8, 4), np.float32))
-    tr.step(x, y)
-    assert telemetry.value("train.mfu") is None
-    assert telemetry.value("train.tflops_delivered") is None
-    assert telemetry.value("train.step_flops") is None
-    assert all(f is None for _j, f in tr._live_cost.values())
