@@ -5,11 +5,20 @@ Reference capability: the fused ``contrib`` multi-head attention ops
 (Lq, Lk) score matrix; this is the online-softmax streaming algorithm, so
 HBM traffic is O(L*D) not O(L^2) (SURVEY.md §5.7 TPU plan).
 
-Layout: (B, H, L, D). The Pallas path tiles Lq into BQ-row blocks and
-streams Lk in BK-column blocks through VMEM, with a float32 accumulator
-and running (max, denom) per query row; the MXU sees two
-(BQ, D) x (D, BK) / (BQ, BK) x (BK, D) matmuls per step. The fallback is
-the same algorithm as a ``lax.scan`` over KV blocks, which XLA fuses
+Layout: (B, H, L, D) outside.  The Pallas path hands the kernel its
+operands as (B*H, D, L) — L on the lanes, D on the sublanes — which is the
+layout XLA itself prefers for a D of 64 (a 64-wide minor dimension is
+padded to 128 lanes in HBM and twice the bytes move), and holds the scores
+transposed, keys on sublanes and queries on lanes: the softmax's max and
+sum then run down the sublanes, and the running max, the denominator and
+the log-sum-exp are lane-major rows, which is how they are stored.  One
+grid program takes G (batch x head) rows, a BQ block of queries and
+streams Lk in BK blocks through VMEM with a float32 accumulator: the MXU
+sees G batched (BK, D) x (D, BQ) / (D, BK) x (BK, BQ) matmuls per step.  G
+comes from the shapes alone (:func:`_rows_per_program`): as many rows as
+give a program a few microseconds of work and fit VMEM.  When one BK block
+holds all of Lk the body is a plain one-pass softmax.  The fallback is the
+same algorithm as a ``lax.scan`` over KV blocks, which XLA fuses
 adequately on CPU and keeps memory O(L*BK).
 
 Gradients: custom VJP; the backward pass recomputes scores blockwise from
@@ -27,6 +36,7 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from .. import telemetry as _telem
 from .kernel_mode import kernel_mode
 
 __all__ = ["flash_attention"]
@@ -52,6 +62,47 @@ def _pick_block(n, preferred=512):
 # Pallas TPU forward
 # ---------------------------------------------------------------------------
 
+# What one program's blocks, scratch and temporaries may take of VMEM, as
+# _program_vmem_bytes reckons them: under Mosaic's default scoped limit
+# (16 MiB on the v5e), with room for what the arithmetic does not see.
+_VMEM_BUDGET = 14 * 2 ** 20
+_VMEM_DEFAULT_LIMIT = 16 * 2 ** 20
+# HBM bytes a program should move before more rows stop paying: 2.6 us at
+# the v5e's 819 GB/s against the ~0.35 us a grid step costs by itself.
+_PROGRAM_HBM_BYTES = 2 * 2 ** 20
+
+
+def _program_vmem_bytes(g, bq, bk, d, itemsize, streaming):
+    """VMEM bytes one program of ``g`` rows holds: the double-buffered
+    Q/O and K/V blocks (d on sublanes, padded to the dtype's tile), the
+    log-sum-exp block, the float32 scratch of the streaming body and the
+    (g, bk, bq) score / probability temporaries with the float32 P.V."""
+    sublanes = 32 // itemsize                  # 8 float32 rows, 16 bf16
+    dp = -(-d // sublanes) * sublanes
+    blocks = 2 * g * dp * (2 * bq + 2 * bk) * itemsize
+    lse = 2 * 8 * -(-g // 8) * bq * 4
+    scratch = g * (d + 2 * 8) * bq * 4 if streaming else 0
+    temps = g * bq * (bk * (4 + 4 + itemsize) + d * 4)
+    return blocks + lse + scratch + temps
+
+
+def _rows_per_program(bh, bq, bk, d, itemsize, streaming):
+    """G, the (batch x head) rows one grid program takes: a divisor of
+    ``bh`` (a multiple of 8 where one fits: the log-sum-exp block is then
+    (G, bq), rows on sublanes) whose blocks fit ``_VMEM_BUDGET``, the
+    smallest that moves ``_PROGRAM_HBM_BYTES`` or else the largest that
+    fits.  A function of the shapes and the dtype alone."""
+    fits = [g for g in range(1, bh + 1) if bh % g == 0 and
+            _program_vmem_bytes(g, bq, bk, d, itemsize, streaming)
+            <= _VMEM_BUDGET] or [1]
+    pool = [g for g in fits if g % 8 == 0] or fits
+    row_bytes = (2 * bq + 2 * bk) * d * itemsize
+    for g in pool:
+        if g * row_bytes >= _PROGRAM_HBM_BYTES:
+            return g
+    return pool[-1]
+
+
 def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -59,16 +110,63 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     bh, lq, d = q.shape
     lk = k.shape[1]
     nq, nk = lq // bq, lk // bk
+    shape = (bq, bk, d, q.dtype.itemsize, nk > 1)
+    # from the rows this call sees: a chip's own, inside _per_batch_shard
+    g = _rows_per_program(bh, *shape)
+    _telem.set_gauge("flash.fwd.rows_per_program", g)
+    # rows on sublanes where G fills whole tiles; otherwise mosaic's
+    # (8, 128) tile is met by a broadcast sublane dim, sliced off below
+    lse_rows = g % 8 == 0
 
-    def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_i, l_i):
+    def operand(x):
+        # the CPU backend has no batched bf16 x bf16 -> f32 dot, so the
+        # interpreter multiplies in float32: the same products, exactly
+        return x.astype(jnp.float32) if interpret else x
+
+    def scores(q_ref, k_ref, i, j):
+        # (g, d, bk) x (g, d, bq) over d -> (g, bk, bq): keys on sublanes
+        s = lax.dot_general(
+            operand(k_ref[...]), operand(q_ref[...]),
+            (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * sm_scale
+        if causal:
+            kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+            qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
+        return s
+
+    def p_dot_v(v_ref, p):
+        # (g, d, bk) x (g, bk, bq) -> (g, d, bq)
+        vb = v_ref[...]
+        return lax.dot_general(
+            operand(vb), operand(p.astype(vb.dtype)),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+
+    def store_lse(lse_ref, lse):                # lse (g, 1, bq)
+        if lse_rows:
+            lse_ref[...] = lse[:, 0, :]
+        else:
+            lse_ref[...] = jnp.broadcast_to(lse, (g, 8, bq))
+
+    def one_pass(q_ref, k_ref, v_ref, o_ref, lse_ref):
+        # all of Lk is in the block: nothing to stream, no running state
+        s = scores(q_ref, k_ref, pl.program_id(1), 0)
+        m = jnp.max(s, axis=1, keepdims=True)   # (g, 1, bq)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=1, keepdims=True)   # >= 1: the max's own term
+        o_ref[...] = (p_dot_v(v_ref, p) / l).astype(o_ref.dtype)
+        store_lse(lse_ref, m + jnp.log(l))
+
+    def streaming(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_i, l_i):
         i = pl.program_id(1)
         j = pl.program_id(2)
 
         @pl.when(j == 0)
         def _init():
-            m_i[:] = jnp.full_like(m_i, _NEG_INF)
-            l_i[:] = jnp.zeros_like(l_i)
-            acc[:] = jnp.zeros_like(acc)
+            m_i[...] = jnp.full_like(m_i, _NEG_INF)
+            l_i[...] = jnp.zeros_like(l_i)
+            acc[...] = jnp.zeros_like(acc)
 
         # Causal: the whole KV block is in the future of the whole Q block
         # when j*bk > i*bq + bq - 1 — skip its compute entirely.
@@ -76,61 +174,57 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
 
         @pl.when(live)
         def _step():
-            qb = q_ref[0]                       # (bq, d)
-            kb = k_ref[0]                       # (bk, d)
-            vb = v_ref[0]                       # (bk, d)
-            s = lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            if causal:
-                qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-                kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-                s = jnp.where(qpos >= kpos, s, _NEG_INF)
-            m_new = jnp.maximum(m_i[:], jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)              # (bq, bk) f32
-            alpha = jnp.exp(m_i[:] - m_new)     # (bq, 1)
-            l_i[:] = l_i[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc[:] = acc[:] * alpha + lax.dot_general(
-                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_i[:] = m_new
+            s = scores(q_ref, k_ref, i, j)
+            m_new = jnp.maximum(m_i[...], jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)              # (g, bk, bq) f32
+            alpha = jnp.exp(m_i[...] - m_new)   # (g, 1, bq)
+            l_i[...] = l_i[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc[...] = acc[...] * alpha + p_dot_v(v_ref, p)
+            m_i[...] = m_new
 
         @pl.when(j == nk - 1)
         def _fin():
-            denom = jnp.maximum(l_i[:], 1e-30)
-            o_ref[0] = (acc[:] / denom).astype(o_ref.dtype)
-            # lse is (bq,) but mosaic tiling wants an (8, 128k) block, so
-            # the output carries a broadcast sublane dim (sliced off by the
-            # wrapper)
-            lse = (m_i[:] + jnp.log(denom))[:, 0]
-            lse_ref[0] = jnp.broadcast_to(lse[None, :], (8, bq))
+            denom = jnp.maximum(l_i[...], 1e-30)
+            o_ref[...] = (acc[...] / denom).astype(o_ref.dtype)
+            store_lse(lse_ref, m_i[...] + jnp.log(denom))
 
-    grid = (bh, nq, nk)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+    if lse_rows:
+        lse_spec = pl.BlockSpec((g, bq), lambda b, i, j: (b, i))
+        lse_shape = jax.ShapeDtypeStruct((bh, lq), jnp.float32)
+    else:
+        lse_spec = pl.BlockSpec((g, 8, bq), lambda b, i, j: (b, 0, i))
+        lse_shape = jax.ShapeDtypeStruct((bh, 8, lq), jnp.float32)
+    # where one row does not fit the budget (blocks asked for through
+    # MXTPU_FLASH_BLOCK_Q/KV), mosaic's limit is raised by what it is over
+    over = _program_vmem_bytes(g, *shape) - _VMEM_BUDGET
+    out_t, lse = pl.pallas_call(
+        one_pass if nk == 1 else streaming,
+        grid=(bh // g, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((g, d, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((g, d, bk), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((g, d, bk), lambda b, i, j: (b, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, 8, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((g, d, bq), lambda b, i, j: (b, 0, i)),
+            lse_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 8, lq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, d, lq), q.dtype),
+            lse_shape,
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+        scratch_shapes=[] if nk == 1 else [
+            pltpu.VMEM((g, d, bq), jnp.float32),
+            pltpu.VMEM((g, 1, bq), jnp.float32),
+            pltpu.VMEM((g, 1, bq), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_DEFAULT_LIMIT + over if over > 0 else None),
         name="mxtpu_flash_fwd",
         interpret=interpret,
-    )(q, k, v)
-    return out, lse[:, 0, :]
+    )(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)))
+    return jnp.swapaxes(out_t, 1, 2), lse if lse_rows else lse[:, 0, :]
 
 
 # ---------------------------------------------------------------------------
@@ -215,6 +309,8 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
 # ---------------------------------------------------------------------------
 
 def _use_pallas(lq, lk, d):
+    """``(bq, bk)`` for :func:`_pallas_forward`, or None where the scan
+    runs: no kernel mode, or shapes the kernel does not tile."""
     if kernel_mode() is None:
         return None
     import os
@@ -280,6 +376,8 @@ def _flash(q, k, v, causal, sm_scale):
 
 def _flash_fwd(q, k, v, causal, sm_scale):
     blocks = _use_pallas(q.shape[1], k.shape[1], q.shape[2])
+    # counted while tracing: one per attention layer of a compiled program
+    _telem.inc("flash.fwd.scan" if blocks is None else "flash.fwd.pallas")
     if blocks is not None:
         kernel = functools.partial(
             _pallas_forward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
@@ -306,8 +404,11 @@ def flash_attention(query, key, value, causal=False, sm_scale=None):
 
     Differentiable (custom VJP, blockwise backward) and tape-aware: with
     NDArray inputs under ``autograd.record()`` it records one tape node.
-    On TPU with 128-aligned L and D the core runs as a Pallas kernel;
-    otherwise a blockwise-scan XLA fallback with identical semantics.
+    On TPU, with Lq and Lk multiples of 128 and D a multiple of 64, the
+    core runs as a Pallas kernel (G batch x head rows to a grid program, G
+    from the shapes); otherwise a blockwise-scan XLA fallback with identical
+    semantics.  Counters ``flash.fwd.pallas`` / ``flash.fwd.scan`` say which
+    was traced, gauge ``flash.fwd.rows_per_program`` the last G.
     """
     from ..ndarray.ndarray import NDArray, apply_nary
 

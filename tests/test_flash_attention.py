@@ -159,3 +159,144 @@ def test_kernel_is_wrapped_in_shard_map_under_a_partitioned_step():
     assert out.sharding.spec[0] == "dp"
     np.testing.assert_allclose(np.asarray(out), np.asarray(fresh()(q, k, v)),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the kernel's grid: G (batch x head) rows per program, chosen from shapes
+# ---------------------------------------------------------------------------
+
+def _flash_module():
+    # the package re-exports the op under the submodule's own name
+    import importlib
+    return importlib.import_module("mxnet_tpu.ops.flash_attention")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("bh,seq,d,block,rows", [
+    pytest.param(24, 128, 64, 128, 24, id="one-pass-lse-rows"),
+    pytest.param(6, 128, 64, 128, 6, id="one-pass-lse-broadcast"),
+    pytest.param(16, 256, 64, 128, 16, id="streaming"),
+    pytest.param(3, 256, 128, 256, 3, id="one-pass-d128-block256"),
+])
+def test_pallas_forward_rows_per_program_matches_scan(bh, seq, d, block,
+                                                      rows, causal):
+    from mxnet_tpu import telemetry
+    mod = _flash_module()
+    rng = np.random.RandomState(bh + seq)
+    q, k, v = (jnp.asarray(rng.randn(bh, seq, d), jnp.float32)
+               for _ in range(3))
+    scale = 1.0 / np.sqrt(d)
+    out, lse = mod._pallas_forward(q, k, v, causal, scale, block, block,
+                                   interpret=True)
+    assert telemetry.value("flash.fwd.rows_per_program") == rows
+    ref, ref_lse = mod._scan_forward(q, k, v, causal, scale, block)
+    assert out.shape == (bh, seq, d) and lse.shape == (bh, seq)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_pallas_forward_bf16_under_jit_in_interpret_mode():
+    """bf16 operands inside a jit: what the benchmark's CPU rehearsal and
+    chip_smoke's run (the CPU backend has no batched bf16 dot)."""
+    mod = _flash_module()
+    rng = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(16, 128, 64), jnp.bfloat16)
+               for _ in range(3))
+    out, lse = jax.jit(lambda q, k, v: mod._pallas_forward(
+        q, k, v, False, 0.125, 128, 128, interpret=True))(q, k, v)
+    ref, ref_lse = mod._scan_forward(q, k, v, False, 0.125, 128)
+    assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=2e-2)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               atol=2e-2)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_gradients_through_the_kernel_match_the_scan_path(causal):
+    from mxnet_tpu.ops.kernel_mode import interpret_kernels
+    mod = _flash_module()
+    rng = np.random.RandomState(8)
+    q, k, v = (jnp.asarray(rng.randn(8, 256, 64), jnp.float32)
+               for _ in range(3))
+
+    def grads():
+        # the mode is read while tracing: a fresh function each time
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(mod._flash(q, k, v, causal, 0.125) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    with interpret_kernels():
+        assert mod._use_pallas(256, 256, 64) is not None
+        val, got = grads()
+    assert mod._use_pallas(256, 256, 64) is None
+    ref_val, want = grads()
+    np.testing.assert_allclose(float(val), float(ref_val), rtol=1e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("bh,seq,d,itemsize", [
+    (1536, 128, 64, 2),     # BERT-base b128 s128, a chip's rows
+    (384, 512, 64, 2),      # BERT-base b32 s512
+    (8, 2048, 128, 2),      # chip_smoke's long shape (512-blocks, streamed)
+    (7, 128, 64, 2),        # a prime number of rows
+    (1536, 128, 64, 4),     # float32 operands
+])
+def test_rows_per_program_is_a_pure_function_of_the_shapes(
+        bh, seq, d, itemsize, monkeypatch):
+    mod = _flash_module()
+    block = mod._pick_block(seq, 512)
+    streaming = seq > block
+    g = mod._rows_per_program(bh, block, block, d, itemsize, streaming)
+    assert g >= 1 and bh % g == 0
+    assert mod._program_vmem_bytes(g, block, block, d, itemsize,
+                                   streaming) <= mod._VMEM_BUDGET
+    assert mod._VMEM_BUDGET <= mod._VMEM_DEFAULT_LIMIT
+    # no more rows than give a program its work, unless fewer do not exist
+    row_bytes = 4 * block * d * itemsize
+    smaller = [x for x in range(1, g) if bh % x == 0 and
+               (x % 8 == 0) == (g % 8 == 0)]
+    assert all(x * row_bytes < mod._PROGRAM_HBM_BYTES for x in smaller)
+    # the environment has no say, and the same shapes give the same G
+    monkeypatch.setenv("MXTPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("MXTPU_FLASH_ROWS", "1")
+    assert mod._rows_per_program(bh, block, block, d, itemsize,
+                                 streaming) == g
+
+
+def test_rows_per_program_at_the_benchmark_shapes():
+    mod = _flash_module()
+    assert mod._rows_per_program(1536, 128, 128, 64, 2, False) == 32
+    assert mod._rows_per_program(384, 512, 512, 64, 2, False) == 4
+    assert mod._rows_per_program(8, 512, 512, 128, 2, True) == 2
+    assert mod._rows_per_program(7, 128, 128, 64, 2, False) == 7
+    # a block that no budget holds still gets one row, and its limit
+    assert mod._rows_per_program(8, 2048, 2048, 128, 2, False) == 1
+    assert mod._program_vmem_bytes(1, 2048, 2048, 128, 2, False) \
+        > mod._VMEM_BUDGET
+
+
+def test_flash_counters_say_which_path_was_traced():
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.kernel_mode import interpret_kernels
+    mod = _flash_module()
+    q = jnp.zeros((16, 128, 64), jnp.float32)
+
+    def trace():
+        jax.make_jaxpr(lambda q: mod._flash(q, q, q, False, 0.125))(q)
+
+    scan0 = telemetry.value("flash.fwd.scan") or 0
+    pallas0 = telemetry.value("flash.fwd.pallas") or 0
+    trace()
+    assert telemetry.value("flash.fwd.scan") == scan0 + 1
+    assert (telemetry.value("flash.fwd.pallas") or 0) == pallas0
+    telemetry.set_gauge("flash.fwd.rows_per_program", 0)
+    with interpret_kernels():
+        trace()
+    assert telemetry.value("flash.fwd.pallas") == pallas0 + 1
+    assert telemetry.value("flash.fwd.scan") == scan0 + 1
+    assert telemetry.value("flash.fwd.rows_per_program") == 16
