@@ -51,7 +51,14 @@ SIZES = {
         bert=dict(num_layers=12, units=768, hidden_size=3072, num_heads=12,
                   vocab_size=30522, max_length=128),
         bert_batch=64, bert_seq=128,
-        flash_shapes=((768, 128, 64), (8, 2048, 128)),
+        # (B*H, L, D, Dv, causal): BERT-base, a long causal one, and latent
+        # attention as the kanana cell calls it (Q.K over 192, V at 128)
+        flash_shapes=((768, 128, 64, 64, (False, True)),
+                      (8, 2048, 128, 128, (False, True)),
+                      (64, 4096, 192, 128, (True,))),
+        # the kanana cell's expert products: a worst-case buffer of which an
+        # eighth is routed, 16 experts held
+        gmm=dict(rows=49152, routed=6144, groups=16, k=2048, n=768),
         ln_rows=8192, ln_dim=768,
         bucket_elems=25_557_032,            # one ResNet-50 of parameters
         paged=dict(batch=8, heads=32, kv_heads=8, head_dim=128, block=16,
@@ -67,7 +74,9 @@ SIZES = {
         bert=dict(num_layers=1, units=64, hidden_size=128, num_heads=1,
                   vocab_size=128, max_length=128),
         bert_batch=2, bert_seq=128,
-        flash_shapes=((2, 128, 64),),
+        flash_shapes=((2, 128, 64, 64, (False, True)),
+                      (2, 128, 192, 128, (True,))),
+        gmm=dict(rows=256, routed=150, groups=4, k=128, n=128),
         ln_rows=32, ln_dim=128,
         bucket_elems=20_000,
         paged=dict(batch=2, heads=4, kv_heads=2, head_dim=64, block=8,
@@ -440,7 +449,7 @@ def _kernels_flash(run):
     from mxnet_tpu.ops import flash_attention
     ph = "4 kernels flash_attention"
 
-    def naive(q, k, v, causal):
+    def naive_rows(q, k, v, causal):
         # plain XLA softmax(QK^T)V in float32 over the same bf16 inputs
         q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
         s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(q.shape[-1])
@@ -449,11 +458,26 @@ def _kernels_flash(run):
             s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -1e30)
         return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
 
-    for bh, L, d in run.sizes["flash_shapes"]:
+    def naive(q, k, v, causal):
+        # 8 rows at a time where all the (L, L) scores at once would not
+        # fit beside their gradients (64 rows at L = 4096: 4.3 GB a copy)
+        bh, L = q.shape[1], q.shape[2]
+        if bh * L * L * 4 < 2 ** 30 or bh % 8:
+            return naive_rows(q, k, v, causal)
+
+        def blocks(a):
+            return a.reshape(bh // 8, 1, 8, L, a.shape[-1])
+        out = jax.lax.map(lambda qkv: naive_rows(*qkv, causal),
+                          (blocks(q), blocks(k), blocks(v)))
+        return out.reshape(1, bh, L, v.shape[-1])
+
+    for bh, L, d, dv, causals in run.sizes["flash_shapes"]:
         rng = np.random.RandomState(SEED)
-        q, k, v, g = (jnp.asarray(rng.randn(1, bh, L, d), jnp.bfloat16)
-                      for _ in range(4))
-        for causal in (False, True):
+        q, k = (jnp.asarray(rng.randn(1, bh, L, d), jnp.bfloat16)
+                for _ in range(2))
+        v, g = (jnp.asarray(rng.randn(1, bh, L, dv), jnp.bfloat16)
+                for _ in range(2))
+        for causal in causals:
             def loss(fn, q, k, v):
                 out = fn(q, k, v).astype(jnp.float32)
                 return jnp.sum(out * g.astype(jnp.float32)), out
@@ -476,10 +500,64 @@ def _kernels_flash(run):
             errs = [assert_close("flash out", out, ref, 2e-2)]
             errs += [assert_close(f"flash d{n}", a, b, 2e-2)
                      for n, a, b in zip("qkv", grads, ref_grads)]
-            say(ph, f"(B*H,L,D)=({bh},{L},{d}) bf16 causal={causal} "
+            say(ph, f"(B*H,L,D,Dv)=({bh},{L},{d},{dv}) bf16 causal={causal} "
                     f"compile_s={dt:.2f} max_abs_err out,dq,dk,dv="
                     f"{[float(f'{e:.2e}') for e in errs]} "
                     f"(tolerance 2e-2 x scale)")
+
+
+def _kernels_gmm(run):
+    """``mxtpu_gmm`` and its two backward kernels against ``lax.ragged_dot``
+    in float32 over the same bf16 operands: a buffer of which a part is
+    routed, in groups of uneven size."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.grouped_matmul import grouped_matmul
+    ph = "4 kernels grouped_matmul"
+    z = run.sizes["gmm"]
+    rng = np.random.RandomState(SEED)
+    sizes = rng.multinomial(z["routed"], np.ones(z["groups"]) / z["groups"])
+    gs = jnp.asarray(sizes, jnp.int32)
+    lhs = jnp.asarray(rng.randn(z["rows"], z["k"]), jnp.bfloat16)
+    rhs = jnp.asarray(rng.randn(z["groups"], z["k"], z["n"]) * z["k"] ** -.5,
+                      jnp.bfloat16)
+    g = jnp.asarray(rng.randn(z["rows"], z["n"]), jnp.bfloat16)
+
+    def both(fn):
+        def loss(lhs, rhs):
+            out = fn(lhs, rhs).astype(jnp.float32)
+            return jnp.sum(out * g.astype(jnp.float32)), out
+        return lambda lhs, rhs: jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(lhs, rhs)
+
+    exe, dt = _compiled(run, "mxtpu_gmm",
+                        both(lambda a, b: grouped_matmul(a, b, gs)), lhs, rhs)
+    (_, out), grads = exe(lhs, rhs)
+    # ragged_dot leaves the rows past the total unspecified on a TPU (the
+    # v5e gave leftovers, NaN among them), so the reference masks them
+    routed_rows = jnp.arange(z["rows"])[:, None] < int(sizes.sum())
+    (_, ref), ref_grads = jax.jit(both(
+        lambda a, b: jnp.where(routed_rows, jax.lax.ragged_dot(
+            jnp.where(routed_rows, a, 0).astype(jnp.float32),
+            b.astype(jnp.float32), gs,
+            precision=jax.lax.Precision.HIGHEST), 0)))(lhs, rhs)
+    # bf16 operands and results, f32 accumulation: 2 bf16 ulps of the
+    # largest reference value, as for flash
+    errs = [assert_close("gmm out", out, ref, 2e-2)]
+    errs += [assert_close(f"gmm d{n}", a, b, 2e-2)
+             for n, a, b in zip(("lhs", "rhs"), grads, ref_grads)]
+    routed = int(sizes.sum())
+    assert not np.asarray(out[routed:], np.float32).any(), \
+        "rows past the last routed one are not zero"
+    t0 = time.perf_counter()
+    for _ in range(10):
+        res = exe(lhs, rhs)
+    jax.block_until_ready(res)
+    say(ph, f"(rows,routed,groups,K,N)=({z['rows']},{routed},{z['groups']},"
+            f"{z['k']},{z['n']}) bf16 group sizes {sizes.min()}..{sizes.max()} "
+            f"compile_s={dt:.2f} fwd+bwd_ms={(time.perf_counter() - t0) * 100:.3f} "
+            f"max_abs_err out,dlhs,drhs={[float(f'{e:.2e}') for e in errs]} "
+            f"(tolerance 2e-2 x scale)")
 
 
 def _kernels_layernorm(run):
@@ -634,6 +712,7 @@ def _kernels_paged(run):
 
 def phase_kernels(run):
     _kernels_flash(run)
+    _kernels_gmm(run)
     _kernels_layernorm(run)
     _kernels_bucket_update(run)
     _kernels_paged(run)

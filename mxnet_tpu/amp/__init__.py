@@ -15,6 +15,7 @@ jitted XLA program, so mixed precision is compiled, not interpreted.
 from __future__ import annotations
 
 import functools
+import inspect
 from contextlib import contextmanager
 
 import numpy as _np
@@ -32,7 +33,9 @@ _target_dtype = None
 _originals = {}
 
 
-def _cast_arrays(args, kwargs, dtype):
+def _cast_arrays(args, kwargs, dtype, keep=()):
+    """Cast every floating NDArray among the arguments to ``dtype``; ``keep``
+    names those left alone, by position and by keyword."""
     import jax.numpy as jnp
     from ..ndarray.ndarray import NDArray
 
@@ -44,7 +47,8 @@ def _cast_arrays(args, kwargs, dtype):
                 return x.astype(dtype)
         return x
 
-    return [cast(a) for a in args], {k: cast(v) for k, v in kwargs.items()}
+    return ([a if i in keep else cast(a) for i, a in enumerate(args)],
+            {k: v if k in keep else cast(v) for k, v in kwargs.items()})
 
 
 def _widest_dtype(args, kwargs):
@@ -59,11 +63,15 @@ def _widest_dtype(args, kwargs):
     return None if widest is None else str(widest)
 
 
-def _wrap(fn, mode, target_dtype):
+def _wrap(fn, mode, target_dtype, keep=()):
+    if keep:        # the names, and where they stand in the signature
+        order = list(inspect.signature(fn).parameters)
+        keep = set(keep) | {order.index(name) for name in keep}
+
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         if mode == "low":
-            args, kwargs = _cast_arrays(args, kwargs, target_dtype)
+            args, kwargs = _cast_arrays(args, kwargs, target_dtype, keep)
         elif mode == "fp32":
             args, kwargs = _cast_arrays(args, kwargs, "float32")
         elif mode == "widest":
@@ -101,7 +109,8 @@ def init(target_dtype="bfloat16", target_precision_ops=None,
             fn = getattr(ops_mod, name, None)
             if fn is None or not callable(fn):
                 continue
-            wrapped = _wrap(fn, mode, target_dtype)
+            wrapped = _wrap(fn, mode, target_dtype,
+                            lists.KEEP_DTYPE_ARGS.get(name, ()))
             _originals[name] = fn
             setattr(ops_mod, name, wrapped)
             # the gluon F namespace is the `mxnet_tpu.ndarray` module

@@ -13,6 +13,10 @@ Everything unlisted runs in whatever dtype arrives.
 TARGET_DTYPE_OPS = [
     "FullyConnected", "Convolution", "Deconvolution", "dot", "batch_dot",
     "linalg_gemm2", "RNN",
+    # latent attention (RoPE + the flash kernel) and the routed experts'
+    # grouped products; the shared expert and every projection are Dense,
+    # i.e. FullyConnected
+    "mla_attention", "moe_experts",
 ]
 
 # the reference's fp32 blacklist: softmax family, norms, losses, exp/log/pow
@@ -22,7 +26,15 @@ FP32_OPS = [
     "L2Normalization", "norm", "exp", "log", "log2", "log10", "expm1",
     "log1p", "erf", "gamma", "gammaln", "smooth_l1", "mean", "sum", "nansum",
     "prod", "nanprod", "cumsum",
+    # RMSNorm (the layer's and the latent's) and the router's sigmoid scores,
+    # whose top-k must not move with bf16 rounding
+    "rms_norm", "moe_router",
 ]
+
+# arguments that keep the dtype they arrive in although their op is listed
+# above: the router's expert ids and float32 combine weights on their way into
+# the bfloat16 expert products (the combine sums in float32)
+KEEP_DTYPE_ARGS = {"moe_experts": ("experts", "weights")}
 
 WIDEST_TYPE_CASTS = [
     "add_n", "concat", "stack", "where", "broadcast_add", "broadcast_sub",

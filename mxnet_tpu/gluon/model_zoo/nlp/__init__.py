@@ -11,9 +11,10 @@ from .transformer import *  # noqa: F401,F403
 from .language_model import *  # noqa: F401,F403
 from .sampler import *  # noqa: F401,F403
 from .llama import *  # noqa: F401,F403
+from .deepseek_v3 import *  # noqa: F401,F403
 
 from . import attention, bert, transformer, language_model, sampler, \
-    llama  # noqa
+    llama, deepseek_v3  # noqa
 
 _MODELS = {}
 for _m in (bert, transformer, language_model):

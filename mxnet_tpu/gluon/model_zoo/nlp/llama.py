@@ -22,6 +22,10 @@ import math
 from ....base import MXNetError
 from ...block import HybridBlock
 from ... import nn
+# RMSNorm and RoPE math: one source for the registered ops, the blocks here
+# and the serving engine's decode steps
+from ....ops.norm_rope import rms_norm as _rms, \
+    rope_interleaved as _rot_interleaved
 
 __all__ = ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "RMSNorm",
            "llama3_8b", "llama_tiny"]
@@ -50,25 +54,6 @@ class LlamaConfig:
         if num_heads % num_kv_heads:
             raise MXNetError("num_kv_heads must divide num_heads")
         self.head_dim = hidden_size // num_heads
-
-
-def _rms(d, w, eps):
-    """Shared RMSNorm math (layer forward AND kv-cache decode — one
-    source so the decode parity can't drift)."""
-    import jax.numpy as jnp
-    # reduce in fp32 for bf16 inputs (standard practice)
-    d32 = d.astype(jnp.float32)
-    var = jnp.mean(d32 * d32, axis=-1, keepdims=True)
-    return (d32 / jnp.sqrt(var + eps)).astype(d.dtype) * w
-
-
-def _rot_interleaved(u, cos, sin):
-    """Shared interleaved-pair RoPE rotation; cos/sin broadcast against
-    u[..., 0::2] ((t, d/2) in the forward, (d/2,) at a decode step)."""
-    import jax.numpy as jnp
-    u1, u2 = u[..., 0::2], u[..., 1::2]
-    return jnp.stack([u1 * cos - u2 * sin,
-                      u2 * cos + u1 * sin], axis=-1).reshape(u.shape)
 
 
 # Query rows fed to the cache-attention einsums are padded to this many
@@ -134,10 +119,8 @@ class RMSNorm(HybridBlock):
                                           init="ones")
 
     def hybrid_forward(self, F, x, weight):
-        from ....ndarray.ndarray import apply_nary
-        eps = self._eps
-        return apply_nary(lambda d, w: _rms(d, w, eps), [x, weight],
-                          name="rms_norm")
+        # the registered op, so that amp places it (float32)
+        return F.rms_norm(x, weight, eps=self._eps)
 
 
 def _dense(units, use_tp, mode, **kw):

@@ -3339,3 +3339,97 @@ def one_hot_encode(indices, out):
 
 onehot_encode = one_hot_encode
 __all__.append("onehot_encode")
+
+
+# ======================================================================
+# latent attention and the dropless expert layer (no reference
+# counterpart; gluon.model_zoo.nlp.deepseek_v3 is built from these, and
+# amp/lists.py says which of them run in float32 and which in bfloat16)
+# ======================================================================
+
+@_register
+def rms_norm(data, weight, eps=1e-5):
+    """Root-mean-square norm over the last axis, reduced in float32
+    (``ops.norm_rope.rms_norm``, which the serving engine shares)."""
+    from ..ops.norm_rope import rms_norm as _rms
+    return apply_nary(lambda d, w: _rms(d, w, eps), [data, weight],
+                      name="rms_norm")
+
+
+@_register
+def moe_router(data, weight, bias, top_k=1, routed_scaling_factor=1.0,
+               norm_topk_prob=True):
+    """Sigmoid top-k router (``parallel.moe.route_sigmoid_top_k``).  data:
+    (..., d); weight: (E, d); bias: (E,), added for the selection only.
+    Returns ``[experts, weights]``, both (..., top_k) float32: the ids of the
+    chosen experts (whole numbers in a float array, as ``topk`` gives its
+    indices, so that the tape can carry them) and their combine weights."""
+    from ..parallel.moe import route_sigmoid_top_k
+
+    def fn(d, w, b):
+        experts, gates = route_sigmoid_top_k(
+            d.reshape(-1, d.shape[-1]), w, b, top_k,
+            scale=routed_scaling_factor, norm_topk_prob=norm_topk_prob)
+        lead = d.shape[:-1] + (top_k,)
+        return (experts.astype(jnp.float32).reshape(lead),
+                gates.reshape(lead))
+    return apply_nary(fn, [data, weight, bias], n_out=2, name="moe_router")
+
+
+@_register
+def moe_experts(data, experts, weights, w_gate, w_up, w_down,
+                expert_offset=0):
+    """The held experts' part of a dropless SwiGLU expert layer
+    (``parallel.moe.dropless_moe_apply``).  data: (..., d); experts, weights:
+    (..., k) from ``moe_router`` (``amp`` leaves both as they arrive:
+    ``lists.KEEP_DTYPE_ARGS``); w_gate, w_up: (held, d, h); w_down:
+    (held, h, d), the experts ``expert_offset .. expert_offset + held``."""
+    from ..parallel.moe import dropless_moe_apply
+
+    def fn(d, e, g, wg, wu, wd):
+        k = e.shape[-1]
+        out = dropless_moe_apply(
+            d.reshape(-1, d.shape[-1]), e.reshape(-1, k).astype(jnp.int32),
+            g.reshape(-1, k), wg, wu, wd, expert_offset=expert_offset)
+        return out.reshape(d.shape)
+    return apply_nary(fn, [data, experts, weights, w_gate, w_up, w_down],
+                      name="moe_experts")
+
+
+@_register
+def mla_attention(q, kv, k_pe, num_heads=1, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128, rope_theta=10000.0):
+    """Causal multi-head latent attention in its expanded (training) form.
+
+    q: (B, T, H * (nope + rope)), a head ``[q_nope | q_pe]``; kv:
+    (B, T, H * (nope + v)), a head ``[k_nope | v]``, the up-projection of
+    the normalised latent; k_pe: (B, T, rope), one rotary key shared by all
+    heads.  RoPE (interleaved pairs) on ``q_pe`` and ``k_pe``, then
+    ``softmax(q k^T (nope + rope)^-1/2) v`` through ``flash_attention`` with
+    Q and K at ``nope + rope`` and V at ``v``.  Returns (B, T, H * v)."""
+    from ..ops.flash_attention import flash_attention
+    from ..ops.norm_rope import rope_interleaved as _rot_interleaved
+    h, nope, rope, dv = num_heads, qk_nope_head_dim, qk_rope_head_dim, \
+        v_head_dim
+
+    def fn(qd, kvd, ped):
+        b, t = qd.shape[0], qd.shape[1]
+        with jax.named_scope("mla.attention"):
+            qd = qd.reshape(b, t, h, nope + rope).transpose(0, 2, 1, 3)
+            kvd = kvd.reshape(b, t, h, nope + dv).transpose(0, 2, 1, 3)
+            ang = jnp.arange(t, dtype=jnp.float32)[:, None] * \
+                rope_theta ** (-jnp.arange(0, rope, 2,
+                                           dtype=jnp.float32) / rope)
+            cos, sin = jnp.cos(ang), jnp.sin(ang)             # (t, rope/2)
+            q_pe = _rot_interleaved(qd[..., nope:], cos, sin)
+            k_pe = _rot_interleaved(ped[:, None], cos, sin)   # (b, 1, t, r)
+            query = jnp.concatenate(
+                [qd[..., :nope], q_pe.astype(qd.dtype)], axis=-1)
+            key = jnp.concatenate(
+                [kvd[..., :nope],
+                 jnp.broadcast_to(k_pe.astype(kvd.dtype),
+                                  (b, h, t, rope))], axis=-1)
+            out = flash_attention(query, key, kvd[..., nope:], causal=True,
+                                  sm_scale=(nope + rope) ** -0.5)
+            return out.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
+    return apply_nary(fn, [q, kv, k_pe], name="mla_attention")

@@ -72,31 +72,36 @@ _VMEM_DEFAULT_LIMIT = 16 * 2 ** 20
 _PROGRAM_HBM_BYTES = 2 * 2 ** 20
 
 
-def _program_vmem_bytes(g, bq, bk, d, itemsize, streaming):
+def _program_vmem_bytes(g, bq, bk, d, itemsize, streaming, dv=None):
     """VMEM bytes one program of ``g`` rows holds: the double-buffered
-    Q/O and K/V blocks (d on sublanes, padded to the dtype's tile), the
-    log-sum-exp block, the float32 scratch of the streaming body and the
-    (g, bk, bq) score / probability temporaries with the float32 P.V."""
+    Q/K blocks at ``d`` and V/O blocks at ``dv`` (the head dim on sublanes,
+    padded to the dtype's tile), the log-sum-exp block, the float32 scratch
+    of the streaming body and the (g, bk, bq) score / probability
+    temporaries with the float32 P.V."""
+    dv = d if dv is None else dv
     sublanes = 32 // itemsize                  # 8 float32 rows, 16 bf16
     dp = -(-d // sublanes) * sublanes
-    blocks = 2 * g * dp * (2 * bq + 2 * bk) * itemsize
+    dvp = -(-dv // sublanes) * sublanes
+    blocks = 2 * g * (dp + dvp) * (bq + bk) * itemsize
     lse = 2 * 8 * -(-g // 8) * bq * 4
-    scratch = g * (d + 2 * 8) * bq * 4 if streaming else 0
-    temps = g * bq * (bk * (4 + 4 + itemsize) + d * 4)
+    scratch = g * (dv + 2 * 8) * bq * 4 if streaming else 0
+    temps = g * bq * (bk * (4 + 4 + itemsize) + dv * 4)
     return blocks + lse + scratch + temps
 
 
-def _rows_per_program(bh, bq, bk, d, itemsize, streaming):
+def _rows_per_program(bh, bq, bk, d, itemsize, streaming, dv=None):
     """G, the (batch x head) rows one grid program takes: a divisor of
     ``bh`` (a multiple of 8 where one fits: the log-sum-exp block is then
     (G, bq), rows on sublanes) whose blocks fit ``_VMEM_BUDGET``, the
     smallest that moves ``_PROGRAM_HBM_BYTES`` or else the largest that
-    fits.  A function of the shapes and the dtype alone."""
+    fits.  A function of the shapes and the dtype alone; ``dv`` is the head
+    dim of V and O where it is not Q's and K's ``d``."""
+    dv = d if dv is None else dv
     fits = [g for g in range(1, bh + 1) if bh % g == 0 and
-            _program_vmem_bytes(g, bq, bk, d, itemsize, streaming)
+            _program_vmem_bytes(g, bq, bk, d, itemsize, streaming, dv)
             <= _VMEM_BUDGET] or [1]
     pool = [g for g in fits if g % 8 == 0] or fits
-    row_bytes = (2 * bq + 2 * bk) * d * itemsize
+    row_bytes = (bq + bk) * (d + dv) * itemsize
     for g in pool:
         if g * row_bytes >= _PROGRAM_HBM_BYTES:
             return g
@@ -108,9 +113,9 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     from jax.experimental.pallas import tpu as pltpu
 
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[2]
     nq, nk = lq // bq, lk // bk
-    shape = (bq, bk, d, q.dtype.itemsize, nk > 1)
+    shape = (bq, bk, d, q.dtype.itemsize, nk > 1, dv)
     # from the rows this call sees: a chip's own, inside _per_batch_shard
     g = _rows_per_program(bh, *shape)
     _telem.set_gauge("flash.fwd.rows_per_program", g)
@@ -136,7 +141,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
         return s
 
     def p_dot_v(v_ref, p):
-        # (g, d, bk) x (g, bk, bq) -> (g, d, bq)
+        # (g, dv, bk) x (g, bk, bq) -> (g, dv, bq)
         vb = v_ref[...]
         return lax.dot_general(
             operand(vb), operand(p.astype(vb.dtype)),
@@ -203,18 +208,18 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
         in_specs=[
             pl.BlockSpec((g, d, bq), lambda b, i, j: (b, 0, i)),
             pl.BlockSpec((g, d, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((g, d, bk), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((g, dv, bk), lambda b, i, j: (b, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((g, d, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((g, dv, bq), lambda b, i, j: (b, 0, i)),
             lse_spec,
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, d, lq), q.dtype),
+            jax.ShapeDtypeStruct((bh, dv, lq), q.dtype),
             lse_shape,
         ],
         scratch_shapes=[] if nk == 1 else [
-            pltpu.VMEM((g, d, bq), jnp.float32),
+            pltpu.VMEM((g, dv, bq), jnp.float32),
             pltpu.VMEM((g, 1, bq), jnp.float32),
             pltpu.VMEM((g, 1, bq), jnp.float32),
         ],
@@ -233,10 +238,10 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
 
 def _scan_forward(q, k, v, causal, sm_scale, bk):
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[2]
     nk = lk // bk
     kb = k.reshape(bh, nk, bk, d).transpose(1, 0, 2, 3)   # (nk, bh, bk, d)
-    vb = v.reshape(bh, nk, bk, d).transpose(1, 0, 2, 3)
+    vb = v.reshape(bh, nk, bk, dv).transpose(1, 0, 2, 3)
     qpos = lax.broadcasted_iota(jnp.int32, (lq, bk), 0)
 
     def step(carry, blk):
@@ -256,7 +261,7 @@ def _scan_forward(q, k, v, causal, sm_scale, bk):
             preferred_element_type=jnp.float32)
         return (acc, m_new, l_new, j + 1), None
 
-    init = (jnp.zeros((bh, lq, d), jnp.float32),
+    init = (jnp.zeros((bh, lq, dv), jnp.float32),
             jnp.full((bh, lq, 1), _NEG_INF, jnp.float32),
             jnp.zeros((bh, lq, 1), jnp.float32),
             jnp.int32(0))
@@ -273,10 +278,10 @@ def _scan_forward(q, k, v, causal, sm_scale, bk):
 
 def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, dv = k.shape[1], v.shape[2]
     nk = lk // bk
     kb = k.reshape(bh, nk, bk, d).transpose(1, 0, 2, 3)
-    vb = v.reshape(bh, nk, bk, d).transpose(1, 0, 2, 3)
+    vb = v.reshape(bh, nk, bk, dv).transpose(1, 0, 2, 3)
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32),
                     axis=-1, keepdims=True)                 # (bh, lq, 1)
     qpos = lax.broadcasted_iota(jnp.int32, (lq, bk), 0)
@@ -298,19 +303,21 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
         return dq, (dk_j, dv_j)
 
     steps = (kb, vb, jnp.arange(nk, dtype=jnp.int32))
-    dq, (dk, dv) = lax.scan(step, jnp.zeros((bh, lq, d), jnp.float32), steps)
+    dq, (dk, dvs) = lax.scan(step, jnp.zeros((bh, lq, d), jnp.float32),
+                             steps)
     dk = dk.transpose(1, 0, 2, 3).reshape(bh, lk, d)
-    dv = dv.transpose(1, 0, 2, 3).reshape(bh, lk, d)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    dvs = dvs.transpose(1, 0, 2, 3).reshape(bh, lk, dv)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dvs.astype(v.dtype)
 
 
 # ---------------------------------------------------------------------------
 # Public op
 # ---------------------------------------------------------------------------
 
-def _use_pallas(lq, lk, d):
+def _use_pallas(lq, lk, d, dv=None):
     """``(bq, bk)`` for :func:`_pallas_forward`, or None where the scan
-    runs: no kernel mode, or shapes the kernel does not tile."""
+    runs: no kernel mode, or shapes the kernel does not tile (``dv``: the
+    head dim of V where it is not ``d``)."""
     if kernel_mode() is None:
         return None
     import os
@@ -339,7 +346,7 @@ def _use_pallas(lq, lk, d):
     bk = _pick_block(lk, pref_k)
     # d=64 is fine: Mosaic pads the lane dim; BERT-base heads (768/12) hit
     # this. Verified on TPU v5e vs the scan path (max abs diff 1.8e-7 f32).
-    if bq is None or bk is None or d % 64:
+    if bq is None or bk is None or d % 64 or (dv or d) % 64:
         return None
     return bq, bk
 
@@ -375,7 +382,7 @@ def _flash(q, k, v, causal, sm_scale):
 
 
 def _flash_fwd(q, k, v, causal, sm_scale):
-    blocks = _use_pallas(q.shape[1], k.shape[1], q.shape[2])
+    blocks = _use_pallas(q.shape[1], k.shape[1], q.shape[2], v.shape[2])
     # counted while tracing: one per attention layer of a compiled program
     _telem.inc("flash.fwd.scan" if blocks is None else "flash.fwd.pallas")
     if blocks is not None:
@@ -400,7 +407,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(query, key, value, causal=False, sm_scale=None):
     """softmax(QK^T * sm_scale [+ causal mask]) V without materializing the
-    score matrix. query/key/value: (B, H, L, D) NDArrays or jax arrays.
+    score matrix. query/key: (B, H, L, D), value: (B, H, L, Dv) NDArrays or
+    jax arrays; Dv may differ from D (latent attention: 192 / 128) and the
+    result is (B, H, L, Dv).  ``sm_scale`` defaults to D ** -0.5.
 
     Differentiable (custom VJP, blockwise backward) and tape-aware: with
     NDArray inputs under ``autograd.record()`` it records one tape node.
@@ -417,11 +426,11 @@ def flash_attention(query, key, value, causal=False, sm_scale=None):
             raise ValueError("flash_attention expects (B, H, L, D) inputs, "
                              f"got shape {qd.shape}")
         b, h, lq, d = qd.shape
-        lk = kd.shape[2]
+        lk, dv = kd.shape[2], vd.shape[3]
         scale = 1.0 / math.sqrt(d) if sm_scale is None else float(sm_scale)
         out = _flash(qd.reshape(b * h, lq, d), kd.reshape(b * h, lk, d),
-                     vd.reshape(b * h, lk, d), bool(causal), scale)
-        return out.reshape(b, h, lq, d)
+                     vd.reshape(b * h, lk, dv), bool(causal), scale)
+        return out.reshape(b, h, lq, dv)
 
     if isinstance(query, NDArray):
         key = key if isinstance(key, NDArray) else NDArray(jnp.asarray(key))

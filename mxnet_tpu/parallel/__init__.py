@@ -38,5 +38,6 @@ from .pipeline_parallel import (pipeline_apply, stack_stage_params,
                                 Pipeline, one_f_one_b_schedule,
                                 bubble_fraction, split_into_stages,
                                 PipelineStageExecutor)
-from .moe import moe_apply, MoEDense, load_balance_loss
+from .moe import (moe_apply, MoEDense, load_balance_loss,
+                  route_sigmoid_top_k, dropless_moe_apply)
 from . import ps

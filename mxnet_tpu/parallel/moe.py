@@ -1,24 +1,39 @@
-"""Mixture-of-Experts with expert parallelism over an 'ep' mesh axis.
+"""Mixture-of-experts layers: two of them, for two regimes.
 
-Reference capability (SURVEY.md §2.5 "EP/MoE" row — absent upstream as a
-first-class layer, present here because MoE is a headline TPU workload).
-GShard-style top-k routing with static capacity: dispatch/combine are
-einsums over a (tokens, experts, capacity) one-hot, so every shape is
-static and XLA shards the expert dimension over 'ep' — the all-to-all
-falls out of the sharding algebra instead of being hand-written.
+1. ``moe_apply`` / ``MoEDense`` — GShard top-1 routing with a static
+   capacity (SURVEY.md §2.5 "EP/MoE" row; absent upstream).  Dispatch and
+   combine are einsums over a dense (tokens, experts, capacity) one-hot, so
+   every shape is static, tokens over an expert's capacity are dropped, and
+   with the expert weights sharded ``P('ep', ...)`` XLA derives the
+   all-to-all from the sharding algebra.  Functional parameters, not a
+   ``HybridBlock``; used by the multi-chip dry run.
 
-Functional core (``moe_apply``) + a gluon ``MoEDense`` block whose expert
-weights carry a ``P('ep', ...)`` shard spec for the fused trainer.
+2. ``route_sigmoid_top_k`` / ``dropless_moe_apply`` — dropless top-k routing
+   for a layer that holds a share of the experts (DeepSeek-V3-style: sigmoid
+   scores, a selection bias, normalised and scaled weights).  The layer is
+   told which ``held`` of the ``n_routed_experts`` live here
+   (``expert_offset``), routes over all of them, and computes its own
+   experts' part of the result; assignments to absent experts are left out.
+   No capacity, no drop: assignments are sorted by expert, the rows of held
+   experts gathered into a buffer that takes the worst case
+   (``tokens * min(top_k, held)`` rows), and the expert products run over
+   ``group_sizes`` (``ops.grouped_matmul``), doing work for the rows that
+   are routed, not for the buffer.  On one chip it runs without its
+   exchange; nothing here stands in for absent chips.  The Gluon block
+   around it is ``gluon.model_zoo.nlp.deepseek_v3.MoEBlock``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from .. import telemetry as _telem
 from ..base import MXNetError
 
-__all__ = ["moe_apply", "MoEDense", "load_balance_loss"]
+__all__ = ["moe_apply", "MoEDense", "load_balance_loss",
+           "route_sigmoid_top_k", "dropless_moe_apply", "buffer_rows"]
 
 
 def _top1_dispatch(logits, capacity):
@@ -115,3 +130,146 @@ class MoEDense:
                            params["w_down"],
                            capacity_factor=self.capacity_factor)
         return y.reshape(lead + (x.shape[-1],)), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k over a held share of the experts
+# ---------------------------------------------------------------------------
+
+def route_sigmoid_top_k(x, router_w, bias, top_k, scale=1.0,
+                        norm_topk_prob=True):
+    """DeepSeek-V3's ``noaux_tc`` router with trivial groups.
+
+    x: (T, d); router_w: (E, d), one row an expert (the ``nn.Dense``
+    layout); bias: (E,), the ``e_score_correction_bias``, which takes part
+    in the selection only.  Scores are ``sigmoid(x @ router_w.T)`` in
+    float32 at full matmul precision; the ``top_k`` of ``scores + bias`` are
+    chosen; the weights are the *unbiased* scores of the chosen, divided by
+    their sum (``norm_topk_prob``) and multiplied by ``scale``.  Returns
+    ``(experts (T, top_k) int32, weights (T, top_k) float32)``."""
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+            precision=lax.Precision.HIGHEST))
+        _, experts = lax.top_k(
+            lax.stop_gradient(scores) + bias.astype(jnp.float32), top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if norm_topk_prob:
+            weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        return experts.astype(jnp.int32), weights * scale
+
+
+def buffer_rows(tokens, top_k, held):
+    """Rows of the dispatch buffer: the worst case, every token sending all
+    it can (it chooses ``top_k`` distinct experts) to experts held here."""
+    return tokens * min(top_k, held)
+
+
+# The plan of one layer's dispatch, a tuple of index arrays (no gradient):
+#   choice_of_row (R,)   the flat choice t * k + j that sits in each row
+#   row_is_routed (R,)   False for the rows past the last routed one
+#   row_of_choice (T, k) where each choice landed (0 where not held)
+#   choice_is_held (T, k)
+
+@jax.custom_vjp
+def _dispatch(x, plan):
+    """``buffer[r] = x[token of the choice in row r]`` for the routed rows,
+    zeros past them.  Its transpose is written as a gather too (a row of
+    the buffer belongs to exactly one choice), which the scatter-add that
+    autodiff would derive is not."""
+    choice_of_row, row_is_routed, row_of_choice, _ = plan
+    k = row_of_choice.shape[1]
+    return jnp.where(row_is_routed[:, None], x[choice_of_row // k], 0)
+
+
+def _dispatch_fwd(x, plan):
+    return _dispatch(x, plan), plan
+
+
+def _dispatch_bwd(plan, g):
+    _, _, row_of_choice, choice_is_held = plan
+    dx = jnp.sum(jnp.where(choice_is_held[..., None],
+                           g[row_of_choice].astype(jnp.float32), 0), axis=1)
+    return dx.astype(g.dtype), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(buffer, weights, plan):
+    """``out[t] = sum_j weights[t, j] * buffer[row_of_choice[t, j]]`` over
+    the choices held here, summed in float32."""
+    _, _, row_of_choice, choice_is_held = plan
+    picked = buffer[row_of_choice].astype(jnp.float32)     # (T, k, d)
+    w = jnp.where(choice_is_held, weights.astype(jnp.float32), 0)
+    return jnp.sum(picked * w[..., None], axis=1).astype(buffer.dtype)
+
+
+def _combine_fwd(buffer, weights, plan):
+    return _combine(buffer, weights, plan), (buffer, weights, plan)
+
+
+def _combine_bwd(res, g):
+    buffer, weights, plan = res
+    choice_of_row, row_is_routed, row_of_choice, choice_is_held = plan
+    k = weights.shape[1]
+    w_of_row = jnp.where(row_is_routed,
+                         weights.reshape(-1)[choice_of_row], 0)
+    dbuffer = (g[choice_of_row // k].astype(jnp.float32)
+               * w_of_row.astype(jnp.float32)[:, None]).astype(buffer.dtype)
+    dweights = jnp.where(
+        choice_is_held,
+        jnp.sum(buffer[row_of_choice].astype(jnp.float32)
+                * g.astype(jnp.float32)[:, None, :], axis=-1), 0)
+    return dbuffer, dweights.astype(weights.dtype), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dropless_moe_apply(x, experts, weights, w_gate, w_up, w_down, *,
+                       expert_offset=0):
+    """The routed experts' part of a SwiGLU expert layer, for the experts
+    held here.
+
+    x: (T, d); experts: (T, k) int32 ids over all routed experts; weights:
+    (T, k); w_gate, w_up: (held, d, h); w_down: (held, h, d), the weights of
+    experts ``expert_offset .. expert_offset + held``.  Returns (T, d):
+    ``sum_{j: experts[t, j] held} weights[t, j] * E_j(x[t])``.  A choice of
+    an absent expert adds nothing; no choice of a held expert is dropped.
+    """
+    from ..ops.grouped_matmul import grouped_matmul
+    tokens, k = experts.shape
+    held = w_gate.shape[0]
+    rows = buffer_rows(tokens, k, held)
+    _telem.inc("moe.layers")
+    _telem.set_gauge("moe.experts_held", held)
+    _telem.set_gauge("moe.rows_buffer", rows)
+    _telem.set_gauge("moe.top_k", k)
+
+    with jax.named_scope("moe.dispatch"):
+        local = experts - expert_offset
+        choice_is_held = (local >= 0) & (local < held)          # (T, k)
+        # absent experts sort behind every held one; the sort is stable, so
+        # inside an expert's group the rows stay in token order
+        key = jnp.where(choice_is_held, local, held).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=key.dtype)[None],
+            axis=0, dtype=jnp.int32)
+        # where each choice landed: the inverse of the sort
+        row_of_choice = jnp.zeros(tokens * k, jnp.int32).at[order].set(
+            jnp.arange(tokens * k, dtype=jnp.int32),
+            unique_indices=True).reshape(tokens, k)
+        row_of_choice = jnp.where(choice_is_held, row_of_choice, 0)
+        row_is_routed = jnp.arange(rows, dtype=jnp.int32) < \
+            jnp.sum(group_sizes)
+        plan = (order[:rows], row_is_routed, row_of_choice, choice_is_held)
+        buffer = _dispatch(x, plan)
+    with jax.named_scope("moe.experts"):
+        gate = grouped_matmul(buffer, w_gate, group_sizes)
+        up = grouped_matmul(buffer, w_up, group_sizes)
+        out = grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
+    with jax.named_scope("moe.combine"):
+        return _combine(out, weights, plan)

@@ -577,8 +577,9 @@ class InferenceEngine:
         import jax
         import jax.numpy as jnp
         from jax import lax
-        from ..gluon.model_zoo.nlp.llama import (_QPAD, _rms,
-                                                 _rot_interleaved)
+        from ..gluon.model_zoo.nlp.llama import _QPAD
+        from ..ops.norm_rope import rms_norm as _rms, \
+            rope_interleaved as _rot_interleaved
         from ..ops import quant_kv as _qkv
         from ..ops.flash_attention import flash_attention
         cfg = self.cfg
@@ -672,8 +673,9 @@ class InferenceEngine:
         storage rounding of PAST tokens' K/V."""
         import jax
         import jax.numpy as jnp
-        from ..gluon.model_zoo.nlp.llama import (_cache_attention, _rms,
-                                                 _rot_interleaved)
+        from ..gluon.model_zoo.nlp.llama import _cache_attention
+        from ..ops.norm_rope import rms_norm as _rms, \
+            rope_interleaved as _rot_interleaved
         from ..ops import quant_kv as _qkv
         cfg = self.cfg
         h, kvh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -829,7 +831,8 @@ class InferenceEngine:
         import jax
         import jax.numpy as jnp
         from jax import lax
-        from ..gluon.model_zoo.nlp.llama import _rms, _rot_interleaved
+        from ..ops.norm_rope import rms_norm as _rms, \
+            rope_interleaved as _rot_interleaved
         from ..ops.flash_attention import _NEG_INF, _pick_block
         cfg = self.cfg
         h, kvh, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim

@@ -39,6 +39,56 @@ def test_fp32_ops_cast_up(amp_bf16):
     assert str(out.data.dtype) == "float32"
 
 
+def test_latent_attention_and_expert_ops_are_placed(amp_bf16):
+    """The ops of model_zoo.nlp.deepseek_v3: the router's scores and RMSNorm
+    run in float32 whatever arrives, latent attention and the routed
+    experts' grouped products in bfloat16 (float32 parameters cast at the
+    op, as FullyConnected's are)."""
+    from mxnet_tpu.amp import lists
+    assert {"mla_attention", "moe_experts"} <= set(lists.TARGET_DTYPE_OPS)
+    assert {"rms_norm", "moe_router"} <= set(lists.FP32_OPS)
+    x16 = nd.random.uniform(shape=(2, 8, 16)).astype("bfloat16")
+    normed = nd.rms_norm(x16, nd.ones((16,)), eps=1e-6)
+    assert str(normed.data.dtype) == "float32"
+    chosen, gates = nd.moe_router(x16, nd.random.uniform(shape=(4, 16)),
+                                  nd.zeros((4,)), top_k=2)
+    assert str(chosen.data.dtype) == str(gates.data.dtype) == "float32"
+    assert chosen.shape == gates.shape == (2, 8, 2)
+    assert (gates.asnumpy() > 0).all()
+    # the ids and the combine weights reach the expert op as the router made
+    # them (float32) ...
+    w = [nd.random.uniform(shape=(4, 16, 8)),
+         nd.random.uniform(shape=(4, 16, 8)),
+         nd.random.uniform(shape=(4, 8, 16))]
+    fine = gates * 0 + (1 + 2.0 ** -10)
+    experts = nd.moe_experts(normed, chosen, fine, *w)
+    assert str(experts.data.dtype) == "bfloat16"
+    assert experts.shape == (2, 8, 16)
+    # ... because amp leaves the two arguments lists.KEEP_DTYPE_ARGS names as
+    # they arrive, by position and by keyword, and casts the rest
+    assert lists.KEEP_DTYPE_ARGS["moe_experts"] == ("experts", "weights")
+    seen = {}
+    spy = amp._wrap(
+        lambda data, experts, weights: seen.update(
+            data=data, experts=experts, weights=weights),
+        "low", "bfloat16", lists.KEEP_DTYPE_ARGS["moe_experts"])
+    spy(normed, chosen, weights=fine)
+    assert [str(seen[k].data.dtype) for k in ("data", "experts", "weights")] \
+        == ["bfloat16", "float32", "float32"]
+    attn = nd.mla_attention(
+        nd.random.uniform(shape=(1, 8, 2 * 12)),
+        nd.random.uniform(shape=(1, 8, 2 * 16)),
+        nd.random.uniform(shape=(1, 8, 4)), num_heads=2, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8)
+    assert str(attn.data.dtype) == "bfloat16"
+    assert attn.shape == (1, 8, 16)
+    # and the model zoo's RMSNorm block goes through the placed op
+    from mxnet_tpu.gluon.model_zoo.nlp.llama import RMSNorm
+    norm = RMSNorm(16)
+    norm.initialize()
+    assert str(norm(x16).data.dtype) == "float32"
+
+
 def test_bf16_training_step(amp_bf16):
     net = gluon.nn.Dense(4)
     net.initialize()

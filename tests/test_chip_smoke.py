@@ -94,8 +94,11 @@ def test_rehearsal_passes(chip_smoke_rehearsal):
     lines = text.strip().splitlines()
     assert "REHEARSAL" in text
     for phase in ("1 context", "2a resnet", "2b resnet", "3 bert",
+                  "4 kernels flash_attention", "4 kernels grouped_matmul",
                   "4 kernels paged_decode_attention", "5 serve"):
         assert f"[{phase}" in text, phase
+    # latent attention's shape (Q.K over 192, V at 128) went through too
+    assert "(B*H,L,D,Dv)=(2,128,192,128) bf16 causal=True" in text
     assert json.loads(lines[-1]) == {
         "ok": True, "rehearsal": True,
         "device": {"platform": "cpu", "kind": "cpu", "count": 1}}

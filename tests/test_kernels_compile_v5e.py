@@ -1,0 +1,81 @@
+"""The Pallas kernels of the kanana cell, compiled at the cell's own shapes
+for a *described* v5e (no chip attached): Mosaic runs inside that compile, so
+a block it will not tile or more VMEM than a kernel may use fails here, at no
+chip time.  Nothing runs; nothing here is a time.
+
+The topology is described inside a fixture, never at import (one process at
+a time may load the TPU's library: on-chip-measurement guide, section 2), and
+this is the one file that does it."""
+import os
+from unittest import mock
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    env = {"TPU_LOG_DIR": "disabled", "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+           "TPU_WORKER_HOSTNAMES": "localhost", "TPU_SKIP_MDS_QUERY": "1"}
+    with mock.patch.dict(os.environ, {k: v for k, v in env.items()
+                                      if k not in os.environ}):
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - whatever stops it, skip
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    """Compile for the described chip with the persistent cache off (an
+    entry written by such a compile cannot be read back without a chip) and
+    the kernels' backend probe answering as it would there."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            return jax.jit(fn).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_flash_forward_and_backward_at_the_mla_shape(one_chip):
+    from mxnet_tpu.ops import flash_attention
+
+    def shape(d):
+        return jax.ShapeDtypeStruct((2, 32, 4096, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a, causal=True).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+    text = _compile(grads, shape(192), shape(192), shape(128))
+    assert text.count("tpu_custom_call") == 1
+    assert "mxtpu_flash_fwd" in text
+
+
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
+def test_grouped_product_and_both_backward_kernels_at_the_expert_widths(
+        one_chip, k, n):
+    from mxnet_tpu.ops.grouped_matmul import grouped_matmul
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def all_three(lhs, rhs, sizes):
+        out, vjp = jax.vjp(lambda a, b: grouped_matmul(a, b, sizes), lhs,
+                           rhs)
+        return (out,) + vjp(out)
+    text = _compile(all_three, shaped((49152, k)), shaped((16, k, n)),
+                    shaped((16,), jnp.int32))
+    assert text.count("tpu_custom_call") == 3
+    for name in ("mxtpu_gmm", "mxtpu_gmm_dlhs", "mxtpu_gmm_drhs"):
+        assert name in text
