@@ -56,6 +56,11 @@ SIZES = {
         flash_shapes=((768, 128, 64, 64, (False, True)),
                       (8, 2048, 128, 128, (False, True)),
                       (64, 4096, 192, 128, (True,))),
+        # (B*H, L, D, Dv, causal) of the backward kernel against the scan:
+        # the attention calls of the benchmark's BERT and kanana cells
+        flash_bwd_shapes=((1536, 128, 64, 64, False),
+                          (384, 512, 64, 64, False),
+                          (64, 4096, 192, 128, True)),
         # the kanana cell's expert products: a worst-case buffer of which an
         # eighth is routed, 16 experts held
         gmm=dict(rows=49152, routed=6144, groups=16, k=2048, n=768),
@@ -76,6 +81,8 @@ SIZES = {
         bert_batch=2, bert_seq=128,
         flash_shapes=((2, 128, 64, 64, (False, True)),
                       (2, 128, 192, 128, (True,))),
+        flash_bwd_shapes=((2, 128, 64, 64, False),
+                          (2, 128, 192, 128, True)),
         gmm=dict(rows=256, routed=150, groups=4, k=128, n=128),
         ln_rows=32, ln_dim=128,
         bucket_elems=20_000,
@@ -408,20 +415,23 @@ def phase_bert(run):
         assert_finite("loss", l)
     assert len(set(losses)) > 1, \
         f"the loss never moved on a fixed batch: {losses}"
-    calls = mosaic_calls(trainer.compiled_step_text(*data), "mxtpu_flash_fwd")
+    hlo = trainer.compiled_step_text(*data)
+    calls = mosaic_calls(hlo, "mxtpu_flash_fwd")
+    bwd_calls = mosaic_calls(hlo, "mxtpu_flash_bwd")
     if run.rehearsal:
         say(ph, "REHEARSAL: no Mosaic on the CPU, compiled step not checked")
     else:
         layers = s["bert"]["num_layers"]
-        assert len(calls) >= layers, \
-            f"{len(calls)} flash Mosaic calls in the compiled step, " \
-            f"expected one for each of {layers} layers"
+        assert len(calls) >= layers and len(bwd_calls) >= layers, \
+            f"{len(calls)} forward and {len(bwd_calls)} backward flash " \
+            f"Mosaic calls in the compiled step, expected one of each for " \
+            f"each of {layers} layers"
     run.record["bert_losses"] = losses
     say(ph, f"layers={s['bert']['num_layers']} units={s['bert']['units']} "
             f"batch={batch} seq={s['bert_seq']} bf16 adam "
             f"first_call_s={first:.2f} steady_step_s={steady:.4f} "
             f"compile_s~={first - steady:.2f} "
-            f"flash_mosaic_calls_in_step={len(calls)} "
+            f"flash_mosaic_calls_in_step={len(calls)}+{len(bwd_calls)} "
             f"losses={[round(l, 4) for l in losses]}")
     memory_line(ph)
 
@@ -492,6 +502,9 @@ def _kernels_flash(run):
                 run_one(lambda q, k, v: flash_attention(q, k, v,
                                                         causal=causal)),
                 q, k, v)
+            assert run.rehearsal or mosaic_calls(exe.as_text(),
+                                                 "mxtpu_flash_bwd"), \
+                "no Mosaic call of mxtpu_flash_bwd in the compiled gradient"
             (_, out), grads = exe(q, k, v)
             (_, ref), ref_grads = jax.jit(run_one(
                 lambda q, k, v: naive(q, k, v, causal)))(q, k, v)
@@ -504,6 +517,49 @@ def _kernels_flash(run):
                     f"compile_s={dt:.2f} max_abs_err out,dq,dk,dv="
                     f"{[float(f'{e:.2e}') for e in errs]} "
                     f"(tolerance 2e-2 x scale)")
+
+
+def _kernels_flash_backward(run):
+    """``mxtpu_flash_bwd`` against the float32 scan it replaced
+    (``_scan_backward``, what runs wherever there is no kernel), from the
+    forward kernel's own residuals."""
+    import importlib
+    import jax
+    import jax.numpy as jnp
+    mod = importlib.import_module("mxnet_tpu.ops.flash_attention")
+    ph = "4 kernels flash_attention backward"
+    for bh, L, d, dv, causal in run.sizes["flash_bwd_shapes"]:
+        rng = np.random.RandomState(SEED)
+        q, k = (jnp.asarray(rng.randn(bh, L, d), jnp.bfloat16)
+                for _ in range(2))
+        v, do = (jnp.asarray(rng.randn(bh, L, dv), jnp.bfloat16)
+                 for _ in range(2))
+        kw = dict(causal=causal, sm_scale=d ** -0.5)
+        blocks = dict(zip(("bq", "bk"), mod._use_pallas(L, L, d, dv)),
+                      interpret=run.rehearsal)
+        out, lse = jax.jit(lambda q, k, v: mod._pallas_forward(
+            q, k, v, **kw, **blocks))(q, k, v)
+        exe, dt = _compiled(
+            run, "mxtpu_flash_bwd",
+            lambda *a: mod._pallas_backward(*a, **kw, **blocks),
+            q, k, v, out, lse, do)
+        got = exe(q, k, v, out, lse, do)
+        want = jax.jit(lambda *a: mod._scan_backward(
+            *a, **kw, bk=mod._pick_block(L, 256)))(q, k, v, out, lse, do)
+        # P and dS enter the MXU in bf16 where the scan keeps them float32:
+        # 2 bf16 ulps of the largest reference value, as the forward's line
+        errs = [assert_close(f"flash bwd d{n}", a, b, 2e-2)
+                for n, a, b in zip("qkv", got, want)]
+        t0 = time.perf_counter()
+        for _ in range(10):
+            got = exe(q, k, v, out, lse, do)
+        jax.block_until_ready(got)
+        say(ph, f"(B*H,L,D,Dv)=({bh},{L},{d},{dv}) bf16 causal={causal} "
+                f"blocks={blocks['bq']}x{blocks['bk']} compile_s={dt:.2f} "
+                f"bwd_ms={(time.perf_counter() - t0) * 100:.3f} "
+                f"max_abs_err dq,dk,dv vs scan="
+                f"{[float(f'{e:.2e}') for e in errs]} "
+                f"(tolerance 2e-2 x scale)")
 
 
 def _kernels_gmm(run):
@@ -712,6 +768,7 @@ def _kernels_paged(run):
 
 def phase_kernels(run):
     _kernels_flash(run)
+    _kernels_flash_backward(run)
     _kernels_gmm(run)
     _kernels_layernorm(run)
     _kernels_bucket_update(run)
@@ -878,12 +935,14 @@ def phase_all_chips(run):
         assert trainer.comm_stats()["zero1"] == shard
         hlo = trainer.compiled_step_text(*data)
         flash = len(mosaic_calls(hlo, "mxtpu_flash_fwd"))
+        flash_bwd = len(mosaic_calls(hlo, "mxtpu_flash_bwd"))
         in_step = len(mosaic_calls(hlo, "mxtpu_bucket_adam"))
         if not run.rehearsal:
             # XLA partitions the replicated step itself and cannot
-            # partition a Mosaic call: the kernel is there because the op
-            # wrapped it in a shard_map (ops/flash_attention.py)
+            # partition a Mosaic call: the kernels are there because the op
+            # wrapped each in a shard_map (ops/flash_attention.py)
             assert flash >= s["bert"]["num_layers"], flash
+            assert flash_bwd >= s["bert"]["num_layers"], flash_bwd
         if shard:
             # every vector leaf is a bucket cut in n equal shards
             assert per_chip * n == total, (per_chip, n, total)
@@ -900,7 +959,7 @@ def phase_all_chips(run):
                 f"shard_updates={shard} first_call_s={first:.2f} "
                 f"steady_step_s={steady:.4f} "
                 f"opt_state_elems_per_chip={per_chip} of {total} "
-                f"flash_mosaic_calls_in_step={flash} "
+                f"flash_mosaic_calls_in_step={flash}+{flash_bwd} "
                 f"bucket_update_mosaic_calls_in_step={in_step} "
                 f"bytes_in_use={used} "
                 f"losses={[round(l, 4) for l in losses]}")

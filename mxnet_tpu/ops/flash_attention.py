@@ -22,8 +22,20 @@ same algorithm as a ``lax.scan`` over KV blocks, which XLA fuses
 adequately on CPU and keeps memory O(L*BK).
 
 Gradients: custom VJP; the backward pass recomputes scores blockwise from
-the saved logsumexp (standard flash-attention backward), also as a scan —
-no O(L^2) residuals are ever stored.
+the saved logsumexp (standard flash-attention backward) — no O(L^2)
+residuals are ever stored.  Wherever the forward gets its kernel the
+backward is a Pallas kernel too (``mxtpu_flash_bwd``,
+:func:`_pallas_backward`): the same (rows, D, L) operands and transposed
+scores, bf16 into the MXU and float32 accumulation, one program computing
+dV, dK and dQ of a (BK, BQ) block of scores from one S and one dP — five
+products, where a dK/dV kernel and a dQ kernel would take seven.  One pass
+where a block holds all of L; otherwise the Q blocks are walked inside each
+KV block, dK / dV accumulate over the inner walk, the row's dQ in a float32
+VMEM scratch over the outer one, and causal blocks that are wholly masked
+are skipped.  G is the forward's rule over the backward's own VMEM
+arithmetic (:func:`_backward_rows_per_program`).  Everywhere else, and
+where one row's dQ does not fit VMEM, it is the float32 scan
+(:func:`_scan_backward`), which is also the tests' reference.
 """
 from __future__ import annotations
 
@@ -72,6 +84,13 @@ _VMEM_DEFAULT_LIMIT = 16 * 2 ** 20
 _PROGRAM_HBM_BYTES = 2 * 2 ** 20
 
 
+def _padded_head_dims(d, dv, itemsize):
+    """``d + dv`` as a block holds them: the head dim is on sublanes, padded
+    to the dtype's tile (8 float32 rows, 16 bf16)."""
+    sublanes = 32 // itemsize
+    return sum(-(-x // sublanes) * sublanes for x in (d, dv))
+
+
 def _program_vmem_bytes(g, bq, bk, d, itemsize, streaming, dv=None):
     """VMEM bytes one program of ``g`` rows holds: the double-buffered
     Q/K blocks at ``d`` and V/O blocks at ``dv`` (the head dim on sublanes,
@@ -79,33 +98,40 @@ def _program_vmem_bytes(g, bq, bk, d, itemsize, streaming, dv=None):
     of the streaming body and the (g, bk, bq) score / probability
     temporaries with the float32 P.V."""
     dv = d if dv is None else dv
-    sublanes = 32 // itemsize                  # 8 float32 rows, 16 bf16
-    dp = -(-d // sublanes) * sublanes
-    dvp = -(-dv // sublanes) * sublanes
-    blocks = 2 * g * (dp + dvp) * (bq + bk) * itemsize
+    blocks = 2 * g * _padded_head_dims(d, dv, itemsize) * (bq + bk) * itemsize
     lse = 2 * 8 * -(-g // 8) * bq * 4
     scratch = g * (dv + 2 * 8) * bq * 4 if streaming else 0
     temps = g * bq * (bk * (4 + 4 + itemsize) + dv * 4)
     return blocks + lse + scratch + temps
 
 
+def _pick_rows(bh, vmem_bytes, row_bytes):
+    """The divisor of ``bh`` a kernel gives each grid program: one whose
+    blocks fit ``_VMEM_BUDGET`` by ``vmem_bytes(g)`` (a multiple of 8 where
+    one fits), the smallest that moves ``_PROGRAM_HBM_BYTES`` at
+    ``row_bytes`` a row or else the largest that fits; 1 where nothing
+    fits."""
+    fits = [g for g in range(1, bh + 1)
+            if bh % g == 0 and vmem_bytes(g) <= _VMEM_BUDGET] or [1]
+    pool = [g for g in fits if g % 8 == 0] or fits
+    for g in pool:
+        if g * row_bytes >= _PROGRAM_HBM_BYTES:
+            return g
+    return pool[-1]
+
+
 def _rows_per_program(bh, bq, bk, d, itemsize, streaming, dv=None):
-    """G, the (batch x head) rows one grid program takes: a divisor of
-    ``bh`` (a multiple of 8 where one fits: the log-sum-exp block is then
+    """G, the (batch x head) rows one forward grid program takes: a divisor
+    of ``bh`` (a multiple of 8 where one fits: the log-sum-exp block is then
     (G, bq), rows on sublanes) whose blocks fit ``_VMEM_BUDGET``, the
     smallest that moves ``_PROGRAM_HBM_BYTES`` or else the largest that
     fits.  A function of the shapes and the dtype alone; ``dv`` is the head
     dim of V and O where it is not Q's and K's ``d``."""
     dv = d if dv is None else dv
-    fits = [g for g in range(1, bh + 1) if bh % g == 0 and
-            _program_vmem_bytes(g, bq, bk, d, itemsize, streaming, dv)
-            <= _VMEM_BUDGET] or [1]
-    pool = [g for g in fits if g % 8 == 0] or fits
-    row_bytes = (bq + bk) * (d + dv) * itemsize
-    for g in pool:
-        if g * row_bytes >= _PROGRAM_HBM_BYTES:
-            return g
-    return pool[-1]
+    return _pick_rows(
+        bh, lambda g: _program_vmem_bytes(g, bq, bk, d, itemsize, streaming,
+                                          dv),
+        (bq + bk) * (d + dv) * itemsize)
 
 
 def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
@@ -122,6 +148,21 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     # rows on sublanes where G fills whole tiles; otherwise mosaic's
     # (8, 128) tile is met by a broadcast sublane dim, sliced off below
     lse_rows = g % 8 == 0
+
+    def in_hbm(x):
+        # Q, K, V, O and the log-sum-exp stream between HBM and the
+        # program's blocks, which is what G and the kernel's bytes are
+        # reckoned from: XLA, left to choose, parks a whole operand in VMEM
+        # (prefetched beside the op before), and the kernel then runs ahead
+        # of the HBM rate it is held against
+        # (inside a compiled program only: an eager call's operands are
+        # its program's own arguments, in HBM, and the constraint is no
+        # eager operation)
+        if interpret or not isinstance(q, jax.core.Tracer):
+            return x
+        if isinstance(x, jax.ShapeDtypeStruct):
+            return pltpu.HBM(x.shape, x.dtype)
+        return pltpu.with_memory_space_constraint(x, pltpu.HBM)
 
     def operand(x):
         # the CPU backend has no batched bf16 x bf16 -> f32 dot, so the
@@ -214,10 +255,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
             pl.BlockSpec((g, dv, bq), lambda b, i, j: (b, 0, i)),
             lse_spec,
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, dv, lq), q.dtype),
-            lse_shape,
-        ],
+        out_shape=[in_hbm(jax.ShapeDtypeStruct((bh, dv, lq), q.dtype)),
+                   in_hbm(lse_shape)],
         scratch_shapes=[] if nk == 1 else [
             pltpu.VMEM((g, dv, bq), jnp.float32),
             pltpu.VMEM((g, 1, bq), jnp.float32),
@@ -228,7 +267,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
             vmem_limit_bytes=_VMEM_DEFAULT_LIMIT + over if over > 0 else None),
         name="mxtpu_flash_fwd",
         interpret=interpret,
-    )(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)))
+    )(*(in_hbm(jnp.swapaxes(a, 1, 2)) for a in (q, k, v)))
     return jnp.swapaxes(out_t, 1, 2), lse if lse_rows else lse[:, 0, :]
 
 
@@ -273,7 +312,7 @@ def _scan_forward(q, k, v, causal, sm_scale, bk):
 
 
 # ---------------------------------------------------------------------------
-# Backward (blockwise, shared by both paths)
+# Backward as a scan (where there is no kernel; the tests' reference)
 # ---------------------------------------------------------------------------
 
 def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
@@ -308,6 +347,188 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
     dk = dk.transpose(1, 0, 2, 3).reshape(bh, lk, d)
     dvs = dvs.transpose(1, 0, 2, 3).reshape(bh, lk, dv)
     return dq.astype(q.dtype), dk.astype(k.dtype), dvs.astype(v.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Pallas TPU backward
+# ---------------------------------------------------------------------------
+
+def _backward_vmem_bytes(g, bq, bk, lq, d, itemsize, streaming, dv=None):
+    """VMEM bytes one backward program of ``g`` rows holds: the
+    double-buffered Q / dQ and O / dO blocks at ``bq``, K / dK and V / dV at
+    ``bk`` (twice the forward's), the log-sum-exp block, the float32
+    dK / dV accumulators and the row's whole dQ of the streaming body, and
+    the (g, bk, bq) S / P and dP / dS temporaries in float32 with their
+    casts, delta and the three float32 products."""
+    dv = d if dv is None else dv
+    blocks = 4 * g * _padded_head_dims(d, dv, itemsize) * (bq + bk) * itemsize
+    lse = 2 * g * 8 * bq * 4
+    scratch = g * ((d + dv) * bk + d * lq) * 4 if streaming else 0
+    temps = g * (bk * bq * (4 + 4 + 2 * itemsize) + 8 * bq * 4
+                 + ((d + dv) * bk + d * bq) * 4)
+    return blocks + lse + scratch + temps
+
+
+def _backward_rows_per_program(bh, bq, bk, lq, d, itemsize, streaming,
+                               dv=None):
+    """G of the backward kernel, by the forward's rule (:func:`_pick_rows`)
+    over :func:`_backward_vmem_bytes`; a row moves eight blocks where the
+    forward moves four."""
+    dv = d if dv is None else dv
+    return _pick_rows(
+        bh, lambda g: _backward_vmem_bytes(g, bq, bk, lq, d, itemsize,
+                                           streaming, dv),
+        2 * (bq + bk) * (d + dv) * itemsize)
+
+
+def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
+                     interpret=False):
+    """dQ, dK, dV of the kernel's forward, operands as (rows, D, L) and the
+    scores transposed like the forward's (keys on sublanes, queries on
+    lanes; ``lse`` and ``delta = rowsum(O dO)`` are lane-major rows).  One
+    program takes ``g`` rows and a (bk, bq) block of scores: S and dP from
+    two products, dV, dK and dQ from three more, bf16 operands and float32
+    accumulation.  Where one block holds all of Lq and Lk that is the whole
+    kernel; otherwise the grid walks the Q blocks inside each KV block,
+    dK / dV accumulate over the inner walk and the row's dQ over the outer
+    one (a float32 scratch of the whole row, written out on the last KV
+    block), and a causal block that is wholly masked is skipped: it would
+    add exact zeros."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, lq, d = q.shape
+    lk, dv = k.shape[1], v.shape[2]
+    nq, nk = lq // bq, lk // bk
+    one = nq == 1 and nk == 1
+    # from the rows this call sees: a chip's own, inside _per_batch_shard
+    g = _backward_rows_per_program(bh, bq, bk, lq, d, q.dtype.itemsize,
+                                   not one, dv)
+    _telem.set_gauge("flash.bwd.rows_per_program", g)
+
+    def dot(a, b, contract):
+        # batched over the g rows; float32 in the interpreter, as the forward
+        if interpret:
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        return lax.dot_general(a, b, (contract, ((0,), (0,))),
+                               preferred_element_type=jnp.float32)
+
+    def products(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, i, j, mask):
+        """The block's dV, dK and dQ in float32, dK and dQ still without
+        ``sm_scale`` (applied to the small results, not to the scores)."""
+        qb, kb, vb, dob = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
+        # (g, d, bk) x (g, d, bq) over d -> (g, bk, bq)
+        s = dot(kb, qb, ((1,), (1,))) * sm_scale
+        if mask:
+            kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+            qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[...])                      # lse (g, 1, bq)
+        delta = jnp.sum(o_ref[...].astype(jnp.float32) *
+                        dob.astype(jnp.float32), axis=1, keepdims=True)
+        ds = p * (dot(vb, dob, ((1,), (1,))) - delta)
+        p, ds = p.astype(qb.dtype), ds.astype(qb.dtype)
+        return (dot(dob, p, ((2,), (2,))),                 # (g, dv, bk)
+                dot(qb, ds, ((2,), (2,))),                 # (g, d, bk)
+                dot(kb, ds, ((2,), (1,))))                 # (g, d, bq)
+
+    def one_pass(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
+                 dq_ref, dk_ref, dv_ref):
+        dvb, dkb, dqb = products(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
+                                 0, 0, causal)
+        dv_ref[...] = dvb.astype(dv_ref.dtype)
+        dk_ref[...] = (dkb * sm_scale).astype(dk_ref.dtype)
+        dq_ref[...] = (dqb * sm_scale).astype(dq_ref.dtype)
+
+    def streaming(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
+                  dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+        j = pl.program_id(1)                    # the KV block, outer
+        i = pl.program_id(2)                    # the Q block, inner
+
+        @pl.when(i == 0)
+        def _init_kv():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
+
+        @pl.when(j == 0)
+        def _init_q():
+            dq_acc[i] = jnp.zeros(dq_acc.shape[1:], dq_acc.dtype)
+
+        def step(mask):
+            dvb, dkb, dqb = products(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                                     do_ref, i, j, mask)
+            dv_acc[...] += dvb
+            dk_acc[...] += dkb
+            dq_acc[i] += dqb
+
+        if causal:
+            # wholly masked (its first key after the block's last query):
+            # skipped; cut by the diagonal: masked; wholly visible: plain
+            live = (i + 1) * bq > j * bk
+            cut = (j + 1) * bk - 1 > i * bq
+            pl.when(live & cut)(lambda: step(True))
+            pl.when(live & jnp.logical_not(cut))(lambda: step(False))
+        else:
+            step(False)
+
+        @pl.when(i == nq - 1)
+        def _fin_kv():
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+            dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+
+        @pl.when(j == nk - 1)
+        def _fin_q():
+            dq_ref[...] = (dq_acc[i] * sm_scale).astype(dq_ref.dtype)
+
+    def at_q(b, j, i):
+        # a skipped block asks for the first live one's Q-side blocks again
+        # (the last there is, where Lq ends before this KV block), so
+        # nothing is fetched for it
+        if causal:
+            i = jnp.minimum(jnp.maximum(i, (j * bk) // bq), nq - 1)
+        return (b, 0, i)
+
+    def at_kv(b, j, i):
+        return (b, 0, j)
+
+    def at_dq(b, j, i):
+        # parked on block 0 until the last KV block, when each Q block's sum
+        # is complete and is written out once
+        return (b, 0, jnp.where(j == nk - 1, i, 0))
+
+    dq_t, dk_t, dv_t = pl.pallas_call(
+        one_pass if one else streaming,
+        grid=(bh // g, nk, nq),
+        in_specs=[
+            pl.BlockSpec((g, d, bq), at_q),
+            pl.BlockSpec((g, d, bk), at_kv),
+            pl.BlockSpec((g, dv, bk), at_kv),
+            pl.BlockSpec((g, dv, bq), at_q),
+            pl.BlockSpec((g, 1, bq), at_q),
+            pl.BlockSpec((g, dv, bq), at_q),
+        ],
+        out_specs=[
+            pl.BlockSpec((g, d, bq), at_dq),
+            pl.BlockSpec((g, d, bk), at_kv),
+            pl.BlockSpec((g, dv, bk), at_kv),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, d, lq), q.dtype),
+            jax.ShapeDtypeStruct((bh, d, lk), k.dtype),
+            jax.ShapeDtypeStruct((bh, dv, lk), v.dtype),
+        ],
+        scratch_shapes=[] if one else [
+            pltpu.VMEM((nq, g, d, bq), jnp.float32),
+            pltpu.VMEM((g, d, bk), jnp.float32),
+            pltpu.VMEM((g, dv, bk), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="mxtpu_flash_bwd",
+        interpret=interpret,
+    )(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+      jnp.swapaxes(out, 1, 2), lse[:, None, :], jnp.swapaxes(do, 1, 2))
+    return tuple(jnp.swapaxes(a, 1, 2) for a in (dq_t, dk_t, dv_t))
 
 
 # ---------------------------------------------------------------------------
@@ -351,10 +572,11 @@ def _use_pallas(lq, lk, d, dv=None):
     return bq, bk
 
 
-def _per_batch_shard(kernel, q):
-    """``kernel``, wrapped in a shard_map over the data axis when this call
-    is being traced into a step that XLA partitions over that axis by itself
-    (the trainer's plain dp path: a jit over batch-sharded inputs).
+def _dp_mesh(q):
+    """The mesh whose data axis this call's kernels are split over by hand,
+    or None: set when the call is being traced into a step that XLA
+    partitions over that axis by itself (the trainer's plain dp path: a jit
+    over batch-sharded inputs).
 
     XLA refuses to partition a Mosaic call ("Mosaic kernels cannot be
     automatically partitioned. Please wrap the call in a shard_map"), so
@@ -363,25 +585,40 @@ def _per_batch_shard(kernel, q):
     (``parallel.mesh_scope``); a step that is already inside a shard_map
     (ZeRO-1) masks it with ``mesh_scope(None)``.  A mesh with a second axis
     of size > 1 is left to fail as before: only the leading dimension is
-    known to be splittable."""
+    known to be splittable.  Read once, where the op is called: the scope
+    covers the step's forward trace, and the backward, traced after it has
+    closed, is handed what the forward saw."""
     from ..parallel.mesh import AXIS_DP, current_mesh
     mesh = current_mesh()
     if mesh is None or not isinstance(q, jax.core.Tracer):
-        return kernel
+        return None
     n = mesh.shape.get(AXIS_DP, 1)
     if n == 1 or n != mesh.size or q.shape[0] % n:
+        return None
+    return mesh
+
+
+def _per_batch_shard(kernel, mesh):
+    """``kernel``, inside a shard_map over the data axis of ``mesh``
+    (:func:`_dp_mesh`) with every operand and result split over its leading
+    (batch x head) dimension; ``kernel`` itself where there is none."""
+    if mesh is None:
         return kernel
-    rows = P(AXIS_DP)
-    return shard_map(kernel, mesh=mesh, in_specs=(rows,) * 3,
-                     out_specs=(rows, rows), check_vma=False)
+    from ..parallel.mesh import AXIS_DP
+    return shard_map(kernel, mesh=mesh, in_specs=P(AXIS_DP),
+                     out_specs=P(AXIS_DP), check_vma=False)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash(q, k, v, causal, sm_scale):
-    return _flash_fwd(q, k, v, causal, sm_scale)[0]
+    return _flash_on(q, k, v, causal, sm_scale, _dp_mesh(q))
 
 
-def _flash_fwd(q, k, v, causal, sm_scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_on(q, k, v, causal, sm_scale, mesh):
+    return _flash_fwd(q, k, v, causal, sm_scale, mesh)[0]
+
+
+def _flash_fwd(q, k, v, causal, sm_scale, mesh):
     blocks = _use_pallas(q.shape[1], k.shape[1], q.shape[2], v.shape[2])
     # counted while tracing: one per attention layer of a compiled program
     _telem.inc("flash.fwd.scan" if blocks is None else "flash.fwd.pallas")
@@ -389,20 +626,35 @@ def _flash_fwd(q, k, v, causal, sm_scale):
         kernel = functools.partial(
             _pallas_forward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
             bk=blocks[1], interpret=kernel_mode() == "interpret")
-        out, lse = _per_batch_shard(kernel, q)(q, k, v)
+        out, lse = _per_batch_shard(kernel, mesh)(q, k, v)
     else:
         bk = _pick_block(k.shape[1], 256) or k.shape[1]
         out, lse = _scan_forward(q, k, v, causal, sm_scale, bk)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, res, g):
+def _flash_bwd(causal, sm_scale, mesh, res, do):
     q, k, v, out, lse = res
-    bk = _pick_block(k.shape[1], 256) or k.shape[1]
-    return _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk)
+    lq, lk, d, dv = q.shape[1], k.shape[1], q.shape[2], v.shape[2]
+    blocks = _use_pallas(lq, lk, d, dv)
+    # a streamed row's float32 dQ is held whole in VMEM: where one row is
+    # over the budget (L = 16384 at d = 192) the scan runs
+    if blocks is not None and _backward_vmem_bytes(
+            1, *blocks, lq, d, q.dtype.itemsize, (lq, lk) != blocks,
+            dv) > _VMEM_BUDGET:
+        blocks = None
+    # counted while tracing, as the forward's
+    _telem.inc("flash.bwd.scan" if blocks is None else "flash.bwd.pallas")
+    if blocks is None:
+        bk = _pick_block(lk, 256) or lk
+        return _scan_backward(q, k, v, out, lse, do, causal, sm_scale, bk)
+    kernel = functools.partial(
+        _pallas_backward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
+        bk=blocks[1], interpret=kernel_mode() == "interpret")
+    return _per_batch_shard(kernel, mesh)(q, k, v, out, lse, do)
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_flash_on.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(query, key, value, causal=False, sm_scale=None):
@@ -414,10 +666,12 @@ def flash_attention(query, key, value, causal=False, sm_scale=None):
     Differentiable (custom VJP, blockwise backward) and tape-aware: with
     NDArray inputs under ``autograd.record()`` it records one tape node.
     On TPU, with Lq and Lk multiples of 128 and D a multiple of 64, the
-    core runs as a Pallas kernel (G batch x head rows to a grid program, G
-    from the shapes); otherwise a blockwise-scan XLA fallback with identical
-    semantics.  Counters ``flash.fwd.pallas`` / ``flash.fwd.scan`` say which
-    was traced, gauge ``flash.fwd.rows_per_program`` the last G.
+    core runs as a Pallas kernel, forward and backward (G batch x head rows
+    to a grid program, G from the shapes); otherwise a blockwise-scan XLA
+    fallback with identical semantics.  Counters ``flash.fwd.pallas`` /
+    ``flash.fwd.scan`` and ``flash.bwd.pallas`` / ``flash.bwd.scan`` say
+    which was traced, gauges ``flash.fwd.rows_per_program`` and
+    ``flash.bwd.rows_per_program`` the last G.
     """
     from ..ndarray.ndarray import NDArray, apply_nary
 

@@ -146,6 +146,14 @@ def test_kernel_is_wrapped_in_shard_map_under_a_partitioned_step():
         # jax cache key: every trace below gets a function of its own
         return lambda q, k, v: flash_attention(q, k, v, causal=True)
 
+    def fresh_grad(scope):
+        # as DataParallelTrainer's loss_of: the scope covers the forward's
+        # trace and has closed when value_and_grad traces the backward
+        def loss(q, k, v):
+            with mesh_scope(scope):
+                return jnp.sum(fresh()(q, k, v) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2))
+
     with interpret_kernels():
         with mesh_scope(mesh):
             wrapped = str(jax.make_jaxpr(fresh())(q, k, v))
@@ -154,11 +162,23 @@ def test_kernel_is_wrapped_in_shard_map_under_a_partitioned_step():
             out = jax.jit(fresh())(*sharded)
         with mesh_scope(None):
             bare = str(jax.make_jaxpr(fresh())(q, k, v))
+        wrapped_grad = str(jax.make_jaxpr(fresh_grad(mesh))(q, k, v))
+        grads = jax.jit(fresh_grad(mesh))(*sharded)
+        bare_grad = str(jax.make_jaxpr(fresh_grad(None))(q, k, v))
     assert "shard_map" in wrapped and "pallas_call" in wrapped
     assert "shard_map" not in bare and "pallas_call" in bare
+    # the gradient: the forward's kernel and the backward's (six operands,
+    # three results), each in a shard_map of its own
+    assert wrapped_grad.count("shard_map") == 2
+    assert wrapped_grad.count("mxtpu_flash_bwd") == 1
+    assert "shard_map" not in bare_grad and "mxtpu_flash_bwd" in bare_grad
     assert out.sharding.spec[0] == "dp"
     np.testing.assert_allclose(np.asarray(out), np.asarray(fresh()(q, k, v)),
                                rtol=2e-5, atol=2e-5)
+    for got, want in zip(grads, fresh_grad(None)(q, k, v)):
+        assert got.sharding.spec[0] == "dp"
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -212,6 +232,23 @@ def test_pallas_forward_bf16_under_jit_in_interpret_mode():
                                np.asarray(ref, np.float32), atol=2e-2)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                atol=2e-2)
+
+
+def test_forward_operands_are_held_to_hbm_inside_a_compiled_program_only():
+    """Traced for the chip (no interpreter), the forward pins Q, K and V to
+    HBM; an eager call must not try to: the constraint is no eager
+    operation (it raised on the v5e in the model's first eager forward),
+    so the call gets as far as this backend's own refusal of Mosaic."""
+    mod = _flash_module()
+    q = jnp.ones((8, 128, 64), jnp.bfloat16)
+
+    def forward(q):
+        return mod._pallas_forward(q, q, q, False, 0.125, 128, 128)
+
+    assert str(jax.make_jaxpr(forward)(q)).count(
+        "with_memory_space_constraint") == 3
+    with pytest.raises(ValueError, match="interpret mode"):
+        forward(q)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -280,23 +317,158 @@ def test_rows_per_program_at_the_benchmark_shapes():
         > mod._VMEM_BUDGET
 
 
-def test_flash_counters_say_which_path_was_traced():
+@pytest.mark.parametrize("direction,g", [("fwd", 16), ("bwd", 8)])
+def test_flash_counters_say_which_path_was_traced(direction, g):
     from mxnet_tpu import telemetry
     from mxnet_tpu.ops.kernel_mode import interpret_kernels
     mod = _flash_module()
     q = jnp.zeros((16, 128, 64), jnp.float32)
 
     def trace():
-        jax.make_jaxpr(lambda q: mod._flash(q, q, q, False, 0.125))(q)
+        # the mode is read while tracing: a fresh function each time
+        def flash(q):
+            return mod._flash(q, q, q, False, 0.125)
+        jax.make_jaxpr(flash if direction == "fwd" else
+                       jax.grad(lambda q: flash(q).sum()))(q)
 
-    scan0 = telemetry.value("flash.fwd.scan") or 0
-    pallas0 = telemetry.value("flash.fwd.pallas") or 0
+    scan, pallas, rows = (f"flash.{direction}.{n}" for n in
+                          ("scan", "pallas", "rows_per_program"))
+    scan0 = telemetry.value(scan) or 0
+    pallas0 = telemetry.value(pallas) or 0
     trace()
-    assert telemetry.value("flash.fwd.scan") == scan0 + 1
-    assert (telemetry.value("flash.fwd.pallas") or 0) == pallas0
-    telemetry.set_gauge("flash.fwd.rows_per_program", 0)
+    assert telemetry.value(scan) == scan0 + 1
+    assert (telemetry.value(pallas) or 0) == pallas0
+    telemetry.set_gauge(rows, 0)
     with interpret_kernels():
         trace()
-    assert telemetry.value("flash.fwd.pallas") == pallas0 + 1
-    assert telemetry.value("flash.fwd.scan") == scan0 + 1
-    assert telemetry.value("flash.fwd.rows_per_program") == 16
+    assert telemetry.value(pallas) == pallas0 + 1
+    assert telemetry.value(scan) == scan0 + 1
+    assert telemetry.value(rows) == g
+
+
+# ---------------------------------------------------------------------------
+# the backward kernel: dQ, dK, dV from one program of G rows
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("bh,lq,lk,d,dv,rows", [
+    pytest.param(16, 128, 128, 64, 64, 8, id="one-pass-G8"),
+    pytest.param(1, 128, 128, 64, 64, 1, id="one-pass-G1"),
+    pytest.param(8, 256, 256, 64, 64, 8, id="streaming-G8"),
+    pytest.param(1, 256, 256, 64, 64, 1, id="streaming-G1"),
+    pytest.param(8, 128, 128, 192, 128, 4, id="one-pass-mla-G4"),
+    pytest.param(1, 128, 128, 192, 128, 1, id="one-pass-mla-G1"),
+    pytest.param(2, 256, 256, 192, 128, 2, id="streaming-mla-G2"),
+    pytest.param(1, 256, 256, 192, 128, 1, id="streaming-mla-G1"),
+    pytest.param(2, 128, 256, 64, 64, 2, id="lq-under-lk"),
+    pytest.param(2, 384, 128, 64, 64, 2, id="lq-over-lk"),
+])
+def test_pallas_backward_matches_scan_and_naive(bh, lq, lk, d, dv, rows,
+                                                causal):
+    """The kernel's dQ / dK / dV at 128-blocks (one block: the one-pass
+    body; more: the streaming one with its causal skip) against the scan
+    from the same residuals and against jax.grad of plain softmax(QK^T)V."""
+    from mxnet_tpu import telemetry
+    mod = _flash_module()
+    rng = np.random.RandomState(bh + lq + d)
+    q = jnp.asarray(rng.randn(bh, lq, d), jnp.float32)
+    k = jnp.asarray(rng.randn(bh, lk, d), jnp.float32)
+    v = jnp.asarray(rng.randn(bh, lk, dv), jnp.float32)
+    do = jnp.asarray(rng.randn(bh, lq, dv), jnp.float32)
+    scale = d ** -0.5
+    out, lse = mod._scan_forward(q, k, v, causal, scale, 128)
+    got = mod._pallas_backward(q, k, v, out, lse, do, causal, scale, 128,
+                               128, interpret=True)
+    assert telemetry.value("flash.bwd.rows_per_program") == rows
+    scan = mod._scan_backward(q, k, v, out, lse, do, causal, scale, 128)
+    naive = jax.vjp(lambda *a: _naive(*(x[None] for x in a), causal,
+                                      scale)[0], q, k, v)[1](do)
+    for a, b, c in zip(got, scan, naive):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert float(jnp.abs(c).max()) > 0.1        # a gradient to speak of
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_pallas_backward_bf16_under_jit_in_interpret_mode(causal):
+    """bf16 operands inside a jit, as the benchmark's CPU rehearsal and
+    chip_smoke's run them: results in bf16, the scan's to bf16 rounding."""
+    mod = _flash_module()
+    rng = np.random.RandomState(9)
+    q, k, v, do = (jnp.asarray(rng.randn(8, 256, 64), jnp.bfloat16)
+                   for _ in range(4))
+    out, lse = mod._scan_forward(q, k, v, causal, 0.125, 128)
+    got = jax.jit(lambda *a: mod._pallas_backward(
+        *a, causal, 0.125, 128, 128, interpret=True))(q, k, v, out, lse, do)
+    want = mod._scan_backward(q, k, v, out, lse, do, causal, 0.125, 128)
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.bfloat16
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                   atol=2e-2 * np.abs(b).max())
+
+
+@pytest.mark.parametrize("bh,seq,d,dv,itemsize", [
+    (1536, 128, 64, 64, 2),     # BERT-base b128 s128, a chip's rows
+    (384, 512, 64, 64, 2),      # BERT-base b32 s512
+    (64, 4096, 192, 128, 2),    # the kanana cell's latent attention
+    (8, 2048, 128, 128, 2),     # chip_smoke's long shape
+    (7, 128, 64, 64, 2),        # a prime number of rows
+    (1536, 128, 64, 64, 4),     # float32 operands
+])
+def test_backward_rows_per_program_is_a_pure_function_of_the_shapes(
+        bh, seq, d, dv, itemsize, monkeypatch):
+    mod = _flash_module()
+    block = mod._pick_block(seq, 512)
+    streaming = seq > block
+    shape = (block, block, seq, d, itemsize, streaming, dv)
+    g = mod._backward_rows_per_program(bh, *shape)
+    assert g >= 1 and bh % g == 0
+    assert mod._backward_vmem_bytes(g, *shape) <= mod._VMEM_BUDGET
+    # the backward holds more a row than the forward, so never more rows
+    assert mod._backward_vmem_bytes(g, *shape) > mod._program_vmem_bytes(
+        g, block, block, d, itemsize, streaming, dv)
+    assert g <= mod._rows_per_program(bh, block, block, d, itemsize,
+                                      streaming, dv)
+    # the environment has no say, and the same shapes give the same G
+    monkeypatch.setenv("MXTPU_FLASH_BLOCK_Q", "128")
+    monkeypatch.setenv("MXTPU_FLASH_ROWS", "1")
+    assert mod._backward_rows_per_program(bh, *shape) == g
+
+
+def test_backward_rows_per_program_at_the_benchmark_shapes():
+    mod = _flash_module()
+    rows = mod._backward_rows_per_program
+    assert rows(1536, 128, 128, 128, 64, 2, False) == 16     # the s128 cells
+    assert rows(384, 128, 128, 128, 64, 2, False) == 16      # dp4: as one chip
+    assert rows(384, 512, 512, 512, 64, 2, False) == 3       # s512
+    assert rows(64, 512, 512, 4096, 192, 2, True, 128) == 1  # kanana
+    assert rows(8, 512, 512, 2048, 128, 2, True) == 1
+    # a streamed row's float32 dQ is held whole: at L = 16384 and d = 192
+    # one row is over the budget, and _flash_bwd keeps the scan
+    assert mod._backward_vmem_bytes(1, 512, 512, 8192, 192, 2, True, 128) \
+        <= mod._VMEM_BUDGET
+    assert mod._backward_vmem_bytes(1, 512, 512, 16384, 192, 2, True, 128) \
+        > mod._VMEM_BUDGET
+
+
+def test_backward_keeps_the_scan_where_a_row_is_over_the_budget(monkeypatch):
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.kernel_mode import interpret_kernels
+    mod = _flash_module()
+    q = jnp.zeros((1, 256, 64), jnp.float32)
+
+    def trace():
+        jax.make_jaxpr(jax.grad(
+            lambda q: mod._flash(q, q, q, True, 0.125).sum()))(q)
+
+    monkeypatch.setattr(mod, "_VMEM_BUDGET", 2 ** 19)
+    fwd0 = telemetry.value("flash.fwd.pallas") or 0
+    scan0 = telemetry.value("flash.bwd.scan") or 0
+    with interpret_kernels():
+        trace()
+    assert telemetry.value("flash.fwd.pallas") == fwd0 + 1
+    assert telemetry.value("flash.bwd.scan") == scan0 + 1

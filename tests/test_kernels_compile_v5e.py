@@ -1,4 +1,4 @@
-"""The Pallas kernels of the kanana cell, compiled at the cell's own shapes
+"""The Pallas kernels of the benchmark's cells, compiled at the cells' own shapes
 for a *described* v5e (no chip attached): Mosaic runs inside that compile, so
 a block it will not tile or more VMEM than a kernel may use fails here, at no
 chip time.  Nothing runs; nothing here is a time.
@@ -7,6 +7,7 @@ The topology is described inside a fixture, never at import (one process at
 a time may load the TPU's library: on-chip-measurement guide, section 2), and
 this is the one file that does it."""
 import os
+import re
 from unittest import mock
 
 import pytest
@@ -47,19 +48,32 @@ def _compile(fn, *shapes):
         compilation_cache.reset_cache()
 
 
-def test_flash_forward_and_backward_at_the_mla_shape(one_chip):
+@pytest.mark.parametrize("b,h,seq,d,dv,causal", [
+    pytest.param(2, 32, 4096, 192, 128, True, id="mla-s4096"),
+    pytest.param(32, 12, 512, 64, 64, False, id="bert-s512"),
+    pytest.param(128, 12, 128, 64, 64, False, id="bert-s128"),
+])
+def test_flash_forward_and_backward_at_the_mla_shape(one_chip, b, h, seq, d,
+                                                     dv, causal):
+    """The forward kernel and the backward kernel at the three cells' own
+    shapes (the latent-attention one streams 512-blocks, BERT's are one
+    pass): one Mosaic call each, by name, and no scan left."""
     from mxnet_tpu.ops import flash_attention
 
     def shape(d):
-        return jax.ShapeDtypeStruct((2, 32, 4096, d), jnp.bfloat16,
+        return jax.ShapeDtypeStruct((b, h, seq, d), jnp.bfloat16,
                                     sharding=one_chip)
 
     def grads(q, k, v):
-        return jax.grad(lambda *a: flash_attention(*a, causal=True).astype(
+        return jax.grad(lambda *a: flash_attention(*a, causal=causal).astype(
             jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
-    text = _compile(grads, shape(192), shape(192), shape(128))
-    assert text.count("tpu_custom_call") == 1
-    assert "mxtpu_flash_fwd" in text
+    text = _compile(grads, shape(d), shape(d), shape(dv))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    # the pallas_call's name= is a component of the call's op_name
+    assert sorted(re.search(r'op_name="[^"]*(mxtpu_flash_\w+)', ln).group(1)
+                  for ln in calls) == ["mxtpu_flash_bwd", "mxtpu_flash_fwd"]
+    assert " while(" not in text
 
 
 @pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)])
