@@ -35,6 +35,7 @@ import contextlib
 import gc
 import json
 import math
+import os
 import re
 import sys
 import time
@@ -170,7 +171,6 @@ def timed_steps(step, n):
 def phase_context(run):
     import jax
     import mxnet_tpu as mx
-    from mxnet_tpu.telemetry import costmodel
     ph = "1 context"
     ctx = run.ctx
     dev = ctx.jax_device
@@ -181,7 +181,11 @@ def phase_context(run):
     assert a.context == ctx, a.context
     assert a.data.devices() == {dev}, a.data.devices()
     assert float(a.asnumpy().sum()) == 1024.0
-    peak = costmodel.chip_peak_flops(jax.devices()[0])
+    # benchmark/peaks.json is the one table of peaks the repo keeps, looked
+    # up by exact device_kind as benchmark/manifest.py does
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "peaks.json")) as f:
+        peak = json.load(f).get(dev.device_kind, {}).get("bf16_flops_per_s")
     say(ph, f"context={ctx} jax_device={dev} "
             f"chip_peak_flops[{dev.device_kind!r}]={peak}")
     if not run.rehearsal:

@@ -178,8 +178,8 @@ def reinit_distributed(coordinator, num_processes, process_id):
     client, and several ``lru_cache``\\ d accessors.
 
     Also re-exports the MXTPU_* env so children forked after the change
-    inherit the new world.  Returns the elapsed seconds (the bench
-    ``coordinator_reinit_ms`` source).
+    inherit the new world.  Returns the elapsed seconds (the
+    ``coordinator_reinit_ms`` of ``ElasticController.stats()``).
     """
     import time as _time
 

@@ -68,13 +68,13 @@ def elastic_block(enabled=False, dp=1, membership_epoch=0, transitions=0,
                   degraded=False, reshard_ms=None, pause_ms=None,
                   drain_ms=None, drains=0, pending_notices=0,
                   autoscale_decisions=None):
-    """The bench.py ``elastic`` observability block (the ``comm`` /
-    ``serving`` block discipline): static config/counters are always
+    """The summary of an ``ElasticController``'s ``stats()`` (the ``comm``
+    / ``serving`` block discipline): static config/counters are always
     real; MEASURED fields (``reshard_ms``, ``pause_ms``, ``drain_ms``,
     ``autoscale_decisions``) default to ``None`` —
     null-when-unmeasured, so a CPU run can never pass off an absent
     measurement as "resharding is free" (the PR 6 honesty rule, gated
-    by tests/test_bench_line.py).  ISSUE 13 grew the block with the
+    by tests/test_elastic.py).  ISSUE 13 grew the block with the
     notice-drain and autoscaling evidence: ``drain_ms`` (last
     notice-driven drain commit), ``drains``/``pending_notices``
     counters, and ``autoscale_decisions`` (None until a real autoscale

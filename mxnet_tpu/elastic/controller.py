@@ -117,7 +117,7 @@ class ElasticController:
         self._dist_reinit = None
         self.dist_reinits = 0
         self.last_reinit_ms = None
-        # observability (the bench `elastic` block + tests)
+        # observability (stats() + tests)
         self.transitions = 0
         self.drains = 0
         self.degraded = False
@@ -425,8 +425,8 @@ class ElasticController:
                     pause_ms=self.last_pause_ms)
         self.last_event = info
         if _telem.enabled():
-            # the bench `elastic` block and live scrapes read these off
-            # the registry — same numbers as stats(), one source
+            # live scrapes read these off the registry — same numbers
+            # as stats(), one source
             _telem.set_context(step=None if step is None else int(step),
                                epoch=self._applied_epoch)
             _telem.inc("elastic.transitions")
@@ -464,8 +464,7 @@ class ElasticController:
 
     # -- observability ---------------------------------------------------
     def stats(self):
-        """The bench ``elastic`` block inputs (see
-        :func:`mxnet_tpu.elastic.elastic_block`)."""
+        """What :func:`mxnet_tpu.elastic.elastic_block` summarises."""
         return {"enabled": self._enabled,
                 "dp": self.target_dp(include_pending=False),
                 "membership_epoch": self._membership.epoch,

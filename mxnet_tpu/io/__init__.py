@@ -472,7 +472,7 @@ class ImageRecordIter(DataIter):
     def _reset_async(self):
         """(Re)build the threaded decode fan-out for the pure-Python path
         so ``preprocess_threads`` is actually honored (it used to be
-        accepted and ignored here — the bench's ``decode_threads: 1``).
+        accepted and ignored here).
         Determinism mode keeps decode synchronous: per-sample host RNG
         (rand_mirror) draws must happen in a fixed order."""
         from .. import debug as _debug

@@ -21,9 +21,8 @@ threaded decode pool):
     consumer always receives device-resident arrays.
 
 Both record per-stage wall time (decode / H2D / consumer compute /
-consumer stall) in a ``PipelineStats`` so ``bench.py`` can report the
-``input_pipeline`` block with an ``overlap_efficiency`` figure, and both
-emit ``mx.profiler`` spans (``pipeline:decode`` / ``pipeline:h2d`` /
+consumer stall) in a ``PipelineStats`` (its ``summary()`` carries an
+``overlap_efficiency`` figure), and both emit ``mx.profiler`` spans (``pipeline:decode`` / ``pipeline:h2d`` /
 ``pipeline:stall``) while a profile is running.
 """
 from __future__ import annotations
@@ -86,8 +85,8 @@ class PipelineStats:
                 self.h2d_bytes += nbytes
                 self.batches += 1
         # mirror onto the process telemetry registry (ISSUE 9): the
-        # per-instance accumulator stays the bench `input_pipeline`
-        # source; the registry is what a live scrape sees
+        # per-instance accumulator is what the caller reads; the
+        # registry is what a live scrape sees
         if _telem.enabled():
             _telem.observe(f"io.{stage}_ms", dt * 1e3)
             if stage == "h2d" and nbytes:

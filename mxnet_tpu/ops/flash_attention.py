@@ -240,8 +240,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     else:
         lse_spec = pl.BlockSpec((g, 8, bq), lambda b, i, j: (b, 0, i))
         lse_shape = jax.ShapeDtypeStruct((bh, 8, lq), jnp.float32)
-    # where one row does not fit the budget (blocks asked for through
-    # MXTPU_FLASH_BLOCK_Q/KV), mosaic's limit is raised by what it is over
+    # where one row at the caller's blocks does not fit the budget, mosaic's
+    # limit is raised by what it is over
     over = _program_vmem_bytes(g, *shape) - _VMEM_BUDGET
     out_t, lse = pl.pallas_call(
         one_pass if nk == 1 else streaming,
@@ -541,30 +541,8 @@ def _use_pallas(lq, lk, d, dv=None):
     head dim of V where it is not ``d``)."""
     if kernel_mode() is None:
         return None
-    import os
-
-    def _pref(var, legacy):
-        # tuning knobs (MXTPU_FLASH_BLOCK_Q/KV, legacy alias
-        # MXTPU_FLASH_BQ/BK): preferred block sizes for the kernel
-        # autotune sweep (tools/flash_long_seq.py --block-sweep);
-        # clamped to >=128 so a too-small value still falls back to a
-        # valid divisor instead of silently disabling the kernel, and
-        # malformed values are named
-        raw = os.environ.get(var)
-        if raw is None:
-            raw = os.environ.get(legacy, "512")
-            var = legacy
-        try:
-            return max(int(raw), 128)
-        except ValueError as e:
-            from ..base import MXNetError
-            raise MXNetError(
-                f"{var}={raw!r} is not an integer block size") from e
-
-    pref_q = _pref("MXTPU_FLASH_BLOCK_Q", "MXTPU_FLASH_BQ")
-    pref_k = _pref("MXTPU_FLASH_BLOCK_KV", "MXTPU_FLASH_BK")
-    bq = _pick_block(lq, pref_q)
-    bk = _pick_block(lk, pref_k)
+    bq = _pick_block(lq)
+    bk = _pick_block(lk)
     # d=64 is fine: Mosaic pads the lane dim; BERT-base heads (768/12) hit
     # this. Verified on TPU v5e vs the scan path (max abs diff 1.8e-7 f32).
     if bq is None or bk is None or d % 64 or (dv or d) % 64:

@@ -34,8 +34,8 @@ f32 pool is the original op, untouched.
 
 The Pallas body compiles only on TPU backends (``_use_pallas`` gate,
 like flash); structure tests assert its shape and skip execution
-elsewhere.  TPU-vs-fallback numerics are gated by the TPU round's
-bench_diff, not claimed here.
+elsewhere.  TPU-vs-fallback numerics are checked on the chip by
+``chip_smoke.py``, not claimed here.
 """
 from __future__ import annotations
 

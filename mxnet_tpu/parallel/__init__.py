@@ -24,7 +24,7 @@ scaling is mesh-sharded jit:
 from .mesh import (make_mesh, local_mesh, distributed_init, mesh_scope,
                    current_mesh, data_sharding, replicate_sharding,
                    batch_sharding, MeshConfig, mesh_config_from_env,
-                   parallelism_block, AXIS_DP, AXIS_TP, AXIS_PP)
+                   AXIS_DP, AXIS_TP, AXIS_PP)
 from .data_parallel import DataParallelTrainer, all_reduce_gradients
 from .overlap import OverlapScheduler
 from .tensor_parallel import (shard_params_tp, tp_spec_for_param,

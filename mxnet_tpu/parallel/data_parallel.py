@@ -953,7 +953,7 @@ class DataParallelTrainer:
         ``step_accum``) calls — per-step lrs and PRNG keys are drawn
         host-side from the same streams, and the step body is the same
         ``_grad_fn``/update code.  ``MXTPU_STEPS_PER_CALL=1`` (the
-        default) keeps K-aware loops (estimator/bench) on the per-step
+        default) keeps K-aware loops (``estimator.fit``) on the per-step
         entry points, restoring today's graphs exactly.
         """
         t_step = self._step_entry()
@@ -1479,7 +1479,7 @@ class DataParallelTrainer:
             out["overlap_frac"] = round(
                 max(0.0, min(1.0, 1.0 - exposed / serial)), 4)
         # retire the probe's private numbers onto the registry: the
-        # bench `comm` block and live scrapers read ONE source (ISSUE 9)
+        # `comm` block and live scrapers read ONE source (ISSUE 9)
         for field, metric in (("exposed_comm_ms",
                                "train.exposed_comm_ms"),
                               ("overlap_frac", "train.overlap_frac")):
@@ -1496,7 +1496,7 @@ class DataParallelTrainer:
         / ``est_ici_gb_s``), and ``overlap_efficiency`` estimates how
         much of it a ``step_ms``-long step could hide.  All fields are
         zeros when the sharded pipeline is off — the schema survives so
-        CPU CI regression-tests it (tests/test_bench_line.py)."""
+        CPU CI regression-tests it (tests/test_sharded_sync.py)."""
         dp = self.mesh.shape.get(AXIS_DP, 1)
         if self._pp_active():
             # pipeline-staged state: each chip holds only its stage's

@@ -19,7 +19,7 @@ from ..base import MXNetError
 __all__ = ["make_mesh", "local_mesh", "distributed_init", "mesh_scope",
            "current_mesh", "data_sharding", "replicate_sharding",
            "batch_sharding", "P", "MeshConfig", "mesh_config_from_env",
-           "parallelism_block", "AXIS_DP", "AXIS_TP", "AXIS_PP"]
+           "AXIS_DP", "AXIS_TP", "AXIS_PP"]
 
 _STATE = threading.local()
 
@@ -223,27 +223,6 @@ def mesh_config_from_env(default_devices=None):
         cfg = MeshConfig(dp=n)
     return cfg.resolve(len(default_devices if default_devices is not None
                            else jax.devices()))
-
-
-def parallelism_block(config=None, pp_microbatches=None,
-                      pp_bubble_frac=None, tp_collective_ms=None):
-    """The bench ``parallelism`` observability block (ISSUE 11): mesh
-    shape stamped always (it is configuration, not measurement);
-    ``pp_bubble_frac`` is the ANALYTIC 1F1B bubble fraction — present
-    only when a pipeline axis exists; ``tp_collective_ms`` is MEASURED
-    and therefore null-when-unmeasured (CPU / tp=1), per the PR 6
-    honesty rule."""
-    cfg = config or MeshConfig(dp=1)
-    return {
-        "mesh": cfg.as_dict(),
-        "mesh_spec": cfg.describe(),
-        "pp_microbatches": (None if pp_microbatches is None
-                            else int(pp_microbatches)),
-        "pp_bubble_frac": (None if pp_bubble_frac is None
-                           else round(float(pp_bubble_frac), 4)),
-        "tp_collective_ms": (None if tp_collective_ms is None
-                             else round(float(tp_collective_ms), 3)),
-    }
 
 
 def distributed_init(coordinator=None, num_processes=None, process_id=None):

@@ -18,9 +18,8 @@ module provides the pieces that replace it (ISSUE 3 tentpole):
   PAPERS.md row 9).  The updated-parameter all-gather that completes
   the ZeRO-1 pipeline is a plain ``lax.all_gather`` (params must come
   back exact; only the gradient payload is quantizable).
-- :func:`comm_block` — the ``comm`` observability schema shared by
-  ``bench.py`` / ``tools/bench_pipeline.py`` / the parity tests, so the
-  shape is regression-tested in tier-1 even on CPU (zeros are fine).
+- :func:`comm_block` — the schema of ``DataParallelTrainer.comm_stats``,
+  regression-tested in tier-1 even on CPU (zeros are fine).
 
 ZeRO-1 memory math (fp32, N = dp size): momentum-SGD keeps 4 B/param of
 optimizer state, Adam 8 B/param — replicated on every chip before; with
@@ -263,7 +262,7 @@ def reduce_scatter_bucket(flat, key, dp, mode="fp32",
 
 
 # ---------------------------------------------------------------------------
-# the `comm` observability block (bench.py / tools/bench_pipeline.py)
+# the `comm` observability block (DataParallelTrainer.comm_stats)
 # ---------------------------------------------------------------------------
 
 def comm_block(dp=1, wire_dtype="fp32", buckets=0, bucket_mb=None,
@@ -275,7 +274,7 @@ def comm_block(dp=1, wire_dtype="fp32", buckets=0, bucket_mb=None,
                overlap_frac=None):
     """The per-step ``comm`` block schema.  Every field is always
     present so tier-1 regression-tests the shape
-    (tests/test_bench_line.py) without needing a multichip host — but
+    (tests/test_sharded_sync.py) without needing a multichip host — but
     MEASURED fields (``collective_ms``, ``est_ici_gb_s``,
     ``overlap_efficiency``, ``exposed_comm_ms``, ``overlap_frac``) are
     ``null`` when nothing was measured (CPU / dp=1 / probe skipped)

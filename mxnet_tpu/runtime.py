@@ -40,7 +40,7 @@ def steps_per_call():
     (``MXTPU_STEPS_PER_CALL``, default 1 = today's one-dispatch-per-step
     behavior — the kill switch, same semantics as ``MXTPU_FUSED_STEP``).
     K > 1 makes K-step-capable loops (``estimator.fit`` over a
-    ``DataParallelTrainer``, bench.py) drive
+    ``DataParallelTrainer``) drive
     ``DataParallelTrainer.step_multi`` — K steps scanned device-resident
     per host dispatch, so the per-step eager dispatch + program
     re-entry tax is paid once per K steps (arXiv:2011.03641 host-bound

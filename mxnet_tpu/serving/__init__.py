@@ -60,11 +60,12 @@ def serving_block(max_batch=0, block_size=0, buckets=(), quantized=False,
                   prefill_pool_occupancy=None,
                   decode_pool_occupancy=None, kv_dtype="fp32",
                   kv_capacity_ratio=None, kv_decode_drift=None):
-    """The bench.py ``serving`` observability block (the `comm` block
-    discipline from PR 3/PR 5): static serving config is always real;
+    """The ``serving`` summary ``tools/serve_loadgen.py`` reports (the
+    `comm` block discipline from PR 3/PR 5): static serving config is
+    always real;
     MEASURED fields default to ``None`` — null-when-unmeasured, so a CPU
     run can never pass off an absent measurement as "latency is zero"
-    (the PR 6 honesty rule, tests/test_bench_line.py).  ISSUE 12 grows
+    (the PR 6 honesty rule, tests/test_serving.py).  ISSUE 12 grows
     the front-end fields: ``chunked_prefill``/``router_replicas`` are
     config (always real), ``prefix_hit_rate``/``router_p99_ms`` are
     measured (null until a run actually measured them).  ISSUE 17 adds

@@ -1049,7 +1049,7 @@ class InferenceEngine:
             if self._warmed:
                 # the tier-1 zero-retrace assertion reads the engine's
                 # own counter; the registry twin is what a live scrape
-                # sees (one source of truth for bench/loadgen, ISSUE 9)
+                # sees (one source of truth for the loadgen, ISSUE 9)
                 self.stats["compiles_after_warmup"] += 1
                 _telem.inc("serving.compiles_after_warmup")
                 _telem.event("serving.compile_after_warmup",

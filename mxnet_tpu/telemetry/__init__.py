@@ -44,7 +44,6 @@ from .flight import FlightRecorder, memory_block
 from .prom import prom_text as _render_prom
 from . import tracing
 from . import watchdog
-from . import costmodel
 from . import fleet
 
 __all__ = ["SCHEMA_VERSION", "enabled", "registry", "counter", "gauge",
@@ -55,8 +54,7 @@ __all__ = ["SCHEMA_VERSION", "enabled", "registry", "counter", "gauge",
            "on_step_error", "reset", "configure", "clock",
            "MetricsRegistry", "EventLog", "FlightRecorder",
            "memory_block", "Counter", "Gauge", "Histogram",
-           "DEFAULT_MS_EDGES", "tracing", "watchdog", "costmodel",
-           "fleet"]
+           "DEFAULT_MS_EDGES", "tracing", "watchdog", "fleet"]
 
 
 def _env_enabled():
@@ -165,7 +163,7 @@ def observe(name, v, edges=None):
 
 def value(name):
     """Current value of a counter/gauge (None when absent or disabled)
-    — the thin-reader seam bench blocks and the loadgen consume."""
+    — the thin-reader seam ``tools/serve_loadgen.py`` consumes."""
     if not _ENABLED:
         return None
     return _REGISTRY.value(name)

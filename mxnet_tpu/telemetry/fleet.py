@@ -41,8 +41,8 @@ the aggregation plane that finally consumes them fleet-wide:
 30 s, injectable clock — zero sleeps in tests); ``MXTPU_FLEET_SKEW``
 is the straggler-score threshold (default 2.0).  Exposure:
 ``tools/telemetry_dump.py --fleet`` (multi-host scrape -> merged prom
-text / JSON / ``--trace`` fleet timeline) and the bench ``fleet``
-block (:func:`fleet_block`, null-when-unmeasured on a single process).
+text / JSON / ``--trace`` fleet timeline) and the ``fleet`` summary
+(:func:`fleet_block`, null-when-unmeasured on a single process).
 Topology diagram and merge-semantics table: docs/OBSERVABILITY.md
 §Fleet.
 """
@@ -62,8 +62,7 @@ __all__ = ["FLEET_SCHEMA_VERSION", "FleetCollector", "enabled",
            "fleet_prom_snapshot", "fleet_block"]
 
 #: bump on any BREAKING fleet-snapshot field change (additive fields
-#: keep the version); ``tools/bench_diff.py`` refuses to compare bench
-#: ``fleet`` blocks across a drift, like the telemetry schema
+#: keep the version), like the telemetry schema
 FLEET_SCHEMA_VERSION = 1
 
 
@@ -121,8 +120,8 @@ def ps_transport(host, port, retries=3, policy=None):
 
 
 def transports_from_addrs(addrs, retries=3):
-    """``"h0:p0,h1:p1,..."`` (the ``MXTPU_FLEET_ADDRS`` spec) -> an
-    ordered {rank: transport} map, rank = position in the list."""
+    """``"h0:p0,h1:p1,..."`` -> an ordered {rank: transport} map, rank =
+    position in the list."""
     out = {}
     for rank, part in enumerate(p for p in str(addrs).split(",")
                                 if p.strip()):
@@ -377,7 +376,7 @@ class FleetCollector(EdgeRuleEngine):
 
     def _publish(self, fleet):
         """Thin-reader seam: the fleet-level analysis lands on the LOCAL
-        registry so bench's ``fleet`` block and a live scrape of the
+        registry so :func:`fleet_block`'s caller and a live scrape of the
         coordinator read one source (the ISSUE 9 discipline)."""
         from . import enabled as telem_enabled, inc, set_gauge
         if not telem_enabled():
@@ -437,7 +436,7 @@ class FleetCollector(EdgeRuleEngine):
                     "tripped": sorted(self._tripped)}
 
 
-# -- rendering / bench --------------------------------------------------
+# -- rendering ----------------------------------------------------------
 
 def fleet_prom_snapshot(fleet):
     """A registry-snapshot-shaped view of a fleet snapshot so the PR 9
@@ -465,12 +464,12 @@ def fleet_prom_snapshot(fleet):
 def fleet_block(enabled=False, ranks=0, slowest_rank=None,
                 step_ms_skew=None, scrape_ms=None, stragglers=None,
                 epoch_desync=None, scrape_dead=None):
-    """The bench.py ``fleet`` observability block (the ``comm`` /
+    """The summary of a :class:`FleetCollector` scrape (the ``comm`` /
     ``serving`` / ``elastic`` block discipline): config is always real;
     MEASURED fields default to ``None`` — null-when-unmeasured, so a
     single-process CPU run can never pass off "no fleet to scrape" as
     "zero skew measured" (the PR 6 honesty rule, gated by
-    tests/test_bench_line.py)."""
+    tests/test_fleet.py)."""
     def _r(x, n=3):
         return None if x is None else round(float(x), n)
 

@@ -31,8 +31,8 @@ import pytest
 
 @pytest.fixture(scope="session", autouse=True)
 def chip_smoke_rehearsal(request, tmp_path_factory):
-    """``chip_smoke.py --rehearse-on-cpu`` takes ~30 s of compiles, and
-    tier-1 has no such room (ROADMAP D8).  So it is started here, when
+    """``chip_smoke.py --rehearse-on-cpu`` takes ~30 s of compiles, over
+    the per-test budget below (ROADMAP D8).  So it is started here, when
     the session starts, in a process of its own on one CPU device (no
     all-chips phase: that one is rehearsed by hand, see the verify
     skill), and runs beside the suite; ``tests/test_chip_smoke.py``
@@ -159,10 +159,13 @@ def no_leaked_nondaemon_threads():
 # without a `slow` marker fails the run via test_zz_duration_guard.py
 # ----------------------------------------------------------------------
 
-#: per-test wall budget (call phase) for NON-slow tests.  The tier-1
-#: suite runs under a hard driver timeout; one unmarked 40 s test eats
-#: the headroom of twenty 2 s tests.  Tests legitimately past this go
-#: behind `@pytest.mark.slow` (still tier-1, but visibly budgeted).
+#: per-test wall budget (call phase) for NON-slow tests.  The driver runs
+#: tier-1 over six xdist workers, split by file (`-n 6 --dist loadfile`,
+#: `timeout 1470`, about 300 s): one unmarked 40 s test holds its file's
+#: worker while the others go idle.  Tests legitimately past this go
+#: behind `@pytest.mark.slow`, which the driver's `-m 'not slow'` leaves
+#: out.  Under xdist each worker keeps its own list, and the guard sees
+#: the list of the worker it runs on.
 DURATION_BUDGET_S = 20.0
 
 #: (nodeid, seconds) for every non-slow test whose call phase crossed

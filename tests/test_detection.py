@@ -200,9 +200,7 @@ def test_segmentation_models():
     assert pred.shape == (1, 5, 32, 32)
 
 
-@pytest.mark.slow   # slow-marked (ISSUE 18 tier-1 headroom): zoo
-# registration enumeration (darknet53 full forward); the SSD/RCNN
-# forward + convergence tests keep detection tier-1
+# zoo registration enumeration (darknet53 full forward)
 def test_get_model_detection_names():
     from mxnet_tpu.gluon.model_zoo.vision import get_model
     net = get_model("darknet53")

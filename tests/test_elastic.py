@@ -3,6 +3,7 @@ PS join/announce path, the kvstore epoch fence, controller-led reshards
 with bitwise continuation parity, and the chaos elastic scenarios —
 all deterministic on the simulated 8-device CPU mesh (FakeClock, zero
 sleeps)."""
+import json
 import os
 import socket
 
@@ -490,3 +491,36 @@ def test_estimator_elastic_checkpoint_fallback_stops_cleanly(tmp_path):
     est.fit(batches, epochs=1, resume="auto", checkpoint_manager=mgr,
             elastic_controller=ctrl)
     assert est.global_step == 6 and not est.preempted
+
+
+# ----------------------------------------------------------------------
+# the `elastic` block schema (ISSUE 8): config/counters always real,
+# measured transition timings null-when-unmeasured — a CPU run can't
+# pass off an absent measurement as "resharding is free"
+# ----------------------------------------------------------------------
+
+_ELASTIC_KEYS = {
+    "enabled", "dp", "membership_epoch", "transitions", "degraded",
+    "reshard_ms", "pause_ms", "drain_ms", "drains", "pending_notices",
+    "autoscale_decisions",
+}
+
+
+def test_elastic_block_schema_is_stable():
+    from mxnet_tpu.elastic import elastic_block
+    blk = elastic_block()
+    assert set(blk) == _ELASTIC_KEYS
+    for k in ("reshard_ms", "pause_ms", "drain_ms",
+              "autoscale_decisions"):
+        assert blk[k] is None, k
+    assert blk["enabled"] is False and blk["transitions"] == 0
+    assert blk["drains"] == 0 and blk["pending_notices"] == 0
+    blk2 = elastic_block(enabled=True, dp=4, membership_epoch=2,
+                         transitions=1, reshard_ms=73.7777,
+                         pause_ms=74.1234, drain_ms=5.5555,
+                         drains=1, autoscale_decisions=3)
+    assert blk2["reshard_ms"] == 73.778
+    assert blk2["pause_ms"] == 74.123
+    assert blk2["drain_ms"] == 5.556
+    assert blk2["autoscale_decisions"] == 3
+    assert json.loads(json.dumps(blk)) == blk

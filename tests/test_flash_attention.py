@@ -284,7 +284,7 @@ def test_gradients_through_the_kernel_match_the_scan_path(causal):
     (1536, 128, 64, 4),     # float32 operands
 ])
 def test_rows_per_program_is_a_pure_function_of_the_shapes(
-        bh, seq, d, itemsize, monkeypatch):
+        bh, seq, d, itemsize):
     mod = _flash_module()
     block = mod._pick_block(seq, 512)
     streaming = seq > block
@@ -298,11 +298,6 @@ def test_rows_per_program_is_a_pure_function_of_the_shapes(
     smaller = [x for x in range(1, g) if bh % x == 0 and
                (x % 8 == 0) == (g % 8 == 0)]
     assert all(x * row_bytes < mod._PROGRAM_HBM_BYTES for x in smaller)
-    # the environment has no say, and the same shapes give the same G
-    monkeypatch.setenv("MXTPU_FLASH_BLOCK_Q", "128")
-    monkeypatch.setenv("MXTPU_FLASH_ROWS", "1")
-    assert mod._rows_per_program(bh, block, block, d, itemsize,
-                                 streaming) == g
 
 
 def test_rows_per_program_at_the_benchmark_shapes():
@@ -420,7 +415,7 @@ def test_pallas_backward_bf16_under_jit_in_interpret_mode(causal):
     (1536, 128, 64, 64, 4),     # float32 operands
 ])
 def test_backward_rows_per_program_is_a_pure_function_of_the_shapes(
-        bh, seq, d, dv, itemsize, monkeypatch):
+        bh, seq, d, dv, itemsize):
     mod = _flash_module()
     block = mod._pick_block(seq, 512)
     streaming = seq > block
@@ -433,10 +428,6 @@ def test_backward_rows_per_program_is_a_pure_function_of_the_shapes(
         g, block, block, d, itemsize, streaming, dv)
     assert g <= mod._rows_per_program(bh, block, block, d, itemsize,
                                       streaming, dv)
-    # the environment has no say, and the same shapes give the same G
-    monkeypatch.setenv("MXTPU_FLASH_BLOCK_Q", "128")
-    monkeypatch.setenv("MXTPU_FLASH_ROWS", "1")
-    assert mod._backward_rows_per_program(bh, *shape) == g
 
 
 def test_backward_rows_per_program_at_the_benchmark_shapes():

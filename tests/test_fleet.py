@@ -484,3 +484,35 @@ def test_fleet_prom_snapshot_flattens_per_rank_gauges():
     assert "mxtpu_train_step_ms_rank0 50.0" in text
     assert "mxtpu_train_step_ms_rank1 60.0" in text
     assert "mxtpu_fleet_ranks 2" in text
+
+
+# ----------------------------------------------------------------------
+# the `fleet` block schema (ISSUE 15): config always real, measured
+# skew/scrape fields null-when-unmeasured — a single-process run can't
+# pass off "no fleet to scrape" as "zero skew measured"
+# ----------------------------------------------------------------------
+
+_FLEET_KEYS = {
+    "fleet_schema_version", "enabled", "ranks", "slowest_rank",
+    "step_ms_skew", "scrape_ms", "stragglers", "epoch_desync",
+    "scrape_dead",
+}
+
+
+def test_fleet_block_schema_is_stable():
+    from mxnet_tpu.telemetry.fleet import (fleet_block,
+                                           FLEET_SCHEMA_VERSION)
+    blk = fleet_block()
+    assert set(blk) == _FLEET_KEYS
+    assert blk["fleet_schema_version"] == FLEET_SCHEMA_VERSION
+    for k in ("slowest_rank", "step_ms_skew", "scrape_ms",
+              "stragglers", "epoch_desync", "scrape_dead"):
+        assert blk[k] is None, k
+    assert blk["enabled"] is False and blk["ranks"] == 0
+    blk2 = fleet_block(enabled=True, ranks=4, slowest_rank=2,
+                       step_ms_skew=3.14159, scrape_ms=12.5555,
+                       stragglers=1, epoch_desync=False, scrape_dead=1)
+    assert blk2["step_ms_skew"] == 3.1416
+    assert blk2["scrape_ms"] == 12.556
+    assert blk2["slowest_rank"] == 2 and blk2["scrape_dead"] == 1
+    assert json.loads(json.dumps(blk)) == blk

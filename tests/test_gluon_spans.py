@@ -303,20 +303,10 @@ def test_step_interval_observed_from_the_second_step_on():
         hists["train.step_ms"]["min"]
 
 
-def test_live_mfu_gauges_and_their_cost_analysis_are_gone(monkeypatch):
-    from mxnet_tpu.telemetry import costmodel
-    # the peak known, which is when the old path lowered the step ahead of
-    # time for its cost analysis
-    monkeypatch.setenv("MXTPU_CHIP_PEAK_TFLOPS", "197")
-    aot = []
-    real = costmodel.compiled_flops
-    monkeypatch.setattr(costmodel, "compiled_flops",
-                        lambda *a: aot.append(a) or real(*a))
+def test_live_mfu_gauges_and_their_cost_analysis_are_gone():
     tr = _fused_steps(2)
     snap = telemetry.snapshot()
     for name in ("train.mfu", "train.tflops_delivered", "train.step_flops"):
         assert name not in snap["gauges"]
         assert telemetry.value(name) is None
-    assert aot == []
-    assert not hasattr(costmodel, "live_cost_enabled")
     assert not hasattr(tr, "_live_cost")

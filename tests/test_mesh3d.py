@@ -159,8 +159,7 @@ def test_unset_env_is_bitwise_flat_dp(monkeypatch):
 # ---------------------------------------------------------------------------
 
 @needs8
-@pytest.mark.parametrize(
-    "spec", ["2x2x2", pytest.param("4x1x2", marks=pytest.mark.slow)])
+@pytest.mark.parametrize("spec", ["2x2x2", "4x1x2"])
 # 2x2x2 exercises every axis; 4x1x2 is the degenerate-axis twin
 def test_3d_mesh_matches_pure_dp_reference(spec):
     # batch 32: divides dp=4 x (pp_microbatches=4 x n_micro=2)

@@ -87,9 +87,7 @@ def test_get_model_file_resolution(tmp_path):
         del os.environ["MXTPU_MODEL_STORE"]
 
 
-@pytest.mark.slow   # slow-marked (ISSUE 18 tier-1 headroom): the store
-# registry/format/eviction tests above keep the load path tier-1; this
-# is the end-to-end pretrained one-liner over both container formats
+# the end-to-end pretrained one-liner over both container formats
 def test_pretrained_one_liner_offline(tmp_path):
     """get_model(name, pretrained=True, root=...) — the one-line load.
     Covers both container formats in the store: native save_parameters

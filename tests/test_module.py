@@ -117,9 +117,11 @@ def test_bucketing_module_varlen():
 
 @pytest.mark.skipif(len(__import__("jax").devices()) < 8,
                     reason="needs 8 virtual devices")
-@pytest.mark.slow   # slow-marked (ISSUE 18 tier-1 headroom): legacy
-# Module-API dp split; the gluon/parallel dp paths (test_mesh3d,
-# test_data_parallel) keep multi-device execution tier-1
+@pytest.mark.slow   # legacy Module-API dp split; the gluon/parallel dp
+# paths (test_mesh3d, test_data_parallel) keep multi-device execution
+# tier-1.  Passes alone in 7 s, but un-marked it aborted its xdist worker
+# in the whole run (PR 31: "Fatal Python error: Aborted" under
+# Module.update -> kvstore.push -> optimizer.apply), so it stays out
 def test_module_multi_device_data_parallel():
     """ctx=[cpu(0)..cpu(7)] forms a dp mesh: params replicated, batch
     sharded — the DataParallelExecutorGroup role (reference

@@ -345,8 +345,8 @@ def test_nadam_update_cumulative_schedule():
         np.testing.assert_allclose(w.asnumpy(), [w_ref], rtol=1e-6)
 
 
-@pytest.mark.slow   # ~28 s (second-heaviest non-slow test): tier-1
-# headroom under the 870 s timeout; RNN-vs-torch parity still gates via
+@pytest.mark.slow   # ~28 s, over the 20 s per-test budget;
+# RNN-vs-torch parity still gates via
 # test_torch_rnn_consistency.py
 def test_fused_rnn_op_matches_gluon_layer():
     """nd.RNN (reference src/operator/rnn.cc packed-parameter fused op)

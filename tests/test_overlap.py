@@ -308,8 +308,8 @@ def test_probe_survives_batchnorm_aux_state():
     mean/var) WRITE into parameter buffers during tracing; the plan
     probe (jax.eval_shape) and overlap_probe discard their results, so
     without buffer restore the leaked tracers blew up the next
-    device_put (UnexpectedTracerError — found by bench.py resnet50
-    under MXTPU_BENCH_DP=8)."""
+    device_put (UnexpectedTracerError — found with ResNet-50 over a
+    dp=8 mesh)."""
     np.random.seed(0)
     net = gluon.nn.HybridSequential()
     net.add(gluon.nn.Dense(16), gluon.nn.BatchNorm(),

@@ -44,8 +44,8 @@ def _digits_rec(tmp_path, split):
     return prefix + ".rec"
 
 
-@pytest.mark.slow   # ~40 s: the heaviest non-slow test (tier-1 headroom
-# under the 870 s timeout); the fast pipeline-correctness coverage lives
+@pytest.mark.slow   # ~40 s, over the 20 s per-test budget; the fast
+# pipeline-correctness coverage lives
 # in test_io_pipeline.py::test_pipeline_end_to_end_trains
 def test_real_data_convergence_floor(tmp_path):
     """Real scans through the real pipeline must converge: >0.95 val

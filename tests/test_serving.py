@@ -164,9 +164,8 @@ def test_prefill_parity_bitwise_per_bucket():
         eng.release(slot)
 
 
-@pytest.mark.slow   # slow-marked (ISSUE 18 tier-1 headroom): the BITWISE
-# per-bucket decode/prefill parity gates above stay tier-1; this is the
-# float-eps-vs-unpadded + net.generate() stream twin
+# the float-eps-vs-unpadded + net.generate() stream twin of the BITWISE
+# per-bucket decode/prefill parity gates above
 def test_decode_close_to_unpadded_forward_and_matches_generate():
     """User-visible guarantees vs the UNPADDED forward: logits to float
     eps and the greedy token stream identical to net.generate()."""
@@ -427,8 +426,6 @@ def test_prompt_longer_than_max_context_rejected():
 # loadgen smoke (the tier-1 wiring of tools/serve_loadgen.py)
 # ----------------------------------------------------------------------
 
-@pytest.mark.slow   # CLI smoke; the serving_block schema itself is
-# gated fast in test_bench_line.py
 def test_serve_loadgen_smoke_cli():
     """`tools/serve_loadgen.py --smoke` runs end-to-end and prints one
     JSON line under the driver's tail-window budget."""
@@ -469,3 +466,101 @@ def test_sampler_accepts_compiled_step_function():
                           max_length=8, temperature=1.0, top_k=2)
     samples, scores, lengths = smp(mx.nd.array([5]), {})
     assert samples.shape[0] == 1 and samples.shape[1] == 2
+
+
+# ----------------------------------------------------------------------
+# the `serving` block schema (ISSUE 7): config always real, measured
+# fields null-when-unmeasured — a CPU run can't fake serving latency
+# ----------------------------------------------------------------------
+
+_SERVING_KEYS = {
+    "max_batch", "block_size", "buckets", "quantized", "continuous",
+    "requests", "p50_ms", "p99_ms", "ttft_p50_ms", "tokens_s",
+    "tokens_s_chip", "occupancy", "tokens_per_step",
+    "compiles_after_warmup", "cache_utilization",
+    # ISSUE 12 front-end fields
+    "chunked_prefill", "router_replicas", "prefix_hit_rate",
+    "router_p99_ms",
+    # ISSUE 17 speculative-decoding fields
+    "speculative", "paged_attn", "spec_accept_rate",
+    "tokens_per_dispatch",
+    # ISSUE 18 sharded/disaggregated fleet fields
+    "tp_shards", "disaggregated", "handoff_ms",
+    "prefill_pool_occupancy", "decode_pool_occupancy",
+    # ISSUE 20 low-precision KV fields
+    "kv_dtype", "kv_capacity_ratio", "kv_decode_drift",
+}
+
+
+def test_serving_block_schema_is_stable():
+    from mxnet_tpu.serving import serving_block
+    blk = serving_block()
+    assert set(blk) == _SERVING_KEYS
+    # MEASURED fields are null when nothing was measured
+    for k in ("p50_ms", "p99_ms", "ttft_p50_ms", "tokens_s",
+              "tokens_s_chip", "occupancy", "tokens_per_step",
+              "compiles_after_warmup", "cache_utilization",
+              "prefix_hit_rate", "router_p99_ms", "spec_accept_rate",
+              "tokens_per_dispatch", "handoff_ms",
+              "prefill_pool_occupancy", "decode_pool_occupancy",
+              "kv_capacity_ratio", "kv_decode_drift"):
+        assert blk[k] is None, k
+    # CONFIG fields are always real (front-end off by default)
+    assert blk["chunked_prefill"] is False
+    assert blk["router_replicas"] == 0
+    assert blk["speculative"] is False
+    assert blk["paged_attn"] is False
+    assert blk["tp_shards"] == 0
+    assert blk["disaggregated"] is False
+    assert blk["kv_dtype"] == "fp32"
+    # measured values round-trip, rounded
+    blk2 = serving_block(p99_ms=12.3456, tokens_s_chip=901.239,
+                         occupancy=0.87654, compiles_after_warmup=0,
+                         chunked_prefill=True, router_replicas=4,
+                         prefix_hit_rate=0.98765, router_p99_ms=77.7777,
+                         speculative=True, paged_attn=True,
+                         spec_accept_rate=0.61239,
+                         tokens_per_dispatch=2.71828,
+                         tp_shards=2, disaggregated=True,
+                         handoff_ms=0.12345,
+                         prefill_pool_occupancy=0.43219,
+                         decode_pool_occupancy=0.87654)
+    assert blk2["p99_ms"] == 12.346
+    assert blk2["tokens_s_chip"] == 901.2
+    assert blk2["occupancy"] == 0.8765
+    assert blk2["compiles_after_warmup"] == 0
+    assert blk2["chunked_prefill"] is True
+    assert blk2["router_replicas"] == 4
+    assert blk2["prefix_hit_rate"] == 0.9877
+    assert blk2["router_p99_ms"] == 77.778
+    assert blk2["speculative"] is True
+    assert blk2["paged_attn"] is True
+    assert blk2["spec_accept_rate"] == 0.6124
+    assert blk2["tokens_per_dispatch"] == 2.718
+    assert blk2["tp_shards"] == 2
+    assert blk2["disaggregated"] is True
+    assert blk2["handoff_ms"] == 0.123
+    assert blk2["prefill_pool_occupancy"] == 0.4322
+    assert blk2["decode_pool_occupancy"] == 0.8765
+    assert json.loads(json.dumps(blk)) == blk
+
+
+def test_loadgen_compiles_counter_reads_through_telemetry():
+    """The loadgen's compiles_after_warmup is a before/after DELTA off
+    the process registry (one source of truth), so a second engine in
+    the same process cannot inherit the first one's count."""
+    from mxnet_tpu import telemetry
+    if not telemetry.enabled():
+        return
+    telemetry.reset()
+    # simulate an earlier engine's post-warmup compile in this process
+    telemetry.inc("serving.compiles_after_warmup", 3)
+    import tools.serve_loadgen as slg
+    payload = slg.run_loadgen(n_requests=2, max_batch=2, block_size=8,
+                              max_context=64, mode="continuous",
+                              smoke=True)
+    blk = payload["serving"]
+    # the measured WINDOW saw zero compiles even though the process
+    # counter started at 3 — and the KV utilization gauge rode along
+    assert blk["compiles_after_warmup"] == 0
+    assert blk["cache_utilization"] is not None

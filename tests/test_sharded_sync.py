@@ -7,6 +7,7 @@ the quantized wire modes (bf16 / stochastic-rounding int8) report their
 MEASURED per-bucket error; the eager fused kvstore pushpull matches the
 in-graph traced path and the push-then-pull composition bit-for-bit.
 """
+import json
 import os
 
 import numpy as np
@@ -588,3 +589,39 @@ def test_trainer_allreduce_grads_shares_the_implementation():
     from mxnet_tpu.gluon.trainer import Trainer
     src = inspect.getsource(Trainer._all_reduce_grads)
     assert "all_reduce_gradients" in src
+
+
+# ----------------------------------------------------------------------
+# the `comm` block schema (ISSUE 3): regression-tested on CPU — the
+# sharded-sync observability must ship with every field present (zeros
+# are fine) so a TPU round can't discover a broken schema
+# ----------------------------------------------------------------------
+
+_COMM_KEYS = {
+    "zero1", "dp", "wire_dtype", "buckets", "bucket_mb",
+    "bytes_reduced_per_step", "bytes_gathered_per_step",
+    "grad_bytes_fp32", "collective_ms", "est_ici_gb_s",
+    "overlap_efficiency", "overlap_comm", "exposed_comm_ms",
+    "overlap_frac", "state_bytes_per_chip",
+    "state_bytes_replicated",
+}
+
+
+def test_comm_block_schema_is_stable():
+    from mxnet_tpu.parallel import zero
+    blk = zero.comm_block()
+    assert set(blk) == _COMM_KEYS
+    # static accounting defaults are zeros / fp32 — the CPU shape
+    assert blk["dp"] == 1 and not blk["zero1"]
+    assert blk["wire_dtype"] == "fp32"
+    # MEASURED fields are null when nothing measured (ISSUE 6 honesty
+    # fix: a CPU zero must not read as "measured: comm is free")
+    for k in ("collective_ms", "est_ici_gb_s", "overlap_efficiency",
+              "exposed_comm_ms", "overlap_frac"):
+        assert blk[k] is None, k
+    assert blk["overlap_comm"] is False
+    # measured values still round-trip as numbers
+    blk2 = zero.comm_block(collective_ms=1.8444, overlap_frac=0.51234)
+    assert blk2["collective_ms"] == 1.844
+    assert blk2["overlap_frac"] == 0.5123
+    assert json.loads(json.dumps(blk)) == blk

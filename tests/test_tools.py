@@ -350,116 +350,6 @@ def test_ps_heartbeat_detects_sigkilled_worker(tmp_path):
         srv._sock.close()
 
 
-# ---------------------------------------------------------------------
-# Scaling projection (tools/scaling_efficiency.py): the analytic
-# 8->256-chip roofline the bench attaches as `scaling_projection`
-# (reference metric: BASELINE >=70% scaling efficiency 8->256).
-# ---------------------------------------------------------------------
-
-def _project(**kw):
-    from tools.scaling_efficiency import project_ici_scaling
-    return project_ici_scaling(60.0, 51_114_064, **kw)
-
-
-def test_scaling_projection_ici_only():
-    out = _project()
-    effs = {r["chips"]: r["projected_efficiency"]
-            for r in out["projection"]}
-    # inside one ICI domain: comm ~1ms vs 60ms step -> >95% and
-    # monotonically non-increasing in N
-    assert effs[8] > 0.95 and effs[256] > 0.95
-    assert effs[8] >= effs[64] >= effs[256]
-    assert "host_fed_efficiency" not in out["projection"][0]
-    for r in out["projection"]:
-        if r["chips"] <= 256:
-            assert "t_dcn_ms" not in r
-
-
-def test_scaling_projection_dcn_term_charges_past_one_slice():
-    out = _project(chips=(256, 512, 1024))
-    rows = {r["chips"]: r for r in out["projection"]}
-    assert "t_dcn_ms" not in rows[256]          # one v5e slice: ICI only
-    assert rows[512]["dcn_slices"] == 2
-    assert rows[1024]["dcn_slices"] == 4
-    assert rows[512]["t_dcn_ms"] > 0
-    # DCN hop strictly lowers efficiency vs the intra-slice row
-    assert (rows[512]["projected_efficiency"]
-            < rows[256]["projected_efficiency"])
-    # 4 slices move more cross-slice bytes per host than 2 -> slower
-    assert rows[1024]["t_dcn_ms"] > rows[512]["t_dcn_ms"]
-
-
-def test_scaling_projection_input_feed_cap():
-    # starved host: 100 img/s supply vs 4 chips x 2000 img/s demand
-    out = _project(host_decode_imgs_per_sec=100.0,
-                   per_chip_imgs_per_sec=2000.0, chips_per_host=4)
-    cap = out["inputs"]["input_feed_cap"]
-    assert abs(cap - 100.0 / 8000.0) < 1e-9
-    for r in out["projection"]:
-        # host-fed row carries the cap; the ICI-only number is unchanged
-        assert abs(r["host_fed_efficiency"]
-                   - round(r["projected_efficiency"] * cap, 4)) < 1e-3
-    # ample host (core scale-up): cap saturates at 1.0
-    out2 = _project(host_decode_imgs_per_sec=100.0,
-                    per_chip_imgs_per_sec=2000.0, chips_per_host=4,
-                    host_core_scale=112.0)
-    assert out2["inputs"]["input_feed_cap"] == 1.0
-
-
-def test_bench_projection_plumbs_measured_sweep():
-    import bench
-    resnet = {"batch": 128, "value": 2000.0}
-    rec = {"input_pipeline": {"decode_thread_sweep": [
-        {"threads": 1, "img_s": 410.0}, {"threads": 4, "img_s": 410.0}]}}
-    out = bench._scaling_projection(resnet, rec)
-    assert "error" not in out
-    assert out["inputs"]["host_decode_imgs_per_sec"] == 410.0
-    assert out["inputs"]["per_chip_imgs_per_sec"] == 2000.0
-    assert "input_feed_cap" in out["inputs"]
-    # 512-chip row exercises the DCN term in the shipped payload
-    assert any(r.get("dcn_slices") == 2 for r in out["projection"])
-    # without a sweep the projection still lands, ICI-only
-    out2 = bench._scaling_projection(resnet, None)
-    assert "error" not in out2
-    assert "input_feed_cap" not in out2["inputs"]
-
-
-def test_bench_projection_host_core_slope_derates_feed_cap():
-    """ISSUE 18 satellite: the host core scale-up is de-rated by the
-    MEASURED thread-scaling slope (marginal img/s per added thread over
-    the 1-thread img/s), computed only from in-core sweep points —
-    oversubscribed points measure contention, not parallelism."""
-    import bench
-    resnet = {"batch": 128, "value": 2000.0}
-    rec = {"input_pipeline": {"host_cores": 4, "decode_thread_sweep": [
-        {"threads": 1, "img_s": 100.0}, {"threads": 2, "img_s": 190.0},
-        {"threads": 4, "img_s": 340.0}, {"threads": 8, "img_s": 360.0}]}}
-    out = bench._scaling_projection(resnet, rec)
-    assert "error" not in out
-    inp = out["inputs"]
-    # slope across in-core points (1..4): (340-100)/(4-1) = 80 img/s per
-    # thread; the 8-thread point (past the 4 cores) must NOT drag it
-    # down to (360-100)/7
-    assert inp["host_thread_slope_img_s"] == 80.0
-    assert inp["host_parallel_efficiency"] == 0.8
-    # core scale uses the cores recorded WITH the sweep, not this box's
-    assert abs(inp["host_core_scale"] - 112.0 / 4) < 1e-9
-    # supply = best * core_scale * par_eff; demand = 4 chips * 2000
-    cap = inp["input_feed_cap"]
-    assert abs(cap - min(1.0, 360.0 * 28.0 * 0.8 / 8000.0)) < 1e-6
-
-    # single in-core point (1-core host): the efficiency is unmeasurable
-    # and the projection DISCLOSES the linearity assumption instead of
-    # silently assuming it
-    rec1 = {"input_pipeline": {"host_cores": 1, "decode_thread_sweep": [
-        {"threads": 1, "img_s": 410.0}, {"threads": 4, "img_s": 500.0}]}}
-    out1 = bench._scaling_projection(resnet, rec1)
-    assert "error" not in out1
-    assert out1["inputs"]["host_parallel_efficiency"] \
-        == "unmeasured: linear core scaling ASSUMED"
-    assert "host_thread_slope_img_s" not in out1["inputs"]
-
-
 # ----------------------------------------------------------------------
 # tools/telemetry_dump.py (ISSUE 9): flight-dump/snapshot rendering +
 # the live PS-server scrape path — tier-1 smoke
@@ -506,188 +396,3 @@ def test_telemetry_dump_self_test_prom():
     assert r.returncode == 0, r.stderr
     assert "mxtpu_selftest_counter 3" in r.stdout
     assert 'mxtpu_selftest_ms_bucket{le="+Inf"} 1' in r.stdout
-
-
-# ---------------------------------------------------------------------------
-# tools/bench_diff.py — the cross-round perf gate (ISSUE 11 satellite)
-# ---------------------------------------------------------------------------
-
-def _bench_payload(value=2000.0, step_ms=None, schema=1, platform="tpu"):
-    d = {"metric": "resnet50_train_images_per_sec", "value": value,
-         "unit": "img/s", "vs_baseline": round(value / 380.0, 3),
-         "platform": platform, "telemetry_schema_version": schema,
-         "batch": 128, "mfu": round(value / 8600.0, 4),
-         "comm": {"collective_ms": step_ms, "est_ici_gb_s": None},
-         "extra": {"serving": {"tokens_s_chip": 900.0, "p99_ms": 41.0}}}
-    return d
-
-
-def _write(tmp_path, name, payload):
-    import json
-    p = tmp_path / name
-    p.write_text(json.dumps(payload))
-    return str(p)
-
-
-def test_bench_diff_detects_planted_regression(tmp_path):
-    """The acceptance fixture pair: a planted 20% throughput regression
-    must exit non-zero under --fail-on-regression 10."""
-    from tools import bench_diff
-    old = _write(tmp_path, "old.json", _bench_payload(value=2000.0))
-    new = _write(tmp_path, "new.json", _bench_payload(value=1600.0))
-    rc = bench_diff.main([old, new, "--fail-on-regression", "10",
-                          "--quiet"])
-    assert rc == 1
-    # within threshold: clean exit
-    ok = _write(tmp_path, "ok.json", _bench_payload(value=1950.0))
-    assert bench_diff.main([old, ok, "--fail-on-regression", "10",
-                            "--quiet"]) == 0
-    # without the gate flag the same pair only reports
-    assert bench_diff.main([old, new, "--quiet"]) == 0
-
-
-def test_bench_diff_direction_awareness(tmp_path):
-    """Latency going UP is a regression; latency going DOWN is not —
-    and an improved throughput never gates."""
-    from tools import bench_diff
-    old = _bench_payload(); old["extra"]["serving"]["p99_ms"] = 40.0
-    new = _bench_payload(); new["extra"]["serving"]["p99_ms"] = 60.0
-    o = _write(tmp_path, "o.json", old)
-    n = _write(tmp_path, "n.json", new)
-    assert bench_diff.main([o, n, "--fail-on-regression", "10",
-                            "--quiet"]) == 1
-    faster = _bench_payload(value=2400.0)
-    faster["extra"]["serving"]["p99_ms"] = 20.0
-    f = _write(tmp_path, "f.json", faster)
-    assert bench_diff.main([o, f, "--fail-on-regression", "10",
-                            "--quiet"]) == 0
-
-
-def test_bench_diff_disagg_field_directions(tmp_path):
-    """ISSUE 18 serving fields: handoff_ms gates when it GROWS, pool
-    occupancies gate when they SHRINK; tp_shards is config — a resharded
-    fleet is a changed knob, never a regression."""
-    from tools import bench_diff
-    assert bench_diff.direction("extra.serving.handoff_ms") == "down"
-    assert bench_diff.direction(
-        "extra.serving.prefill_pool_occupancy") == "up"
-    assert bench_diff.direction(
-        "extra.serving.decode_pool_occupancy") == "up"
-    old = _bench_payload()
-    old["extra"]["serving"]["handoff_ms"] = 0.2
-    old["extra"]["serving"]["decode_pool_occupancy"] = 0.9
-    old["extra"]["serving"]["tp_shards"] = 2
-    o = _write(tmp_path, "o.json", old)
-    worse = _bench_payload()
-    worse["extra"]["serving"]["handoff_ms"] = 0.6
-    worse["extra"]["serving"]["decode_pool_occupancy"] = 0.9
-    worse["extra"]["serving"]["tp_shards"] = 2
-    n = _write(tmp_path, "n.json", worse)
-    # handoff latency tripled -> gates
-    assert bench_diff.main([o, n, "--fail-on-regression", "10",
-                            "--quiet"]) == 1
-    starved = _bench_payload()
-    starved["extra"]["serving"]["handoff_ms"] = 0.2
-    starved["extra"]["serving"]["decode_pool_occupancy"] = 0.4
-    starved["extra"]["serving"]["tp_shards"] = 2
-    n2 = _write(tmp_path, "n2.json", starved)
-    # decode pool idling (occupancy halved) -> gates
-    assert bench_diff.main([o, n2, "--fail-on-regression", "10",
-                            "--quiet"]) == 1
-    resharded = _bench_payload()
-    resharded["extra"]["serving"]["handoff_ms"] = 0.2
-    resharded["extra"]["serving"]["decode_pool_occupancy"] = 0.9
-    resharded["extra"]["serving"]["tp_shards"] = 8
-    n3 = _write(tmp_path, "n3.json", resharded)
-    # only the tp_shards knob changed -> clean exit
-    assert bench_diff.main([o, n3, "--fail-on-regression", "10",
-                            "--quiet"]) == 0
-
-
-def test_bench_diff_skips_nulls_and_checks_schema(tmp_path):
-    from tools import bench_diff
-    # null-when-unmeasured on one side: the metric never compares, so a
-    # CPU round with nulls cannot fake a regression
-    old = _bench_payload(step_ms=3.2)
-    new = _bench_payload(step_ms=None)
-    o = _write(tmp_path, "o.json", old)
-    n = _write(tmp_path, "n.json", new)
-    assert bench_diff.main([o, n, "--fail-on-regression", "10",
-                            "--quiet"]) == 0
-    # schema drift: refuse to compare (exit 2) unless allowed
-    drift = _write(tmp_path, "d.json", _bench_payload(schema=2))
-    assert bench_diff.main([o, drift, "--quiet"]) == 2
-    assert bench_diff.main([o, drift, "--allow-schema-drift",
-                            "--quiet"]) == 0
-
-
-def test_bench_diff_platform_mismatch_never_gates(tmp_path):
-    """A CPU round vs a TPU round is apples-to-oranges: it must not read
-    as a 90% regression."""
-    from tools import bench_diff
-    o = _write(tmp_path, "o.json", _bench_payload(value=2000.0))
-    n = _write(tmp_path, "n.json",
-               _bench_payload(value=150.0, platform="cpu"))
-    assert bench_diff.main([o, n, "--fail-on-regression", "10",
-                            "--quiet"]) == 0
-
-
-def test_bench_diff_reads_driver_round_wrappers(tmp_path):
-    """BENCH_r*.json trajectory files ({"cmd", "parsed": ...}) unwrap;
-    an unparsed round (parsed: null) compares as nothing, exit 0."""
-    import json
-    from tools import bench_diff
-    w_old = _write(tmp_path, "BENCH_r01.json",
-                   {"n": 1, "cmd": "python bench.py", "rc": 0,
-                    "parsed": _bench_payload(value=2000.0)})
-    w_new = _write(tmp_path, "BENCH_r02.json",
-                   {"n": 2, "cmd": "python bench.py", "rc": 0,
-                    "parsed": _bench_payload(value=1000.0)})
-    assert bench_diff.main([w_old, w_new, "--fail-on-regression", "10",
-                            "--quiet"]) == 1
-    w_null = _write(tmp_path, "BENCH_r03.json",
-                    {"n": 3, "cmd": "python bench.py", "rc": 1,
-                     "parsed": None})
-    assert bench_diff.main([w_old, w_null, "--fail-on-regression",
-                            "10", "--quiet"]) == 0
-
-
-def test_scaling_efficiency_3d_projection():
-    """tools/scaling_efficiency.py 3D model: more chips on tp/pp axes
-    cost comm/bubble efficiency; every input is surfaced; the tp term
-    discloses itself when unmodeled."""
-    from tools.scaling_efficiency import project_3d_scaling
-    out = project_3d_scaling(
-        60.0, 1.02e8,
-        mesh_shapes=[(256, 1, 1), (64, 4, 1), (32, 4, 2)],
-        act_bytes_per_layer=2.6e6, n_layers=50, base_mfu=0.24)
-    rows = out["projection"]
-    assert [r["chips"] for r in rows] == [256, 256, 256]
-    assert all(0 < r["projected_efficiency"] <= 1 for r in rows)
-    # pure dp pays only the (well-overlapped) grad ring
-    assert rows[0]["projected_efficiency"] > rows[1]["projected_efficiency"]
-    # adding a pipeline axis pays the 1F1B bubble on top
-    assert rows[1]["projected_efficiency"] > rows[2]["projected_efficiency"]
-    assert rows[2]["pp_bubble_frac"] > 0
-    assert rows[0]["pp_bubble_frac"] == 0
-    assert all("projected_mfu" in r for r in rows)
-    # unmodeled tp term must say so rather than read as free
-    out2 = project_3d_scaling(60.0, 1.02e8, mesh_shapes=[(64, 4, 1)])
-    assert "UNMODELED" in out2["projection"][0]["tp_term"]
-    assert out["inputs"]["param_bytes"] == 1.02e8
-
-
-def test_memory_levers_ce_child_on_cpu(monkeypatch, tmp_path):
-    """tools/memory_levers.py's child entry at its CPU smoke scale: the
-    blocked and the materialized LM-head cross-entropy agree on the loss
-    (moved here from the deleted queue-runner tests; the compile cache
-    the child turns on is kept out of the checkout)."""
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    from tools.memory_levers import run_config, MATRIX
-    fused = run_config("ce_fused_32k", "ce", impl="fused", vocab=32768,
-                       tokens=8192)
-    naive = run_config("ce_naive_32k", "ce", impl="naive", vocab=32768,
-                       tokens=8192)
-    assert not fused["oom"] and not naive["oom"]
-    assert abs(fused["loss"] - naive["loss"]) < 0.05, (fused, naive)
-    assert set(MATRIX) >= {"accum_base", "ce_fused_128k", "zero1"}

@@ -1,10 +1,10 @@
-"""Health watchdog + the bench cost model (ISSUE 14).
+"""Health watchdog (ISSUE 14).
 
 Covers the rule catalog (non-finite loss/grad, loss spike vs trailing
 window, FakeClock step stall, serving queue saturation, KV-block leak
 trend), the typed ``watchdog.*`` event + ``reason="watchdog:<rule>"``
-flight-dump contract, the bitwise-inert ``MXTPU_WATCHDOG=0`` kill
-switch, and that ``telemetry.costmodel`` is bench.py's cost model.
+flight-dump contract, and the bitwise-inert ``MXTPU_WATCHDOG=0`` kill
+switch.
 """
 import json
 import math
@@ -15,7 +15,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, parallel, telemetry
-from mxnet_tpu.telemetry import costmodel, watchdog
+from mxnet_tpu.telemetry import watchdog
 from mxnet_tpu.telemetry.watchdog import Watchdog
 from mxnet_tpu.testing import faults
 from mxnet_tpu.testing.faults import FakeClock
@@ -249,30 +249,3 @@ def test_watchdog_chaos_scenario(tmp_path, monkeypatch):
     assert r["trips"] == ["nonfinite_loss", "step_stall"]
     assert r["nan_flight"]["reason"] == "watchdog:nonfinite_loss"
     assert r["stall_flight"]["reason"] == "watchdog:step_stall"
-
-
-# ----------------------------------------------------------------------
-# the bench cost model (telemetry/costmodel.py)
-# ----------------------------------------------------------------------
-
-def test_costmodel_is_the_bench_cost_model():
-    import bench
-    assert bench._resnet_train_flops_per_img() == \
-        costmodel.resnet_train_flops_per_img() == 3 * 4.1e9
-    assert bench._bert_train_flops_per_sample(128) == \
-        costmodel.bert_train_flops_per_sample(128)
-    # attach_mfu: identical payload bytes for identical inputs (the
-    # byte-identity satellite gate)
-    a = costmodel.attach_mfu({"batch": 8}, 1e9, 100.0)
-    b = bench._attach_mfu({"batch": 8}, 1e9, 100.0)
-    assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
-    assert a["flops_source"] == "analytic_2mac"
-    assert a["tflops_delivered"] == round(1e9 * 100.0 / 1e12, 2)
-
-
-def test_chip_peak_env_override(monkeypatch):
-    assert costmodel.chip_peak_flops() is None          # CPU host
-    monkeypatch.setenv("MXTPU_CHIP_PEAK_TFLOPS", "197")
-    assert costmodel.chip_peak_flops() == 197e12
-    monkeypatch.setenv("MXTPU_CHIP_PEAK_TFLOPS", "bogus")
-    assert costmodel.chip_peak_flops() is None

@@ -8,7 +8,10 @@ either to make the test cheaper or to move it behind
 ``@pytest.mark.slow`` where its cost is a visible, budgeted decision.
 
 On partial runs (``pytest tests/test_foo.py``) only the selected tests
-were timed — the guard still holds for exactly what ran.
+were timed — the guard still holds for exactly what ran.  The driver's run
+(six xdist workers, ``--dist loadfile``, ``timeout 1470``, about 300 s) puts
+this module on one worker, which has timed only the files it ran: there the
+guard holds for that worker's share.
 """
 import conftest
 
