@@ -17,9 +17,23 @@ streams Lk in BK blocks through VMEM with a float32 accumulator: the MXU
 sees G batched (BK, D) x (D, BQ) / (D, BK) x (BK, BQ) matmuls per step.  G
 comes from the shapes alone (:func:`_rows_per_program`): as many rows as
 give a program a few microseconds of work and fit VMEM.  When one BK block
-holds all of Lk the body is a plain one-pass softmax.  The fallback is the
-same algorithm as a ``lax.scan`` over KV blocks, which XLA fuses
-adequately on CPU and keeps memory O(L*BK).
+holds all of Lk the body is a plain one-pass softmax.  Otherwise a grid
+step holds as many KV blocks as VMEM has room for
+(:func:`_kv_blocks_per_step`) and walks them in a loop of its own, a block
+at a time through the same online softmax (a grid step costs about half a
+microsecond whatever it computes); with few rows to a program the Q
+block's columns go in two halves, the second's QK^T issued before the
+first's softmax.  Under ``causal`` the walk sorts the blocks from the grid
+indices (:func:`_causal_extent`), as the backward does: a block that is
+wholly masked is skipped, and a grid step that holds only such blocks asks
+for the row's last live K / V blocks again, so nothing is fetched for it; a
+block the diagonal cuts is masked; a wholly visible one runs without the
+iota, the compare and the select.  Where ``sm_scale`` is a power of two and
+the Q block a quarter of the score tile or less it multiplies the
+(G, D, BQ) block of Q and not the (G, BK, BQ) scores: the same numbers, bit
+for bit, from fewer elements.  The fallback is the same algorithm as a
+``lax.scan`` over KV blocks, which XLA fuses adequately on CPU and keeps
+memory O(L*BK).
 
 Gradients: custom VJP; the backward pass recomputes scores blockwise from
 the saved logsumexp (standard flash-attention backward) — no O(L^2)
@@ -134,6 +148,72 @@ def _rows_per_program(bh, bq, bk, d, itemsize, streaming, dv=None):
         (bq + bk) * (d + dv) * itemsize)
 
 
+def _causal_block(i, j, bq, bk):
+    """``(live, cut)`` of Q block ``i`` against KV block ``j`` under the
+    causal mask (query ``a`` sees key ``b`` where ``a >= b``, both counted
+    from 0): live unless the block's first key comes after its last query,
+    cut where its last key comes after its first query.  Live and not cut
+    is wholly visible.  Grid indices in the backward kernel, plain ints in
+    the counts and the tests."""
+    return (i + 1) * bq > j * bk, (j + 1) * bk - 1 > i * bq
+
+
+def _causal_extent(i, bq, bk):
+    """``(visible, live)``: :func:`_causal_block` as counts, for the
+    forward's inner walk — how many of the KV blocks, from the first on, Q
+    block ``i`` sees whole and how many it sees any of (``live`` may pass
+    the last block there is, where Lq is longer than Lk); the blocks between
+    the two are the ones the diagonal cuts.  (Not one written from the
+    other: the divisions cost the backward, which asks once a grid step,
+    0.9 % of its time on the chip.)"""
+    return (i * bq + 1) // bk, ((i + 1) * bq + bk - 1) // bk
+
+
+def _kv_block_fetched(i, j, bq, bk):
+    """The K / V block grid step ``(i, j)`` of a causal forward names: its
+    own while it is live, then the one that holds the last key Q block ``i``
+    sees, which is the row's last live one and in VMEM already, so that no
+    copy is issued for a skipped step.  ``bk`` is what one grid step holds
+    of Lk."""
+    return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+
+
+def _kv_blocks_per_step(nk, g, bq, bk, d, itemsize, dv):
+    """KV blocks one grid step of the streaming forward holds and walks: the
+    largest divisor of ``nk`` whose K / V blocks, double-buffered, fit what
+    :func:`_program_vmem_bytes` leaves of ``_VMEM_BUDGET`` at the ``g``
+    already chosen.  A grid step costs about half a microsecond whatever it
+    computes; the walk inside one costs nothing a block."""
+    used = _program_vmem_bytes(g, bq, bk, d, itemsize, True, dv)
+    more = 2 * g * _padded_head_dims(d, dv, itemsize) * bk * itemsize
+    return max([n for n in range(1, nk + 1) if nk % n == 0 and
+                used + (n - 1) * more <= _VMEM_BUDGET] or [1])
+
+
+def _scale_on_q(sm_scale, d, bk):
+    """Whether ``sm_scale`` multiplies the (G, D, BQ) block of Q in place of
+    the (G, BK, BQ) score tile: where it is a power of two, which commutes
+    with every rounding on the way to the scores, so the result is the same
+    bit for bit, and the Q block is at most a quarter of the tile (a bf16
+    multiply costs the v5e's float32 vector unit two converts besides: at a
+    half, L = 128 at d = 64, it does not pay)."""
+    return math.frexp(sm_scale)[0] == 0.5 and 4 * d <= bk
+
+
+def _forward_block_counts(lq, lk, bq, bk, causal):
+    """``(live, masked)``: of the ``nq * nk`` (Q block, KV block) pairs of
+    one (batch x head) row, those the forward kernel computes and those it
+    masks.  The one-pass body (``nk == 1``) masks every block of a causal
+    call; the streaming one only those the diagonal cuts."""
+    nq, nk = lq // bq, lk // bk
+    if not causal:
+        return nq * nk, 0
+    pairs = [_causal_block(i, j, bq, bk)
+             for i in range(nq) for j in range(nk)]
+    live = sum(lv for lv, _ in pairs)
+    return live, live if nk == 1 else sum(lv and ct for lv, ct in pairs)
+
+
 def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -145,6 +225,19 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     # from the rows this call sees: a chip's own, inside _per_batch_shard
     g = _rows_per_program(bh, *shape)
     _telem.set_gauge("flash.fwd.rows_per_program", g)
+    live, masked = _forward_block_counts(lq, lk, bq, bk, causal)
+    _telem.set_gauge("flash.fwd.blocks_live", live)
+    _telem.set_gauge("flash.fwd.blocks_masked", masked)
+    scale_on_q = _scale_on_q(sm_scale, d, bk)
+    # the streaming body: KV blocks a grid step walks, and the Q block's
+    # columns (queries: independent of each other) in two halves where the
+    # program has few rows to keep the MXU and the vector unit both busy
+    nsub = _kv_blocks_per_step(nk, g, bq, bk, d, q.dtype.itemsize,
+                               dv) if nk > 1 else 1
+    _telem.set_gauge("flash.fwd.kv_blocks_per_step", nsub)
+    halves = 2 if g <= 2 and bq % 256 == 0 else 1
+    cols = [slice(c * bq // halves, (c + 1) * bq // halves)
+            for c in range(halves)]
     # rows on sublanes where G fills whole tiles; otherwise mosaic's
     # (8, 128) tile is met by a broadcast sublane dim, sliced off below
     lse_rows = g % 8 == 0
@@ -169,21 +262,25 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
         # interpreter multiplies in float32: the same products, exactly
         return x.astype(jnp.float32) if interpret else x
 
-    def scores(q_ref, k_ref, i, j):
-        # (g, d, bk) x (g, d, bq) over d -> (g, bk, bq): keys on sublanes
+    def scores(qb, kb, i, j, mask, col=0):
+        # of Q block i from its column `col` on against KV block j
+        if scale_on_q:
+            qb = qb * sm_scale
+        # (g, d, bk) x (g, d, cols) over d -> (g, bk, cols): keys on sublanes
         s = lax.dot_general(
-            operand(k_ref[...]), operand(q_ref[...]),
-            (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
-            qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            operand(kb), operand(qb), (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        if not scale_on_q:
+            s = s * sm_scale
+        if mask:
+            kpos = j * bk + lax.broadcasted_iota(jnp.int32, s.shape[1:], 0)
+            qpos = i * bq + col + lax.broadcasted_iota(jnp.int32,
+                                                       s.shape[1:], 1)
             s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
         return s
 
-    def p_dot_v(v_ref, p):
-        # (g, dv, bk) x (g, bk, bq) -> (g, dv, bq)
-        vb = v_ref[...]
+    def p_dot_v(vb, p):
+        # (g, dv, bk) x (g, bk, cols) -> (g, dv, cols)
         return lax.dot_general(
             operand(vb), operand(p.astype(vb.dtype)),
             (((2,), (1,)), ((0,), (0,))),
@@ -197,11 +294,11 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
 
     def one_pass(q_ref, k_ref, v_ref, o_ref, lse_ref):
         # all of Lk is in the block: nothing to stream, no running state
-        s = scores(q_ref, k_ref, pl.program_id(1), 0)
+        s = scores(q_ref[...], k_ref[...], pl.program_id(1), 0, causal)
         m = jnp.max(s, axis=1, keepdims=True)   # (g, 1, bq)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)   # >= 1: the max's own term
-        o_ref[...] = (p_dot_v(v_ref, p) / l).astype(o_ref.dtype)
+        o_ref[...] = (p_dot_v(v_ref[...], p) / l).astype(o_ref.dtype)
         store_lse(lse_ref, m + jnp.log(l))
 
     def streaming(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_i, l_i):
@@ -214,21 +311,40 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
             l_i[...] = jnp.zeros_like(l_i)
             acc[...] = jnp.zeros_like(acc)
 
-        # Causal: the whole KV block is in the future of the whole Q block
-        # when j*bk > i*bq + bq - 1 — skip its compute entirely.
-        live = (i + 1) * bq > j * bk if causal else True
+        def walk(mask):
+            def block(sub, carry):
+                ks = pl.ds(pl.multiple_of(sub * bk, bk), bk)
+                # every half's QK^T before the first's softmax: the MXU
+                # then has the next product to do under the vector unit
+                tiles = [scores(q_ref[:, :, cs], k_ref[:, :, ks], i,
+                                j * nsub + sub, mask, cs.start)
+                         for cs in cols]
+                for cs, s in zip(cols, tiles):
+                    m_old = m_i[:, :, cs]
+                    m_new = jnp.maximum(
+                        m_old, jnp.max(s, axis=1, keepdims=True))
+                    p = jnp.exp(s - m_new)          # (g, bk, cols) f32
+                    alpha = jnp.exp(m_old - m_new)  # (g, 1, cols)
+                    l_i[:, :, cs] = l_i[:, :, cs] * alpha + jnp.sum(
+                        p, axis=1, keepdims=True)
+                    acc[:, :, cs] = acc[:, :, cs] * alpha + p_dot_v(
+                        v_ref[:, :, ks], p)
+                    m_i[:, :, cs] = m_new
+                return carry
+            return block
 
-        @pl.when(live)
-        def _step():
-            s = scores(q_ref, k_ref, i, j)
-            m_new = jnp.maximum(m_i[...], jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)              # (g, bk, bq) f32
-            alpha = jnp.exp(m_i[...] - m_new)   # (g, 1, bq)
-            l_i[...] = l_i[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc[...] = acc[...] * alpha + p_dot_v(v_ref, p)
-            m_i[...] = m_new
+        if causal:
+            # of this step's KV blocks: the wholly visible run plain, the
+            # ones the diagonal cuts masked, the wholly masked not at all
+            # (and nothing is fetched for a step that has only those: at_kv)
+            visible, live = (jnp.clip(n - j * nsub, 0, nsub)
+                             for n in _causal_extent(i, bq, bk))
+            lax.fori_loop(0, visible, walk(False), 0)
+            lax.fori_loop(visible, live, walk(True), 0)
+        else:
+            lax.fori_loop(0, nsub, walk(False), 0)
 
-        @pl.when(j == nk - 1)
+        @pl.when(j == nk // nsub - 1)
         def _fin():
             denom = jnp.maximum(l_i[...], 1e-30)
             o_ref[...] = (acc[...] / denom).astype(o_ref.dtype)
@@ -240,16 +356,24 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     else:
         lse_spec = pl.BlockSpec((g, 8, bq), lambda b, i, j: (b, 0, i))
         lse_shape = jax.ShapeDtypeStruct((bh, 8, lq), jnp.float32)
+
+    def at_kv(b, i, j):
+        # a skipped step asks for the row's last live K / V blocks again,
+        # which are in VMEM already: no copy is issued for it
+        if causal:
+            j = _kv_block_fetched(i, j, bq, bk * nsub)
+        return (b, 0, j)
+
     # where one row at the caller's blocks does not fit the budget, mosaic's
-    # limit is raised by what it is over
+    # limit is raised by what it is over (nsub is 1 there)
     over = _program_vmem_bytes(g, *shape) - _VMEM_BUDGET
     out_t, lse = pl.pallas_call(
         one_pass if nk == 1 else streaming,
-        grid=(bh // g, nq, nk),
+        grid=(bh // g, nq, nk // nsub),
         in_specs=[
             pl.BlockSpec((g, d, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((g, d, bk), lambda b, i, j: (b, 0, j)),
-            pl.BlockSpec((g, dv, bk), lambda b, i, j: (b, 0, j)),
+            pl.BlockSpec((g, d, bk * nsub), at_kv),
+            pl.BlockSpec((g, dv, bk * nsub), at_kv),
         ],
         out_specs=[
             pl.BlockSpec((g, dv, bq), lambda b, i, j: (b, 0, i)),
@@ -464,8 +588,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
         if causal:
             # wholly masked (its first key after the block's last query):
             # skipped; cut by the diagonal: masked; wholly visible: plain
-            live = (i + 1) * bq > j * bk
-            cut = (j + 1) * bk - 1 > i * bq
+            live, cut = _causal_block(i, j, bq, bk)
             pl.when(live & cut)(lambda: step(True))
             pl.when(live & jnp.logical_not(cut))(lambda: step(False))
         else:
@@ -649,7 +772,11 @@ def flash_attention(query, key, value, causal=False, sm_scale=None):
     fallback with identical semantics.  Counters ``flash.fwd.pallas`` /
     ``flash.fwd.scan`` and ``flash.bwd.pallas`` / ``flash.bwd.scan`` say
     which was traced, gauges ``flash.fwd.rows_per_program`` and
-    ``flash.bwd.rows_per_program`` the last G.
+    ``flash.bwd.rows_per_program`` the last G, ``flash.fwd.blocks_live`` /
+    ``flash.fwd.blocks_masked`` the (Q block, KV block) pairs a row of the
+    last forward kernel computes and those it masks,
+    ``flash.fwd.kv_blocks_per_step`` the KV blocks one of its grid steps
+    walks.
     """
     from ..ndarray.ndarray import NDArray, apply_nary
 

@@ -191,30 +191,241 @@ def _flash_module():
     return importlib.import_module("mxnet_tpu.ops.flash_attention")
 
 
+# (lq, lk, bq, bk) of the streaming forward's cases below: every way a causal
+# row can mix wholly visible, cut and wholly masked (dead) blocks
+_BLOCK_SHAPES = {
+    "square": (256, 256, 128, 128),
+    "visible-cut-dead-in-one-row": (384, 384, 128, 128),
+    "bq-over-bk": (1024, 384, 512, 128),
+    "bq-under-bk": (384, 1024, 128, 512),
+    "lq-over-lk": (512, 256, 128, 128),
+    "lq-under-lk": (256, 512, 128, 128),
+    "column-halves": (512, 512, 256, 256),
+    "kanana": (4096, 4096, 512, 512),
+}
+
+
+def _streaming(bh, shape, d=64, dv=64, note=""):
+    """A case of ``shape``'s blocks; every row fits one program at these
+    sizes, so G is ``bh``."""
+    lq, lk, bq, bk = _BLOCK_SHAPES[shape]
+    return pytest.param(bh, lq, lk, d, dv, bq, bk, bh,
+                        id=f"streaming-{shape}{note}")
+
+
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("bh,seq,d,block,rows", [
-    pytest.param(24, 128, 64, 128, 24, id="one-pass-lse-rows"),
-    pytest.param(6, 128, 64, 128, 6, id="one-pass-lse-broadcast"),
-    pytest.param(16, 256, 64, 128, 16, id="streaming"),
-    pytest.param(3, 256, 128, 256, 3, id="one-pass-d128-block256"),
+@pytest.mark.parametrize("bh,lq,lk,d,dv,bq,bk,rows", [
+    pytest.param(24, 128, 128, 64, 64, 128, 128, 24,
+                 id="one-pass-lse-rows"),
+    pytest.param(6, 128, 128, 64, 64, 128, 128, 6,
+                 id="one-pass-lse-broadcast"),
+    _streaming(16, "square"),
+    pytest.param(3, 256, 256, 128, 128, 256, 256, 3,
+                 id="one-pass-d128-block256"),
+    _streaming(2, "visible-cut-dead-in-one-row"),
+    _streaming(2, "bq-over-bk"),
+    _streaming(2, "bq-under-bk"),
+    _streaming(8, "lq-over-lk", note="-G8"),
+    _streaming(3, "lq-under-lk", note="-G3"),
+    _streaming(4, "visible-cut-dead-in-one-row", 192, 128,
+               note="-mla-192-128-G4"),
+    _streaming(2, "column-halves"),
+    pytest.param(2, 1024, 384, 64, 64, 512, 384, 2,
+                 id="one-pass-two-q-blocks"),
 ])
-def test_pallas_forward_rows_per_program_matches_scan(bh, seq, d, block,
-                                                      rows, causal):
+def test_pallas_forward_rows_per_program_matches_scan(bh, lq, lk, d, dv, bq,
+                                                      bk, rows, causal):
+    """The kernel's output and log-sum-exp (one block of keys: the one-pass
+    body; more: the streaming one, which walks the KV blocks a grid step
+    holds and under ``causal`` skips the dead ones, masks the cut ones and
+    runs the visible ones plain; blocks of 256 queries and up go in two
+    column halves at these G) against the scan and against plain
+    softmax(QK^T)V."""
     from mxnet_tpu import telemetry
     mod = _flash_module()
-    rng = np.random.RandomState(bh + seq)
-    q, k, v = (jnp.asarray(rng.randn(bh, seq, d), jnp.float32)
-               for _ in range(3))
+    rng = np.random.RandomState(bh + lq + lk)
+    q = jnp.asarray(rng.randn(bh, lq, d), jnp.float32)
+    k = jnp.asarray(rng.randn(bh, lk, d), jnp.float32)
+    v = jnp.asarray(rng.randn(bh, lk, dv), jnp.float32)
     scale = 1.0 / np.sqrt(d)
-    out, lse = mod._pallas_forward(q, k, v, causal, scale, block, block,
+    out, lse = mod._pallas_forward(q, k, v, causal, scale, bq, bk,
                                    interpret=True)
     assert telemetry.value("flash.fwd.rows_per_program") == rows
-    ref, ref_lse = mod._scan_forward(q, k, v, causal, scale, block)
-    assert out.shape == (bh, seq, d) and lse.shape == (bh, seq)
+    # at these sizes every KV block fits VMEM: one grid step walks them all
+    assert telemetry.value("flash.fwd.kv_blocks_per_step") == \
+        (lk // bk if lk > bk else 1)
+    ref, ref_lse = mod._scan_forward(q, k, v, causal, scale, bk)
+    assert out.shape == (bh, lq, dv) and lse.shape == (bh, lq)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_naive(q[None], k[None], v[None], causal,
+                                           scale)[0]), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("shape", sorted(_BLOCK_SHAPES))
+def test_causal_block_classes_and_kv_clamp_against_the_mask_itself(shape):
+    """Every (Q block, KV block) pair of the shapes above, sorted by the
+    kernels' predicates, against the causal mask over positions: live where
+    any score is visible, cut where some but not all are; a dead step names
+    the row's last live K / V block (so nothing is fetched for it) and no
+    live step is redirected."""
+    mod = _flash_module()
+    lq, lk, bq, bk = _BLOCK_SHAPES[shape]
+    nq, nk = lq // bq, lk // bk
+    visible = np.arange(lq)[:, None] >= np.arange(lk)[None, :]
+    counts = [0, 0]
+    for i in range(nq):
+        block = [visible[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+                 for j in range(nk)]
+        live_blocks = [j for j in range(nk) if block[j].any()]
+        assert live_blocks and live_blocks == list(range(len(live_blocks)))
+        for j in range(nk):
+            live, cut = mod._causal_block(i, j, bq, bk)
+            assert live == block[j].any()
+            assert (live and cut) == (block[j].any() and not block[j].all())
+            assert (live and not cut) == block[j].all()
+            fetched = int(mod._kv_block_fetched(i, j, bq, bk))
+            assert fetched == (j if live else live_blocks[-1])
+            counts[0] += live
+            counts[1] += live and cut
+        # the same sorting as counts from the first block on (the forward's
+        # inner walk): visible blocks, then cut ones, then dead ones
+        visible_run, live_run = mod._causal_extent(i, bq, bk)
+        assert [mod._causal_block(i, j, bq, bk) for j in range(nk)] == \
+            [(j < live_run, j >= visible_run) for j in range(nk)]
+        # a grid step that holds several KV blocks: one with a live block
+        # names itself, a later one the last that has one
+        for held in (n for n in range(2, nk + 1) if nk % n == 0):
+            for step in range(nk // held):
+                has_live = step * held <= live_blocks[-1]
+                assert int(mod._kv_block_fetched(i, step, bq, bk * held)) \
+                    == (step if has_live else live_blocks[-1] // held)
+    assert mod._forward_block_counts(lq, lk, bq, bk, True) == tuple(counts)
+    assert mod._forward_block_counts(lq, lk, bq, bk, False) == (nq * nk, 0)
+
+
+@pytest.mark.parametrize("lq,lk,bq,bk,causal,live,masked", [
+    pytest.param(*_BLOCK_SHAPES["kanana"], True, 36, 8, id="kanana"),
+    pytest.param(*_BLOCK_SHAPES["kanana"], False, 64, 0,
+                 id="kanana-non-causal"),
+    pytest.param(512, 512, 512, 512, True, 1, 1, id="one-pass-causal"),
+    pytest.param(512, 512, 512, 512, False, 1, 0, id="one-pass-bert-s512"),
+    pytest.param(1024, 384, 512, 384, True, 2, 2,
+                 id="one-pass-masks-every-block"),
+    pytest.param(*_BLOCK_SHAPES["visible-cut-dead-in-one-row"], True, 6, 3,
+                 id="three-classes"),
+])
+def test_forward_gauges_count_the_blocks_a_row_computes_and_masks(
+        lq, lk, bq, bk, causal, live, masked):
+    """Set while tracing (nothing runs here): of the last forward kernel,
+    the (Q block, KV block) pairs a row computes and those it masks."""
+    from mxnet_tpu import telemetry
+    mod = _flash_module()
+    for name in ("flash.fwd.blocks_live", "flash.fwd.blocks_masked"):
+        telemetry.set_gauge(name, -1)
+    q = jax.ShapeDtypeStruct((2, lq, 192), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, lk, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, lk, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: mod._pallas_forward(
+        q, k, v, causal, 192 ** -0.5, bq, bk, interpret=True), q, k, v)
+    assert telemetry.value("flash.fwd.blocks_live") == live
+    assert telemetry.value("flash.fwd.blocks_masked") == masked
+    # two rows here: the kanana call's G, so its four KV blocks a grid step
+    assert telemetry.value("flash.fwd.kv_blocks_per_step") == \
+        {8: 4, 3: 3, 1: 1}[lk // bk]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("shape,held", [
+    (name, held) for name in sorted(_BLOCK_SHAPES) if name != "kanana"
+    for held in (1, 2, 3, 4, 8)
+    if (_BLOCK_SHAPES[name][1] // _BLOCK_SHAPES[name][3]) % held == 0
+    and held <= _BLOCK_SHAPES[name][1] // _BLOCK_SHAPES[name][3]])
+def test_streaming_forward_is_the_same_whatever_a_grid_step_holds(
+        monkeypatch, shape, held, causal):
+    """One KV block a grid step (the grid walks them all), several, or all
+    of Lk in one step: the same online softmax block by block, so the same
+    output and log-sum-exp bit for bit, and the scan's within rounding."""
+    from mxnet_tpu import telemetry
+    mod = _flash_module()
+    lq, lk, bq, bk = _BLOCK_SHAPES[shape]
+    rng = np.random.RandomState(lq + lk + held)
+    q = jnp.asarray(rng.randn(2, lq, 64), jnp.float32)
+    k = jnp.asarray(rng.randn(2, lk, 64), jnp.float32)
+    v = jnp.asarray(rng.randn(2, lk, 64), jnp.float32)
+
+    def run(n):
+        monkeypatch.setattr(mod, "_kv_blocks_per_step", lambda *a: n)
+        got = mod._pallas_forward(q, k, v, causal, 0.125, bq, bk,
+                                  interpret=True)
+        assert telemetry.value("flash.fwd.kv_blocks_per_step") == n
+        return got
+    out, lse = run(held)
+    if held > 1:
+        one_out, one_lse = run(1)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(one_out))
+        np.testing.assert_array_equal(np.asarray(lse), np.asarray(one_lse))
+    ref, ref_lse = mod._scan_forward(q, k, v, causal, 0.125, bk)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_kv_blocks_per_step_fill_what_the_rows_leave_of_vmem():
+    mod = _flash_module()
+    # the kanana cell's call (G = 2): four of a row's eight KV blocks
+    assert mod._rows_per_program(64, 512, 512, 192, 2, True, 128) == 2
+    assert mod._kv_blocks_per_step(8, 2, 512, 512, 192, 2, 128) == 4
+    # chip_smoke's long shape: all four
+    assert mod._kv_blocks_per_step(4, 2, 512, 512, 128, 2, 128) == 4
+    # always a divisor of nk, within the budget, and no fewer with fewer rows
+    for nk, g, d, dv in [(8, 2, 192, 128), (32, 2, 128, 128),
+                         (8, 4, 64, 64), (6, 1, 64, 64), (7, 2, 128, 128)]:
+        n = mod._kv_blocks_per_step(nk, g, 512, 512, d, 2, dv)
+        assert nk % n == 0
+        more = 2 * g * mod._padded_head_dims(d, dv, 2) * 512 * 2
+        assert n == 1 or mod._program_vmem_bytes(
+            g, 512, 512, d, 2, True, dv) + (n - 1) * more <= mod._VMEM_BUDGET
+        assert n <= mod._kv_blocks_per_step(nk, 1, 512, 512, d, 2, dv)
+    # blocks that are over the budget by themselves: one a step
+    assert mod._kv_blocks_per_step(4, 1, 2048, 2048, 128, 2, 128) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("seq,causal", [(256, False), (512, False),
+                                        (768, True)])
+def test_power_of_two_scale_on_q_is_bit_identical_to_the_tile(
+        monkeypatch, seq, causal, dtype):
+    """d = 64 at 256-blocks: 0.125 multiplies the (G, D, BQ) block of Q; on
+    the (G, BK, BQ) score tile, where any other scale stays, it gives the
+    same output and log-sum-exp bit for bit (one pass and streaming)."""
+    mod = _flash_module()
+    assert all(mod._scale_on_q(s, 64, 512) for s in (0.125, 1 / 16, 1.0,
+                                                     64 ** -0.5))
+    assert not any(mod._scale_on_q(s, 64, 512) for s in (
+        192 ** -0.5, 128 ** -0.5, 0.3, 0.75))
+    # the Q block over a quarter of the tile (BERT at L = 128): left alone
+    assert mod._scale_on_q(0.125, 64, 256)
+    assert not mod._scale_on_q(0.125, 64, 128)
+    assert not mod._scale_on_q(1 / 16, 256, 512)
+    rng = np.random.RandomState(seq)
+    q, k, v = (jnp.asarray(rng.randn(4, seq, 64), dtype) for _ in range(3))
+
+    def run():
+        return jax.jit(lambda q, k, v: mod._pallas_forward(
+            q, k, v, causal, 0.125, 256, 256, interpret=True))(q, k, v)
+    on_q = run()
+    monkeypatch.setattr(mod, "_scale_on_q", lambda *a: False)
+    on_tile = run()
+    for a, b in zip(on_q, on_tile):
+        assert a.dtype == b.dtype and np.isfinite(np.asarray(
+            a, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
 
 
 def test_pallas_forward_bf16_under_jit_in_interpret_mode():

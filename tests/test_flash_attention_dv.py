@@ -51,10 +51,12 @@ def test_scan_forward_and_gradients_with_unequal_head_dims(causal, d, dv):
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("seq,blocks", [(128, "one_pass"),
-                                        (256, "streaming")])
+                                        (256, "streaming"),
+                                        (384, "streaming_three_classes")])
 def test_interpreted_kernel_with_unequal_head_dims(causal, seq, blocks):
     """The Pallas body itself (192/128, the MLA shape), one-pass and
-    streaming, through the public op: forward, log-sum-exp and gradients."""
+    streaming (at 384 a causal row holds a visible, a cut and a dead
+    block), through the public op: forward, log-sum-exp and gradients."""
     q, k, v = _qkv(1, 1, 2, seq, 192, 128)
     with interpret_kernels():
         assert mod._use_pallas(seq, seq, 192, 128) is not None
