@@ -65,6 +65,11 @@ SIZES = {
         # the kanana cell's expert products: a worst-case buffer of which an
         # eighth is routed, 16 experts held
         gmm=dict(rows=49152, routed=6144, groups=16, k=2048, n=768),
+        # the keye cell's sparse attention: one sequence of 16384, 32 / 4
+        # heads of 128, 16 index heads of 64, top-2048; the scans that the
+        # attention kernels are held to take `check_rows` of the 32 rows
+        dsa=dict(heads=32, kv_heads=4, seq=16384, d=128, index_heads=16,
+                 index_dim=64, topk=2048, check_rows=2),
         ln_rows=8192, ln_dim=768,
         bucket_elems=25_557_032,            # one ResNet-50 of parameters
         paged=dict(batch=8, heads=32, kv_heads=8, head_dim=128, block=16,
@@ -85,6 +90,8 @@ SIZES = {
         flash_bwd_shapes=((2, 128, 64, 64, False),
                           (2, 128, 192, 128, True)),
         gmm=dict(rows=256, routed=150, groups=4, k=128, n=128),
+        dsa=dict(heads=4, kv_heads=2, seq=256, d=64, index_heads=2,
+                 index_dim=64, topk=32, check_rows=2),
         ln_rows=32, ln_dim=128,
         bucket_elems=20_000,
         paged=dict(batch=2, heads=4, kv_heads=2, head_dim=64, block=8,
@@ -770,9 +777,125 @@ def _kernels_paged(run):
                 f"(tolerance {tol:g} x scale)")
 
 
+def _kernels_sparse(run):
+    """The four kernels of ``ops/sparse_attention.py`` at the keye cell's
+    shape, each against its blocked XLA form: the selection (the share of
+    (query, key) pairs on which the two masks differ: their products sum in
+    different orders, so a score at the threshold may fall either side),
+    the masked flash forward and backward on the kernel's own mask against
+    the scans, the alignment loss and the indexer's gradients against
+    ``jax.grad`` of the XLA form."""
+    import importlib
+    import jax
+    import jax.numpy as jnp
+    sa = importlib.import_module("mxnet_tpu.ops.sparse_attention")
+    fa = importlib.import_module("mxnet_tpu.ops.flash_attention")
+    ph = "4 kernels sparse_attention"
+    z = run.sizes["dsa"]
+    h, hkv, seq, d = z["heads"], z["kv_heads"], z["seq"], z["d"]
+    hi, di, topk, rows = z["index_heads"], z["index_dim"], z["topk"], \
+        z["check_rows"]
+    rng = np.random.RandomState(SEED)
+
+    def draw(*shape, dtype=jnp.bfloat16):
+        return jnp.asarray(rng.randn(*shape), dtype)
+    q, k, v = draw(1, h, seq, d), draw(1, hkv, seq, d), draw(1, hkv, seq, d)
+    qi, ki = draw(1, hi, seq, di), draw(1, seq, di)
+    w = draw(1, seq, hi, dtype=jnp.float32)
+    scale, sm = hi ** -0.5 * di ** -0.5, d ** -0.5
+    mode = dict(interpret=run.rehearsal)
+
+    def clock(exe, *args):
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = exe(*args)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) * 200
+
+    # 1. the selection
+    swapped = (jnp.swapaxes(qi, 2, 3), jnp.swapaxes(ki, 1, 2),
+               jnp.swapaxes(w, 1, 2))
+    exe, dt = _compiled(run, "mxtpu_dsa_index_select",
+                        lambda *a: sa._pallas_index_select(
+                            *a, topk, scale, **mode), *swapped)
+    mask, tau, lse_i = exe(*swapped)
+    want_mask, want_tau, want_lse = jax.jit(jax.vmap(
+        lambda *a: sa._xla_index_select(*a, topk, scale)))(qi, ki, w)
+    kept = float(jnp.sum(mask != 0))
+    differ = float(jnp.sum((mask != 0) != (want_mask != 0))) / kept
+    assert kept >= seq * min(topk, seq) * 0.9 and differ < 1e-2, \
+        (kept, differ)
+    assert_close("dsa tau", tau, want_tau, 1e-2)
+    assert_close("dsa lse_i", lse_i, want_lse, 1e-2)
+    say(ph, f"select (L,HI,dI,topk)=({seq},{hi},{di},{topk}) "
+            f"compile_s={dt:.2f} ms={clock(exe, *swapped):.3f} "
+            f"kept_share={kept / (seq * (seq + 1) / 2):.4f} "
+            f"pairs_that_differ_from_the_xla_form={differ:.2e}")
+
+    # 2. attention over the kernel's own mask, forward and backward
+    flat = (h, seq, d)
+    qr, kr, vr = (a.reshape(flat) for a in (
+        q, jnp.repeat(k, h // hkv, 1), jnp.repeat(v, h // hkv, 1)))
+    do = draw(*flat)
+    blocks = dict(zip(("bq", "bk"), fa._use_pallas(seq, seq, d, d)), **mode)
+    kw = dict(causal=True, sm_scale=sm)
+    fwd, dt = _compiled(run, "mxtpu_dsa_attn_fwd",
+                        lambda q, k, v, m: fa._pallas_forward(
+                            q, k, v, mask=m, **kw, **blocks), qr, kr, vr, mask)
+    out, lse = fwd(qr, kr, vr, mask)
+    bwd, dt_b = _compiled(run, "mxtpu_dsa_attn_bwd",
+                          lambda *a: fa._pallas_backward(
+                              *a[:6], mask=a[6], **kw, **blocks),
+                          qr, kr, vr, out, lse, do, mask)
+    grads = bwd(qr, kr, vr, out, lse, do, mask)
+    some = (qr[:rows], kr[:rows], vr[:rows])
+    bk = fa._pick_block(seq, 256)
+    want_out, want_lse = jax.jit(lambda *a: fa._scan_forward(
+        *a[:3], **kw, bk=bk, mask=a[3]))(*some, mask)
+    errs = [assert_close("dsa out", out[:rows], want_out, 2e-2),
+            assert_close("dsa lse", lse[:rows], want_lse, 2e-2)]
+    # dK / dV of a row sum over every query of that row: comparable row by
+    # row, like dQ
+    want = jax.jit(lambda *a: fa._scan_backward(
+        *a[:6], **kw, bk=bk, mask=a[6]))(
+            *some, out[:rows], lse[:rows], do[:rows], mask)
+    errs += [assert_close(f"dsa d{n}", a[:rows], b, 2e-2)
+             for n, a, b in zip("qkv", grads, want)]
+    say(ph, f"attention (rows,L,D)={flat} bf16 blocks="
+            f"{blocks['bq']}x{blocks['bk']} compile_s={dt:.2f}+{dt_b:.2f} "
+            f"fwd_ms={clock(fwd, qr, kr, vr, mask):.3f} "
+            f"bwd_ms={clock(bwd, qr, kr, vr, out, lse, do, mask):.3f} "
+            f"max_abs_err out,lse,dq,dk,dv vs scans on {rows} rows="
+            f"{[float(f'{e:.2e}') for e in errs]} (tolerance 2e-2 x scale)")
+
+    # 3. the alignment loss with the indexer's gradients
+    lse3 = lse.reshape(1, h, seq)
+    args = (jnp.swapaxes(q, 2, 3), jnp.swapaxes(k, 2, 3), lse3, *swapped,
+            mask, lse_i)
+    exe, dt = _compiled(run, "mxtpu_dsa_align_loss",
+                        lambda *a: sa._pallas_index_loss(
+                            *a, sm, scale, **mode), *args)
+    kl, dqi, dki, dw = exe(*args)
+    want_kl, want_g = jax.jit(jax.value_and_grad(
+        lambda qi, ki, w: sa._xla_index_loss(
+            q[0], jnp.repeat(k, h // hkv, 1)[0], lse3[0], qi, ki, w, mask[0],
+            sm, scale), argnums=(0, 1, 2)))(qi[0], ki[0], w[0])
+    errs = [assert_close("dsa kl", jnp.sum(kl), want_kl, 2e-2)]
+    errs += [assert_close(f"dsa d{n}", a[0], b, 3e-2 * float(
+        jnp.max(jnp.abs(b.astype(jnp.float32)))))
+        for n, a, b in zip(("qi", "ki", "w"), (
+            jnp.swapaxes(dqi, 2, 3), jnp.swapaxes(dki, 1, 2),
+            jnp.swapaxes(dw, 1, 2)), want_g)]
+    say(ph, f"align_loss compile_s={dt:.2f} ms={clock(exe, *args):.3f} "
+            f"mean_kl={float(jnp.sum(kl)) / seq:.4e} "
+            f"max_abs_err kl,dqi,dki,dw vs jax.grad of the xla form="
+            f"{[float(f'{e:.2e}') for e in errs]}")
+
+
 def phase_kernels(run):
     _kernels_flash(run)
     _kernels_flash_backward(run)
+    _kernels_sparse(run)
     _kernels_gmm(run)
     _kernels_layernorm(run)
     _kernels_bucket_update(run)

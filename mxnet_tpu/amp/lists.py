@@ -17,6 +17,10 @@ TARGET_DTYPE_OPS = [
     # grouped products; the shared expert and every projection are Dense,
     # i.e. FullyConnected
     "mla_attention", "moe_experts",
+    # sparse grouped-query attention: the attention and the indexer's
+    # q . k products; its index weights, the sum over index heads, the
+    # threshold and both comparisons stay float32 inside the op
+    "sparse_gq_attention",
 ]
 
 # the reference's fp32 blacklist: softmax family, norms, losses, exp/log/pow
@@ -34,7 +38,9 @@ FP32_OPS = [
 # arguments that keep the dtype they arrive in although their op is listed
 # above: the router's expert ids and float32 combine weights on their way into
 # the bfloat16 expert products (the combine sums in float32)
-KEEP_DTYPE_ARGS = {"moe_experts": ("experts", "weights")}
+KEEP_DTYPE_ARGS = {"moe_experts": ("experts", "weights"),
+                   "sparse_gq_attention": ("x_index", "w_index",
+                                           "positions")}
 
 WIDEST_TYPE_CASTS = [
     "add_n", "concat", "stack", "where", "broadcast_add", "broadcast_sub",

@@ -12,9 +12,10 @@ from .language_model import *  # noqa: F401,F403
 from .sampler import *  # noqa: F401,F403
 from .llama import *  # noqa: F401,F403
 from .deepseek_v3 import *  # noqa: F401,F403
+from .keye_vl2 import *  # noqa: F401,F403
 
 from . import attention, bert, transformer, language_model, sampler, \
-    llama, deepseek_v3  # noqa
+    llama, deepseek_v3, keye_vl2  # noqa
 
 _MODELS = {}
 for _m in (bert, transformer, language_model):

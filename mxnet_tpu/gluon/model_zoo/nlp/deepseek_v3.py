@@ -153,7 +153,10 @@ class MLAAttention(HybridBlock):
 
 class MoEBlock(HybridBlock):
     """Router over all ``n_routed_experts``, the ``experts_held`` routed
-    experts that live here, and the shared experts (whole on every chip)."""
+    experts that live here, and the shared experts (whole on every chip).
+    ``cfg.scoring_func`` (``"sigmoid"`` where a configuration has none) says
+    which router: the sigmoid one with its selection bias, or a softmax over
+    all experts with no bias and no scale (``keye_vl2``)."""
 
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
@@ -161,12 +164,14 @@ class MoEBlock(HybridBlock):
         d, w, held = cfg.hidden_size, cfg.moe_intermediate_size, \
             cfg.experts_held
         init = Normal(cfg.initializer_range)
+        self.scoring_func = getattr(cfg, "scoring_func", "sigmoid")
         with self.name_scope():
             self.gate = self.params.get(
                 "router_weight", shape=(cfg.n_routed_experts, d), init=init)
-            self.e_score_correction_bias = self.params.get(
-                "e_score_correction_bias", shape=(cfg.n_routed_experts,),
-                init="zeros", grad_req="null")
+            if self.scoring_func == "sigmoid":
+                self.e_score_correction_bias = self.params.get(
+                    "e_score_correction_bias", shape=(cfg.n_routed_experts,),
+                    init="zeros", grad_req="null")
             self.experts_gate = self.params.get(
                 "experts_gate_weight", shape=(held, d, w), init=init)
             self.experts_up = self.params.get(
@@ -177,13 +182,14 @@ class MoEBlock(HybridBlock):
                 cfg.mlp(cfg.n_shared_experts * w), prefix="shared_") \
                 if cfg.n_shared_experts else None
 
-    def hybrid_forward(self, F, x, gate, e_score_correction_bias,
-                       experts_gate, experts_up, experts_down):
+    def hybrid_forward(self, F, x, gate, experts_gate, experts_up,
+                       experts_down, e_score_correction_bias=None):
         cfg = self.cfg
         experts, weights = F.moe_router(
             x, gate, e_score_correction_bias, top_k=cfg.num_experts_per_tok,
             routed_scaling_factor=cfg.routed_scaling_factor,
-            norm_topk_prob=cfg.norm_topk_prob)
+            norm_topk_prob=cfg.norm_topk_prob,
+            scoring_func=self.scoring_func)
         out = F.moe_experts(x, experts, weights, experts_gate, experts_up,
                             experts_down, expert_offset=cfg.expert_offset)
         if self.shared_experts is None:

@@ -3357,23 +3357,36 @@ def rms_norm(data, weight, eps=1e-5):
 
 
 @_register
-def moe_router(data, weight, bias, top_k=1, routed_scaling_factor=1.0,
-               norm_topk_prob=True):
-    """Sigmoid top-k router (``parallel.moe.route_sigmoid_top_k``).  data:
-    (..., d); weight: (E, d); bias: (E,), added for the selection only.
-    Returns ``[experts, weights]``, both (..., top_k) float32: the ids of the
-    chosen experts (whole numbers in a float array, as ``topk`` gives its
-    indices, so that the tape can carry them) and their combine weights."""
-    from ..parallel.moe import route_sigmoid_top_k
+def moe_router(data, weight, bias=None, top_k=1, routed_scaling_factor=1.0,
+               norm_topk_prob=True, scoring_func="sigmoid"):
+    """Top-k router.  data: (..., d); weight: (E, d).  ``scoring_func``
+    ``"sigmoid"`` (``parallel.moe.route_sigmoid_top_k``): bias (E,) is added
+    for the selection only, the weights are scaled by
+    ``routed_scaling_factor``; ``"softmax"``
+    (``parallel.moe.route_softmax_top_k``): no bias, no scale.  Returns
+    ``[experts, weights]``, both (..., top_k) float32: the ids of the chosen
+    experts (whole numbers in a float array, as ``topk`` gives its indices,
+    so that the tape can carry them) and their combine weights."""
+    from ..parallel.moe import route_sigmoid_top_k, route_softmax_top_k
+    if scoring_func not in ("sigmoid", "softmax") or \
+            (scoring_func == "sigmoid") != (bias is not None):
+        raise MXNetError(f"moe_router: scoring_func {scoring_func!r} "
+                         f"{'without' if bias is None else 'with'} a bias")
 
-    def fn(d, w, b):
-        experts, gates = route_sigmoid_top_k(
-            d.reshape(-1, d.shape[-1]), w, b, top_k,
-            scale=routed_scaling_factor, norm_topk_prob=norm_topk_prob)
+    def fn(d, w, *b):
+        flat = d.reshape(-1, d.shape[-1])
+        if b:
+            experts, gates = route_sigmoid_top_k(
+                flat, w, b[0], top_k, scale=routed_scaling_factor,
+                norm_topk_prob=norm_topk_prob)
+        else:
+            experts, gates = route_softmax_top_k(
+                flat, w, top_k, norm_topk_prob=norm_topk_prob)
         lead = d.shape[:-1] + (top_k,)
         return (experts.astype(jnp.float32).reshape(lead),
                 gates.reshape(lead))
-    return apply_nary(fn, [data, weight, bias], n_out=2, name="moe_router")
+    return apply_nary(fn, [data, weight] + ([] if bias is None else [bias]),
+                      n_out=2, name="moe_router")
 
 
 @_register
@@ -3433,3 +3446,57 @@ def mla_attention(q, kv, k_pe, num_heads=1, qk_nope_head_dim=128,
                                   sm_scale=(nope + rope) ** -0.5)
             return out.transpose(0, 2, 1, 3).reshape(b, t, h * dv)
     return apply_nary(fn, [q, kv, k_pe], name="mla_attention")
+
+
+@_register
+def sparse_gq_attention(q, k, v, q_index, k_index, x_index, w_index,
+                        positions=None, num_heads=1, topk=2048,
+                        rope_theta=10000.0, mrope_section=None):
+    """Causal grouped-query attention over the ``topk`` keys a learned
+    indexer selects for each query, and the indexer's alignment loss
+    (``ops.sparse_attention``).
+
+    q: (B, T, H * d) with ``H = num_heads``; k, v: (B, T, Hkv * d) (the
+    key-value heads are what their width holds of ``d``), per-head norms
+    applied, not yet rotated; q_index: (B, T, HI * dI); k_index: (B, T, dI); x_index: (B, T,
+    hidden), the layer's normalised input without a gradient, and w_index:
+    (HI, hidden), from which the index weights ``x_index @ w_index.T`` are
+    taken here in float32 (``amp`` leaves both as they arrive); positions:
+    (3, B, T) temporal / height / width, or None for text (all three the
+    token's index).  Rotary in half-split pairs: q and k over ``d`` with the
+    frequencies divided among the streams by ``mrope_section``, the index
+    query and key over ``dI`` at the temporal position.  Returns ``[out (B,
+    T, H * d), index_loss (B,)]``."""
+    from ..ops.norm_rope import rope_half_split, sectioned_angles
+    from ..ops.sparse_attention import sparse_gq_attention as _sparse
+    h = num_heads
+
+    def fn(qd, kd, vd, qid, kid, xd, wd, *pos):
+        b, t = qd.shape[0], qd.shape[1]
+        d, di = qd.shape[2] // h, kid.shape[2]
+        hkv, hi = kd.shape[2] // d, wd.shape[0]
+        with jax.named_scope("gqa.project"):
+            p3 = pos[0] if pos else jnp.broadcast_to(
+                jnp.arange(t, dtype=jnp.int32), (3, b, t))
+            ang = sectioned_angles(
+                p3 if mrope_section else p3[0], d, rope_theta,
+                tuple(mrope_section) if mrope_section else None)[:, None]
+            ang_i = sectioned_angles(p3[0], di, rope_theta)[:, None]
+
+            def heads(a, n, width, angles):     # (B, T, n * w) -> (B, n, T, w)
+                a = a.reshape(b, t, n, width).transpose(0, 2, 1, 3)
+                return rope_half_split(
+                    a.astype(jnp.float32), jnp.cos(angles),
+                    jnp.sin(angles)).astype(a.dtype)
+            query, key = heads(qd, h, d, ang), heads(kd, hkv, d, ang)
+            value = vd.reshape(b, t, hkv, d).transpose(0, 2, 1, 3)
+            qi = heads(qid, hi, di, ang_i)
+            ki = heads(kid, 1, di, ang_i)[:, 0]
+            w = jnp.matmul(xd.astype(jnp.float32), wd.astype(jnp.float32).T,
+                           precision=lax.Precision.HIGHEST)
+        out, loss = _sparse(query, key, value, qi, ki, w, topk)
+        return out.transpose(0, 2, 1, 3).reshape(b, t, h * d), loss
+    return apply_nary(
+        fn, [q, k, v, q_index, k_index, x_index, w_index]
+        + ([] if positions is None else [positions]), n_out=2,
+        name="sparse_gq_attention")

@@ -65,7 +65,7 @@ from jax.sharding import PartitionSpec as P
 from .. import telemetry as _telem
 from .kernel_mode import kernel_mode
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "masked_flash"]
 
 _NEG_INF = -1e30
 
@@ -105,13 +105,24 @@ def _padded_head_dims(d, dv, itemsize):
     return sum(-(-x // sublanes) * sublanes for x in (d, dv))
 
 
-def _program_vmem_bytes(g, bq, bk, d, itemsize, streaming, dv=None):
+def _mask_vmem_bytes(bq, bk):
+    """What a (bk, bq) tile of a selection mask adds to a program: the
+    double-buffered int8 block and its int32 widening."""
+    return bq * bk * (2 + 4)
+
+
+def _program_vmem_bytes(g, bq, bk, d, itemsize, streaming, dv=None,
+                        masked=False):
     """VMEM bytes one program of ``g`` rows holds: the double-buffered
     Q/K blocks at ``d`` and V/O blocks at ``dv`` (the head dim on sublanes,
     padded to the dtype's tile), the log-sum-exp block, the float32 scratch
     of the streaming body and the (g, bk, bq) score / probability
-    temporaries with the float32 P.V."""
+    temporaries with the float32 P.V; ``masked``: a selection mask's tile
+    besides."""
     dv = d if dv is None else dv
+    if masked:
+        return _mask_vmem_bytes(bq, bk) + _program_vmem_bytes(
+            g, bq, bk, d, itemsize, streaming, dv)
     blocks = 2 * g * _padded_head_dims(d, dv, itemsize) * (bq + bk) * itemsize
     lse = 2 * 8 * -(-g // 8) * bq * 4
     scratch = g * (dv + 2 * 8) * bq * 4 if streaming else 0
@@ -134,7 +145,8 @@ def _pick_rows(bh, vmem_bytes, row_bytes):
     return pool[-1]
 
 
-def _rows_per_program(bh, bq, bk, d, itemsize, streaming, dv=None):
+def _rows_per_program(bh, bq, bk, d, itemsize, streaming, dv=None,
+                      masked=False):
     """G, the (batch x head) rows one forward grid program takes: a divisor
     of ``bh`` (a multiple of 8 where one fits: the log-sum-exp block is then
     (G, bq), rows on sublanes) whose blocks fit ``_VMEM_BUDGET``, the
@@ -144,7 +156,7 @@ def _rows_per_program(bh, bq, bk, d, itemsize, streaming, dv=None):
     dv = d if dv is None else dv
     return _pick_rows(
         bh, lambda g: _program_vmem_bytes(g, bq, bk, d, itemsize, streaming,
-                                          dv),
+                                          dv, masked),
         (bq + bk) * (d + dv) * itemsize)
 
 
@@ -178,14 +190,15 @@ def _kv_block_fetched(i, j, bq, bk):
     return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
 
 
-def _kv_blocks_per_step(nk, g, bq, bk, d, itemsize, dv):
+def _kv_blocks_per_step(nk, g, bq, bk, d, itemsize, dv, masked=False):
     """KV blocks one grid step of the streaming forward holds and walks: the
     largest divisor of ``nk`` whose K / V blocks, double-buffered, fit what
     :func:`_program_vmem_bytes` leaves of ``_VMEM_BUDGET`` at the ``g``
     already chosen.  A grid step costs about half a microsecond whatever it
     computes; the walk inside one costs nothing a block."""
-    used = _program_vmem_bytes(g, bq, bk, d, itemsize, True, dv)
-    more = 2 * g * _padded_head_dims(d, dv, itemsize) * bk * itemsize
+    used = _program_vmem_bytes(g, bq, bk, d, itemsize, True, dv, masked)
+    more = 2 * g * _padded_head_dims(d, dv, itemsize) * bk * itemsize \
+        + (2 * bq * bk if masked else 0)
     return max([n for n in range(1, nk + 1) if nk % n == 0 and
                 used + (n - 1) * more <= _VMEM_BUDGET] or [1])
 
@@ -214,26 +227,38 @@ def _forward_block_counts(lq, lk, bq, bk, causal):
     return live, live if nk == 1 else sum(lv and ct for lv, ct in pairs)
 
 
-def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
+def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
+                    mask=None):
+    """``mask``: a selection of keys a query, (batches, Lk, Lq) int8 with
+    the keys first like the score tile, nonzero where query ``t`` attends
+    to key ``s`` (the causal mask is part of it: the call is ``causal``,
+    which here only says which blocks hold nothing); the ``bh`` rows are
+    then whole batches of ``bh / batches`` heads, one mask a batch.  Every
+    live block is masked by its tile of it and the kernel is
+    ``mxtpu_dsa_attn_fwd``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, lq, d = q.shape
     lk, dv = k.shape[1], v.shape[2]
     nq, nk = lq // bq, lk // bk
-    shape = (bq, bk, d, q.dtype.itemsize, nk > 1, dv)
+    masked = mask is not None
+    shape = (bq, bk, d, q.dtype.itemsize, nk > 1, dv) + \
+        ((True,) if masked else ())
     # from the rows this call sees: a chip's own, inside _per_batch_shard
-    g = _rows_per_program(bh, *shape)
+    # (a program's rows share a mask tile: they are one batch's heads)
+    heads = bh // mask.shape[0] if masked else bh
+    g = _rows_per_program(heads, *shape)
     _telem.set_gauge("flash.fwd.rows_per_program", g)
-    live, masked = _forward_block_counts(lq, lk, bq, bk, causal)
+    live, cut_blocks = _forward_block_counts(lq, lk, bq, bk, causal)
     _telem.set_gauge("flash.fwd.blocks_live", live)
-    _telem.set_gauge("flash.fwd.blocks_masked", masked)
+    _telem.set_gauge("flash.fwd.blocks_masked", cut_blocks)
     scale_on_q = _scale_on_q(sm_scale, d, bk)
     # the streaming body: KV blocks a grid step walks, and the Q block's
     # columns (queries: independent of each other) in two halves where the
     # program has few rows to keep the MXU and the vector unit both busy
-    nsub = _kv_blocks_per_step(nk, g, bq, bk, d, q.dtype.itemsize,
-                               dv) if nk > 1 else 1
+    nsub = _kv_blocks_per_step(nk, g, bq, bk, d, q.dtype.itemsize, dv,
+                               *shape[6:]) if nk > 1 else 1
     _telem.set_gauge("flash.fwd.kv_blocks_per_step", nsub)
     halves = 2 if g <= 2 and bq % 256 == 0 else 1
     cols = [slice(c * bq // halves, (c + 1) * bq // halves)
@@ -262,8 +287,9 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
         # interpreter multiplies in float32: the same products, exactly
         return x.astype(jnp.float32) if interpret else x
 
-    def scores(qb, kb, i, j, mask, col=0):
-        # of Q block i from its column `col` on against KV block j
+    def scores(qb, kb, i, j, mask, col=0, keep=None):
+        # of Q block i from its column `col` on against KV block j; `keep`:
+        # the (bk, cols) tile of the selection mask, in the iota's place
         if scale_on_q:
             qb = qb * sm_scale
         # (g, d, bk) x (g, d, cols) over d -> (g, bk, cols): keys on sublanes
@@ -272,7 +298,9 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
             preferred_element_type=jnp.float32)
         if not scale_on_q:
             s = s * sm_scale
-        if mask:
+        if keep is not None:
+            s = jnp.where((keep.astype(jnp.int32) != 0)[None], s, _NEG_INF)
+        elif mask:
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, s.shape[1:], 0)
             qpos = i * bq + col + lax.broadcasted_iota(jnp.int32,
                                                        s.shape[1:], 1)
@@ -292,16 +320,19 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
         else:
             lse_ref[...] = jnp.broadcast_to(lse, (g, 8, bq))
 
-    def one_pass(q_ref, k_ref, v_ref, o_ref, lse_ref):
+    def one_pass(q_ref, k_ref, v_ref, *refs):
         # all of Lk is in the block: nothing to stream, no running state
-        s = scores(q_ref[...], k_ref[...], pl.program_id(1), 0, causal)
+        o_ref, lse_ref = refs[-2:]
+        s = scores(q_ref[...], k_ref[...], pl.program_id(1), 0, causal,
+                   keep=refs[0][0] if masked else None)
         m = jnp.max(s, axis=1, keepdims=True)   # (g, 1, bq)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)   # >= 1: the max's own term
         o_ref[...] = (p_dot_v(v_ref[...], p) / l).astype(o_ref.dtype)
         store_lse(lse_ref, m + jnp.log(l))
 
-    def streaming(q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_i, l_i):
+    def streaming(q_ref, k_ref, v_ref, *refs):
+        o_ref, lse_ref, acc, m_i, l_i = refs[-5:]
         i = pl.program_id(1)
         j = pl.program_id(2)
 
@@ -317,7 +348,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
                 # every half's QK^T before the first's softmax: the MXU
                 # then has the next product to do under the vector unit
                 tiles = [scores(q_ref[:, :, cs], k_ref[:, :, ks], i,
-                                j * nsub + sub, mask, cs.start)
+                                j * nsub + sub, mask, cs.start,
+                                refs[0][0, ks, cs] if masked else None)
                          for cs in cols]
                 for cs, s in zip(cols, tiles):
                     m_old = m_i[:, :, cs]
@@ -339,7 +371,10 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
             # (and nothing is fetched for a step that has only those: at_kv)
             visible, live = (jnp.clip(n - j * nsub, 0, nsub)
                              for n in _causal_extent(i, bq, bk))
-            lax.fori_loop(0, visible, walk(False), 0)
+            if masked:          # no block of a learned selection is whole
+                visible = 0
+            else:
+                lax.fori_loop(0, visible, walk(False), 0)
             lax.fori_loop(visible, live, walk(True), 0)
         else:
             lax.fori_loop(0, nsub, walk(False), 0)
@@ -367,6 +402,10 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
     # where one row at the caller's blocks does not fit the budget, mosaic's
     # limit is raised by what it is over (nsub is 1 there)
     over = _program_vmem_bytes(g, *shape) - _VMEM_BUDGET
+    mask_spec = [pl.BlockSpec(
+        (1, bk * nsub, bq),
+        lambda b, i, j: (b * g // heads, at_kv(b, i, j)[2], i))] \
+        if masked else []
     out_t, lse = pl.pallas_call(
         one_pass if nk == 1 else streaming,
         grid=(bh // g, nq, nk // nsub),
@@ -374,7 +413,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
             pl.BlockSpec((g, d, bq), lambda b, i, j: (b, 0, i)),
             pl.BlockSpec((g, d, bk * nsub), at_kv),
             pl.BlockSpec((g, dv, bk * nsub), at_kv),
-        ],
+        ] + mask_spec,
         out_specs=[
             pl.BlockSpec((g, dv, bq), lambda b, i, j: (b, 0, i)),
             lse_spec,
@@ -389,9 +428,10 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_DEFAULT_LIMIT + over if over > 0 else None),
-        name="mxtpu_flash_fwd",
+        name="mxtpu_dsa_attn_fwd" if masked else "mxtpu_flash_fwd",
         interpret=interpret,
-    )(*(in_hbm(jnp.swapaxes(a, 1, 2)) for a in (q, k, v)))
+    )(*(in_hbm(jnp.swapaxes(a, 1, 2)) for a in (q, k, v)),
+      *([in_hbm(mask)] if masked else []))
     return jnp.swapaxes(out_t, 1, 2), lse if lse_rows else lse[:, 0, :]
 
 
@@ -399,7 +439,16 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False):
 # Blockwise XLA fallback (same algorithm, lax.scan over KV blocks)
 # ---------------------------------------------------------------------------
 
-def _scan_forward(q, k, v, causal, sm_scale, bk):
+def _mask_blocks(mask, bh, nk, bk):
+    """A selection mask (batches, Lk, Lq) as the scans walk it: (nk, bh, Lq,
+    bk) bool, a batch's mask repeated for its heads (the scans are the
+    fallback and the tests' reference: the copy is theirs alone)."""
+    nb, _, lq = mask.shape
+    blocks = (mask != 0).reshape(nb, nk, bk, lq).transpose(1, 0, 3, 2)
+    return jnp.repeat(blocks, bh // nb, axis=1)
+
+
+def _scan_forward(q, k, v, causal, sm_scale, bk, mask=None):
     bh, lq, d = q.shape
     lk, dv = k.shape[1], v.shape[2]
     nk = lk // bk
@@ -409,10 +458,12 @@ def _scan_forward(q, k, v, causal, sm_scale, bk):
 
     def step(carry, blk):
         acc, m_i, l_i, j = carry
-        kj, vj = blk
+        kj, vj = blk[:2]
         s = jnp.einsum("bqd,bkd->bqk", q, kj,
                        preferred_element_type=jnp.float32) * sm_scale
-        if causal:
+        if mask is not None:
+            s = jnp.where(blk[2], s, _NEG_INF)
+        elif causal:
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, (lq, bk), 1)
             s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
         m_new = jnp.maximum(m_i, jnp.max(s, axis=-1, keepdims=True))
@@ -428,7 +479,9 @@ def _scan_forward(q, k, v, causal, sm_scale, bk):
             jnp.full((bh, lq, 1), _NEG_INF, jnp.float32),
             jnp.zeros((bh, lq, 1), jnp.float32),
             jnp.int32(0))
-    (acc, m_i, l_i, _), _ = lax.scan(step, init, (kb, vb))
+    (acc, m_i, l_i, _), _ = lax.scan(
+        step, init, (kb, vb) if mask is None
+        else (kb, vb, _mask_blocks(mask, bh, nk, bk)))
     denom = jnp.maximum(l_i, 1e-30)
     out = (acc / denom).astype(q.dtype)
     lse = (m_i + jnp.log(denom))[..., 0]
@@ -439,7 +492,7 @@ def _scan_forward(q, k, v, causal, sm_scale, bk):
 # Backward as a scan (where there is no kernel; the tests' reference)
 # ---------------------------------------------------------------------------
 
-def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
+def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk, mask=None):
     bh, lq, d = q.shape
     lk, dv = k.shape[1], v.shape[2]
     nk = lk // bk
@@ -450,10 +503,12 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
     qpos = lax.broadcasted_iota(jnp.int32, (lq, bk), 0)
 
     def step(dq, blk):
-        kj, vj, j = blk
+        kj, vj, j = blk[:3]
         s = jnp.einsum("bqd,bkd->bqk", q, kj,
                        preferred_element_type=jnp.float32) * sm_scale
-        if causal:
+        if mask is not None:
+            s = jnp.where(blk[3], s, _NEG_INF)
+        elif causal:
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, (lq, bk), 1)
             s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
         p = jnp.exp(s - lse[..., None])                     # (bh, lq, bk)
@@ -466,6 +521,8 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
         return dq, (dk_j, dv_j)
 
     steps = (kb, vb, jnp.arange(nk, dtype=jnp.int32))
+    if mask is not None:
+        steps += (_mask_blocks(mask, bh, nk, bk),)
     dq, (dk, dvs) = lax.scan(step, jnp.zeros((bh, lq, d), jnp.float32),
                              steps)
     dk = dk.transpose(1, 0, 2, 3).reshape(bh, lk, d)
@@ -477,7 +534,8 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk):
 # Pallas TPU backward
 # ---------------------------------------------------------------------------
 
-def _backward_vmem_bytes(g, bq, bk, lq, d, itemsize, streaming, dv=None):
+def _backward_vmem_bytes(g, bq, bk, lq, d, itemsize, streaming, dv=None,
+                         masked=False):
     """VMEM bytes one backward program of ``g`` rows holds: the
     double-buffered Q / dQ and O / dO blocks at ``bq``, K / dK and V / dV at
     ``bk`` (twice the forward's), the log-sum-exp block, the float32
@@ -485,6 +543,9 @@ def _backward_vmem_bytes(g, bq, bk, lq, d, itemsize, streaming, dv=None):
     the (g, bk, bq) S / P and dP / dS temporaries in float32 with their
     casts, delta and the three float32 products."""
     dv = d if dv is None else dv
+    if masked:
+        return _mask_vmem_bytes(bq, bk) + _backward_vmem_bytes(
+            g, bq, bk, lq, d, itemsize, streaming, dv)
     blocks = 4 * g * _padded_head_dims(d, dv, itemsize) * (bq + bk) * itemsize
     lse = 2 * g * 8 * bq * 4
     scratch = g * ((d + dv) * bk + d * lq) * 4 if streaming else 0
@@ -494,19 +555,19 @@ def _backward_vmem_bytes(g, bq, bk, lq, d, itemsize, streaming, dv=None):
 
 
 def _backward_rows_per_program(bh, bq, bk, lq, d, itemsize, streaming,
-                               dv=None):
+                               dv=None, masked=False):
     """G of the backward kernel, by the forward's rule (:func:`_pick_rows`)
     over :func:`_backward_vmem_bytes`; a row moves eight blocks where the
     forward moves four."""
     dv = d if dv is None else dv
     return _pick_rows(
         bh, lambda g: _backward_vmem_bytes(g, bq, bk, lq, d, itemsize,
-                                           streaming, dv),
+                                           streaming, dv, masked),
         2 * (bq + bk) * (d + dv) * itemsize)
 
 
 def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
-                     interpret=False):
+                     interpret=False, mask=None):
     """dQ, dK, dV of the kernel's forward, operands as (rows, D, L) and the
     scores transposed like the forward's (keys on sublanes, queries on
     lanes; ``lse`` and ``delta = rowsum(O dO)`` are lane-major rows).  One
@@ -517,7 +578,10 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
     dK / dV accumulate over the inner walk and the row's dQ over the outer
     one (a float32 scratch of the whole row, written out on the last KV
     block), and a causal block that is wholly masked is skipped: it would
-    add exact zeros."""
+    add exact zeros.  ``mask``: the forward's selection mask; every live
+    block is then masked by its tile, the kernel is ``mxtpu_dsa_attn_bwd``,
+    and where a row's dQ is over the VMEM rule (L = 16384 at d = 128: 8 MiB)
+    Mosaic's limit is raised by what it is over, as the forward's is."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -525,9 +589,13 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
     lk, dv = k.shape[1], v.shape[2]
     nq, nk = lq // bq, lk // bk
     one = nq == 1 and nk == 1
+    masked = mask is not None
+    shape = (bq, bk, lq, d, q.dtype.itemsize, not one, dv) + \
+        ((True,) if masked else ())
     # from the rows this call sees: a chip's own, inside _per_batch_shard
-    g = _backward_rows_per_program(bh, bq, bk, lq, d, q.dtype.itemsize,
-                                   not one, dv)
+    # (a program's rows share a mask tile: they are one batch's heads)
+    heads = bh // mask.shape[0] if masked else bh
+    g = _backward_rows_per_program(heads, *shape)
     _telem.set_gauge("flash.bwd.rows_per_program", g)
 
     def dot(a, b, contract):
@@ -537,13 +605,17 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
         return lax.dot_general(a, b, (contract, ((0,), (0,))),
                                preferred_element_type=jnp.float32)
 
-    def products(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, i, j, mask):
+    def products(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, i, j, mask,
+                 keep=None):
         """The block's dV, dK and dQ in float32, dK and dQ still without
         ``sm_scale`` (applied to the small results, not to the scores)."""
         qb, kb, vb, dob = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
         # (g, d, bk) x (g, d, bq) over d -> (g, bk, bq)
         s = dot(kb, qb, ((1,), (1,))) * sm_scale
-        if mask:
+        if keep is not None:
+            s = jnp.where((keep[0].astype(jnp.int32) != 0)[None], s,
+                          _NEG_INF)
+        elif mask:
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
             qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
             s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
@@ -556,16 +628,16 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
                 dot(qb, ds, ((2,), (2,))),                 # (g, d, bk)
                 dot(kb, ds, ((2,), (1,))))                 # (g, d, bq)
 
-    def one_pass(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
-                 dq_ref, dk_ref, dv_ref):
+    def one_pass(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *refs):
+        dq_ref, dk_ref, dv_ref = refs[-3:]
         dvb, dkb, dqb = products(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
-                                 0, 0, causal)
+                                 0, 0, causal, refs[0] if masked else None)
         dv_ref[...] = dvb.astype(dv_ref.dtype)
         dk_ref[...] = (dkb * sm_scale).astype(dk_ref.dtype)
         dq_ref[...] = (dqb * sm_scale).astype(dq_ref.dtype)
 
-    def streaming(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
-                  dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc):
+    def streaming(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *refs):
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[-6:]
         j = pl.program_id(1)                    # the KV block, outer
         i = pl.program_id(2)                    # the Q block, inner
 
@@ -580,7 +652,8 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
 
         def step(mask):
             dvb, dkb, dqb = products(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                                     do_ref, i, j, mask)
+                                     do_ref, i, j, mask,
+                                     refs[0] if masked else None)
             dv_acc[...] += dvb
             dk_acc[...] += dkb
             dq_acc[i] += dqb
@@ -589,8 +662,11 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
             # wholly masked (its first key after the block's last query):
             # skipped; cut by the diagonal: masked; wholly visible: plain
             live, cut = _causal_block(i, j, bq, bk)
-            pl.when(live & cut)(lambda: step(True))
-            pl.when(live & jnp.logical_not(cut))(lambda: step(False))
+            if masked:          # no block of a learned selection is whole
+                pl.when(live)(lambda: step(True))
+            else:
+                pl.when(live & cut)(lambda: step(True))
+                pl.when(live & jnp.logical_not(cut))(lambda: step(False))
         else:
             step(False)
 
@@ -619,6 +695,10 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
         # is complete and is written out once
         return (b, 0, jnp.where(j == nk - 1, i, 0))
 
+    over = _backward_vmem_bytes(g, *shape) - _VMEM_BUDGET if masked else 0
+    mask_spec = [pl.BlockSpec(
+        (1, bk, bq), lambda b, j, i: (b * g // heads, j, at_q(b, j, i)[2]))] \
+        if masked else []
     dq_t, dk_t, dv_t = pl.pallas_call(
         one_pass if one else streaming,
         grid=(bh // g, nk, nq),
@@ -629,7 +709,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
             pl.BlockSpec((g, dv, bq), at_q),
             pl.BlockSpec((g, 1, bq), at_q),
             pl.BlockSpec((g, dv, bq), at_q),
-        ],
+        ] + mask_spec,
         out_specs=[
             pl.BlockSpec((g, d, bq), at_dq),
             pl.BlockSpec((g, d, bk), at_kv),
@@ -646,11 +726,14 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
             pltpu.VMEM((g, dv, bk), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        name="mxtpu_flash_bwd",
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            **({"vmem_limit_bytes": _VMEM_DEFAULT_LIMIT + over}
+               if over > 0 else {})),
+        name="mxtpu_dsa_attn_bwd" if masked else "mxtpu_flash_bwd",
         interpret=interpret,
     )(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
-      jnp.swapaxes(out, 1, 2), lse[:, None, :], jnp.swapaxes(do, 1, 2))
+      jnp.swapaxes(out, 1, 2), lse[:, None, :], jnp.swapaxes(do, 1, 2),
+      *([mask] if masked else []))
     return tuple(jnp.swapaxes(a, 1, 2) for a in (dq_t, dk_t, dv_t))
 
 
@@ -716,46 +799,95 @@ def _flash(q, k, v, causal, sm_scale):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_on(q, k, v, causal, sm_scale, mesh):
-    return _flash_fwd(q, k, v, causal, sm_scale, mesh)[0]
+    return _forward(q, k, v, None, causal, sm_scale, mesh)[0]
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, mesh):
+    return _forward(q, k, v, None, causal, sm_scale, mesh)
+
+
+def _forward(q, k, v, mask, causal, sm_scale, mesh):
+    """``(out, residuals)``; ``mask``: a selection mask
+    (:func:`_pallas_forward`) or None."""
     blocks = _use_pallas(q.shape[1], k.shape[1], q.shape[2], v.shape[2])
     # counted while tracing: one per attention layer of a compiled program
-    _telem.inc("flash.fwd.scan" if blocks is None else "flash.fwd.pallas")
+    # (under a selection mask by the sparse op's own names)
+    name = "flash.fwd" if mask is None else "dsa.attn.fwd"
+    _telem.inc(name + (".scan" if blocks is None else ".pallas"))
     if blocks is not None:
         kernel = functools.partial(
             _pallas_forward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
             bk=blocks[1], interpret=kernel_mode() == "interpret")
-        out, lse = _per_batch_shard(kernel, mesh)(q, k, v)
+        if mask is None:
+            out, lse = _per_batch_shard(kernel, mesh)(q, k, v)
+        else:
+            out, lse = _per_batch_shard(
+                lambda q, k, v, mask: kernel(q, k, v, mask=mask), mesh)(
+                    q, k, v, mask)
     else:
         bk = _pick_block(k.shape[1], 256) or k.shape[1]
-        out, lse = _scan_forward(q, k, v, causal, sm_scale, bk)
-    return out, (q, k, v, out, lse)
+        out, lse = _scan_forward(q, k, v, causal, sm_scale, bk, mask)
+    return out, (q, k, v, out, lse, mask)
 
 
 def _flash_bwd(causal, sm_scale, mesh, res, do):
-    q, k, v, out, lse = res
+    q, k, v, out, lse, mask = res
     lq, lk, d, dv = q.shape[1], k.shape[1], q.shape[2], v.shape[2]
     blocks = _use_pallas(lq, lk, d, dv)
     # a streamed row's float32 dQ is held whole in VMEM: where one row is
-    # over the budget (L = 16384 at d = 192) the scan runs
-    if blocks is not None and _backward_vmem_bytes(
+    # over the budget (L = 16384 at d = 192) the scan runs; under a
+    # selection mask the kernel runs with Mosaic's limit raised instead
+    # (the scan at L = 16384 holds (rows, L, bk) float32 scores)
+    if mask is None and blocks is not None and _backward_vmem_bytes(
             1, *blocks, lq, d, q.dtype.itemsize, (lq, lk) != blocks,
             dv) > _VMEM_BUDGET:
         blocks = None
     # counted while tracing, as the forward's
-    _telem.inc("flash.bwd.scan" if blocks is None else "flash.bwd.pallas")
+    name = "flash.bwd" if mask is None else "dsa.attn.bwd"
+    _telem.inc(name + (".scan" if blocks is None else ".pallas"))
     if blocks is None:
         bk = _pick_block(lk, 256) or lk
-        return _scan_backward(q, k, v, out, lse, do, causal, sm_scale, bk)
+        return _scan_backward(q, k, v, out, lse, do, causal, sm_scale, bk,
+                              mask)
     kernel = functools.partial(
         _pallas_backward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
         bk=blocks[1], interpret=kernel_mode() == "interpret")
-    return _per_batch_shard(kernel, mesh)(q, k, v, out, lse, do)
+    if mask is None:
+        return _per_batch_shard(kernel, mesh)(q, k, v, out, lse, do)
+    return _per_batch_shard(
+        lambda *a: kernel(*a[:6], mask=a[6]), mesh)(
+            q, k, v, out, lse, do, mask)
 
 
 _flash_on.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _masked_flash_on(q, k, v, mask, sm_scale, mesh):
+    return _masked_flash_fwd(q, k, v, mask, sm_scale, mesh)[0]
+
+
+def _masked_flash_fwd(q, k, v, mask, sm_scale, mesh):
+    out, res = _forward(q, k, v, mask, True, sm_scale, mesh)
+    return (out, res[4]), res
+
+
+def _masked_flash_bwd(sm_scale, mesh, res, cts):
+    # the log-sum-exp's cotangent is not read; the mask gets none
+    return _flash_bwd(True, sm_scale, mesh, res, cts[0]) + (None,)
+
+
+_masked_flash_on.defvjp(_masked_flash_fwd, _masked_flash_bwd)
+
+
+def masked_flash(q, k, v, mask, sm_scale):
+    """``(out, lse)`` of attention over a per-query selection of the causal
+    past: q, k, v (rows, L, d) with the rows whole batches of heads, mask
+    (batches, L, L) int8, keys first, causality included
+    (:func:`_pallas_forward`).  Differentiable in q, k, v by the kernels
+    :func:`flash_attention` has; the log-sum-exp (rows, L) comes without a
+    gradient (the index loss reads it detached)."""
+    return _masked_flash_on(q, k, v, mask, sm_scale, _dp_mesh(q))
 
 
 def flash_attention(query, key, value, causal=False, sm_scale=None):

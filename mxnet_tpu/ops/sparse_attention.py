@@ -1,0 +1,561 @@
+"""Grouped-query attention under a learned sparse-attention indexer
+(DeepSeek Sparse Attention as ``model_type: KeyeVL2`` configures it): each
+query attends to the ``topk`` keys of its causal past that a small indexer
+scores highest, and the indexer is trained to follow the attention it steers.
+
+For one sequence, with ``t`` a query and ``s <= t`` a key::
+
+    I[t, s]  = scale * sum_j w[t, j] * relu(qI[t, j] . kI[s])      index score
+    tau_t    = the topk-th largest of {I[t, s] : s <= t}
+    S_t      = {s <= t : I[t, s] >= tau_t}       (all of the past if t < topk)
+    o[t, a]  = sum_{s in S_t} softmax_{S_t}(q[t, a] . k[s, a // rep] * sm) v[s]
+    L_I      = mean_t KL(pbar_t || softmax_{S_t} I[t, .]),
+               pbar_t[s] = mean_a P[t, a, s], without a gradient
+
+The selection is defined by the threshold, so a tie takes both and no
+sort's order enters.  ``L_I`` is the only thing the indexer's operands get a
+gradient from (attention sees the selection as a constant), and q, k, v get
+none from it.
+
+Four parts, each a Pallas kernel on the chip and plain XLA elsewhere (the
+XLA forms walk blocks of queries, so they hold (block, L) and never (L, L)
+in float32; they are also the tests' reference):
+
+- ``mxtpu_dsa_index_select``: a block of 128 queries against every key block of
+  their past — the scores as 16 thin products a tile into a VMEM scratch of
+  (L, 128) order-preserving int32 keys, then the exact k-th largest by a
+  radix search over the bit pattern (32 compare-and-count passes over the
+  scratch, no sort), and out go the selection as an int8 mask (keys first,
+  like the flash kernels' score tiles; causality included), ``tau`` and the
+  log-sum-exp of the selected scores.
+- ``mxtpu_dsa_attn_fwd`` / ``mxtpu_dsa_attn_bwd``: the flash kernels of
+  ``ops/flash_attention.py`` with the mask as one more operand: they stream
+  every causal block and mask it by its tile (with a token-level selection
+  no block is empty, so there is nothing to skip but the causal dead half).
+  A kernel that gathers ``topk`` keys a query would move 1 MB a (query,
+  kv head) for 8 MFLOP; the two cross near L = 67k.
+- ``mxtpu_dsa_align_loss``: ``L_I`` and, in the same pass, its gradient for
+  qI, kI and w (the loss's cotangent is a scalar, so the backward is a
+  multiplication): per tile the 32 heads' probabilities from the attention
+  kernel's own log-sum-exp, the indexer's scores again, and the three
+  products of the indexer's backward.
+
+K and V are repeated to the query heads before the kernels, as ``llama.py``
+does (gauge ``gqa.kv_repeat``); reading the kv heads in place is a later
+optimisation.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from .. import telemetry as _telem
+from .flash_attention import _VMEM_BUDGET, _pick_block, masked_flash
+from .kernel_mode import kernel_mode
+
+__all__ = ["sparse_gq_attention", "kth_largest", "selected_share"]
+
+_INT_MIN = np.int32(-2 ** 31)
+_INDEX_BQ = 128         # queries a program of mxtpu_dsa_index_select takes (lanes)
+_LOSS_BLOCK = 256       # the tile of mxtpu_dsa_align_loss, both ways
+
+
+def selected_share(seq, topk):
+    """The share of the causal pairs a selection of ``topk`` keeps:
+    ``sum_t min(t + 1, topk)`` over ``seq (seq + 1) / 2``."""
+    t = np.arange(1, seq + 1)
+    return float(np.minimum(t, topk).sum() / (seq * (seq + 1) / 2))
+
+
+# ---------------------------------------------------------------------------
+# order-preserving keys and the exact k-th largest
+# ---------------------------------------------------------------------------
+
+def _sort_key(x):
+    """float32 -> int32 with the same order (an involution: applied to a key
+    it gives the float's bits back)."""
+    bits = lax.bitcast_convert_type(x, jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _from_sort_key(key):
+    return lax.bitcast_convert_type(
+        key ^ ((key >> 31) & jnp.int32(0x7FFFFFFF)), jnp.float32)
+
+
+def _kth_largest_key(keys, k, count):
+    """The largest int32 ``T`` with ``count(keys >= T) >= k``: the k-th
+    largest key itself.  Built bit by bit from the top (the sign bit of an
+    int32 counts as the highest, inverted): 32 passes of compare and count,
+    ``count(cand)`` summing ``keys >= cand`` over the axis searched."""
+    def body(n, t):
+        cand = t ^ jnp.left_shift(jnp.int32(1), jnp.int32(31) - n)
+        return jnp.where(count(cand) >= k, cand, t)
+    return lax.fori_loop(0, 32, body, jnp.full_like(k, _INT_MIN))
+
+
+def kth_largest(x, k):
+    """Exact k-th largest of each row of ``x`` (..., n) float32, ``k``
+    (...,) int32 >= 1, by a radix search over the floats' bit pattern: no
+    sort, and ties change nothing (the value is what is asked for)."""
+    keys = _sort_key(x.astype(jnp.float32))
+    t = _kth_largest_key(
+        keys, k.astype(jnp.int32)[..., None],
+        lambda cand: jnp.sum(keys >= cand, axis=-1, keepdims=True,
+                             dtype=jnp.int32))
+    return _from_sort_key(t)[..., 0]
+
+
+# ---------------------------------------------------------------------------
+# XLA forms (a block of queries at a time)
+# ---------------------------------------------------------------------------
+
+def _query_blocks(n, block):
+    block = min(block, n)
+    while n % block:
+        block //= 2
+    return block
+
+
+def _index_scores(qi, ki, w, scale):
+    """(bq, L) float32 scores of a block of queries: qi (HI, bq, dI), ki
+    (L, dI), w (bq, HI); the products in the operands' dtype with float32
+    accumulation, the rest float32."""
+    pre = jnp.einsum("hqd,kd->hqk", qi, ki,
+                     preferred_element_type=jnp.float32)
+    return scale * jnp.einsum("hqk,qh->qk", jax.nn.relu(pre),
+                              w.astype(jnp.float32),
+                              precision=lax.Precision.HIGHEST)
+
+
+def _select_block(scores, t0, topk):
+    """``(selected (bq, L) bool, tau (bq,))`` of a block of queries that
+    starts at position ``t0``."""
+    bq, n = scores.shape
+    qpos = t0 + jnp.arange(bq, dtype=jnp.int32)
+    causal = jnp.arange(n, dtype=jnp.int32)[None] <= qpos[:, None]
+    keys = jnp.where(causal, _sort_key(scores), _INT_MIN)
+    t = _kth_largest_key(
+        keys, jnp.minimum(topk, qpos + 1)[:, None],
+        lambda cand: jnp.sum(keys >= cand, axis=-1, keepdims=True,
+                             dtype=jnp.int32))
+    return (keys >= t) & causal, _from_sort_key(t)[:, 0]
+
+
+def _xla_index_select(qi, ki, w, topk, scale):
+    """One sequence: qi (HI, L, dI), ki (L, dI), w (L, HI) ->
+    ``(mask (L, L) int8 keys first, tau (L,), lse_i (L,))``."""
+    seq = ki.shape[0]
+    bq = _query_blocks(seq, 256)
+
+    def block(n):
+        t0 = n * bq
+        scores = _index_scores(lax.dynamic_slice_in_dim(qi, t0, bq, 1), ki,
+                               lax.dynamic_slice_in_dim(w, t0, bq, 0), scale)
+        sel, tau = _select_block(scores, t0, topk)
+        lse = jax.nn.logsumexp(jnp.where(sel, scores, -jnp.inf), axis=-1)
+        # a 0 / 1 mask, not a narrowed tensor: nothing to scale
+        return sel.T.astype(jnp.int8), tau, lse  # mxlint: disable=HB21
+    mask, tau, lse = lax.map(block, jnp.arange(seq // bq, dtype=jnp.int32))
+    return (mask.transpose(1, 0, 2).reshape(seq, seq), tau.reshape(seq),
+            lse.reshape(seq))
+
+
+def _xlogy(p, logq):
+    return jnp.where(p > 0, p * logq, 0.0)
+
+
+def _xla_index_loss(q, k, lse, qi, ki, w, mask, sm_scale, scale):
+    """One sequence's ``sum_t KL_t``: q, k (H, L, d) (k repeated to the
+    query heads), lse (H, L) the attention's log-sum-exp, the indexer's
+    operands and the mask as :func:`_xla_index_select` takes and gives them.
+    Differentiable in qi, ki, w (``jax.grad`` of this is the reference of
+    the kernel's fused gradients)."""
+    seq = ki.shape[0]
+    bq = _query_blocks(seq, 256)
+
+    @jax.checkpoint
+    def block(qi, ki, w, n):
+        t0 = n * bq
+        sel = lax.dynamic_slice_in_dim(mask, t0, bq, 1).T != 0    # (bq, L)
+        s = jnp.einsum("hqd,hkd->hqk", lax.dynamic_slice_in_dim(q, t0, bq, 1),
+                       k, preferred_element_type=jnp.float32) * sm_scale
+        p = jnp.exp(s - lax.dynamic_slice_in_dim(lse, t0, bq, 1)[..., None])
+        pbar = lax.stop_gradient(jnp.where(sel, jnp.mean(p, axis=0), 0.0))
+        scores = _index_scores(lax.dynamic_slice_in_dim(qi, t0, bq, 1), ki,
+                               lax.dynamic_slice_in_dim(w, t0, bq, 0), scale)
+        logq = scores - jax.nn.logsumexp(
+            jnp.where(sel, scores, -jnp.inf), axis=-1, keepdims=True)
+        return jnp.sum(_xlogy(pbar, jnp.log(jnp.maximum(pbar, 1e-37)))
+                       - jnp.where(sel, pbar * logq, 0.0))
+    return lax.fori_loop(0, seq // bq,
+                         lambda n, acc: acc + block(qi, ki, w, n),
+                         jnp.float32(0))
+
+
+# ---------------------------------------------------------------------------
+# Pallas: index scores + selection
+# ---------------------------------------------------------------------------
+
+def _pallas_index_select(qi, ki, w, topk, scale, interpret=False):
+    """qi (B, HI, dI, L), ki (B, dI, L), w (B, HI, L) float32 ->
+    ``(mask (B, L, L) int8, tau (B, L), lse_i (B, L))``: the head dim on
+    sublanes and the sequence on lanes, as the flash kernels take theirs."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nb, hi, di, seq = qi.shape
+    bq = _INDEX_BQ
+    bk = _pick_block(seq)
+    nq, nk = seq // bq, seq // bk
+
+    def operand(x):
+        return x.astype(jnp.float32) if interpret else x
+
+    def kernel(qi_ref, ki_ref, w_ref, mask_ref, tau_ref, lse_ref, keys):
+        i = pl.program_id(1)
+        live = ((i + 1) * bq + bk - 1) // bk
+        qpos = i * bq + lax.broadcasted_iota(jnp.int32, (1, bq), 1)
+
+        def at(j):
+            return pl.ds(pl.multiple_of(j * bk, bk), bk)
+
+        def score(j, carry):
+            kb = operand(ki_ref[0, :, at(j)])                  # (dI, bk)
+            acc = jnp.zeros((bk, bq), jnp.float32)
+            for h in range(hi):
+                pre = lax.dot_general(
+                    kb, operand(qi_ref[0, h]), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # (bk, bq)
+                acc = acc + jnp.maximum(pre, 0.0) * w_ref[0, h:h + 1, :]
+            kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+            keys[at(j), :] = jnp.where(qpos >= kpos, _sort_key(acc * scale),
+                                       _INT_MIN)
+            return carry
+        lax.fori_loop(0, live, score, 0)
+
+        def count(cand):
+            def add(j, n):
+                return n + jnp.sum((keys[at(j), :] >= cand).astype(jnp.int32),
+                                   axis=0, keepdims=True)
+            return lax.fori_loop(0, live, add, jnp.zeros((1, bq), jnp.int32))
+        t = _kth_largest_key(None, jnp.minimum(topk, qpos + 1), count)
+
+        def largest(j, m):
+            kj = keys[at(j), :]
+            return jnp.maximum(m, jnp.max(jnp.where(
+                kj >= t, _from_sort_key(kj), -jnp.inf), axis=0, keepdims=True))
+        m = lax.fori_loop(0, live, largest,
+                          jnp.full((1, bq), -jnp.inf, jnp.float32))
+
+        def write(j, total):
+            kj = keys[at(j), :]
+            sel = kj >= t
+            mask_ref[0, at(j), :] = sel.astype(  # mxlint: disable=HB21
+                jnp.int8)
+            return total + jnp.sum(jnp.where(
+                sel, jnp.exp(_from_sort_key(kj) - m), 0.0), axis=0,
+                keepdims=True)
+        total = lax.fori_loop(0, live, write,
+                              jnp.zeros((1, bq), jnp.float32))
+
+        def dead(j, carry):
+            mask_ref[0, at(j), :] = jnp.zeros((bk, bq), jnp.int8)
+            return carry
+        lax.fori_loop(live, nk, dead, 0)
+        tau_ref[0] = _from_sort_key(t)
+        lse_ref[0] = m + jnp.log(total)
+
+    # the scratch of keys, the resident mask block and the row of kI, each
+    # double-buffered where it is an operand: 17 MiB at L = 16384
+    need = seq * bq * 4 + 2 * seq * bq + 2 * di * seq * qi.dtype.itemsize \
+        + 2 * hi * (di * qi.dtype.itemsize + 4) * bq + 6 * bk * bq * 4
+    mask, tau, lse = pl.pallas_call(
+        kernel,
+        grid=(nb, nq),
+        in_specs=[
+            pl.BlockSpec((1, hi, di, bq), lambda b, i: (b, 0, 0, i)),
+            pl.BlockSpec((1, di, seq), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, hi, bq), lambda b, i: (b, 0, i)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, seq, bq), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i: (b, 0, i)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((nb, seq, seq), jnp.int8),
+                   jax.ShapeDtypeStruct((nb, 1, seq), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, 1, seq), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((seq, bq), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            **({"vmem_limit_bytes": need + 4 * 2 ** 20}
+               if need > _VMEM_BUDGET else {})),
+        name="mxtpu_dsa_index_select",
+        interpret=interpret,
+    )(qi, ki, w)
+    return mask, tau[:, 0], lse[:, 0]
+
+
+# ---------------------------------------------------------------------------
+# Pallas: the index loss with its gradients
+# ---------------------------------------------------------------------------
+
+def _pallas_index_loss(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
+                       interpret=False, gradients=True):
+    """q (B, H, d, L), k (B, Hkv, d, L), lse (B, H, L), qi (B, HI, dI, L), ki
+    (B, dI, L), w (B, HI, L), mask (B, L, L) int8, lse_i (B, L) ->
+    ``(kl (B, L), dqi (B, HI, dI, L), dki (B, dI, L), dw (B, HI, L))``, all
+    float32: a query's ``KL_t`` and the gradients of ``sum_t KL_t``; without
+    ``gradients`` (a forward nobody differentiates: evaluation, a shape
+    probe) ``kl`` alone, and the indexer's backward products are not
+    computed.  Both passes of a rematerialised layer under ``jax.grad`` are
+    differentiated traces and take the full form."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nb, h, d, seq = q.shape
+    hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[2]
+    rep = h // hkv
+    blk = _pick_block(seq, _LOSS_BLOCK)
+    n = seq // blk
+
+    def operand(x):
+        return x.astype(jnp.float32) if interpret else x
+
+    def kernel(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, mask_ref,
+               lsei_ref, kl_ref, *grad_refs):
+        i = pl.program_id(1)
+        j = pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _init_q():
+            kl_ref[...] = jnp.zeros_like(kl_ref)
+            for ref in grad_refs[::2]:              # dqi, dw
+                ref[...] = jnp.zeros_like(ref)
+
+        if gradients:
+            dqi_ref, dki_ref, dw_ref = grad_refs
+
+            @pl.when((i == 0) & (j == 0))
+            def _init_k():
+                dki_ref[...] = jnp.zeros_like(dki_ref)
+
+        @pl.when(j <= i)
+        def _tile():
+            ks = pl.ds(pl.multiple_of(j * blk, blk), blk)
+            sel = mask_ref[0].astype(jnp.int32) != 0            # (bk, bq)
+
+            def head(a, pbar):
+                s = lax.dot_general(
+                    operand(k_ref[0, a // rep]), operand(q_ref[0, a]),
+                    (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                return pbar + jnp.exp(s - lse_ref[0, pl.ds(a, 1), :])
+            pbar = lax.fori_loop(0, h, head,
+                                 jnp.zeros((blk, blk), jnp.float32))
+            pbar = jnp.where(sel, pbar * (1.0 / h), 0.0)
+
+            kb = operand(ki_ref[0, :, ks])                      # (dI, bk)
+
+            def pre(hh):
+                return lax.dot_general(
+                    kb, operand(qi_ref[0, hh]), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # (bk, bq)
+            scores = jnp.zeros((blk, blk), jnp.float32)
+            for hh in range(hi):
+                scores = scores + jnp.maximum(pre(hh), 0.0) \
+                    * w_ref[0, hh:hh + 1, :]
+            logq = scores * scale - lsei_ref[0]
+            kl_ref[0] += jnp.sum(
+                jnp.where(pbar > 0,
+                          pbar * (jnp.log(jnp.maximum(pbar, 1e-37)) - logq),
+                          0.0), axis=0, keepdims=True)
+            # d sum_t KL_t / d I, times the scale on the way to the products
+            di_tile = jnp.where(sel, jnp.exp(logq) - pbar, 0.0) * scale
+            for hh in range(hi if gradients else 0):
+                p = pre(hh)
+                dw_ref[0, hh:hh + 1, :] += jnp.sum(
+                    di_tile * jnp.maximum(p, 0.0), axis=0, keepdims=True)
+                g = jnp.where(p > 0, di_tile * w_ref[0, hh:hh + 1, :], 0.0)
+                g = operand(g.astype(qi_ref.dtype))
+                dqi_ref[0, hh] += lax.dot_general(
+                    kb, g, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # (dI, bq)
+                dki_ref[0, :, ks] += lax.dot_general(
+                    operand(qi_ref[0, hh]), g, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # (dI, bk)
+
+    def at_k(b, i, j):
+        # a dead tile names the row's last live key block again: no fetch
+        return jnp.minimum(j, i)
+
+    itemsize = q.dtype.itemsize
+    need = 2 * (h * d * blk + hkv * d * blk + hi * di * blk) * itemsize \
+        + 2 * di * seq * (itemsize + 4) + 2 * hi * di * blk * 4 \
+        + 12 * blk * blk * 4
+    outs = pl.pallas_call(
+        kernel,
+        grid=(nb, n, n),
+        in_specs=[
+            pl.BlockSpec((1, h, d, blk), lambda b, i, j: (b, 0, 0, i)),
+            pl.BlockSpec((1, hkv, d, blk),
+                         lambda b, i, j: (b, 0, 0, at_k(b, i, j))),
+            pl.BlockSpec((1, h, blk), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, hi, di, blk), lambda b, i, j: (b, 0, 0, i)),
+            pl.BlockSpec((1, di, seq), lambda b, i, j: (b, 0, 0)),
+            pl.BlockSpec((1, hi, blk), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, blk, blk),
+                         lambda b, i, j: (b, at_k(b, i, j), i)),
+            pl.BlockSpec((1, 1, blk), lambda b, i, j: (b, 0, i)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, blk), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, hi, di, blk), lambda b, i, j: (b, 0, 0, i)),
+            pl.BlockSpec((1, di, seq), lambda b, i, j: (b, 0, 0)),
+            pl.BlockSpec((1, hi, blk), lambda b, i, j: (b, 0, i)),
+        ][:4 if gradients else 1],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, seq), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, hi, di, seq), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, di, seq), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, hi, seq), jnp.float32)
+                   ][:4 if gradients else 1],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            **({"vmem_limit_bytes": need + 4 * 2 ** 20}
+               if need > _VMEM_BUDGET else {})),
+        name="mxtpu_dsa_align_loss",
+        interpret=interpret,
+    )(q, k, lse, qi, ki, w, mask, lse_i[:, None, :])
+    return (outs[0][:, 0],) + tuple(outs[1:])
+
+
+# ---------------------------------------------------------------------------
+# the three steps, each with its two forms
+# ---------------------------------------------------------------------------
+
+def _kernels(seq, *head_dims):
+    """``"mosaic"`` / ``"interpret"`` where this call's shapes take the
+    Pallas kernels (a kernel mode, L a multiple of 128, head dims of 64s),
+    else None: the XLA forms."""
+    mode = kernel_mode()
+    if mode is None or seq % 128 or any(x % 64 for x in head_dims):
+        return None
+    return mode
+
+
+def _index_select(qi, ki, w, topk, scale):
+    """qi (B, HI, L, dI), ki (B, L, dI), w (B, L, HI) -> mask (B, L, L)
+    int8 (keys first), tau (B, L), lse_i (B, L); no gradient."""
+    qi, ki, w = (lax.stop_gradient(a) for a in (qi, ki, w))
+    mode = _kernels(ki.shape[1], qi.shape[3])
+    _telem.inc("dsa.index.xla" if mode is None else "dsa.index.pallas")
+    _telem.inc("dsa.select.radix")
+    if mode is None:
+        return jax.vmap(functools.partial(
+            _xla_index_select, topk=topk, scale=scale))(qi, ki, w)
+    return _pallas_index_select(
+        jnp.swapaxes(qi, 2, 3), jnp.swapaxes(ki, 1, 2),
+        jnp.swapaxes(w, 1, 2).astype(jnp.float32), topk, scale,
+        interpret=mode == "interpret")
+
+
+def _index_loss_kernel(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
+                       mode, gradients):
+    return _pallas_index_loss(
+        *(jnp.swapaxes(a, 2, 3) for a in (q, k)), lse,
+        jnp.swapaxes(qi, 2, 3), jnp.swapaxes(ki, 1, 2),
+        jnp.swapaxes(w, 1, 2).astype(jnp.float32), mask, lse_i, sm_scale,
+        scale, interpret=mode == "interpret", gradients=gradients)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _index_loss(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale, mode):
+    # not under differentiation: the loss alone
+    return jnp.sum(_index_loss_kernel(q, k, lse, qi, ki, w, mask, lse_i,
+                                      sm_scale, scale, mode, False)[0],
+                   axis=1)
+
+
+def _index_loss_fwd(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
+                    mode):
+    kl, dqi, dki, dw = _index_loss_kernel(q, k, lse, qi, ki, w, mask, lse_i,
+                                          sm_scale, scale, mode, True)
+    grads = (jnp.swapaxes(dqi, 2, 3), jnp.swapaxes(dki, 1, 2),
+             jnp.swapaxes(dw, 1, 2))
+    return jnp.sum(kl, axis=1), (grads, tuple(
+        jnp.zeros((0,), a.dtype) for a in (qi, ki, w)))
+
+
+def _index_loss_bwd(sm_scale, scale, mode, res, ct):
+    grads, like = res
+    return (None, None, None) + tuple(
+        (g * ct.reshape((-1,) + (1,) * (g.ndim - 1))).astype(a.dtype)
+        for g, a in zip(grads, like)) + (None, None)
+
+
+_index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
+
+
+def _index_loss_sum(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale):
+    """``sum_t KL_t`` a sequence, (B,): q (B, H, L, d), k (B, Hkv, L, d),
+    lse (B, H, L), the rest as :func:`_index_select` takes and gives them.
+    A gradient for qi, ki and w only."""
+    q, k, lse = (lax.stop_gradient(a) for a in (q, k, lse))
+    mode = _kernels(ki.shape[1], q.shape[3], qi.shape[3])
+    _telem.inc("dsa.index_loss.xla" if mode is None
+               else "dsa.index_loss.pallas")
+    if mode is None:
+        k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
+        return jax.vmap(functools.partial(
+            _xla_index_loss, sm_scale=sm_scale, scale=scale))(
+                q, k, lse, qi, ki, w, mask)
+    return _index_loss(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
+                       mode)
+
+
+def sparse_gq_attention(q, k, v, qi, ki, w, topk, sm_scale=None,
+                        index_scale=None):
+    """Attention of every query over the ``topk`` keys of its causal past
+    that the indexer scores highest, and the indexer's alignment loss.
+
+    q: (B, H, L, d); k, v: (B, Hkv, L, d), ``H`` a multiple of ``Hkv`` (head
+    ``a`` reads kv head ``a // (H / Hkv)``), rotary applied; qi: (B, HI, L,
+    dI) index queries; ki: (B, L, dI) the one index key head; w: (B, L, HI)
+    index weights.  ``sm_scale`` defaults to ``d ** -0.5``, ``index_scale``
+    to ``HI ** -0.5 * dI ** -0.5``.  Returns ``(out (B, H, L, d), loss
+    (B,))``, ``loss`` a sequence's ``mean_t KL(pbar_t || softmax_{S_t} I)``.
+    Gradients: q, k, v through ``out`` with the selection a constant; qi,
+    ki, w through ``loss`` with ``pbar`` a constant (module docstring).
+
+    Counters, while tracing: ``dsa.layers``; ``dsa.index.pallas`` / ``.xla``;
+    ``dsa.select.radix``; ``dsa.attn.fwd.pallas`` / ``.scan`` and
+    ``dsa.attn.bwd.*`` (``ops/flash_attention.py``);
+    ``dsa.index_loss.pallas`` / ``.xla``.  Gauges ``dsa.topk``,
+    ``dsa.index_heads``, ``dsa.selected_share``, ``gqa.kv_repeat``."""
+    b, h, seq, d = q.shape
+    hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[3]
+    sm_scale = d ** -0.5 if sm_scale is None else float(sm_scale)
+    scale = hi ** -0.5 * di ** -0.5 if index_scale is None \
+        else float(index_scale)
+    _telem.inc("dsa.layers")
+    _telem.set_gauge("dsa.topk", topk)
+    _telem.set_gauge("dsa.index_heads", hi)
+    _telem.set_gauge("dsa.selected_share", selected_share(seq, topk))
+    _telem.set_gauge("gqa.kv_repeat", h // hkv)
+
+    with jax.named_scope("dsa.index"), jax.named_scope("dsa.select"):
+        mask, _, lse_i = _index_select(qi, ki, w, topk, scale)
+    with jax.named_scope("dsa.attention"):
+        rows = (b * h, seq, d)
+        out, lse = masked_flash(
+            q.reshape(rows), jnp.repeat(k, h // hkv, axis=1).reshape(rows),
+            jnp.repeat(v, h // hkv, axis=1).reshape(rows), mask, sm_scale)
+    with jax.named_scope("dsa.index_loss"):
+        loss = _index_loss_sum(q, k, lse.reshape(b, h, seq), qi, ki, w, mask,
+                               lse_i, sm_scale, scale) / seq
+    return out.reshape(b, h, seq, d), loss

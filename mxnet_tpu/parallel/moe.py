@@ -8,9 +8,11 @@
    all-to-all from the sharding algebra.  Functional parameters, not a
    ``HybridBlock``; used by the multi-chip dry run.
 
-2. ``route_sigmoid_top_k`` / ``dropless_moe_apply`` — dropless top-k routing
-   for a layer that holds a share of the experts (DeepSeek-V3-style: sigmoid
-   scores, a selection bias, normalised and scaled weights).  The layer is
+2. ``route_sigmoid_top_k`` / ``route_softmax_top_k`` /
+   ``dropless_moe_apply`` — dropless top-k routing for a layer that holds a
+   share of the experts (DeepSeek-V3-style: sigmoid scores, a selection
+   bias, normalised and scaled weights; or a plain softmax over all
+   experts, its top-k renormalised).  The layer is
    told which ``held`` of the ``n_routed_experts`` live here
    (``expert_offset``), routes over all of them, and computes its own
    experts' part of the result; assignments to absent experts are left out.
@@ -33,7 +35,8 @@ from .. import telemetry as _telem
 from ..base import MXNetError
 
 __all__ = ["moe_apply", "MoEDense", "load_balance_loss",
-           "route_sigmoid_top_k", "dropless_moe_apply", "buffer_rows"]
+           "route_sigmoid_top_k", "route_softmax_top_k",
+           "dropless_moe_apply", "buffer_rows"]
 
 
 def _top1_dispatch(logits, capacity):
@@ -157,6 +160,24 @@ def route_sigmoid_top_k(x, router_w, bias, top_k, scale=1.0,
         if norm_topk_prob:
             weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
         return experts.astype(jnp.int32), weights * scale
+
+
+def route_softmax_top_k(x, router_w, top_k, norm_topk_prob=True):
+    """A softmax router (``model_type: KeyeVL2``, the Qwen-MoE lineage): the
+    gates are ``softmax(x @ router_w.T)`` over all experts, float32 at full
+    matmul precision; the ``top_k`` largest are chosen and, with
+    ``norm_topk_prob``, divided by their sum.  No bias, no scale.  x: (T,
+    d); router_w: (E, d).  Returns ``(experts (T, top_k) int32, weights (T,
+    top_k) float32)`` as :func:`route_sigmoid_top_k` does."""
+    with jax.named_scope("moe.route"):
+        gates = jax.nn.softmax(jnp.matmul(
+            x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+            precision=lax.Precision.HIGHEST), axis=-1)
+        _, experts = lax.top_k(lax.stop_gradient(gates), top_k)
+        weights = jnp.take_along_axis(gates, experts, axis=-1)
+        if norm_topk_prob:
+            weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        return experts.astype(jnp.int32), weights
 
 
 def buffer_rows(tokens, top_k, held):

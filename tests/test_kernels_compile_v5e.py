@@ -93,3 +93,47 @@ def test_grouped_product_and_both_backward_kernels_at_the_expert_widths(
     assert text.count("tpu_custom_call") == 3
     for name in ("mxtpu_gmm", "mxtpu_gmm_dlhs", "mxtpu_gmm_drhs"):
         assert name in text
+
+
+@pytest.mark.parametrize("part", ["index_select", "attention", "align_loss"])
+def test_sparse_attention_kernels_at_the_keye_shape(one_chip, part):
+    """The keye cell's four kernels at its own shapes (one sequence of
+    16384, 32 / 4 heads of 128, 16 index heads of 64, top-2048): the
+    index / select kernel with its (L, 128) scratch of keys, the masked
+    streaming flash forward and backward (a row's float32 dQ is 8 MiB:
+    Mosaic's limit is raised), and the alignment loss with the indexer's
+    gradients: one Mosaic call each, by name, and no scan left."""
+    from mxnet_tpu.ops import sparse_attention as sa
+    from mxnet_tpu.ops.flash_attention import masked_flash
+    b, h, hkv, seq, d, hi, di, topk = 1, 32, 4, 16384, 128, 16, 64, 2048
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    mask, f32 = shape((b, seq, seq), jnp.int8), jnp.float32
+    indexer = (shape((b, hi, seq, di)), shape((b, seq, di)),
+               shape((b, seq, hi), f32))
+    if part == "index_select":
+        text = _compile(lambda qi, ki, w: sa._index_select(
+            qi, ki, w, topk, 1 / 32), *indexer)
+        names = ["mxtpu_dsa_index_select"]
+    elif part == "attention":
+        def both(q, k, v, mask):
+            return jax.grad(lambda q, k, v: jnp.sum(masked_flash(
+                q, k, v, mask, d ** -0.5)[0].astype(f32)),
+                argnums=(0, 1, 2))(q, k, v)
+        rows = shape((b * h, seq, d))
+        text = _compile(both, rows, rows, rows, mask)
+        names = ["mxtpu_dsa_attn_fwd", "mxtpu_dsa_attn_bwd"]
+    else:
+        def grads(q, k, lse, qi, ki, w, mask, lse_i):
+            return jax.grad(lambda qi, ki, w: jnp.sum(sa._index_loss_sum(
+                q, k, lse, qi, ki, w, mask, lse_i, d ** -0.5, 1 / 32)),
+                argnums=(0, 1, 2))(qi, ki, w)
+        text = _compile(grads, shape((b, h, seq, d)), shape((b, hkv, seq, d)),
+                        shape((b, h, seq), f32), *indexer, mask,
+                        shape((b, seq), f32))
+        names = ["mxtpu_dsa_align_loss"]
+    for name in names:
+        assert re.search(name + r"\b", text), name
+    assert text.count("tpu_custom_call") == len(names)
+    assert " while(" not in text

@@ -1,0 +1,193 @@
+"""``ops/sparse_attention.py``: the exact k-th largest against ``lax.top_k``,
+the selection with ties and short pasts, and each Pallas kernel (in the
+interpreter) against its XLA form and against a dense masked softmax."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from mxnet_tpu import telemetry
+from mxnet_tpu.ops import sparse_attention as sa
+from mxnet_tpu.ops.flash_attention import masked_flash
+from mxnet_tpu.ops.kernel_mode import interpret_kernels
+
+
+def _rand(seed, *shape):
+    return jnp.asarray(np.random.RandomState(seed).randn(*shape),
+                       jnp.float32)
+
+
+@pytest.mark.parametrize("case", ["random", "ties", "negative", "k-is-n",
+                                  "k-is-1", "infinities"])
+def test_kth_largest_is_exact(case):
+    rng = np.random.RandomState(0)
+    x = rng.randn(16, 200).astype(np.float32)
+    k = np.full(16, 17, np.int32)
+    if case == "ties":
+        x = np.round(x * 2) / 2         # a dozen values, many times each
+    elif case == "negative":
+        x = -np.abs(x) - 1
+    elif case == "k-is-n":
+        k[:] = 200
+    elif case == "k-is-1":
+        k[:] = 1
+    elif case == "infinities":
+        x[:, ::7] = -np.inf
+        x[:, 3] = np.inf
+    k[::3] = np.minimum(k[::3] + 5, 200)        # k varies by row
+    got = np.asarray(sa.kth_largest(jnp.asarray(x), jnp.asarray(k)))
+    ordered = np.asarray(lax.top_k(jnp.asarray(x), 200)[0])
+    want = ordered[np.arange(16), k - 1]
+    assert (got == want).all()          # the value itself, bit for bit
+
+
+def _dense_selection(scores, topk):
+    """(L, L) bool, queries first: by ``lax.top_k``'s threshold."""
+    n = scores.shape[0]
+    causal = jnp.tril(jnp.ones((n, n), bool))
+    ordered = lax.top_k(jnp.where(causal, scores, -jnp.inf), n)[0]
+    tau = ordered[jnp.arange(n), jnp.minimum(topk, jnp.arange(n) + 1) - 1]
+    return causal & (scores >= tau[:, None])
+
+
+@pytest.mark.parametrize("mode", ["xla", "interpret"])
+def test_selection_against_top_k_with_ties_and_short_pasts(mode):
+    """Rows with ``t < k`` take their whole past; a tie at the threshold
+    takes every key that ties (one index head with weights of 0 and 1 and
+    few distinct products gives many)."""
+    seq, topk, hi, di = 256, 16, 2, 64
+    rng = np.random.RandomState(1)
+    qi = jnp.asarray(rng.randint(0, 2, (1, hi, seq, di)), jnp.float32)
+    ki = jnp.asarray(rng.randint(0, 2, (1, seq, di)), jnp.float32)
+    w = jnp.asarray(rng.randint(0, 3, (1, seq, hi)), jnp.float32)
+    scale = 0.125
+    if mode == "interpret":
+        with interpret_kernels():
+            mask, tau, lse = sa._index_select(qi, ki, w, topk, scale)
+    else:
+        mask, tau, lse = sa._index_select(qi, ki, w, topk, scale)
+    scores = scale * jnp.einsum(
+        "hqk,qh->qk", jax.nn.relu(jnp.einsum("hqd,kd->hqk", qi[0], ki[0])),
+        w[0])
+    want = np.asarray(_dense_selection(scores, topk))
+    got = np.asarray(mask[0]).T != 0
+    assert (got == want).all()
+    kept = got.sum(1)
+    assert (kept[:topk] == np.arange(1, topk + 1)).all()    # the whole past
+    assert kept.max() > topk                                # a tie took both
+    assert (kept[topk:] >= topk).all()
+    want_lse = jax.nn.logsumexp(jnp.where(want, scores, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(lse[0], want_lse, rtol=1e-6)
+    ordered = np.asarray(lax.top_k(jnp.where(np.tril(np.ones((seq, seq),
+                                                             bool)),
+                                             scores, -jnp.inf), seq)[0])
+    assert (np.asarray(tau[0]) == ordered[
+        np.arange(seq), np.minimum(topk, np.arange(seq) + 1) - 1]).all()
+
+
+def _operands(seed, b, h, hkv, seq, d, hi, di):
+    return (_rand(seed, b, h, seq, d), _rand(seed + 1, b, hkv, seq, d),
+            _rand(seed + 2, b, hkv, seq, d), _rand(seed + 3, b, hi, seq, di),
+            _rand(seed + 4, b, seq, di), _rand(seed + 5, b, seq, hi))
+
+
+def _dense(q, k, v, qi, ki, w, topk):
+    """The whole op densely: (L, L) scores, a masked softmax, autodiff."""
+    b, h, seq, d = q.shape
+    hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[3]
+    scale = hi ** -0.5 * di ** -0.5
+    scores = scale * jnp.einsum(
+        "bhqk,bqh->bqk",
+        jax.nn.relu(jnp.einsum("bhqd,bkd->bhqk", qi, ki)), w)
+    sel = jnp.stack([_dense_selection(s, topk)
+                     for s in lax.stop_gradient(scores)])
+    kr, vr = (jnp.repeat(a, h // hkv, axis=1) for a in (k, v))
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, kr) * d ** -0.5
+    p = jax.nn.softmax(jnp.where(sel[:, None], logits, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p, vr)
+    pbar = lax.stop_gradient(jnp.mean(p, axis=1))
+    logq = jax.nn.log_softmax(jnp.where(sel, scores, -jnp.inf), axis=-1)
+    kl = jnp.where(pbar > 0, pbar * (jnp.log(jnp.where(pbar > 0, pbar, 1.0))
+                                     - jnp.where(sel, logq, 0.0)), 0.0)
+    return out, jnp.sum(kl, axis=(1, 2)) / seq
+
+
+def _both(fn, args):
+    def scalar(*a):
+        out, loss = fn(*a)
+        weight = jnp.cos(jnp.arange(out.size, dtype=jnp.float32)
+                         ).reshape(out.shape)
+        return jnp.sum(out * weight) + 3.0 * jnp.sum(loss), (out, loss)
+    return jax.jit(jax.grad(scalar, argnums=tuple(range(6)),
+                            has_aux=True))(*args)
+
+
+@pytest.mark.parametrize("seq,topk,mode", [
+    pytest.param(256, 40, "interpret", id="one-pass-kernels"),
+    pytest.param(384, 64, "interpret", id="streaming-3-blocks-kernels"),
+    pytest.param(96, 10, "xla", id="xla-forms-no-128-multiple"),
+    pytest.param(512, 64, "xla", id="xla-forms-two-query-blocks"),
+])
+def test_sparse_attention_forward_and_backward_against_dense(seq, topk, mode):
+    """Output, index loss and all six gradients: the four kernels in the
+    interpreter (or the XLA forms) against a dense masked softmax under
+    autodiff.  q, k, v get their gradient from the output alone, the
+    indexer's operands from the loss alone."""
+    args = _operands(10, 2, 4, 2, seq, 64, 2, 64)
+    telemetry.reset()
+    if mode == "interpret":
+        with interpret_kernels():
+            grads, (out, loss) = _both(
+                lambda *a: sa.sparse_gq_attention(*a, topk), args)
+        assert telemetry.value("dsa.attn.fwd.pallas") == 1
+        assert telemetry.value("dsa.attn.bwd.pallas") == 1
+        assert telemetry.value("dsa.index.pallas") == 1
+        assert telemetry.value("dsa.index_loss.pallas") == 1
+    else:
+        grads, (out, loss) = _both(
+            lambda *a: sa.sparse_gq_attention(*a, topk), args)
+        assert telemetry.value("dsa.attn.fwd.scan") == 1
+        assert telemetry.value("dsa.index.xla") == 1
+    assert telemetry.value("dsa.select.radix") == 1
+    assert telemetry.value("dsa.selected_share") == pytest.approx(
+        sa.selected_share(seq, topk))
+    want_grads, (want_out, want_loss) = _both(
+        lambda *a: _dense(*a, topk), args)
+    np.testing.assert_allclose(out, want_out, atol=2e-5)
+    np.testing.assert_allclose(loss, want_loss, rtol=2e-5)
+    for name, got, want in zip("q k v qi ki w".split(), grads, want_grads):
+        assert float(jnp.abs(got - want).max()) <= \
+            2e-4 * float(jnp.abs(want).max()), name
+    if mode == "interpret":
+        # a forward nobody differentiates takes the kernel without the
+        # indexer's backward products: the same loss
+        with interpret_kernels():
+            plain = sa.sparse_gq_attention(*args, topk)[1]
+        np.testing.assert_allclose(plain, loss, rtol=1e-6)
+
+
+def test_masked_flash_rows_share_their_batch_mask():
+    """Two batches with different masks in one call: a program's rows are
+    one batch's heads, so each batch reads its own mask."""
+    b, h, seq, d = 2, 4, 256, 64
+    q, k, v = (_rand(s, b * h, seq, d) for s in (1, 2, 3))
+    rng = np.random.RandomState(4)
+    keep = np.tril(rng.rand(b, seq, seq) < 0.3) | np.eye(seq, dtype=bool)
+    mask = jnp.asarray(keep.transpose(0, 2, 1), jnp.int8)      # keys first
+    with interpret_kernels():
+        out, lse = masked_flash(q, k, v, mask, d ** -0.5)
+    logits = jnp.einsum("rqd,rkd->rqk", q, k) * d ** -0.5
+    sel = jnp.repeat(jnp.asarray(keep), h, axis=0)
+    p = jax.nn.softmax(jnp.where(sel, logits, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(out, jnp.einsum("rqk,rkd->rqd", p, v),
+                               atol=2e-5)
+    np.testing.assert_allclose(
+        lse, jax.nn.logsumexp(jnp.where(sel, logits, -jnp.inf), axis=-1),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_selected_share_at_the_cell_shape():
+    assert sa.selected_share(16384, 2048) == pytest.approx(0.2344, abs=1e-4)
+    assert sa.selected_share(2048, 2048) == 1.0
