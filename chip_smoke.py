@@ -783,8 +783,8 @@ def _kernels_sparse(run):
     (query, key) pairs on which the two masks differ: their products sum in
     different orders, so a score at the threshold may fall either side),
     the masked flash forward and backward on the kernel's own mask against
-    the scans, the alignment loss and the indexer's gradients against
-    ``jax.grad`` of the XLA form."""
+    the scans, the alignment loss's value kernel against the XLA form and
+    its gradient kernel against ``jax.grad`` of that."""
     import importlib
     import jax
     import jax.numpy as jnp
@@ -868,14 +868,19 @@ def _kernels_sparse(run):
             f"max_abs_err out,lse,dq,dk,dv vs scans on {rows} rows="
             f"{[float(f'{e:.2e}') for e in errs]} (tolerance 2e-2 x scale)")
 
-    # 3. the alignment loss with the indexer's gradients
+    # 3. the alignment loss: the value kernel and the gradient kernel, each
+    # alone
     lse3 = lse.reshape(1, h, seq)
     args = (jnp.swapaxes(q, 2, 3), jnp.swapaxes(k, 2, 3), lse3, *swapped,
             mask, lse_i)
-    exe, dt = _compiled(run, "mxtpu_dsa_align_loss",
-                        lambda *a: sa._pallas_index_loss(
-                            *a, sm, scale, **mode), *args)
-    kl, dqi, dki, dw = exe(*args)
+    value, dt = _compiled(run, "mxtpu_dsa_align_loss",
+                          lambda *a: sa._pallas_index_loss(
+                              *a, sm, scale, **mode), *args)
+    grad, dt_g = _compiled(run, "mxtpu_dsa_align_loss_grad",
+                           lambda *a: sa._pallas_index_loss_grad(
+                               *a, sm, scale, **mode), *args)
+    kl = value(*args)
+    dqi, dki, dw = grad(*args)
     want_kl, want_g = jax.jit(jax.value_and_grad(
         lambda qi, ki, w: sa._xla_index_loss(
             q[0], jnp.repeat(k, h // hkv, 1)[0], lse3[0], qi, ki, w, mask[0],
@@ -886,9 +891,11 @@ def _kernels_sparse(run):
         for n, a, b in zip(("qi", "ki", "w"), (
             jnp.swapaxes(dqi, 2, 3), jnp.swapaxes(dki, 1, 2),
             jnp.swapaxes(dw, 1, 2)), want_g)]
-    say(ph, f"align_loss compile_s={dt:.2f} ms={clock(exe, *args):.3f} "
+    say(ph, f"align_loss compile_s={dt:.2f}+{dt_g:.2f} "
+            f"value_ms={clock(value, *args):.3f} "
+            f"grad_ms={clock(grad, *args):.3f} "
             f"mean_kl={float(jnp.sum(kl)) / seq:.4e} "
-            f"max_abs_err kl,dqi,dki,dw vs jax.grad of the xla form="
+            f"max_abs_err kl vs the xla form, dqi,dki,dw vs jax.grad of it="
             f"{[float(f'{e:.2e}') for e in errs]}")
 
 

@@ -17,7 +17,7 @@ sort's order enters.  ``L_I`` is the only thing the indexer's operands get a
 gradient from (attention sees the selection as a constant), and q, k, v get
 none from it.
 
-Four parts, each a Pallas kernel on the chip and plain XLA elsewhere (the
+Three steps, five Pallas kernels on the chip and plain XLA elsewhere (the
 XLA forms walk blocks of queries, so they hold (block, L) and never (L, L)
 in float32; they are also the tests' reference):
 
@@ -34,11 +34,22 @@ in float32; they are also the tests' reference):
   no block is empty, so there is nothing to skip but the causal dead half).
   A kernel that gathers ``topk`` keys a query would move 1 MB a (query,
   kv head) for 8 MFLOP; the two cross near L = 67k.
-- ``mxtpu_dsa_align_loss``: ``L_I`` and, in the same pass, its gradient for
-  qI, kI and w (the loss's cotangent is a scalar, so the backward is a
-  multiplication): per tile the 32 heads' probabilities from the attention
-  kernel's own log-sum-exp, the indexer's scores again, and the three
-  products of the indexer's backward.
+- ``mxtpu_dsa_align_loss`` and ``mxtpu_dsa_align_loss_grad``: ``L_I`` as a
+  custom VJP whose rules each compute only what they hand on.  The value
+  kernel (the primal and the forward rule): per (256, 256) tile the 32
+  heads' probabilities from the attention kernel's own log-sum-exp, their
+  mean on the selection, the indexer's scores again, ``KL_t``.  The gradient
+  kernel (the backward rule; the loss's cotangent is a scalar a sequence,
+  so it multiplies the result): the mean probability again, each index
+  head's ``relu(kI . qI)`` once, kept in VMEM between the scores and the
+  three products of the indexer's backward.  The forward rule's residuals
+  are its own operands, so under ``jax.grad`` of a ``jax.checkpoint`` the
+  recomputation's value kernel has no reader and is removed: a
+  rematerialised layer runs each kernel once.  A layer trained without
+  ``jax.checkpoint`` runs both as well and so builds the mean probability
+  twice, where one kernel that did both would build it once (some 1.4
+  times that kernel's products); that is the price of one path — the op
+  cannot see whether it is being recomputed, and a flag would be a knob.
 
 K and V are repeated to the query heads before the kernels, as ``llama.py``
 does (gauge ``gqa.kv_repeat``); reading the kv heads in place is a later
@@ -61,7 +72,7 @@ __all__ = ["sparse_gq_attention", "kth_largest", "selected_share"]
 
 _INT_MIN = np.int32(-2 ** 31)
 _INDEX_BQ = 128         # queries a program of mxtpu_dsa_index_select takes (lanes)
-_LOSS_BLOCK = 256       # the tile of mxtpu_dsa_align_loss, both ways
+_LOSS_BLOCK = 256       # the tile of both mxtpu_dsa_align_loss kernels, both ways
 
 
 def selected_share(seq, topk):
@@ -174,7 +185,7 @@ def _xla_index_loss(q, k, lse, qi, ki, w, mask, sm_scale, scale):
     query heads), lse (H, L) the attention's log-sum-exp, the indexer's
     operands and the mask as :func:`_xla_index_select` takes and gives them.
     Differentiable in qi, ki, w (``jax.grad`` of this is the reference of
-    the kernel's fused gradients)."""
+    the gradient kernel)."""
     seq = ki.shape[0]
     bq = _query_blocks(seq, 256)
 
@@ -302,86 +313,198 @@ def _pallas_index_select(qi, ki, w, topk, scale, interpret=False):
 
 
 # ---------------------------------------------------------------------------
-# Pallas: the index loss with its gradients
+# Pallas: the index loss, and its gradients
 # ---------------------------------------------------------------------------
 
-def _pallas_index_loss(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
-                       interpret=False, gradients=True):
-    """q (B, H, d, L), k (B, Hkv, d, L), lse (B, H, L), qi (B, HI, dI, L), ki
-    (B, dI, L), w (B, HI, L), mask (B, L, L) int8, lse_i (B, L) ->
-    ``(kl (B, L), dqi (B, HI, dI, L), dki (B, dI, L), dw (B, HI, L))``, all
-    float32: a query's ``KL_t`` and the gradients of ``sum_t KL_t``; without
-    ``gradients`` (a forward nobody differentiates: evaluation, a shape
-    probe) ``kl`` alone, and the indexer's backward products are not
-    computed.  Both passes of a rematerialised layer under ``jax.grad`` are
-    differentiated traces and take the full form."""
+def _loss_tile(q_ref, k_ref, lse_ref, qi_ref, ki_ref, mask_ref, ks, sm_scale,
+               operand):
+    """What both kernels build of a live (blk, blk) tile, keys first:
+    ``(sel, pbar, kb, relu)`` — the selection, the heads' mean probability
+    on it, the tile's (dI, blk) block of index keys (the slice ``ks`` of
+    the row) and a function giving ``relu(kI . qI[hh])`` (blk, blk)
+    float32."""
+    h, hkv, blk = q_ref.shape[1], k_ref.shape[1], q_ref.shape[3]
+    rep = h // hkv
+    sel = mask_ref[0].astype(jnp.int32) != 0                    # (bk, bq)
+
+    # every head unrolled, a kv head's block of K read once for the query
+    # heads that share it: the scheduler overlaps one head's exp with the
+    # next one's product, where a rolled loop drains the MXU every turn
+    # (25.7 ms a call rolled, 11.9 a kv head a turn, 9.9 unrolled: PERF.md)
+    pbar = jnp.zeros((blk, blk), jnp.float32)
+    for g in range(hkv):
+        kg = operand(k_ref[0, g])                               # (d, bk)
+        for a in range(g * rep, (g + 1) * rep):
+            s = lax.dot_general(
+                kg, operand(q_ref[0, a]), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            pbar = pbar + jnp.exp(s - lse_ref[0, a:a + 1, :])
+    pbar = jnp.where(sel, pbar * (1.0 / h), 0.0)
+    kb = operand(ki_ref[0, :, ks])
+
+    def relu(hh):
+        return jnp.maximum(lax.dot_general(
+            kb, operand(qi_ref[0, hh]), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32), 0.0)           # (bk, bq)
+    return sel, pbar, kb, relu
+
+
+def _loss_call(kernel, name, q, k, lse, qi, ki, w, mask, lse_i, out_specs,
+               out_shape, scratch, more_vmem, interpret):
+    """One of the two kernels over the grid they share: (batch, query
+    block, key block), the key block innermost, a dead tile (key block past
+    the query block) naming the row's last live key block again so that
+    nothing is fetched for it.  ``more_vmem``: the bytes of the kernel's own
+    output blocks and scratch beside the operands' blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     nb, h, d, seq = q.shape
     hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[2]
-    rep = h // hkv
     blk = _pick_block(seq, _LOSS_BLOCK)
     n = seq // blk
 
-    def operand(x):
-        return x.astype(jnp.float32) if interpret else x
+    def at_k(i, j):
+        return jnp.minimum(j, i)
+    need = 2 * (h * d * blk + hkv * d * blk + hi * di * blk
+                + di * seq) * q.dtype.itemsize + 12 * blk * blk * 4 \
+        + more_vmem
+    return pl.pallas_call(
+        kernel,
+        grid=(nb, n, n),
+        in_specs=[
+            pl.BlockSpec((1, h, d, blk), lambda b, i, j: (b, 0, 0, i)),
+            pl.BlockSpec((1, hkv, d, blk),
+                         lambda b, i, j: (b, 0, 0, at_k(i, j))),
+            pl.BlockSpec((1, h, blk), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, hi, di, blk), lambda b, i, j: (b, 0, 0, i)),
+            pl.BlockSpec((1, di, seq), lambda b, i, j: (b, 0, 0)),
+            pl.BlockSpec((1, hi, blk), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, blk, blk), lambda b, i, j: (b, at_k(i, j), i)),
+            pl.BlockSpec((1, 1, blk), lambda b, i, j: (b, 0, i)),
+        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            **({"vmem_limit_bytes": need + 4 * 2 ** 20}
+               if need > _VMEM_BUDGET else {})),
+        name=name,
+        interpret=interpret,
+    )(q, k, lse, qi, ki, w, mask, lse_i[:, None, :])
+
+
+def _interpreter_cast(interpret):
+    """The interpreter multiplies in float32 what Mosaic takes as it is."""
+    return (lambda x: x.astype(jnp.float32)) if interpret else (lambda x: x)
+
+
+# Both kernels are jitted so that a model's layers share one trace of the
+# unrolled body (a pallas_call traces its kernel anew every time it is
+# called: 2.3 s a gradient kernel on the chip's host, PERF.md §6).
+_LOSS_STATIC = ("sm_scale", "scale", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_LOSS_STATIC)
+def _pallas_index_loss(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
+                       interpret=False):
+    """The value kernel.  q (B, H, d, L), k (B, Hkv, d, L), lse (B, H, L), qi
+    (B, HI, dI, L), ki (B, dI, L), w (B, HI, L) float32, mask (B, L, L) int8,
+    lse_i (B, L) -> ``kl (B, L)`` float32, a query's ``KL_t``: per tile the
+    heads' probabilities from the attention's log-sum-exp, their mean on the
+    selection, the index scores (one product an index head), and the tile's
+    share of the divergence."""
+    from jax.experimental import pallas as pl
+
+    nb, seq, hi = q.shape[0], q.shape[3], qi.shape[1]
+    blk = _pick_block(seq, _LOSS_BLOCK)
+    operand = _interpreter_cast(interpret)
 
     def kernel(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, mask_ref,
-               lsei_ref, kl_ref, *grad_refs):
+               lsei_ref, kl_ref):
         i = pl.program_id(1)
         j = pl.program_id(2)
 
         @pl.when(j == 0)
-        def _init_q():
+        def _init():
             kl_ref[...] = jnp.zeros_like(kl_ref)
-            for ref in grad_refs[::2]:              # dqi, dw
-                ref[...] = jnp.zeros_like(ref)
-
-        if gradients:
-            dqi_ref, dki_ref, dw_ref = grad_refs
-
-            @pl.when((i == 0) & (j == 0))
-            def _init_k():
-                dki_ref[...] = jnp.zeros_like(dki_ref)
 
         @pl.when(j <= i)
         def _tile():
-            ks = pl.ds(pl.multiple_of(j * blk, blk), blk)
-            sel = mask_ref[0].astype(jnp.int32) != 0            # (bk, bq)
-
-            def head(a, pbar):
-                s = lax.dot_general(
-                    operand(k_ref[0, a // rep]), operand(q_ref[0, a]),
-                    (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32) * sm_scale
-                return pbar + jnp.exp(s - lse_ref[0, pl.ds(a, 1), :])
-            pbar = lax.fori_loop(0, h, head,
-                                 jnp.zeros((blk, blk), jnp.float32))
-            pbar = jnp.where(sel, pbar * (1.0 / h), 0.0)
-
-            kb = operand(ki_ref[0, :, ks])                      # (dI, bk)
-
-            def pre(hh):
-                return lax.dot_general(
-                    kb, operand(qi_ref[0, hh]), (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)         # (bk, bq)
+            _, pbar, _, relu = _loss_tile(
+                q_ref, k_ref, lse_ref, qi_ref, ki_ref, mask_ref,
+                pl.ds(pl.multiple_of(j * blk, blk), blk), sm_scale, operand)
             scores = jnp.zeros((blk, blk), jnp.float32)
             for hh in range(hi):
-                scores = scores + jnp.maximum(pre(hh), 0.0) \
-                    * w_ref[0, hh:hh + 1, :]
+                scores = scores + relu(hh) * w_ref[0, hh:hh + 1, :]
             logq = scores * scale - lsei_ref[0]
             kl_ref[0] += jnp.sum(
                 jnp.where(pbar > 0,
                           pbar * (jnp.log(jnp.maximum(pbar, 1e-37)) - logq),
                           0.0), axis=0, keepdims=True)
+
+    return _loss_call(
+        kernel, "mxtpu_dsa_align_loss", q, k, lse, qi, ki, w, mask, lse_i,
+        pl.BlockSpec((1, 1, blk), lambda b, i, j: (b, 0, i)),
+        jax.ShapeDtypeStruct((nb, 1, seq), jnp.float32), [], 0,
+        interpret)[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=_LOSS_STATIC)
+def _pallas_index_loss_grad(q, k, lse, qi, ki, w, mask, lse_i, sm_scale,
+                            scale, interpret=False):
+    """The gradient kernel: the value kernel's operands -> ``(dqi (B, HI,
+    dI, L), dki (B, dI, L), dw (B, HI, L))`` float32, the gradients of
+    ``sum_t KL_t``.  Per tile the heads' mean probability again, every index
+    head's ``relu(kI . qI)`` once — kept as computed in a float32 VMEM
+    scratch of (HI, blk, blk), 4 MiB at 16 heads, between the scores and
+    the products that need ``d I`` (computing them again read 19.2 ms a
+    call where the scratch read 17.3, PERF.md §6; rounding them to halve
+    the scratch would change ``dw``) — then ``d I = softmax_S(I) - pbar``
+    and the three products of the indexer's backward, ``g`` in the
+    operands' dtype.  No ``log`` and no ``kl``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nb, seq = q.shape[0], q.shape[3]
+    hi, di = qi.shape[1], qi.shape[2]
+    blk = _pick_block(seq, _LOSS_BLOCK)
+    operand = _interpreter_cast(interpret)
+
+    def kernel(q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, mask_ref,
+               lsei_ref, dqi_ref, dki_ref, dw_ref, relu_ref):
+        i = pl.program_id(1)
+        j = pl.program_id(2)
+
+        @pl.when(j == 0)
+        def _init_q():
+            dqi_ref[...] = jnp.zeros_like(dqi_ref)
+            dw_ref[...] = jnp.zeros_like(dw_ref)
+
+        @pl.when((i == 0) & (j == 0))
+        def _init_k():
+            dki_ref[...] = jnp.zeros_like(dki_ref)
+
+        @pl.when(j <= i)
+        def _tile():
+            ks = pl.ds(pl.multiple_of(j * blk, blk), blk)
+            sel, pbar, kb, relu = _loss_tile(
+                q_ref, k_ref, lse_ref, qi_ref, ki_ref, mask_ref, ks,
+                sm_scale, operand)
+            scores = jnp.zeros((blk, blk), jnp.float32)
+            for hh in range(hi):
+                r = relu(hh)
+                relu_ref[hh] = r
+                scores = scores + r * w_ref[0, hh:hh + 1, :]
             # d sum_t KL_t / d I, times the scale on the way to the products
-            di_tile = jnp.where(sel, jnp.exp(logq) - pbar, 0.0) * scale
-            for hh in range(hi if gradients else 0):
-                p = pre(hh)
-                dw_ref[0, hh:hh + 1, :] += jnp.sum(
-                    di_tile * jnp.maximum(p, 0.0), axis=0, keepdims=True)
-                g = jnp.where(p > 0, di_tile * w_ref[0, hh:hh + 1, :], 0.0)
+            di_tile = jnp.where(
+                sel, jnp.exp(scores * scale - lsei_ref[0]) - pbar, 0.0) * scale
+            for hh in range(hi):
+                r = relu_ref[hh]
+                dw_ref[0, hh:hh + 1, :] += jnp.sum(di_tile * r, axis=0,
+                                                   keepdims=True)
+                g = jnp.where(r > 0, di_tile * w_ref[0, hh:hh + 1, :], 0.0)
                 g = operand(g.astype(qi_ref.dtype))
                 dqi_ref[0, hh] += lax.dot_general(
                     kb, g, (((1,), (0,)), ((), ())),
@@ -390,48 +513,20 @@ def _pallas_index_loss(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
                     operand(qi_ref[0, hh]), g, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)         # (dI, bk)
 
-    def at_k(b, i, j):
-        # a dead tile names the row's last live key block again: no fetch
-        return jnp.minimum(j, i)
-
-    itemsize = q.dtype.itemsize
-    need = 2 * (h * d * blk + hkv * d * blk + hi * di * blk) * itemsize \
-        + 2 * di * seq * (itemsize + 4) + 2 * hi * di * blk * 4 \
-        + 12 * blk * blk * 4
-    outs = pl.pallas_call(
-        kernel,
-        grid=(nb, n, n),
-        in_specs=[
-            pl.BlockSpec((1, h, d, blk), lambda b, i, j: (b, 0, 0, i)),
-            pl.BlockSpec((1, hkv, d, blk),
-                         lambda b, i, j: (b, 0, 0, at_k(b, i, j))),
-            pl.BlockSpec((1, h, blk), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, hi, di, blk), lambda b, i, j: (b, 0, 0, i)),
-            pl.BlockSpec((1, di, seq), lambda b, i, j: (b, 0, 0)),
-            pl.BlockSpec((1, hi, blk), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, blk, blk),
-                         lambda b, i, j: (b, at_k(b, i, j), i)),
-            pl.BlockSpec((1, 1, blk), lambda b, i, j: (b, 0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, blk), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((1, hi, di, blk), lambda b, i, j: (b, 0, 0, i)),
-            pl.BlockSpec((1, di, seq), lambda b, i, j: (b, 0, 0)),
-            pl.BlockSpec((1, hi, blk), lambda b, i, j: (b, 0, i)),
-        ][:4 if gradients else 1],
-        out_shape=[jax.ShapeDtypeStruct((nb, 1, seq), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, hi, di, seq), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, di, seq), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, hi, seq), jnp.float32)
-                   ][:4 if gradients else 1],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-            **({"vmem_limit_bytes": need + 4 * 2 ** 20}
-               if need > _VMEM_BUDGET else {})),
-        name="mxtpu_dsa_align_loss",
-        interpret=interpret,
-    )(q, k, lse, qi, ki, w, mask, lse_i[:, None, :])
-    return (outs[0][:, 0],) + tuple(outs[1:])
+    return tuple(_loss_call(
+        kernel, "mxtpu_dsa_align_loss_grad", q, k, lse, qi, ki, w, mask,
+        lse_i,
+        [pl.BlockSpec((1, hi, di, blk), lambda b, i, j: (b, 0, 0, i)),
+         pl.BlockSpec((1, di, seq), lambda b, i, j: (b, 0, 0)),
+         pl.BlockSpec((1, hi, blk), lambda b, i, j: (b, 0, i))],
+        [jax.ShapeDtypeStruct((nb, hi, di, seq), jnp.float32),
+         jax.ShapeDtypeStruct((nb, di, seq), jnp.float32),
+         jax.ShapeDtypeStruct((nb, hi, seq), jnp.float32)],
+        [pltpu.VMEM((hi, blk, blk), jnp.float32)],
+        # the float32 gradient blocks, double-buffered (dki a row of the
+        # whole sequence, resident), and the scratch
+        2 * (hi * di * blk + di * seq + hi * blk) * 4 + hi * blk * blk * 4,
+        interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -464,38 +559,53 @@ def _index_select(qi, ki, w, topk, scale):
         interpret=mode == "interpret")
 
 
-def _index_loss_kernel(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
-                       mode, gradients):
-    return _pallas_index_loss(
-        *(jnp.swapaxes(a, 2, 3) for a in (q, k)), lse,
-        jnp.swapaxes(qi, 2, 3), jnp.swapaxes(ki, 1, 2),
-        jnp.swapaxes(w, 1, 2).astype(jnp.float32), mask, lse_i, sm_scale,
-        scale, interpret=mode == "interpret", gradients=gradients)
+def _loss_layout(q, k, qi, ki, w):
+    """The operands as the two loss kernels take them: the head dim on
+    sublanes, the sequence on lanes."""
+    return (jnp.swapaxes(q, 2, 3), jnp.swapaxes(k, 2, 3),
+            jnp.swapaxes(qi, 2, 3), jnp.swapaxes(ki, 1, 2),
+            jnp.swapaxes(w, 1, 2).astype(jnp.float32))
+
+
+def _index_loss_value(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
+                      mode):
+    _telem.inc("dsa.index_loss.value.pallas")
+    qt, kt, qit, kit, wt = _loss_layout(q, k, qi, ki, w)
+    return jnp.sum(_pallas_index_loss(
+        qt, kt, lse, qit, kit, wt, mask, lse_i, sm_scale, scale,
+        interpret=mode == "interpret"), axis=1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
 def _index_loss(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale, mode):
-    # not under differentiation: the loss alone
-    return jnp.sum(_index_loss_kernel(q, k, lse, qi, ki, w, mask, lse_i,
-                                      sm_scale, scale, mode, False)[0],
-                   axis=1)
+    """Each rule computes only what it hands on: the primal and the forward
+    rule run the value kernel, and the forward rule keeps nothing but its
+    own operands, so a recomputation (``jax.checkpoint``) of it under
+    ``jax.grad`` has no reader and is removed; the backward rule, which runs
+    once whatever recomputes, runs the gradient kernel."""
+    return _index_loss_value(q, k, lse, qi, ki, w, mask, lse_i, sm_scale,
+                             scale, mode)
 
 
 def _index_loss_fwd(q, k, lse, qi, ki, w, mask, lse_i, sm_scale, scale,
                     mode):
-    kl, dqi, dki, dw = _index_loss_kernel(q, k, lse, qi, ki, w, mask, lse_i,
-                                          sm_scale, scale, mode, True)
-    grads = (jnp.swapaxes(dqi, 2, 3), jnp.swapaxes(dki, 1, 2),
-             jnp.swapaxes(dw, 1, 2))
-    return jnp.sum(kl, axis=1), (grads, tuple(
-        jnp.zeros((0,), a.dtype) for a in (qi, ki, w)))
+    return (_index_loss_value(q, k, lse, qi, ki, w, mask, lse_i, sm_scale,
+                              scale, mode),
+            (q, k, lse, qi, ki, w, mask, lse_i))
 
 
 def _index_loss_bwd(sm_scale, scale, mode, res, ct):
-    grads, like = res
+    q, k, lse, qi, ki, w, mask, lse_i = res
+    _telem.inc("dsa.index_loss.grad.pallas")
+    qt, kt, qit, kit, wt = _loss_layout(q, k, qi, ki, w)
+    dqi, dki, dw = _pallas_index_loss_grad(
+        qt, kt, lse, qit, kit, wt, mask, lse_i, sm_scale, scale,
+        interpret=mode == "interpret")
+    grads = (jnp.swapaxes(dqi, 2, 3), jnp.swapaxes(dki, 1, 2),
+             jnp.swapaxes(dw, 1, 2))
     return (None, None, None) + tuple(
         (g * ct.reshape((-1,) + (1,) * (g.ndim - 1))).astype(a.dtype)
-        for g, a in zip(grads, like)) + (None, None)
+        for g, a in zip(grads, (qi, ki, w))) + (None, None)
 
 
 _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
@@ -535,7 +645,11 @@ def sparse_gq_attention(q, k, v, qi, ki, w, topk, sm_scale=None,
     Counters, while tracing: ``dsa.layers``; ``dsa.index.pallas`` / ``.xla``;
     ``dsa.select.radix``; ``dsa.attn.fwd.pallas`` / ``.scan`` and
     ``dsa.attn.bwd.*`` (``ops/flash_attention.py``);
-    ``dsa.index_loss.pallas`` / ``.xla``.  Gauges ``dsa.topk``,
+    ``dsa.index_loss.pallas`` / ``.xla`` (a layer that takes the loss's
+    kernels or the XLA form), ``dsa.index_loss.value.pallas`` and
+    ``dsa.index_loss.grad.pallas`` (each kernel where it is traced: the
+    value kernel in every trace of the forward, the gradient kernel in the
+    backward rule, once a differentiated layer).  Gauges ``dsa.topk``,
     ``dsa.index_heads``, ``dsa.selected_share``, ``gqa.kv_repeat``."""
     b, h, seq, d = q.shape
     hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[3]

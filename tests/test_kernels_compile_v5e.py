@@ -101,8 +101,9 @@ def test_sparse_attention_kernels_at_the_keye_shape(one_chip, part):
     16384, 32 / 4 heads of 128, 16 index heads of 64, top-2048): the
     index / select kernel with its (L, 128) scratch of keys, the masked
     streaming flash forward and backward (a row's float32 dQ is 8 MiB:
-    Mosaic's limit is raised), and the alignment loss with the indexer's
-    gradients: one Mosaic call each, by name, and no scan left."""
+    Mosaic's limit is raised), and the alignment loss's value kernel and
+    gradient kernel (the latter with its 4 MiB scratch and the resident
+    ``dki`` row): one Mosaic call each, by name, and no scan left."""
     from mxnet_tpu.ops import sparse_attention as sa
     from mxnet_tpu.ops.flash_attention import masked_flash
     b, h, hkv, seq, d, hi, di, topk = 1, 32, 4, 16384, 128, 16, 64, 2048
@@ -126,13 +127,14 @@ def test_sparse_attention_kernels_at_the_keye_shape(one_chip, part):
         names = ["mxtpu_dsa_attn_fwd", "mxtpu_dsa_attn_bwd"]
     else:
         def grads(q, k, lse, qi, ki, w, mask, lse_i):
-            return jax.grad(lambda qi, ki, w: jnp.sum(sa._index_loss_sum(
-                q, k, lse, qi, ki, w, mask, lse_i, d ** -0.5, 1 / 32)),
+            return jax.value_and_grad(
+                lambda qi, ki, w: jnp.sum(sa._index_loss_sum(
+                    q, k, lse, qi, ki, w, mask, lse_i, d ** -0.5, 1 / 32)),
                 argnums=(0, 1, 2))(qi, ki, w)
         text = _compile(grads, shape((b, h, seq, d)), shape((b, hkv, seq, d)),
                         shape((b, h, seq), f32), *indexer, mask,
                         shape((b, seq), f32))
-        names = ["mxtpu_dsa_align_loss"]
+        names = ["mxtpu_dsa_align_loss", "mxtpu_dsa_align_loss_grad"]
     for name in names:
         assert re.search(name + r"\b", text), name
     assert text.count("tpu_custom_call") == len(names)

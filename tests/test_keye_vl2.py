@@ -256,6 +256,10 @@ def test_trains_through_the_fused_step_under_amp_with_the_kernels(bf16):
     assert telemetry.value("dsa.attn.fwd.pallas") in (1, 2)
     assert telemetry.value("dsa.index.pallas") >= 1
     assert telemetry.value("dsa.index_loss.pallas") >= 1
+    # the loss's two kernels: the value kernel in every trace of the
+    # forward, the gradient kernel in the backward rule alone, once a layer
+    assert telemetry.value("dsa.index_loss.value.pallas") >= 1
+    assert telemetry.value("dsa.index_loss.grad.pallas") == 1
     for name in ("dsa.attn.fwd.scan", "dsa.attn.bwd.scan", "dsa.index.xla",
                  "dsa.index_loss.xla"):
         assert not telemetry.value(name)

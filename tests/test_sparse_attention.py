@@ -161,11 +161,114 @@ def test_sparse_attention_forward_and_backward_against_dense(seq, topk, mode):
         assert float(jnp.abs(got - want).max()) <= \
             2e-4 * float(jnp.abs(want).max()), name
     if mode == "interpret":
-        # a forward nobody differentiates takes the kernel without the
-        # indexer's backward products: the same loss
+        # a forward nobody differentiates takes the value kernel alone: the
+        # same loss
         with interpret_kernels():
             plain = sa.sparse_gq_attention(*args, topk)[1]
         np.testing.assert_allclose(plain, loss, rtol=1e-6)
+
+
+def _loss_kernel_operands(seq, topk):
+    """The alignment loss's operands as ``sparse_gq_attention`` hands them
+    on: the selection and both log-sum-exps from the XLA forms."""
+    q, k, v, qi, ki, w = _operands(20, 2, 4, 2, seq, 64, 2, 64)
+    sm_scale, scale = 64 ** -0.5, 2 ** -0.5 * 64 ** -0.5
+    mask, _, lse_i = sa._index_select(qi, ki, w, topk, scale)
+    rows = (2 * 4, seq, 64)
+    _, lse = masked_flash(q.reshape(rows), jnp.repeat(k, 2, 1).reshape(rows),
+                          jnp.repeat(v, 2, 1).reshape(rows), mask, sm_scale)
+    return (q, k, lse.reshape(2, 4, seq), qi, ki, w, mask, lse_i), \
+        (sm_scale, scale)
+
+
+@pytest.mark.parametrize("seq,topk", [
+    pytest.param(256, 40, id="one-tile"),
+    pytest.param(384, 64, id="3x3-tiles-of-128"),
+])
+@pytest.mark.parametrize("kernel", ["value", "gradient"])
+def test_alignment_loss_kernels_against_the_xla_form(kernel, seq, topk):
+    """Each of the two kernels alone (in the interpreter) against
+    ``jax.value_and_grad`` of ``_xla_index_loss``: the value kernel's
+    ``kl`` summed a sequence, the gradient kernel's ``dqi``, ``dki``,
+    ``dw``.  With three blocks a side the dead tiles, a row's several key
+    blocks and ``dki`` gathered over the query blocks are all there."""
+    (q, k, lse, qi, ki, w, mask, lse_i), scales = \
+        _loss_kernel_operands(seq, topk)
+    want_kl, want_grads = jax.vmap(jax.value_and_grad(
+        lambda qi, ki, w, q, k, lse, mask: sa._xla_index_loss(
+            q, k, lse, qi, ki, w, mask, *scales), argnums=(0, 1, 2)))(
+                qi, ki, w, q, jnp.repeat(k, 2, axis=1), lse, mask)
+    laid_out = sa._loss_layout(q, k, qi, ki, w)
+    args = (*laid_out[:2], lse, *laid_out[2:], mask, lse_i, *scales)
+    if kernel == "value":
+        kl = sa._pallas_index_loss(*args, interpret=True)
+        assert kl.shape == (2, seq) and kl.dtype == jnp.float32
+        np.testing.assert_allclose(jnp.sum(kl, axis=1), want_kl, rtol=2e-5)
+        return
+    dqi, dki, dw = sa._pallas_index_loss_grad(*args, interpret=True)
+    for name, got, want in zip(
+            ("qi", "ki", "w"),
+            (jnp.swapaxes(dqi, 2, 3), jnp.swapaxes(dki, 1, 2),
+             jnp.swapaxes(dw, 1, 2)), want_grads):
+        assert got.dtype == jnp.float32 and got.shape == want.shape, name
+        assert float(jnp.abs(got - want).max()) <= \
+            2e-4 * float(jnp.abs(want).max()), name
+
+
+def _pallas_calls(jaxpr, inside=()):
+    """``(kernel name, the primitives it is nested in)`` of every
+    ``pallas_call`` of a jaxpr, sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], inside))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub, inside + (eqn.primitive.name,))
+    return found
+
+
+def test_remat_runs_one_value_and_one_gradient_kernel_a_layer():
+    """``jax.value_and_grad`` of two ``jax.checkpoint`` layers around the
+    op, after dead-code elimination: the value kernel once a layer, in the
+    first pass (nothing of the recomputation reads its ``kl``, so it is not
+    there), and the gradient kernel once a layer, in the backward."""
+    from jax._src.interpreters import partial_eval as pe
+    args = _operands(30, 1, 2, 1, 128, 64, 2, 64)
+
+    @jax.checkpoint
+    def layer(x, q, *rest):
+        out, loss = sa.sparse_gq_attention(q + x, *rest, 16)
+        return jnp.mean(out), jnp.sum(loss)
+
+    def two_layers(*a):
+        x, total = 0.0, 0.0
+        for _ in range(2):
+            x, loss = layer(x, *a)
+            total = total + loss
+        return x + total
+    telemetry.reset()
+    with interpret_kernels():
+        closed = jax.make_jaxpr(jax.value_and_grad(
+            two_layers, argnums=tuple(range(6))))(*args)
+    jaxpr, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
+    calls = _pallas_calls(jaxpr)
+    value = [inside for name, inside in calls
+             if name == "mxtpu_dsa_align_loss"]
+    grad = [inside for name, inside in calls
+            if name == "mxtpu_dsa_align_loss_grad"]
+    assert len(value) == 2 and len(grad) == 2, calls
+    # the recomputation lives in the backward's remat equation
+    assert all("remat2" in inside for inside in grad), grad
+    assert not any("remat2" in inside for inside in value), value
+    # the other forward kernels are what the recomputation does run again
+    assert sum(name == "mxtpu_dsa_attn_fwd" for name, _ in calls) == 4
+    # the counters count traces, not what runs: the value kernel in the
+    # custom VJP's primal and again in its forward rule, the gradient kernel
+    # in the backward rule, once a layer
+    assert telemetry.value("dsa.index_loss.pallas") == \
+        telemetry.value("dsa.layers")
+    assert telemetry.value("dsa.index_loss.grad.pallas") == 2
+    assert telemetry.value("dsa.index_loss.value.pallas") >= 2
 
 
 def test_masked_flash_rows_share_their_batch_mask():
