@@ -70,6 +70,9 @@ SIZES = {
         # attention kernels are held to take `check_rows` of the 32 rows
         dsa=dict(heads=32, kv_heads=4, seq=16384, d=128, index_heads=16,
                  index_dim=64, topk=2048, check_rows=2),
+        # the kimi cell's scan: one sequence of 8192, 32 heads of 128 / 128;
+        # the XLA form it is held to takes `check_heads` of the heads
+        kda=dict(heads=32, seq=8192, d=128, check_heads=2),
         ln_rows=8192, ln_dim=768,
         bucket_elems=25_557_032,            # one ResNet-50 of parameters
         paged=dict(batch=8, heads=32, kv_heads=8, head_dim=128, block=16,
@@ -92,6 +95,7 @@ SIZES = {
         gmm=dict(rows=256, routed=150, groups=4, k=128, n=128),
         dsa=dict(heads=4, kv_heads=2, seq=256, d=64, index_heads=2,
                  index_dim=64, topk=32, check_rows=2),
+        kda=dict(heads=2, seq=128, d=32, check_heads=2),
         ln_rows=32, ln_dim=128,
         bucket_elems=20_000,
         paged=dict(batch=2, heads=4, kv_heads=2, head_dim=64, block=8,
@@ -777,6 +781,17 @@ def _kernels_paged(run):
                 f"(tolerance {tol:g} x scale)")
 
 
+def _clock_ms(exe, *args):
+    """Mean milliseconds of five calls of a compiled program, by the host
+    clock around ``block_until_ready``."""
+    import jax
+    t0 = time.perf_counter()
+    for _ in range(5):
+        out = exe(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) * 200
+
+
 def _kernels_sparse(run):
     """The four kernels of ``ops/sparse_attention.py`` at the keye cell's
     shape, each against its blocked XLA form: the selection (the share of
@@ -805,13 +820,6 @@ def _kernels_sparse(run):
     scale, sm = hi ** -0.5 * di ** -0.5, d ** -0.5
     mode = dict(interpret=run.rehearsal)
 
-    def clock(exe, *args):
-        t0 = time.perf_counter()
-        for _ in range(5):
-            out = exe(*args)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) * 200
-
     # 1. the selection
     swapped = (jnp.swapaxes(qi, 2, 3), jnp.swapaxes(ki, 1, 2),
                jnp.swapaxes(w, 1, 2))
@@ -828,7 +836,7 @@ def _kernels_sparse(run):
     assert_close("dsa tau", tau, want_tau, 1e-2)
     assert_close("dsa lse_i", lse_i, want_lse, 1e-2)
     say(ph, f"select (L,HI,dI,topk)=({seq},{hi},{di},{topk}) "
-            f"compile_s={dt:.2f} ms={clock(exe, *swapped):.3f} "
+            f"compile_s={dt:.2f} ms={_clock_ms(exe, *swapped):.3f} "
             f"kept_share={kept / (seq * (seq + 1) / 2):.4f} "
             f"pairs_that_differ_from_the_xla_form={differ:.2e}")
 
@@ -863,8 +871,8 @@ def _kernels_sparse(run):
              for n, a, b in zip("qkv", grads, want)]
     say(ph, f"attention (rows,L,D)={flat} bf16 blocks="
             f"{blocks['bq']}x{blocks['bk']} compile_s={dt:.2f}+{dt_b:.2f} "
-            f"fwd_ms={clock(fwd, qr, kr, vr, mask):.3f} "
-            f"bwd_ms={clock(bwd, qr, kr, vr, out, lse, do, mask):.3f} "
+            f"fwd_ms={_clock_ms(fwd, qr, kr, vr, mask):.3f} "
+            f"bwd_ms={_clock_ms(bwd, qr, kr, vr, out, lse, do, mask):.3f} "
             f"max_abs_err out,lse,dq,dk,dv vs scans on {rows} rows="
             f"{[float(f'{e:.2e}') for e in errs]} (tolerance 2e-2 x scale)")
 
@@ -892,17 +900,79 @@ def _kernels_sparse(run):
             jnp.swapaxes(dqi, 2, 3), jnp.swapaxes(dki, 1, 2),
             jnp.swapaxes(dw, 1, 2)), want_g)]
     say(ph, f"align_loss compile_s={dt:.2f}+{dt_g:.2f} "
-            f"value_ms={clock(value, *args):.3f} "
-            f"grad_ms={clock(grad, *args):.3f} "
+            f"value_ms={_clock_ms(value, *args):.3f} "
+            f"grad_ms={_clock_ms(grad, *args):.3f} "
             f"mean_kl={float(jnp.sum(kl)) / seq:.4e} "
             f"max_abs_err kl vs the xla form, dqi,dki,dw vs jax.grad of it="
             f"{[float(f'{e:.2e}') for e in errs]}")
+
+
+def _kernels_kda(run):
+    """The scan of ``ops/linear_attention.py`` at the kimi cell's shape, the
+    forward kernel and the backward kernel each alone, against the XLA form
+    of the same chunked mathematics on some of the heads."""
+    import importlib
+    import jax
+    import jax.numpy as jnp
+    la = importlib.import_module("mxnet_tpu.ops.linear_attention")
+    ph = "4 kernels linear_attention"
+    z = run.sizes["kda"]
+    h, seq, d, some = z["heads"], z["seq"], z["d"], z["check_heads"]
+    rng = np.random.RandomState(SEED)
+    size, mode = la.chunk_size(seq), dict(interpret=run.rehearsal)
+
+    beta = 1 / (1 + np.exp(-rng.randn(1, seq, h)))
+    q, k, v = (rng.randn(1, seq, h, d) for _ in range(3))
+    # the configuration's draws: rate uniform on [1, 16] a head, dt
+    # log-uniform on [1e-3, 1e-1] a channel
+    g = -rng.uniform(1, 16, (1, 1, h, 1)) \
+        * np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (1, seq, h, d)))
+
+    def flat(a, dtype=jnp.bfloat16, heads=h):
+        return jnp.asarray(a[:, :, :heads].reshape(1, seq, -1), dtype)
+
+    def operands(heads):
+        return (flat(q, heads=heads), flat(k, heads=heads),
+                flat(v, heads=heads), flat(g, jnp.float32, heads),
+                flat(beta, jnp.float32, heads))
+    zeros = jnp.zeros((1, h, d, d), jnp.float32)
+    do = flat(rng.randn(1, seq, h, d))
+
+    every = operands(h)
+    fwd, dt = _compiled(run, "mxtpu_kda_fwd",
+                        lambda *a: la._pallas_forward(*a, h, size, **mode),
+                        *every)
+    out, states, final = fwd(*every)
+    bwd, dt_b = _compiled(run, "mxtpu_kda_bwd",
+                          lambda *a: la._pallas_backward(*a, size, **mode),
+                          *every, states, do, zeros)
+    grads = bwd(*every, states, do, zeros)
+
+    few = operands(some)
+    want_out, want_states, want_final = jax.jit(
+        lambda *a: la._xla_forward(*a, some, size))(*few)
+    want = jax.jit(lambda *a: la._xla_backward(*a, size))(
+        *few, want_states, do[..., :some * d], zeros[:, :some])
+    errs = [assert_close("kda o", out[..., :some * d], want_out, 2e-2),
+            assert_close("kda final state", final[:, :some], want_final,
+                         2e-2)]
+    errs += [assert_close(f"kda d{n}", a[..., :some * w], b, 3e-2 * float(
+        jnp.max(jnp.abs(b.astype(jnp.float32)))))
+        for n, a, b, w in zip(("q", "k", "v", "g", "beta"), grads, want,
+                              (d, d, d, d, 1))]
+    say(ph, f"scan (L,H,dk,dv,chunk)=({seq},{h},{d},{d},{size}) bf16 "
+            f"compile_s={dt:.2f}+{dt_b:.2f} "
+            f"fwd_ms={_clock_ms(fwd, *every):.3f} "
+            f"bwd_ms={_clock_ms(bwd, *every, states, do, zeros):.3f} "
+            f"max_abs_err o,state,dq,dk,dv,dg,dbeta vs the xla form on "
+            f"{some} heads={[float(f'{e:.2e}') for e in errs]}")
 
 
 def phase_kernels(run):
     _kernels_flash(run)
     _kernels_flash_backward(run)
     _kernels_sparse(run)
+    _kernels_kda(run)
     _kernels_gmm(run)
     _kernels_layernorm(run)
     _kernels_bucket_update(run)

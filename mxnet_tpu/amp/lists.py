@@ -21,6 +21,10 @@ TARGET_DTYPE_OPS = [
     # q . k products; its index weights, the sum over index heads, the
     # threshold and both comparisons stay float32 inside the op
     "sparse_gq_attention",
+    # Kimi Delta Attention's scan: q, k (normalised in float32 inside the op)
+    # and v into the products; the log-decay, beta, every decay factor, the
+    # triangular inverse and the carried state stay float32 inside the op
+    "kda_attention",
 ]
 
 # the reference's fp32 blacklist: softmax family, norms, losses, exp/log/pow
@@ -33,6 +37,8 @@ FP32_OPS = [
     # RMSNorm (the layer's and the latent's) and the router's sigmoid scores,
     # whose top-k must not move with bf16 rounding
     "rms_norm", "moe_router",
+    # Kimi Delta Attention's decay (softplus, exp) and step size (sigmoid)
+    "kda_gate",
 ]
 
 # arguments that keep the dtype they arrive in although their op is listed
@@ -40,7 +46,8 @@ FP32_OPS = [
 # the bfloat16 expert products (the combine sums in float32)
 KEEP_DTYPE_ARGS = {"moe_experts": ("experts", "weights"),
                    "sparse_gq_attention": ("x_index", "w_index",
-                                           "positions")}
+                                           "positions"),
+                   "kda_attention": ("g", "beta")}
 
 WIDEST_TYPE_CASTS = [
     "add_n", "concat", "stack", "where", "broadcast_add", "broadcast_sub",

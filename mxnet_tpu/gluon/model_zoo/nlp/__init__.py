@@ -13,9 +13,10 @@ from .sampler import *  # noqa: F401,F403
 from .llama import *  # noqa: F401,F403
 from .deepseek_v3 import *  # noqa: F401,F403
 from .keye_vl2 import *  # noqa: F401,F403
+from .kimi_linear import *  # noqa: F401,F403
 
 from . import attention, bert, transformer, language_model, sampler, \
-    llama, deepseek_v3, keye_vl2  # noqa
+    llama, deepseek_v3, keye_vl2, kimi_linear  # noqa
 
 _MODELS = {}
 for _m in (bert, transformer, language_model):

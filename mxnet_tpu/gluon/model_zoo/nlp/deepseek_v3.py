@@ -1,16 +1,21 @@
 """DeepSeek-V3-style decoder LM (``model_type: deepseek_v3``): multi-head
 latent attention and a dropless sigmoid-routed expert layer with shared
 experts, built for training through ``DataParallelTrainer``.
+:class:`MLAAttention` and :class:`MoEBlock` have two users: this decoder
+(kanana) and ``kimi_linear``, whose fourth layers take the attention without
+positions.
 
 Per layer, with ``h`` the (B, T, hidden) residual stream:
 
 - ``x = RMSNorm(h)``; **MLA**: ``q = x W_q`` -> heads of ``[q_nope | q_pe]``;
   ``[c | k_pe] = x W_kva`` (latent ``kv_lora_rank`` + one rotary key shared
   by all heads); ``c = RMSNorm(c)``; ``c W_kvb`` -> heads of
-  ``[k_nope | v]``; RoPE on ``q_pe`` and ``k_pe``; causal
+  ``[k_nope | v]``; RoPE on ``q_pe`` and ``k_pe`` unless the configuration
+  says ``mla_use_nope`` (then the ``rope`` dims stay and nothing is rotated:
+  ``nd.mla_attention(use_nope=True)``); causal
   ``softmax(q k^T (nope + rope)^-1/2) v`` -> ``W_o``.  The expanded form:
   no weight absorption, no cache (serving's business).  ``q_lora_rank`` is
-  null in the configuration this was written for, so W_q is one matrix.
+  null in both configurations that use it, so W_q is one matrix.
 - ``h += attn``; ``y = RMSNorm(h)``.  The first ``first_k_dense_replace``
   layers: ``h += SwiGLU(y)`` of width ``intermediate_size``.
 - the others: ``s = sigmoid(y W_g)`` in float32; the ``num_experts_per_tok``
@@ -146,7 +151,8 @@ class MLAAttention(HybridBlock):
             q, kv, k_pe, num_heads=cfg.num_attention_heads,
             qk_nope_head_dim=cfg.qk_nope_head_dim,
             qk_rope_head_dim=cfg.qk_rope_head_dim,
-            v_head_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta)
+            v_head_dim=cfg.v_head_dim, rope_theta=cfg.rope_theta,
+            use_nope=getattr(cfg, "mla_use_nope", False))
         with jax.named_scope("mla.project"):
             return self.o_proj(out)
 

@@ -3411,17 +3411,21 @@ def moe_experts(data, experts, weights, w_gate, w_up, w_down,
 
 @_register
 def mla_attention(q, kv, k_pe, num_heads=1, qk_nope_head_dim=128,
-                  qk_rope_head_dim=64, v_head_dim=128, rope_theta=10000.0):
+                  qk_rope_head_dim=64, v_head_dim=128, rope_theta=10000.0,
+                  use_nope=False):
     """Causal multi-head latent attention in its expanded (training) form.
 
     q: (B, T, H * (nope + rope)), a head ``[q_nope | q_pe]``; kv:
     (B, T, H * (nope + v)), a head ``[k_nope | v]``, the up-projection of
     the normalised latent; k_pe: (B, T, rope), one rotary key shared by all
-    heads.  RoPE (interleaved pairs) on ``q_pe`` and ``k_pe``, then
-    ``softmax(q k^T (nope + rope)^-1/2) v`` through ``flash_attention`` with
-    Q and K at ``nope + rope`` and V at ``v``.  Returns (B, T, H * v)."""
+    heads.  RoPE (interleaved pairs) on ``q_pe`` and ``k_pe`` — none with
+    ``use_nope`` (``mla_use_nope``: the ``rope`` dims stay, no position enters)
+    — then ``softmax(q k^T (nope + rope)^-1/2) v`` through
+    ``flash_attention`` with Q and K at ``nope + rope`` and V at ``v``.
+    Returns (B, T, H * v)."""
     from ..ops.flash_attention import flash_attention
     from ..ops.norm_rope import rope_interleaved as _rot_interleaved
+    from .. import telemetry as _telem
     h, nope, rope, dv = num_heads, qk_nope_head_dim, qk_rope_head_dim, \
         v_head_dim
 
@@ -3430,14 +3434,18 @@ def mla_attention(q, kv, k_pe, num_heads=1, qk_nope_head_dim=128,
         with jax.named_scope("mla.attention"):
             qd = qd.reshape(b, t, h, nope + rope).transpose(0, 2, 1, 3)
             kvd = kvd.reshape(b, t, h, nope + dv).transpose(0, 2, 1, 3)
-            ang = jnp.arange(t, dtype=jnp.float32)[:, None] * \
-                rope_theta ** (-jnp.arange(0, rope, 2,
-                                           dtype=jnp.float32) / rope)
-            cos, sin = jnp.cos(ang), jnp.sin(ang)             # (t, rope/2)
-            q_pe = _rot_interleaved(qd[..., nope:], cos, sin)
-            k_pe = _rot_interleaved(ped[:, None], cos, sin)   # (b, 1, t, r)
-            query = jnp.concatenate(
-                [qd[..., :nope], q_pe.astype(qd.dtype)], axis=-1)
+            if use_nope:
+                _telem.inc("mla.nope")
+                query, k_pe = qd, ped[:, None]
+            else:
+                ang = jnp.arange(t, dtype=jnp.float32)[:, None] * \
+                    rope_theta ** (-jnp.arange(0, rope, 2,
+                                               dtype=jnp.float32) / rope)
+                cos, sin = jnp.cos(ang), jnp.sin(ang)         # (t, rope/2)
+                q_pe = _rot_interleaved(qd[..., nope:], cos, sin)
+                k_pe = _rot_interleaved(ped[:, None], cos, sin)  # (b, 1, t, r)
+                query = jnp.concatenate(
+                    [qd[..., :nope], q_pe.astype(qd.dtype)], axis=-1)
             key = jnp.concatenate(
                 [kvd[..., :nope],
                  jnp.broadcast_to(k_pe.astype(kvd.dtype),
@@ -3500,3 +3508,78 @@ def sparse_gq_attention(q, k, v, q_index, k_index, x_index, w_index,
         fn, [q, k, v, q_index, k_index, x_index, w_index]
         + ([] if positions is None else [positions]), n_out=2,
         name="sparse_gq_attention")
+
+
+# ======================================================================
+# Kimi Delta Attention (gluon.model_zoo.nlp.kimi_linear is built from
+# these; amp/lists.py keeps the decay's path and beta float32)
+# ======================================================================
+
+@_register
+def causal_conv1d(data, weight):
+    """Causal depthwise convolution over time, then SiLU.  data: (B, T, C);
+    weight: (C, K), a weight a channel and tap, no bias: ``y[t] = silu(sum_i
+    w[:, i] x[t - K + 1 + i])`` with zeros before the sequence starts, so
+    token ``t`` sees ``t - K + 1 .. t`` and no later one.  Taken in float32
+    where the weight is (the result's dtype is the promotion of both)."""
+    def fn(x, w):
+        with jax.named_scope("kda.conv"):
+            taps, t = w.shape[1], x.shape[1]
+            dtype = jnp.result_type(x.dtype, w.dtype)
+            # the shifted rows are cut from the input as it arrives and
+            # widened a tap at a time: cut from a float32 copy of a bfloat16
+            # input, the unaligned slices cost twice the bytes (4.4 against
+            # 2.3 ms a call at (8192, 4096), forward and backward)
+            padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+            y = _builtins.sum(
+                padded[:, i:i + t].astype(dtype) * w[:, i].astype(dtype)
+                for i in range(taps))
+            return jax.nn.silu(y)
+    return apply_nary(fn, [data, weight], name="causal_conv1d")
+
+
+@_register
+def kda_gate(f, b, a_log, dt_bias):
+    """Kimi Delta Attention's decay and step size, float32.  f: (B, T, H *
+    dk), the low-rank pair's output; b: (B, T, H); a_log: (H,); dt_bias: (H *
+    dk,).  Returns ``[g, beta]``: ``g = -exp(a_log) softplus(f + dt_bias)``
+    (B, T, H * dk), the log of the decay a channel, a head's ``a_log`` over
+    its ``dk`` channels; ``beta = sigmoid(b)``."""
+    def fn(fd, bd, al, db):
+        with jax.named_scope("kda.gate"):
+            f32 = jnp.float32
+            heads = al.shape[0]
+            rate = jnp.repeat(jnp.exp(al.astype(f32)), fd.shape[-1] // heads)
+            g = -rate * jax.nn.softplus(fd.astype(f32) + db.astype(f32))
+            return g, jax.nn.sigmoid(bd.astype(f32))
+    return apply_nary(fn, [f, b, a_log, dt_bias], n_out=2, name="kda_gate")
+
+
+@_register
+def kda_attention(q, k, v, g, beta, num_heads=1):
+    """Kimi Delta Attention's token mixer: the gated delta rule with a decay
+    a key channel, ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} +
+    beta_t k_t v_t^T``, ``o_t = S_t^T q_t dk^-1/2``, from a zero state at
+    the start of every sequence, as a chunked scan
+    (``ops.linear_attention``: the kernels on the chip, the same tiles under
+    ``lax.scan`` elsewhere).
+
+    q, k, g: (B, T, H * dk) with ``H = num_heads``; v: (B, T, H * dv); beta:
+    (B, T, H); g and beta from ``kda_gate`` (``amp`` leaves both as they
+    arrive: float32; a log-decay under -5.5 a token is taken as -5.5).  Each
+    head of q and of k is divided by its 2-norm first (``x / sqrt(sum x^2 +
+    1e-6)``), in float32 inside the scan's tiles.  Returns (B, T, H * dv)."""
+    from ..ops.linear_attention import kda_attention as _kda
+    from .. import telemetry as _telem
+    h = num_heads
+
+    def fn(qd, kd, vd, gd, bd):
+        b, t = qd.shape[0], qd.shape[1]
+        _telem.inc("kda.layers")
+
+        def heads(a):
+            return a.reshape(b, t, h, -1)
+        with jax.named_scope("kda.scan"):
+            out, _ = _kda(heads(qd), heads(kd), heads(vd), heads(gd), bd)
+            return out.reshape(b, t, -1)
+    return apply_nary(fn, [q, k, v, g, beta], name="kda_attention")

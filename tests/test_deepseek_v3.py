@@ -317,6 +317,42 @@ def test_mla_attention_op_at_the_published_head_dims(kernels):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("nope_switch", [False, True])
+def test_mla_attention_nope_switch_off_gives_what_it_gave(nope_switch):
+    """``nd.mla_attention(use_nope=False)`` is the call it was — the rotated
+    result, and the very program (its jaxpr equals the default call's, so
+    kanana's step traces what it traced) — and ``use_nope=True`` is attention
+    over the same operands with no rotation at all (``kimi_linear``)."""
+    from mxnet_tpu.ndarray import ops as nd_ops
+    rng = np.random.RandomState(4)
+    h, nope, rope, dv, t = 2, 16, 8, 16, 24
+    q = jnp.asarray(rng.randn(1, t, h * (nope + rope)), jnp.float32)
+    kv = jnp.asarray(rng.randn(1, t, h * (nope + dv)), jnp.float32)
+    k_pe = jnp.asarray(rng.randn(1, t, rope), jnp.float32)
+    kw = dict(num_heads=h, qk_nope_head_dim=nope, qk_rope_head_dim=rope,
+              v_head_dim=dv, rope_theta=1e6)
+
+    def call(**more):
+        return lambda *a: nd_ops.mla_attention(
+            *(mx.nd.NDArray(x) for x in a), **kw, **more).data
+    got = call(use_nope=nope_switch)(q, kv, k_pe)
+    q4 = q.reshape(1, t, h, -1).transpose(0, 2, 1, 3)
+    kv4 = kv.reshape(1, t, h, -1).transpose(0, 2, 1, 3)
+    q_pe, key_pe = q4[..., nope:], k_pe[:, None]
+    if not nope_switch:
+        angles = ref.rope_angles(jnp.arange(t), rope, 1e6)
+        q_pe = ref.rope_interleaved(q_pe, angles)
+        key_pe = ref.rope_interleaved(key_pe, angles)
+        assert str(jax.make_jaxpr(call())(q, kv, k_pe)) == \
+            str(jax.make_jaxpr(call(use_nope=False))(q, kv, k_pe))
+    want = ref.causal_attention(
+        jnp.concatenate([q4[..., :nope], q_pe], -1),
+        jnp.concatenate([kv4[..., :nope],
+                         jnp.broadcast_to(key_pe, (1, h, t, rope))], -1),
+        kv4[..., nope:]).transpose(0, 2, 1, 3).reshape(1, t, h * dv)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # counters, the constructor, remat, the benchmark's copy of the reference
 # ---------------------------------------------------------------------------

@@ -139,3 +139,55 @@ def test_sparse_attention_kernels_at_the_keye_shape(one_chip, part):
         assert re.search(name + r"\b", text), name
     assert text.count("tpu_custom_call") == len(names)
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("seq,d,dv,causal", [(8192, 192, 128, True)],
+                         ids=["nope-mla-s8192"])
+def test_flash_kernels_at_the_kimi_mla_shape(one_chip, seq, d, dv, causal):
+    """The kimi cell's one latent-attention layer: one sequence of 8192, 32
+    heads at 192 / 128 — the backward kernel still holds a row's float32 dQ
+    in VMEM there, so no scan is left."""
+    from mxnet_tpu.ops import flash_attention
+
+    def shape(d):
+        return jax.ShapeDtypeStruct((1, 32, seq, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a, causal=causal).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+    text = _compile(grads, shape(d), shape(d), shape(dv))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(re.search(r'op_name="[^"]*(mxtpu_flash_\w+)', ln).group(1)
+                  for ln in calls) == ["mxtpu_flash_bwd", "mxtpu_flash_fwd"]
+    assert " while(" not in text
+
+
+def test_scan_kernels_at_the_kimi_shape(one_chip):
+    """The kimi cell's chunked scan: one sequence of 8192, 32 heads of 128 /
+    128, chunks of 64, bfloat16 operands with the log-decay and beta
+    float32, q and k normalised inside the tiles as the step has them (the
+    op has no other variant) — the forward kernel and the backward kernel,
+    one Mosaic call each, by name (neither name carries ``mxtpu_flash`` or ``mxtpu_gmm``),
+    and no XLA scan left."""
+    from mxnet_tpu.ops.linear_attention import kda_attention
+    b, t, h, d = 1, 8192, 32, 128
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def grads(q, k, v, g, beta):
+        return jax.grad(lambda *a: kda_attention(*a)[0].astype(
+            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    heads = shape((b, t, h, d))
+    text = _compile(grads, heads, heads, heads,
+                    shape((b, t, h, d), jnp.float32),
+                    shape((b, t, h), jnp.float32))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = sorted(re.search(r'op_name="[^"]*(mxtpu_\w+)', ln).group(1)
+                   for ln in calls)
+    assert names == ["mxtpu_kda_bwd", "mxtpu_kda_fwd"]
+    assert not re.search(r"mxtpu_(flash|gmm)", text)
+    assert " while(" not in text
