@@ -65,6 +65,11 @@ SIZES = {
         # the kanana cell's expert products: a worst-case buffer of which an
         # eighth is routed, 16 experts held
         gmm=dict(rows=49152, routed=6144, groups=16, k=2048, n=768),
+        # the expert layer around them at that cell's shape (8192 tokens
+        # choose 6 of 128 experts, 16 held: a worst case of 49152 rows in
+        # buffers of 12288) at fills of 1/32, 1/8 and over 1/4 of it
+        moe=dict(tokens=8192, top_k=6, held=16, experts=128, d=2048, h=768,
+                 routed=(1536, 6144, 14746)),
         # the keye cell's sparse attention: one sequence of 16384, 32 / 4
         # heads of 128, 16 index heads of 64, top-2048; the scans that the
         # attention kernels are held to take `check_rows` of the 32 rows
@@ -93,6 +98,8 @@ SIZES = {
         flash_bwd_shapes=((2, 128, 64, 64, False),
                           (2, 128, 192, 128, True)),
         gmm=dict(rows=256, routed=150, groups=4, k=128, n=128),
+        moe=dict(tokens=128, top_k=2, held=2, experts=8, d=128, h=128,
+                 routed=(8, 100, 200)),
         dsa=dict(heads=4, kv_heads=2, seq=256, d=64, index_heads=2,
                  index_dim=64, topk=32, check_rows=2),
         kda=dict(heads=2, seq=128, d=32, check_heads=2),
@@ -631,6 +638,74 @@ def _kernels_gmm(run):
             f"(tolerance 2e-2 x scale)")
 
 
+def _expert_layer_buffers(run):
+    """``dropless_moe_apply`` at three fills of its worst case — one buffer
+    of a quarter of it twice, then two, counted on the device — against one
+    buffer of the worst case on the same operands: output and the five
+    gradients.  The same products (a buffer's size changes no tile of the
+    kernels), sums in another order."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel import moe
+    ph = "4 kernels expert layer"
+    z = run.sizes["moe"]
+    tokens, k, held, d, h = (z[n] for n in ("tokens", "top_k", "held", "d",
+                                            "h"))
+    rng = np.random.RandomState(SEED)
+    x, g = (jnp.asarray(rng.randn(tokens, d), jnp.bfloat16) for _ in range(2))
+    weights = jnp.asarray(rng.rand(tokens, k) + 0.1, jnp.float32)
+    stacks = [jnp.asarray(rng.randn(held, a, b) * a ** -.5, jnp.bfloat16)
+              for a, b in ((d, h), (d, h), (h, d))]
+    full = moe.buffer_rows(tokens, k, held)
+
+    def both(layer):
+        def loss(x, weights, *stacks, experts):
+            out = layer(x, weights, *stacks, experts=experts)
+            return jnp.sum(out.astype(jnp.float32)
+                           * g.astype(jnp.float32)), out
+        return lambda experts, *floats: jax.value_and_grad(
+            loss, argnums=tuple(range(5)), has_aux=True)(
+                *floats, experts=experts)
+
+    def layer(x, weights, *stacks, experts):
+        return moe.dropless_moe_apply(x, experts, weights, *stacks)
+
+    def worst_case(x, weights, *stacks, experts):
+        return moe._experts_at(full, 0, x, weights, *stacks,
+                               moe._make_plan(experts, held, 0)
+                               ).astype(x.dtype)
+
+    t, j = np.meshgrid(np.arange(tokens), np.arange(k), indexing="ij")
+    spread = rng.randint(0, z["experts"] - held, size=(tokens, 1))
+    absent = held + (spread + j) % (z["experts"] - held)
+    exe = top = None
+    for routed in z["routed"]:
+        # `routed` of the choices go to held experts, the first of each
+        # token before the second of any
+        rank = j * tokens + rng.permutation(tokens)[:, None]
+        experts = jnp.asarray(np.where(rank < routed, (t + j) % held, absent),
+                              jnp.int32)
+        floats = (experts, x, weights, *stacks)
+        if exe is None:
+            exe, dt = _compiled(run, "mxtpu_gmm", both(layer), *floats)
+            top = jax.jit(both(worst_case))
+        (_, out), grads = exe(*floats)
+        (_, want), want_grads = top(*floats)
+        errs = [assert_close("expert layer out", out, want, 2e-2)]
+        errs += [assert_close(f"expert layer d{n}", a, b, 2e-2)
+                 for n, a, b in zip(("x", "weights", "gate", "up", "down"),
+                                    grads, want_grads)]
+        say(ph, f"(tokens,top_k,held,d,h)=({tokens},{k},{held},{d},{h}) "
+                f"bf16 routed={routed} of {full} through "
+                f"{moe.rung_rows(routed, tokens, k, held)} rows in buffers "
+                f"of {moe.window_rows(tokens, k, held)} compile_s={dt:.2f} "
+                f"fwd+bwd_ms={_clock_ms(exe, *floats):.3f} "
+                f"worst_case_fwd+bwd_ms={_clock_ms(top, *floats):.3f} "
+                f"max_abs_err out,dx,dweights,dgate,dup,ddown vs one buffer "
+                f"of the worst case={[float(f'{e:.2e}') for e in errs]} "
+                f"(tolerance 2e-2 x scale)")
+
+
 def _kernels_layernorm(run):
     import jax
     import jax.numpy as jnp
@@ -974,6 +1049,7 @@ def phase_kernels(run):
     _kernels_sparse(run)
     _kernels_kda(run)
     _kernels_gmm(run)
+    _expert_layer_buffers(run)
     _kernels_layernorm(run)
     _kernels_bucket_update(run)
     _kernels_paged(run)

@@ -16,15 +16,40 @@
    told which ``held`` of the ``n_routed_experts`` live here
    (``expert_offset``), routes over all of them, and computes its own
    experts' part of the result; assignments to absent experts are left out.
-   No capacity, no drop: assignments are sorted by expert, the rows of held
-   experts gathered into a buffer that takes the worst case
-   (``tokens * min(top_k, held)`` rows), and the expert products run over
-   ``group_sizes`` (``ops.grouped_matmul``), doing work for the rows that
-   are routed, not for the buffer.  On one chip it runs without its
-   exchange; nothing here stands in for absent chips.  The Gluon block
-   around it is ``gluon.model_zoo.nlp.deepseek_v3.MoEBlock``.
+   No capacity, no drop: assignments are sorted by expert and the rows of
+   held experts gathered into a buffer for the expert products over
+   ``group_sizes`` (``ops.grouped_matmul``), which do work for the rows
+   that are routed.  Everything around the products is XLA over static
+   shapes and pays for the buffer, so the buffer follows the routed count:
+   the worst case (``tokens * min(top_k, held)`` rows, ``buffer_rows``) is
+   4 to 32 times what a step routes, and a buffer has a quarter of its
+   rows.  The sort and the index plan are made once, ``T * k`` scalars;
+   the gather, the three products, the activation and the weighted sum
+   back to tokens are one function of a buffer's rows, and a loop whose
+   trip count the device reads from the routed count runs it over as many
+   buffers (``window_rows``) as the rows need (``rung_rows``) — one in
+   most steps, four at the worst case, float32 sums between them: no
+   routing is dropped or clipped at any fill, and no array of the layer
+   has the worst case's rows.  The token-side sums are taken in the row
+   domain too (a gather into token order, a sum over neighbours, a gather
+   of T), so a buffer's traffic is about ``5 rows + 2 T`` rows of d where
+   the worst-case form moved ``7 R``.
+   The loops are not differentiated through (their trip count is data):
+   the layer is one custom VJP that keeps its operands alone, with a loop
+   of its own in each rule; the backward rule's loop computes each buffer
+   again and transposes that.  Under a decoder's per-layer ``remat`` this
+   is what ran before (12 grouped products a layer and buffer: the
+   recomputed forward loop has no reader and is dropped); a model without
+   ``remat`` pays the three forward products a second time and holds none
+   of the layer's buffers between the passes.
+   On one chip it runs without its exchange; nothing here stands in for
+   absent chips.  The Gluon block around it is
+   ``gluon.model_zoo.nlp.deepseek_v3.MoEBlock``.
 """
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +61,7 @@ from ..base import MXNetError
 
 __all__ = ["moe_apply", "MoEDense", "load_balance_loss",
            "route_sigmoid_top_k", "route_softmax_top_k",
-           "dropless_moe_apply", "buffer_rows"]
+           "dropless_moe_apply", "buffer_rows", "window_rows", "rung_rows"]
 
 
 def _top1_dispatch(logits, capacity):
@@ -181,95 +206,267 @@ def route_softmax_top_k(x, router_w, top_k, norm_topk_prob=True):
 
 
 def buffer_rows(tokens, top_k, held):
-    """Rows of the dispatch buffer: the worst case, every token sending all
-    it can (it chooses ``top_k`` distinct experts) to experts held here."""
+    """Rows of the dispatch buffer's worst case, every token sending all it
+    can (it chooses ``top_k`` distinct experts) to experts held here."""
     return tokens * min(top_k, held)
 
 
-# The plan of one layer's dispatch, a tuple of index arrays (no gradient):
-#   choice_of_row (R,)   the flat choice t * k + j that sits in each row
-#   row_is_routed (R,)   False for the rows past the last routed one
-#   row_of_choice (T, k) where each choice landed (0 where not held)
-#   choice_is_held (T, k)
+_TILE = 128                     # rows of the grouped product's tile
+_PART = 4                       # buffers to the worst case
+
+
+def window_rows(tokens, top_k, held):
+    """Rows of one buffer: a quarter of the worst case, in whole tiles of
+    the grouped product (the worst case itself where that is smaller)."""
+    full = buffer_rows(tokens, top_k, held)
+    return min(full, -(-full // (_PART * _TILE)) * _TILE)
+
+
+def rung_rows(routed, tokens, top_k, held):
+    """The buffer rows a step computes over when ``routed`` rows go to held
+    experts: whole buffers of :func:`window_rows`, as many as take them all
+    (no count needs more than the worst case and a buffer's rounding)."""
+    rows = window_rows(tokens, top_k, held)
+    return -(-routed // rows) * rows
+
+
+class _Plan(NamedTuple):
+    """One layer's routing as index arrays (no gradient), made once outside
+    the loop over buffers: ``T * k`` scalars each."""
+    choice_of_row: jax.Array    # (T * k,) the flat choice t * k + j in each
+    #                             row: sorted by expert, then token
+    row_of_choice: jax.Array    # (T, k) where each choice landed
+    choice_is_held: jax.Array   # (T, k)
+    group_sizes: jax.Array      # (held,) rows of each held expert
+    routed: jax.Array           # () their sum
+
+
+class _Window(NamedTuple):
+    """``rows`` consecutive rows of the sorted choices, from ``start``: what
+    one pass through the experts works on.  Rows are in sorted order; slots
+    hold the same choices in token order."""
+    choice_of_row: jax.Array    # (rows,)
+    row_is_routed: jax.Array    # (rows,)
+    choice_of_slot: jax.Array   # (rows,)
+    row_of_slot: jax.Array      # (rows,) the permutation between the two
+    last_slot: jax.Array        # (T,) of each token's last choice in here
+    token_is_here: jax.Array    # (T,) whether it has one
+    row_of_choice: jax.Array    # (T, k) counted from ``start``
+    choice_is_here: jax.Array   # (T, k)
+    group_sizes: jax.Array      # (held,) the rows of each expert in here
+
+    @property
+    def top_k(self):
+        return self.row_of_choice.shape[1]
+
+
+def _window(plan, rows, start):
+    tokens, k = plan.row_of_choice.shape
+    choices = jnp.arange(tokens * k, dtype=jnp.int32)
+    row_of_choice = plan.row_of_choice - start
+    here = plan.choice_is_held & (row_of_choice >= 0) & (row_of_choice < rows)
+    row_of_choice = jnp.where(here, row_of_choice, 0)
+    # the choices in here are in token order already: a running count packs
+    # them to the front (the others behind them, so that the packing is a
+    # permutation)
+    slots = jnp.cumsum(here.reshape(-1), dtype=jnp.int32)
+    choice_of_slot = jnp.zeros(tokens * k, jnp.int32).at[
+        jnp.where(here.reshape(-1), slots - 1, slots[-1] + choices - slots)
+    ].set(choices, unique_indices=True)[:rows]
+    ends = jnp.cumsum(plan.group_sizes)
+    return _Window(
+        # (the last window may reach past the last choice, and dynamic_slice
+        # would move it back)
+        lax.dynamic_slice(jnp.pad(plan.choice_of_row, (0, rows)),
+                          (start,), (rows,)),
+        start + jnp.arange(rows, dtype=jnp.int32) < plan.routed,
+        choice_of_slot, row_of_choice.reshape(-1)[choice_of_slot],
+        jnp.maximum(slots.reshape(tokens, k)[:, -1] - 1, 0),
+        jnp.any(here, axis=1), row_of_choice, here,
+        jnp.clip(ends, start, start + rows)
+        - jnp.clip(ends - plan.group_sizes, start, start + rows))
+
+
+def _gather_rows(v, window):
+    """``rows[r] = v[token of the choice in row r]`` for the routed rows,
+    zeros past them."""
+    return jnp.where(window.row_is_routed[:, None],
+                     v[window.choice_of_row // window.top_k], 0)
+
+
+def _sum_rows(rows, window, scale=None):
+    """``out[t] = sum of scale[slot] * rows[row of the slot]`` over the
+    slots of token ``t``, float32; zeros for a token with no choice in the
+    window.  One gather brings the rows into token order, where a token's
+    rows are neighbours; each slot then adds the up to ``k - 1`` slots
+    before it that carry its token (shifted slices, one fusion), and the
+    last slot of each token's run is the token's sum: a gather of T."""
+    k, n = window.top_k, rows.shape[0]
+    # k - 1 slots of no token in front, by way of the indices, so that every
+    # shift below is a slice of what the gather wrote.  A slot in use looks
+    # back at slots in use only and no other is read at the end, so neither
+    # needs a mask.
+    front = jnp.zeros(k - 1, jnp.int32)
+    token = jnp.concatenate([front - 1, window.choice_of_slot // k])
+    ordered = rows[jnp.concatenate([front, window.row_of_slot])]
+    if scale is not None:
+        scale = jnp.concatenate([front.astype(scale.dtype), scale])
+
+    def shifted(s):
+        term = ordered[s:s + n].astype(jnp.float32)
+        if scale is not None:
+            term = term * scale[s:s + n, None]
+        return jnp.where((token[s:s + n] == token[k - 1:])[:, None], term, 0)
+
+    runs = sum(shifted(s) for s in range(k))
+    return jnp.where(window.token_is_here[:, None], runs[window.last_slot], 0)
+
 
 @jax.custom_vjp
-def _dispatch(x, plan):
-    """``buffer[r] = x[token of the choice in row r]`` for the routed rows,
-    zeros past them.  Its transpose is written as a gather too (a row of
-    the buffer belongs to exactly one choice), which the scatter-add that
-    autodiff would derive is not."""
-    choice_of_row, row_is_routed, row_of_choice, _ = plan
-    k = row_of_choice.shape[1]
-    return jnp.where(row_is_routed[:, None], x[choice_of_row // k], 0)
+def _dispatch(x, window):
+    """:func:`_gather_rows`; its transpose is :func:`_sum_rows` (a row
+    belongs to exactly one choice of one token), written as the backward
+    rule: the scatter-add of rows of d that autodiff derives from a gather
+    is not."""
+    return _gather_rows(x, window)
 
 
-def _dispatch_fwd(x, plan):
-    return _dispatch(x, plan), plan
-
-
-def _dispatch_bwd(plan, g):
-    _, _, row_of_choice, choice_is_held = plan
-    dx = jnp.sum(jnp.where(choice_is_held[..., None],
-                           g[row_of_choice].astype(jnp.float32), 0), axis=1)
-    return dx.astype(g.dtype), None
-
-
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_dispatch.defvjp(lambda x, window: (_gather_rows(x, window), window),
+                 lambda window, g: (_sum_rows(g, window).astype(g.dtype),
+                                    None))
 
 
 @jax.custom_vjp
-def _combine(buffer, weights, plan):
+def _combine(buffer, weights, window):
     """``out[t] = sum_j weights[t, j] * buffer[row_of_choice[t, j]]`` over
-    the choices held here, summed in float32."""
-    _, _, row_of_choice, choice_is_held = plan
-    picked = buffer[row_of_choice].astype(jnp.float32)     # (T, k, d)
-    w = jnp.where(choice_is_held, weights.astype(jnp.float32), 0)
-    return jnp.sum(picked * w[..., None], axis=1).astype(buffer.dtype)
+    the choices in the window, float32."""
+    scale = weights.reshape(-1)[window.choice_of_slot].astype(jnp.float32)
+    return _sum_rows(buffer, window, scale)
 
 
-def _combine_fwd(buffer, weights, plan):
-    return _combine(buffer, weights, plan), (buffer, weights, plan)
+def _combine_fwd(buffer, weights, window):
+    return _combine(buffer, weights, window), (buffer, weights, window)
 
 
 def _combine_bwd(res, g):
-    buffer, weights, plan = res
-    choice_of_row, row_is_routed, row_of_choice, choice_is_held = plan
-    k = weights.shape[1]
-    w_of_row = jnp.where(row_is_routed,
-                         weights.reshape(-1)[choice_of_row], 0)
-    dbuffer = (g[choice_of_row // k].astype(jnp.float32)
-               * w_of_row.astype(jnp.float32)[:, None]).astype(buffer.dtype)
-    dweights = jnp.where(
-        choice_is_held,
-        jnp.sum(buffer[row_of_choice].astype(jnp.float32)
-                * g.astype(jnp.float32)[:, None, :], axis=-1), 0)
+    buffer, weights, window = res
+    # (g is the float32 copy of a cotangent that arrived in the buffer's
+    # dtype: gathering it there moves half the bytes and loses nothing)
+    g_of_row = _gather_rows(g.astype(buffer.dtype), window
+                            ).astype(jnp.float32)
+    scale = weights.reshape(-1)[window.choice_of_row].astype(jnp.float32)
+    dbuffer = (g_of_row * scale[:, None]).astype(buffer.dtype)
+    # a row-side reduce, and scalars back to (T, k)
+    dscale = jnp.sum(buffer.astype(jnp.float32) * g_of_row, axis=-1)
+    dweights = jnp.where(window.choice_is_here,
+                         dscale[window.row_of_choice], 0)
     return dbuffer, dweights.astype(weights.dtype), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def dropless_moe_apply(x, experts, weights, w_gate, w_up, w_down, *,
-                       expert_offset=0):
-    """The routed experts' part of a SwiGLU expert layer, for the experts
-    held here.
-
-    x: (T, d); experts: (T, k) int32 ids over all routed experts; weights:
-    (T, k); w_gate, w_up: (held, d, h); w_down: (held, h, d), the weights of
-    experts ``expert_offset .. expert_offset + held``.  Returns (T, d):
-    ``sum_{j: experts[t, j] held} weights[t, j] * E_j(x[t])``.  A choice of
-    an absent expert adds nothing; no choice of a held expert is dropped.
-    """
+def _experts_at(rows, start, x, weights, w_gate, w_up, w_down, plan):
+    """The routed experts over ``rows`` rows (static) of the sorted choices
+    from ``start``: every array in here that is d or h wide has ``rows``
+    rows or T.  (T, d) float32, the window's part of every token's sum."""
     from ..ops.grouped_matmul import grouped_matmul
-    tokens, k = experts.shape
-    held = w_gate.shape[0]
-    rows = buffer_rows(tokens, k, held)
-    _telem.inc("moe.layers")
-    _telem.set_gauge("moe.experts_held", held)
-    _telem.set_gauge("moe.rows_buffer", rows)
-    _telem.set_gauge("moe.top_k", k)
+    with jax.named_scope(f"moe.rung{rows}"):
+        window = _window(plan, rows, start)
+        with jax.named_scope("moe.dispatch"):
+            buffer = _dispatch(x, window)
+        with jax.named_scope("moe.experts"):
+            gate = grouped_matmul(buffer, w_gate, window.group_sizes)
+            up = grouped_matmul(buffer, w_up, window.group_sizes)
+            out = grouped_matmul(jax.nn.silu(gate) * up, w_down,
+                                 window.group_sizes)
+        with jax.named_scope("moe.combine"):
+            return _combine(out, weights, window)
 
-    with jax.named_scope("moe.dispatch"):
+
+def _over_windows(plan, rows, body, sums):
+    """``sums`` plus ``body(start)`` for every buffer of ``rows`` rows that
+    holds routed rows: a loop whose trip count the device reads.  Each sum
+    is taken in float32 and kept in the dtype it came in."""
+    def add(total, part):
+        return (total.astype(jnp.float32) + part.astype(jnp.float32)
+                ).astype(total.dtype)
+
+    return lax.fori_loop(
+        0, -(-plan.routed // rows),
+        lambda i, sums: jax.tree.map(add, sums, body(i * rows)), sums)
+
+
+# Both directions are jitted so that a model's layers share one trace (a
+# ``pallas_call`` traces its kernel anew every time it is called).
+# ``kernels`` is the cache's key for what ``ops.kernel_mode`` said when the
+# trace was made.
+
+@functools.partial(jax.jit, static_argnames="kernels")
+def _forward(x, weights, w_gate, w_up, w_down, plan, *, kernels):
+    rows = window_rows(*weights.shape, w_gate.shape[0])
+    return _over_windows(
+        plan, rows,
+        lambda start: _experts_at(rows, start, x, weights, w_gate, w_up,
+                                  w_down, plan),
+        jnp.zeros(x.shape, jnp.float32)).astype(x.dtype)
+
+
+@functools.partial(jax.jit, static_argnames="kernels")
+def _backward(g, *operands, kernels):
+    *floats, plan = operands
+    x, weights, *stacks = floats
+    rows = window_rows(*weights.shape, stacks[0].shape[0])
+
+    def body(start):
+        _, vjp = jax.vjp(lambda *floats: _experts_at(
+            rows, start, *floats, plan), *floats)
+        return list(vjp(g.astype(jnp.float32)))
+
+    # The token-side sums run in float32 from buffer to buffer.  The three
+    # weight gradients stay in their own dtype: an expert's rows are
+    # neighbours, so all of them but the one a buffer's edge cuts get their
+    # whole gradient from one buffer (which adds to zeros, exactly), a
+    # float32 copy of the three would be the layer's largest arrays (0.45 GB
+    # of the kanana step), and a step whose rows fit one buffer — every
+    # step of the benchmark's kimi and kanana cells — has no such expert.
+    sums = _over_windows(
+        plan, rows, body,
+        [jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(weights)]
+        + [jnp.zeros_like(w) for w in stacks])
+    return tuple(d.astype(f.dtype) for d, f in zip(sums, floats))
+
+
+@jax.custom_vjp
+def _routed_experts(x, weights, w_gate, w_up, w_down, plan):
+    """The sum of :func:`_experts_at` over the buffers the routed count
+    needs, one loop a direction.  Neither loop is differentiated (its trip
+    count is data): the forward rule keeps the operands alone, and the
+    backward rule's loop computes each buffer again and applies its VJP,
+    with float32 sums between buffers in both."""
+    from ..ops.kernel_mode import kernel_mode
+    return _forward(x, weights, w_gate, w_up, w_down, plan,
+                    kernels=kernel_mode())
+
+
+def _routed_experts_fwd(*operands):
+    return _routed_experts(*operands), operands
+
+
+def _routed_experts_bwd(operands, g):
+    from ..ops.kernel_mode import kernel_mode
+    return (*_backward(g, *operands, kernels=kernel_mode()), None)
+
+
+_routed_experts.defvjp(_routed_experts_fwd, _routed_experts_bwd)
+
+
+def _make_plan(experts, held, expert_offset):
+    """The :class:`_Plan` of a layer that holds experts ``expert_offset ..
+    expert_offset + held``: one sort of the ``T * k`` choices by expert and
+    its inverse."""
+    tokens, k = experts.shape
+    with jax.named_scope("moe.plan"):
         local = experts - expert_offset
         choice_is_held = (local >= 0) & (local < held)          # (T, k)
         # absent experts sort behind every held one; the sort is stable, so
@@ -283,14 +480,40 @@ def dropless_moe_apply(x, experts, weights, w_gate, w_up, w_down, *,
         row_of_choice = jnp.zeros(tokens * k, jnp.int32).at[order].set(
             jnp.arange(tokens * k, dtype=jnp.int32),
             unique_indices=True).reshape(tokens, k)
-        row_of_choice = jnp.where(choice_is_held, row_of_choice, 0)
-        row_is_routed = jnp.arange(rows, dtype=jnp.int32) < \
-            jnp.sum(group_sizes)
-        plan = (order[:rows], row_is_routed, row_of_choice, choice_is_held)
-        buffer = _dispatch(x, plan)
-    with jax.named_scope("moe.experts"):
-        gate = grouped_matmul(buffer, w_gate, group_sizes)
-        up = grouped_matmul(buffer, w_up, group_sizes)
-        out = grouped_matmul(jax.nn.silu(gate) * up, w_down, group_sizes)
-    with jax.named_scope("moe.combine"):
-        return _combine(out, weights, plan)
+        return _Plan(order, row_of_choice, choice_is_held, group_sizes,
+                     jnp.sum(group_sizes))
+
+
+def dropless_moe_apply(x, experts, weights, w_gate, w_up, w_down, *,
+                       expert_offset=0):
+    """The routed experts' part of a SwiGLU expert layer, for the experts
+    held here.
+
+    x: (T, d); experts: (T, k) int32 ids over all routed experts; weights:
+    (T, k); w_gate, w_up: (held, d, h); w_down: (held, h, d), the weights of
+    experts ``expert_offset .. expert_offset + held``.  Returns (T, d):
+    ``sum_{j: experts[t, j] held} weights[t, j] * E_j(x[t])``.  A choice of
+    an absent expert adds nothing; no choice of a held expert is dropped.
+
+    The sort and the index plan are made once; the gather, the three
+    grouped products, the activation and the sum back to tokens run through
+    buffers of a quarter of the worst case's rows, as many as the rows
+    routed in this step need (:func:`rung_rows`; counted on the device, no
+    host sync, and at the worst case every choice of every token still
+    fits).  The layer is one custom VJP that keeps its operands and nothing
+    else, and its backward rule computes each buffer's forward again before
+    transposing it.  Under a decoder's per-layer ``remat`` that is what
+    happened anyway (the recomputed forward pass now has no reader and the
+    compiler drops it: 12 grouped products a layer, as before); a model
+    without ``remat`` runs the three forward products a second time in its
+    backward pass and holds none of the layer's buffers in between.
+    """
+    tokens, k = experts.shape
+    held = w_gate.shape[0]
+    _telem.inc("moe.layers")
+    _telem.set_gauge("moe.experts_held", held)
+    _telem.set_gauge("moe.rows_buffer", buffer_rows(tokens, k, held))
+    _telem.set_gauge("moe.rows_ladder", window_rows(tokens, k, held))
+    _telem.set_gauge("moe.top_k", k)
+    return _routed_experts(x, weights, w_gate, w_up, w_down,
+                           _make_plan(experts, held, expert_offset))
