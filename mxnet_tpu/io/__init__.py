@@ -14,6 +14,7 @@ import numpy as _np
 
 from ..base import MXNetError
 from ..ndarray.ndarray import NDArray, array, concatenate
+from ..telemetry import tracing as _tracing
 from .prefetch import (DevicePrefetcher, AsyncDecodeIter, PipelineStats,
                        default_prefetch_depth)
 
@@ -410,7 +411,13 @@ class ImageRecordIter(DataIter):
     """Images from a .rec file with decode + augment + batch.
 
     Reference: native ImageRecordIter (src/io/iter_image_recordio_2.cc).
-    Pure-Python path here; the C++ pipeline in src/ accelerates decode."""
+    Pure-Python path here; the C++ pipeline in src/ accelerates decode.
+
+    Each ``next()`` leaves three spans under whatever is ambient (under a
+    ``DevicePrefetcher`` its ``io.decode``): ``io.rec.fetch`` (blocked on
+    the decode pool), ``io.rec.augment`` (the host's passes over the
+    decoded batch) and ``io.rec.stage`` (``array(...)``); ``reset()``
+    leaves ``io.epoch``."""
 
     def __init__(self, path_imgrec, data_shape, batch_size, label_width=1,
                  shuffle=False, rand_crop=False, rand_mirror=False,
@@ -428,6 +435,7 @@ class ImageRecordIter(DataIter):
         self._std = _np.array([std_r, std_g, std_b]).reshape(3, 1, 1)
         self._order = _np.arange(len(self._dataset))
         self._pos = 0
+        self._epoch = 0            # resets so far: io.epoch's number
         self._shuffle_seeds = []   # per-epoch reshuffle seeds (replayable)
         self._path_imgrec = path_imgrec
         self._n_threads = preprocess_threads
@@ -437,6 +445,7 @@ class ImageRecordIter(DataIter):
         c, h, w = self._data_shape
         self._use_native = (_native.available() and c == 3 and h == w)
         self._native_iter = None
+        self._pool_stats = {}     # NativePrefetcher.stats() at last fetch
         self._async_iter = None   # pure-Python threaded decode fan-out
         self.reset()
 
@@ -449,6 +458,12 @@ class ImageRecordIter(DataIter):
         return [DataDesc("softmax_label", (self.batch_size,))]
 
     def reset(self):
+        with _tracing.span("io.epoch", epoch=self._epoch,
+                           records=len(self._dataset)):
+            self._epoch += 1
+            self._reset()
+
+    def _reset(self):
         self._pos = 0
         if self._shuffle:
             # same standalone-restorable scheme as NDArrayIter: ONE
@@ -532,17 +547,39 @@ class ImageRecordIter(DataIter):
             self._async_iter = None
 
     def _next_native(self):
-        batch, labels = next(self._native_iter)  # raises StopIteration at end
+        with _tracing.span("io.rec.fetch", native=True) as sp:
+            # raises StopIteration at end
+            batch, labels = next(self._native_iter)
+            if _tracing.enabled():
+                # what the pool's threads did since the last fetch
+                now = self._native_iter.stats()
+                _tracing.annotate(sp, **{
+                    k: now[k] - self._pool_stats.get(k, 0)
+                    for k in ("decoded", "busy_ns", "full_ns")})
+                self._pool_stats = now
         if len(batch) < self.batch_size:
             raise StopIteration
-        img = batch.astype("float32").transpose(0, 3, 1, 2)  # NHWC->NCHW
-        if self._rand_mirror:
-            flip = _np.random.rand(len(img)) < 0.5
-            img[flip] = img[flip][..., ::-1]
-        img = (img - self._mean[None]) / self._std[None]
+        with _tracing.span("io.rec.augment") as sp:
+            img = batch.astype("float32").transpose(0, 3, 1, 2)  # ->NCHW
+            if self._rand_mirror:
+                flip = _np.random.rand(len(img)) < 0.5
+                img[flip] = img[flip][..., ::-1]
+            img = (img - self._mean[None]) / self._std[None]
+            lab = labels[:, 0] if self._label_width == 1 else labels
+            _tracing.annotate(sp, dtype=str(img.dtype), bytes=img.nbytes)
         self._pos += self.batch_size
-        lab = labels[:, 0] if self._label_width == 1 else labels
-        return DataBatch(data=[array(img)], label=[array(lab)], pad=0)
+        return self._stage(img, lab)
+
+    def _stage(self, img, lab):
+        """The host batch as the ``DataBatch`` handed on, under
+        ``io.rec.stage``: ``array()`` casts to its default dtype and puts
+        the result where the current context says."""
+        with _tracing.span("io.rec.stage") as sp:
+            data, label = array(img), array(lab)
+            _tracing.annotate(
+                sp, bytes=data.data.nbytes + label.data.nbytes,
+                dtype=str(data.dtype), device=data.context.device_type)
+        return DataBatch(data=[data], label=[label], pad=0)
 
     def _decode_sample(self, ds_idx):
         """Decode + preprocess ONE record (thread-safe: recordio readers
@@ -570,16 +607,20 @@ class ImageRecordIter(DataIter):
         if self._use_native:
             return self._next_native()
         if self._async_iter is not None:
-            samples = next(self._async_iter)   # in-order batch
+            samples = next(self._async_iter)   # in-order; io.rec.fetch
         else:
-            samples = [self._decode_sample(self._order[i])
-                       for i in range(self._pos,
-                                      self._pos + self.batch_size)]
-        datas = [img for img, _ in samples]
-        labels = [lab for _, lab in samples]
+            with _tracing.span("io.rec.fetch", native=False):
+                samples = [self._decode_sample(self._order[i])
+                           for i in range(self._pos,
+                                          self._pos + self.batch_size)]
+        # the samples come augmented out of the pool's threads; what is
+        # left for this one is to stack them
+        with _tracing.span("io.rec.augment") as sp:
+            img = _np.stack([img for img, _ in samples])
+            lab = _np.asarray([lab for _, lab in samples])
+            _tracing.annotate(sp, dtype=str(img.dtype), bytes=img.nbytes)
         self._pos += self.batch_size
-        return DataBatch(data=[array(_np.stack(datas))],
-                         label=[array(_np.asarray(labels))], pad=0)
+        return self._stage(img, lab)
 
 
 class MNISTIter(NDArrayIter):

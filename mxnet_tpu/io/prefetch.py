@@ -22,8 +22,9 @@ threaded decode pool):
 
 Both record per-stage wall time (decode / H2D / consumer compute /
 consumer stall) in a ``PipelineStats`` (its ``summary()`` carries an
-``overlap_efficiency`` figure), and both emit ``mx.profiler`` spans (``pipeline:decode`` / ``pipeline:h2d`` /
-``pipeline:stall``) while a profile is running.
+``overlap_efficiency`` figure) and as ``io.*`` spans of
+``telemetry.tracing`` (docs/OBSERVABILITY.md has the tree), each stage
+from one pair of clock reads.
 """
 from __future__ import annotations
 
@@ -135,6 +136,37 @@ class _WorkerFailure:
 _END = _EndOfStream()
 
 
+class _Stage:
+    """One worker stage under its scoped span, timed once: the span's own
+    two stamps also feed ``PipelineStats`` (and through it the registry's
+    ``io.<stage>_ms`` histogram); with ``MXTPU_TRACE=0`` there is no span
+    and the stage reads the clock itself.  A stage that ends in
+    ``StopIteration`` found no work: no span, no sample."""
+
+    __slots__ = ("_stats", "_stage", "_scope", "_t0", "span", "nbytes")
+
+    def __init__(self, stats, stage, name):
+        self._stats, self._stage = stats, stage
+        self._scope = _tracing.span(name)
+        self.nbytes = 0
+
+    def __enter__(self):
+        self.span = self._scope.__enter__()
+        self._t0 = self.span.t0 if self.span.t0 is not None \
+            else time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, *exc):
+        if exc_type is StopIteration:
+            _tracing.discard(self.span)
+        self._scope.__exit__(exc_type, *exc)
+        if exc_type is None:
+            t1 = self.span.t1 if self.span.t1 is not None \
+                else time.perf_counter()
+            self._stats.add(self._stage, t1 - self._t0, self.nbytes)
+        return False
+
+
 def _batch_nbytes(batch):
     """Exact bytes of one delivered batch (tuple/list of array leaves);
     0 when nothing measurable — the gauge then stays unset, never a
@@ -205,8 +237,6 @@ class DevicePrefetcher:
                                 # ahead of it by up to `depth` batches)
         self._skip = 0          # set_state replay-skip, applied by the
                                 # worker on ITS source iterator
-        self._trace_ctx = None  # ambient span captured at worker start
-                                # (ISSUE 14 cross-thread propagation)
         self._batch_nbytes = None   # first delivered batch's exact
                                     # bytes (ISSUE 15 memory honesty)
 
@@ -222,7 +252,7 @@ class DevicePrefetcher:
                               batch_axis=self._batch_axis,
                               data_axis=self._data_axis)
 
-    def _put_leaf(self, x):
+    def _put_leaf(self, x, n):
         import jax
         raw = x.data if isinstance(x, NDArray) else x
         if not hasattr(raw, "ndim"):       # scalars, bucket keys, ...
@@ -232,13 +262,15 @@ class DevicePrefetcher:
             dev = jax.device_put(raw)
         else:
             dev = jax.device_put(raw, sharding)
-        return NDArray(dev)
+        out = NDArray(dev)
+        out._io_batch = n       # what train.step's span says it consumed
+        return out
 
     def _nbytes(self, x):
         raw = x.data if isinstance(x, NDArray) else x
         return getattr(raw, "nbytes", 0)
 
-    def _transfer(self, item):
+    def _transfer(self, item, n):
         if not self._to_device:
             # host-only prefetch (legacy io.PrefetchingIter semantics):
             # the worker's time-in-source is still the decode stat
@@ -249,16 +281,16 @@ class DevicePrefetcher:
             if isinstance(obj, DataBatch):
                 return DataBatch(
                     data=None if obj.data is None else
-                    [self._put_leaf(d) for d in obj.data],
+                    [self._put_leaf(d, n) for d in obj.data],
                     label=None if obj.label is None else
-                    [self._put_leaf(l) for l in obj.label],
+                    [self._put_leaf(l, n) for l in obj.label],
                     pad=obj.pad, index=obj.index,
                     bucket_key=obj.bucket_key,
                     provide_data=obj.provide_data,
                     provide_label=obj.provide_label)
             if isinstance(obj, (list, tuple)):
                 return type(obj)(rec(o) for o in obj)
-            return self._put_leaf(obj)
+            return self._put_leaf(obj, n)
 
         def leaves(obj):
             if isinstance(obj, DataBatch):
@@ -293,15 +325,25 @@ class DevicePrefetcher:
                 continue
         return False
 
-    def _worker(self):
-        # spans the worker opens parent under the trace that was
-        # ambient when the consumer started it (tracing.capture in
-        # _ensure_started) — the prefetcher's decode/h2d stage spans
-        # land inside the training trace, not as orphan roots
-        with _tracing.activate(self._trace_ctx):
-            self._worker_body()
+    def _produce(self, it, n):
+        """Batch ``n`` from the source onto the device, as one trace:
+        root ``io.batch`` over ``io.decode`` (the time in ``next(source)``,
+        ambient there, so the source's own spans are its children) and
+        ``io.h2d``.  A source that is exhausted leaves no span."""
+        with _tracing.span("io.batch", batch=n) as root:
+            try:
+                with _Stage(self.stats, "decode", "io.decode"):
+                    item = next(it)
+            except StopIteration:
+                _tracing.discard(root)
+                raise
+            with _Stage(self.stats, "h2d", "io.h2d") as stage:
+                dev_item, stage.nbytes = self._transfer(item, n)
+                _tracing.annotate(stage.span, bytes=stage.nbytes)
+        return dev_item
 
-    def _worker_body(self):
+    def _worker(self, n):
+        """``n`` numbers the first batch: 0, or ``set_state``'s cursor."""
         try:
             it = iter(self._source)
             while self._skip > 0:   # set_state replay-skip (sources
@@ -315,37 +357,27 @@ class DevicePrefetcher:
             self._enqueue(_WorkerFailure(e))
             return
         while not self._stop.is_set():
-            t0 = time.perf_counter()
             try:
-                item = next(it)
+                dev_item = self._produce(it, n)
             except StopIteration:
                 self._enqueue(_END)
                 return
             except Exception as e:  # noqa: BLE001 — surface in consumer
                 self._enqueue(_WorkerFailure(e))
                 return
-            t1 = time.perf_counter()
-            try:
-                dev_item, nbytes = self._transfer(item)
-            except Exception as e:  # noqa: BLE001 — surface in consumer
-                self._enqueue(_WorkerFailure(e))
+            if not self._enqueue((dev_item, n)):
                 return
-            t2 = time.perf_counter()
-            self.stats.add("decode", t1 - t0)
-            self.stats.add("h2d", t2 - t1, nbytes)
-            _tracing.record("io.decode", t0, t1)
-            _tracing.record("io.h2d", t1, t2, bytes=nbytes)
-            if not self._enqueue((dev_item,)):
-                return
+            n += 1
 
     def _ensure_started(self):
         if self._thread is None and not self._finished:
-            self._trace_ctx = _tracing.capture()
             self._queue = _queue.Queue(maxsize=self._depth)
+            if _telem.enabled():
+                _telem.set_gauge("io.prefetch_depth", self._depth)
             self._stop.clear()
             self._thread = threading.Thread(
-                target=self._worker, name="mxtpu-device-prefetch",
-                daemon=True)
+                target=self._worker, args=(self._consumed,),
+                name="mxtpu-device-prefetch", daemon=True)
             self._thread.start()
 
     # -- consumer -------------------------------------------------------
@@ -356,6 +388,7 @@ class DevicePrefetcher:
         if self._finished:
             raise StopIteration
         self._ensure_started()
+        queued = self._queue.qsize()    # 0: this get really waits
         now = time.perf_counter()
         if self._last_yield is not None:
             self.stats.add("compute", now - self._last_yield)
@@ -368,14 +401,14 @@ class DevicePrefetcher:
                 f"(worker stalled or source hung)")
         t_got = time.perf_counter()
         self.stats.add("stall", t_got - now)
-        _tracing.record("io.wait", now, t_got)
+        _tracing.record("io.wait", now, t_got, queued=queued,
+                        batch=got[1] if isinstance(got, tuple) else None)
         if _telem.enabled():
             # read-ahead occupancy AFTER this get: depth batches queued
             # = the worker is fully ahead; 0 = the consumer is about to
             # stall on the next call
             _telem.set_gauge("io.prefetch_queue_depth",
                              self._queue.qsize())
-            _telem.set_gauge("io.prefetch_depth", self._depth)
         if got is _END:
             self._shutdown()
             raise StopIteration
@@ -598,21 +631,23 @@ class AsyncDecodeIter:
     def __next__(self):
         if self._closed:
             raise StopIteration
+        t0 = time.perf_counter()
         self._fill()
         if not self._pending:
             self.close()
             raise StopIteration
         futs = self._pending.pop(0)
-        t0 = time.perf_counter()
         try:
             results = [f.result() for f in futs]
         except BaseException:
             self.close()
             raise
+        self._fill()       # keep the pool primed while consumer computes
+        # the whole call: handing the pool its work competes with the
+        # pool's threads for the interpreter, and is part of the wait
         t1 = time.perf_counter()
         self.stats.add("decode", t1 - t0)
-        _tracing.record("io.decode_wait", t0, t1)
-        self._fill()       # keep the pool primed while consumer computes
+        _tracing.record("io.rec.fetch", t0, t1, native=False)
         return results
 
     def next(self):

@@ -107,6 +107,7 @@ class NDArray:
 
     __slots__ = ("_data", "_ctx", "_grad", "_grad_req", "_node", "_out_index",
                  "_grad_fresh", "_grad_reduced", "_grad_of", "_grad_hooks",
+                 "_io_batch",   # set by DevicePrefetcher alone: its batch
                  "__weakref__")
 
     # make NDArray win against numpy array in reflected ops

@@ -873,14 +873,19 @@ class DataParallelTrainer:
         except Exception:  # noqa: BLE001 — observability never takes
             pass           # a training step down
 
-    def _trace_step_phases(self, t1, t2, t3):
+    def _trace_step_phases(self, t1, t2, t3, batch=()):
         """Commit the four pre-timed children that tile the ambient
         ``train.step`` root (:func:`_step_span`) from its start to now —
         prepare (param collect / plan / device state), h2d (batch
         placement), dispatch (the compiled call), commit (host-side
-        param bookkeeping + metric publication)."""
+        param bookkeeping + metric publication).  A ``batch`` that came
+        out of a ``DevicePrefetcher`` gives the root its number there,
+        the ``batch`` of its ``io.batch`` and ``io.wait`` spans."""
         root = _trace.current()
         _trace.annotate(root, step=self._num_update)
+        io_batch = getattr(batch[0], "_io_batch", None) if batch else None
+        if io_batch is not None:
+            _trace.annotate(root, batch=io_batch)
         _trace.record("train.phase.prepare", root.t0, t1)
         _trace.record("train.phase.h2d", t1, t2)
         _trace.record("train.phase.dispatch", t2, t3)
@@ -933,7 +938,7 @@ class DataParallelTrainer:
             p._data._set_data(v)
         self._record_step(1, t_step)
         if trc:
-            self._trace_step_phases(tt1, tt2, tt3)
+            self._trace_step_phases(tt1, tt2, tt3, batch)
         return NDArray(loss)
 
     @_step_span

@@ -19,10 +19,11 @@ gap or a p99 tail you cannot decompose.  This module is that timeline:
   offset per process, so a reader can lay a span over a device trace;
 - **ambient context** per thread (:func:`span` nests automatically)
   with EXPLICIT cross-thread propagation — :func:`capture` on the
-  owning thread, :func:`activate` on the worker (``DevicePrefetcher``,
-  router replica workers, the async checkpoint writer all do this), so
-  a span started on a worker thread parents under the trace that
-  spawned the work;
+  owning thread, :func:`activate` on the worker (router replica
+  workers and the async checkpoint writer do this), so a span started
+  on a worker thread parents under the trace that spawned the work
+  (``DevicePrefetcher``'s worker does not: each batch it makes is a
+  trace of its own, ``io.batch``);
 - **manual spans** (:func:`start` / :func:`finish` / :func:`record`)
   for lifecycles that cross call boundaries — a serving request's root
   span lives on the ``Request`` object from admission to finish,
@@ -56,8 +57,8 @@ from jax.profiler import TraceAnnotation
 from ..lint import racecheck as _racecheck
 
 __all__ = ["Span", "enabled", "configure", "configure_from_env",
-           "reset", "clock", "span", "start", "finish", "record",
-           "annotate", "current", "capture", "activate", "spans",
+           "reset", "clock", "span", "start", "finish", "discard",
+           "record", "annotate", "current", "capture", "activate", "spans",
            "dropped", "chrome_trace", "install_compile_listener"]
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -329,6 +330,14 @@ def finish(sp, **args):
     if not _ENABLED:
         return sp
     return _TRACER.finish(sp, **args)
+
+
+def discard(sp):
+    """Close an open span without committing it; the scope it belongs
+    to then exits in silence.  For a scope that turned out to hold no
+    work (the prefetcher's look past the end of its source)."""
+    if sp is not None and sp is not NULL_SPAN and sp.t1 is None:
+        sp.t1, sp.t1_ns = sp.t0, sp.t0_ns
 
 
 def record(name, t0, t1, parent=None, ns=None, **args):

@@ -142,6 +142,9 @@ def _load():
             ctypes.POINTER(ctypes.c_int64), ctypes.POINTER(ctypes.c_void_p)]
         lib.mxtpu_prefetch_reset.argtypes = [
             ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_int64]
+        lib.mxtpu_prefetch_stats.restype = None
+        lib.mxtpu_prefetch_stats.argtypes = [
+            ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64)]
         lib.mxtpu_prefetch_error.restype = ctypes.c_char_p
         lib.mxtpu_prefetch_error.argtypes = [ctypes.c_void_p]
         lib.mxtpu_prefetch_free.argtypes = [ctypes.c_void_p]
@@ -312,6 +315,16 @@ class NativePrefetcher:
             ctypes.cast(aux, ctypes.POINTER(ctypes.c_float)),
             shape=(n, self.label_width)).copy()
         return batch, labels
+
+    def stats(self):
+        """What the pool's threads have done since it was created (resets
+        included; ``mxtpu_prefetch_stats``): ``decoded`` records built into
+        batches, ``busy_ns`` reading + decoding and ``full_ns`` standing
+        before a full queue (both summed over the threads), ``empty_ns``
+        that ``next()`` stood before an empty one."""
+        out = (ctypes.c_int64 * 4)()
+        self._lib.mxtpu_prefetch_stats(self._handle, out)
+        return dict(zip(("decoded", "busy_ns", "full_ns", "empty_ns"), out))
 
     def reset(self, indices=None):
         """Restart the epoch without re-opening/re-scanning the .rec file;

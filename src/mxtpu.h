@@ -82,6 +82,13 @@ int64_t mxtpu_prefetch_next(void *handle, void **data, int64_t *data_size,
  * current one. */
 void mxtpu_prefetch_reset(void *handle, const int64_t *indices,
                           int64_t n_indices);
+/* What the pool has done since it was created, resets included:
+ * out[0] records read (and, in mode 1, decoded) into batches,
+ * out[1] ns its threads spent reading + decoding, summed over threads,
+ * out[2] ns they stood before a full queue, summed over threads,
+ * out[3] ns mxtpu_prefetch_next stood before an empty one.
+ * Relaxed sums: safe to read at any time, from any thread. */
+void mxtpu_prefetch_stats(void *handle, int64_t out[4]);
 /* Error message from the last failed mxtpu_prefetch_next on this handle. */
 const char *mxtpu_prefetch_error(void *handle);
 void mxtpu_prefetch_free(void *handle);

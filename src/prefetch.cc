@@ -11,6 +11,7 @@
 #include "mxtpu.h"
 
 #include <atomic>
+#include <chrono>
 #include <condition_variable>
 #include <cstring>
 #include <map>
@@ -38,6 +39,25 @@ struct IRHeader {
   uint64_t id2;
 };
 
+int64_t NowNs() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
+
+// What mxtpu_prefetch_stats reports: counted over the handle's life, resets
+// included, with relaxed atomics (each is a sum, nothing is ordered by them).
+struct Stats {
+  std::atomic<int64_t> decoded{0};   // records read (+ decoded) into batches
+  std::atomic<int64_t> busy_ns{0};   // workers in BuildBatch, summed
+  std::atomic<int64_t> full_ns{0};   // workers before a full queue, summed
+  std::atomic<int64_t> empty_ns{0};  // the consumer before an empty queue
+};
+
+void Add(std::atomic<int64_t> &sum, int64_t value) {
+  sum.fetch_add(value, std::memory_order_relaxed);
+}
+
 struct Prefetcher {
   void *reader = nullptr;
   std::vector<int64_t> indices;
@@ -63,6 +83,7 @@ struct Prefetcher {
   std::unique_ptr<Batch> current;  // batch handed to Python, kept alive
   std::mutex read_mu;              // RecordIO scratch buffer is per-handle
   int n_threads = 4;
+  Stats stats;
 };
 
 void BuildBatch(Prefetcher *p, int64_t b, Batch *out) {
@@ -167,6 +188,7 @@ void WorkerLoop(Prefetcher *p) {
     int64_t b = p->next_claim.fetch_add(1);
     if (b >= p->n_batches) return;
     auto batch = std::make_unique<Batch>();
+    int64_t t_build = NowNs();
     try {
       BuildBatch(p, b, batch.get());
     } catch (const std::exception &ex) {
@@ -176,11 +198,15 @@ void WorkerLoop(Prefetcher *p) {
       p->cv_consume.notify_all();
       return;
     }
+    int64_t t_built = NowNs();
+    Add(p->stats.busy_ns, t_built - t_build);
+    Add(p->stats.decoded, batch->n_records);
     std::unique_lock<std::mutex> lk(p->mu);
     p->cv_produce.wait(lk, [&] {
       return p->stop || p->ready.size() < p->queue_depth ||
              b < p->next_deliver + static_cast<int64_t>(p->queue_depth);
     });
+    Add(p->stats.full_ns, NowNs() - t_built);
     if (p->stop) return;
     p->ready.emplace(b, std::move(batch));
     p->cv_consume.notify_all();
@@ -219,10 +245,12 @@ int64_t mxtpu_prefetch_next(void *handle, void **data, int64_t *data_size,
   auto *p = static_cast<Prefetcher *>(handle);
   if (!p) return -1;
   if (p->next_deliver >= p->n_batches) return 0;  // end of epoch
+  int64_t t_wait = NowNs();
   std::unique_lock<std::mutex> lk(p->mu);
   p->cv_consume.wait(lk, [&] {
     return p->failed || p->ready.count(p->next_deliver) > 0;
   });
+  Add(p->stats.empty_ns, NowNs() - t_wait);
   if (p->failed) return -1;  // message available via mxtpu_prefetch_error
   p->current = std::move(p->ready[p->next_deliver]);
   p->ready.erase(p->next_deliver);
@@ -254,6 +282,15 @@ void mxtpu_prefetch_reset(void *handle, const int64_t *indices,
   }
   for (int t = 0; t < p->n_threads; ++t)
     p->workers.emplace_back(WorkerLoop, p);
+}
+
+void mxtpu_prefetch_stats(void *handle, int64_t out[4]) {
+  auto *p = static_cast<Prefetcher *>(handle);
+  if (!p) return;
+  out[0] = p->stats.decoded.load(std::memory_order_relaxed);
+  out[1] = p->stats.busy_ns.load(std::memory_order_relaxed);
+  out[2] = p->stats.full_ns.load(std::memory_order_relaxed);
+  out[3] = p->stats.empty_ns.load(std::memory_order_relaxed);
 }
 
 const char *mxtpu_prefetch_error(void *handle) {

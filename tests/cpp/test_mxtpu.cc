@@ -63,8 +63,38 @@ static void test_jpeg_decode_rejects_garbage() {
   printf("jpeg garbage rejection ok\n");
 }
 
+// The pool counts what its threads did: every record built into a batch
+// once, its sums never falling, a reset adding to them.
+static void test_prefetch_stats() {
+  std::string path = tmp_rec();  // test_recordio_roundtrip left 3 records
+  int64_t order[3] = {2, 0, 1};
+  void *p = mxtpu_prefetch_create(path.c_str(), order, 3, 2, 2, 2, 0, 0, 1);
+  assert(p && "prefetcher create");
+  int64_t before[4] = {-1, -1, -1, -1}, after[4];
+  mxtpu_prefetch_stats(p, before);
+  for (int k = 0; k < 4; ++k) assert(before[k] >= 0);
+  for (int epoch = 1; epoch <= 2; ++epoch) {
+    void *data = nullptr, *aux = nullptr;
+    int64_t size = 0, n, delivered = 0;
+    while ((n = mxtpu_prefetch_next(p, &data, &size, &aux)) > 0)
+      delivered += n;
+    assert(n == 0 && delivered == 3);
+    mxtpu_prefetch_stats(p, after);
+    assert(after[0] == 3 * epoch && "decoded == records delivered");
+    assert(after[1] > 0 && "busy_ns");
+    for (int k = 0; k < 4; ++k) {
+      assert(after[k] >= before[k] && "monotone");
+      before[k] = after[k];
+    }
+    mxtpu_prefetch_reset(p, nullptr, 0);
+  }
+  mxtpu_prefetch_free(p);
+  printf("prefetch stats ok\n");
+}
+
 int main() {
   test_recordio_roundtrip();
+  test_prefetch_stats();
   test_reader_missing_file();
   test_jpeg_decode_rejects_garbage();
   printf("ALL CPP TESTS PASSED\n");

@@ -204,9 +204,11 @@ def test_trace_kill_switch_is_bitwise_inert():
         assert np.array_equal(results[True][k], results[False][k]), k
 
 
-def test_prefetcher_worker_spans_parent_under_ambient_trace():
-    """DevicePrefetcher stage spans (worker thread) land inside the
-    trace that was ambient when the consumer started iterating."""
+def test_prefetcher_worker_spans_are_traces_of_their_own():
+    """DevicePrefetcher's worker opens one ``io.batch`` root a batch with
+    its stage spans under it: nothing it records hangs under the span
+    that was ambient when the consumer started iterating.  ``io.wait`` is
+    the consumer's."""
     from mxnet_tpu.io import DevicePrefetcher
     batches = [np.ones((4, 2), np.float32) * i for i in range(3)]
     with tracing.span("epoch") as root:
@@ -215,14 +217,18 @@ def test_prefetcher_worker_spans_parent_under_ambient_trace():
         pf.close()
     assert len(got) == 3
     sp = tracing.spans()
+    roots = [s for s in sp if s["name"] == "io.batch"]
     decodes = [s for s in sp if s["name"] == "io.decode"]
     h2ds = [s for s in sp if s["name"] == "io.h2d"]
     waits = [s for s in sp if s["name"] == "io.wait"]
-    assert len(decodes) == 3 and len(h2ds) == 3 and len(waits) >= 1
-    for s in decodes + h2ds:
-        assert s["trace"] == root.trace
-        assert s["parent"] == root.span
-        assert s["thread"] != root.thread      # worker-side emission
+    assert len(roots) == len(decodes) == len(h2ds) == 3 and len(waits) >= 3
+    assert [r["args"]["batch"] for r in roots] == [0, 1, 2]
+    for r, d, h in zip(roots, decodes, h2ds):
+        assert r["parent"] is None and r["trace"] != root.trace
+        assert d["parent"] == h["parent"] == r["span"]
+        assert d["trace"] == h["trace"] == r["span"]
+        assert r["thread"] != root.thread      # worker-side emission
+        assert h["args"]["bytes"] == 4 * 2 * 4
     for s in waits:                            # consumer-side emission
         assert s["parent"] == root.span
 
