@@ -57,17 +57,26 @@ each chunk, which is all the forward keeps beside its operands: one (dv, dk)
 float32 state a chunk and head, not one a token.
 
 On the chip both directions are one Pallas kernel each (``mxtpu_kda_fwd``,
-``mxtpu_kda_bwd``): grid (batch, heads / ``_HEADS``, chunks), the chunks in
-order with the states in a VMEM scratch, operands read in place from (B, T, H
-d) — a head is a block of 128 lanes — so nothing is transposed on the way in
-or out.  A program's ``_HEADS`` = 2 heads go through a tile one under another:
-``A``, ``T`` and ``Aqk`` are then block diagonal (128, 128), which fills the
-MXU where one head's (64, 64) would leave three quarters of it idle and halves
-the chain of dependent products the inverse is (6490 -> 3987 bundles a grid
-step by the compiler's own schedule, 8.9 -> 5.3 ms a forward call at the kimi
-cell's shape).  Elsewhere the same tile functions run a head at a time under
-``lax.scan`` (the XLA form, also what the kernels are tested against, beside
-the recurrence).
+``mxtpu_kda_bwd``): grid (batch, heads / heads a program, chunks), the chunks
+in order with the states in a VMEM scratch, operands read in place from (B, T,
+H d) — a head is a block of 128 lanes — so nothing is transposed on the way in
+or out.  A *unit* is ``_HEADS`` = 2 heads that go through a tile one under
+another: ``A``, ``T`` and ``Aqk`` are then block diagonal (128, 128), which
+fills the MXU where one head's (64, 64) would leave three quarters of it idle.
+A unit's chunk is a chain of dependent products (the inverse alone is ten in
+series), each a wait of some 130 cycles on an MXU that runs its pushes and
+pops in program order: written one unit after another the chains do not
+overlap, whatever the scheduler would like.  So a program takes 4, 2 or 1
+units (:func:`_kernel_heads`, from the head count alone; one head where it is
+odd), and :func:`_interleaved` traces a unit's tile function once and issues
+its equations a stage at a time, every unit's stage before any unit's next (a
+stage ends where a product waits for a product of the stage): one body, a
+unit's arithmetic untouched, and in program order a unit's product sits in
+the shadow of another's latency (the compiler's schedule at the kimi cell's
+shape, 32 heads: 2074 -> 883 bundles a head and chunk forward, 2915 -> 1435
+backward; PERF.md §6, PR 38).  Elsewhere the same tile functions run a head
+at a time under ``lax.scan`` (the XLA form, also what the kernels are tested
+against, beside the recurrence).
 """
 from __future__ import annotations
 
@@ -76,6 +85,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.extend.core import Literal
 
 from .. import telemetry as _telem
 from .kernel_mode import kernel_mode
@@ -84,7 +94,8 @@ __all__ = ["kda_attention", "chunk_size"]
 
 _CHUNK = 64     # tokens a chunk
 _SUB = 16       # rows that share a reference row in the pair products
-_HEADS = 2      # heads a grid program of the kernels takes
+_HEADS = 2      # heads a unit: one under another in every tile
+_UNITS = (4, 2, 1)  # units a grid program of the kernels may take
 
 _NN = ((1,), (0,))
 _NT = ((1,), (1,))
@@ -169,6 +180,49 @@ def _decays(G, size):
         out.append((n, jnp.exp(G[n:n + _SUB] - ref),
                     jnp.exp(jnp.where(seen, ref - G, -jnp.inf))))
     return out
+
+
+def _stages(jaxpr):
+    """The equations of ``jaxpr`` in order, cut before each product that
+    waits for a product of its own stage."""
+    stages, waits = [[]], set()
+    for eqn in jaxpr.eqns:
+        behind = any(v in waits for v in eqn.invars
+                     if not isinstance(v, Literal))
+        if behind and eqn.primitive is lax.dot_general_p:
+            stages.append([])
+            waits, behind = set(), False
+        if behind or eqn.primitive is lax.dot_general_p:
+            waits.update(eqn.outvars)
+        stages[-1].append(eqn)
+    return stages
+
+
+def _interleaved(fn, units):
+    """``fn(*unit)`` for each of ``units`` (the same shapes), traced once and
+    issued a stage at a time, every unit's stage before any unit's next ->
+    what each returned.  In a kernel that is the order of the program, and an
+    MXU runs in program order: a unit's product then sits in the shadow of
+    another's latency."""
+    closed, shape = jax.make_jaxpr(fn, return_shape=True)(*units[0])
+    jaxpr = closed.jaxpr
+
+    def read(env, v):
+        return v.val if isinstance(v, Literal) else env[v]
+    envs = [dict(zip(jaxpr.constvars + jaxpr.invars,
+                     closed.consts + jax.tree.leaves(unit)))
+            for unit in units]
+    for stage in _stages(jaxpr):
+        for env in envs:
+            for eqn in stage:
+                subfuns, params = eqn.primitive.get_bind_params(eqn.params)
+                out = eqn.primitive.bind(
+                    *subfuns, *(read(env, v) for v in eqn.invars), **params)
+                env.update(zip(eqn.outvars, out
+                               if eqn.primitive.multiple_results else [out]))
+    return [jax.tree.unflatten(jax.tree.structure(shape),
+                               [read(env, v) for v in jaxpr.outvars])
+            for env in envs]
 
 
 def _unit_lower_inverse(A, size, md):
@@ -387,7 +441,9 @@ def _xla_backward(q, k, v, g, beta, states, do, dfinal, size):
 # ---------------------------------------------------------------------------
 
 def _kernel_heads(heads):
-    return _HEADS if heads % _HEADS == 0 else 1
+    """Heads a grid program takes: the most of ``_UNITS`` units of
+    ``_HEADS`` that divide ``heads``, one head where ``heads`` is odd."""
+    return next((n * _HEADS for n in _UNITS if heads % (n * _HEADS) == 0), 1)
 
 
 def _token_spec(pl, size, width, hb, reverse, chunks):
@@ -413,11 +469,17 @@ def _by_program(beta, hb):
     return beta.reshape(b, t, h // hb, hb).transpose(0, 2, 1, 3)
 
 
-def _stacked(ref, hb, width):
-    """A (C, hb d) block as (hb C, d): the program's heads one under
-    another."""
+def _stacked(ref, heads, width):
+    """Heads ``heads`` of a (C, hb d) block as (n C, d): a unit's heads one
+    under another."""
     return jnp.concatenate([ref[:, j * width:(j + 1) * width]
-                            for j in range(hb)])
+                            for j in heads])
+
+
+def _units(hb):
+    """The heads of each unit of a program of ``hb`` heads."""
+    n = min(hb, _HEADS)
+    return [range(u, u + n) for u in range(0, hb, n)]
 
 
 # Jitted so that a model's layers share one trace of the body (a pallas_call
@@ -434,6 +496,7 @@ def _pallas_forward(q, k, v, g, beta, heads, size, interpret=False):
     nb, seq, _ = q.shape
     dk, dv = q.shape[2] // heads, v.shape[2] // heads
     hb, chunks = _kernel_heads(heads), seq // size
+    units = _units(hb)
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, fin_ref,
                state):
@@ -444,14 +507,17 @@ def _pallas_forward(q, k, v, g, beta, heads, size, interpret=False):
             state[...] = jnp.zeros_like(state)
 
         st_ref[...] = state[...]
-        o, new = _chunk_fwd(
-            _stacked(q_ref, hb, dk), _stacked(k_ref, hb, dk),
-            _stacked(v_ref, hb, dv), _stacked(g_ref, hb, dk),
-            _stacked(b_ref, hb, 1), [state[j] for j in range(hb)])
-        for j in range(hb):
-            o_ref[:, j * dv:(j + 1) * dv] = \
-                o[j * size:(j + 1) * size].astype(o_ref.dtype)
-            state[j] = new[j]
+        # every unit's loads before the first store of any
+        done = _interleaved(_chunk_fwd, [(
+            _stacked(q_ref, unit, dk), _stacked(k_ref, unit, dk),
+            _stacked(v_ref, unit, dv), _stacked(g_ref, unit, dk),
+            _stacked(b_ref, unit, 1), [state[j] for j in unit])
+            for unit in units])
+        for unit, (o, new) in zip(units, done):
+            for i, j in enumerate(unit):
+                o_ref[:, j * dv:(j + 1) * dv] = \
+                    o[i * size:(i + 1) * size].astype(o_ref.dtype)
+                state[j] = new[i]
 
         @pl.when(c == chunks - 1)
         def _end():
@@ -491,6 +557,7 @@ def _pallas_backward(q, k, v, g, beta, states, do, dfinal, size,
     nb, seq, _ = q.shape
     heads, _, dv, dk = states.shape[1:]
     hb, chunks = _kernel_heads(heads), seq // size
+    units = _units(hb)
 
     def kernel(q_ref, k_ref, v_ref, g_ref, b_ref, st_ref, do_ref, dfin_ref,
                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate):
@@ -500,18 +567,20 @@ def _pallas_backward(q, k, v, g, beta, states, do, dfinal, size,
         def _start():
             dstate[...] = dfin_ref[...]
 
-        *grads, dstates = _chunk_bwd(
-            _stacked(q_ref, hb, dk), _stacked(k_ref, hb, dk),
-            _stacked(v_ref, hb, dv), _stacked(g_ref, hb, dk),
-            _stacked(b_ref, hb, 1), [st_ref[j] for j in range(hb)],
-            _stacked(do_ref, hb, dv), [dstate[j] for j in range(hb)])
-        for j in range(hb):
-            for ref, grad, width in zip(
-                    (dq_ref, dk_ref, dv_ref, dg_ref, db_ref), grads,
-                    (dk, dk, dv, dk, 1)):
-                ref[:, j * width:(j + 1) * width] = \
-                    grad[j * size:(j + 1) * size].astype(ref.dtype)
-            dstate[j] = dstates[j]
+        done = _interleaved(_chunk_bwd, [(
+            _stacked(q_ref, unit, dk), _stacked(k_ref, unit, dk),
+            _stacked(v_ref, unit, dv), _stacked(g_ref, unit, dk),
+            _stacked(b_ref, unit, 1), [st_ref[j] for j in unit],
+            _stacked(do_ref, unit, dv), [dstate[j] for j in unit])
+            for unit in units])
+        for unit, (*grads, dstates) in zip(units, done):
+            for i, j in enumerate(unit):
+                for ref, grad, width in zip(
+                        (dq_ref, dk_ref, dv_ref, dg_ref, db_ref), grads,
+                        (dk, dk, dv, dk, 1)):
+                    ref[:, j * width:(j + 1) * width] = \
+                        grad[i * size:(i + 1) * size].astype(ref.dtype)
+                dstate[j] = dstates[i]
 
     tokens = functools.partial(_token_spec, pl, size, hb=hb, reverse=True,
                                chunks=chunks)
@@ -600,6 +669,8 @@ def kda_attention(q, k, v, g, beta):
     _telem.set_gauge("kda.heads", h)
     _telem.set_gauge("kda.chunk", size)
     _telem.set_gauge("kda.chunks_per_seq", (t + pad) // size)
+    mode = _kernels(dk, dv)
+    _telem.set_gauge("kda.heads_per_program", _kernel_heads(h) if mode else 1)
 
     f32 = jnp.float32
     operands = [q, k, v, jnp.maximum(g.astype(f32), _MIN_LOG_DECAY),
@@ -608,5 +679,5 @@ def kda_attention(q, k, v, g, beta):
         operands = [jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
                     for a in operands]
     o, final = _scan(*(a.reshape(b, t + pad, -1) for a in operands), h, size,
-                     _kernels(dk, dv))
+                     mode)
     return o[:, :t].reshape(b, t, h, dv), jnp.swapaxes(final, 2, 3)

@@ -269,6 +269,7 @@ def test_trains_through_the_fused_step_under_amp_with_the_kernels(amp_net):
     assert not telemetry.value("kda.bwd.xla")
     assert telemetry.value("mla.nope") >= 1
     assert telemetry.value("kda.heads") == 2
+    assert telemetry.value("kda.heads_per_program") == 2
     assert telemetry.value("kda.chunk") == 64
     assert telemetry.value("kda.chunks_per_seq") == 2
     assert telemetry.value("moe.layers") >= 1

@@ -91,6 +91,46 @@ def test_chunked_scan_is_the_recurrence(kernels, t, rate, dt):
         assert _close(a, b, 2e-5), name
 
 
+# a grid program of the kernels takes 4, 2 or 1 units of two heads (8, 4, 2
+# heads) and runs them stage by stage, or one head where the count is odd.
+# Whatever else shares the program, a unit's arithmetic is its own: the same
+# bits as the unit alone.  Against each head alone a pair differs by the
+# zeros of its block-diagonal tiles, and the XLA form by its batch of heads
+# (a head at a time, the products' sums in XLA's order for the batch):
+# float32's rounding.
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "interpret"])
+@pytest.mark.parametrize("heads,a_program", [(2, 2), (4, 4), (8, 8), (3, 1)])
+def test_a_head_gets_what_it_gets_alone(kernels, heads, a_program):
+    t, dk, dv = 128, 32, 16
+    operands = _draw(21, 1, t, heads, dk, dv, 2.0, 0.05)
+    weights = _weights(1, t, heads, dk, dv)
+
+    # jitted: the units alone share one compiled program
+    both = jax.jit(lambda *a: _value_and_grads(_chunked, a[:5], a[5:]))
+
+    def run(part):
+        with interpret_kernels() if kernels else contextlib.nullcontext():
+            (o, state), grads = both(*(a[:, :, part] for a in operands),
+                                     weights[0][:, :, part],
+                                     weights[1][:, part])
+        return [o, jnp.swapaxes(state, 1, 2), *grads]
+
+    whole = run(slice(None))
+    assert mx.telemetry.value("kda.heads_per_program") == \
+        (a_program if kernels else 1)
+    unit = 2 if kernels and heads % 2 == 0 else 1
+    for first in range(0, heads, unit):
+        part = slice(first, first + unit)
+        for got, want in zip(whole, run(part)):
+            if kernels:
+                assert bool((got[:, :, part] == want).all()), first
+            else:
+                assert _close(got[:, :, part], want, 2e-6), first
+    if unit == 2:
+        for got, want in zip(whole, run(slice(heads - 1, heads))):
+            assert _close(got[:, :, heads - 1:], want, 2e-6)
+
+
 @pytest.mark.parametrize("kernels", [False, True], ids=["xla", "interpret"])
 def test_the_tiles_norm_sees_only_directions(kernels):
     """q and k go in at any length: the tiles divide each head's row by its
