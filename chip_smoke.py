@@ -1068,9 +1068,6 @@ def phase_serve(run):
     s = run.sizes
     mx.random.seed(SEED)
     net = llama3_8b(**s["llama"])
-    # a server needs no gradient buffers: with the default grad_req every
-    # parameter brings a second array of its size (6 GB here)
-    net.collect_params().setattr("grad_req", "null")
     net.initialize(ctx=run.ctx)
     cfg = net.cfg
     net(mx.nd.array(np.zeros((1, 4), np.int32)))     # materialize shapes

@@ -25,8 +25,8 @@ from .base import MXNetError
 from .telemetry import tracing as _trace
 
 __all__ = ["is_recording", "is_training", "set_recording", "set_training",
-           "apply_op", "backward", "mark_variable", "Node",
-           "register_grad_ready_hook"]
+           "apply_op", "backward", "mark_variable", "grad_array",
+           "grad_bytes", "clear_grad", "Node", "register_grad_ready_hook"]
 
 
 class _TapeState(threading.local):
@@ -187,23 +187,53 @@ def apply_op(fn, inputs, n_out=1, name=""):
 
 
 def mark_variable(arr, grad_req="write", stype=None):
-    """attach_grad: reference Imperative::MarkVariables."""
+    """attach_grad: reference Imperative::MarkVariables.
+
+    Records ``grad_req`` and the gradient's storage type and holds no
+    array: a gradient array exists once a backward has produced one or
+    something has asked for one (:func:`grad_array`).  A fused step, a
+    served network and every variable no backward reaches never do, and
+    carry no buffer of the variable's size."""
     if grad_req not in ("write", "add", "null"):
         raise MXNetError(f"invalid grad_req {grad_req!r}")
     arr._grad_req = grad_req
+    arr._grad_stype = stype
     # attach_grad detaches the array from any producing graph, matching the
     # reference behaviour of NDArray.attach_grad (python/mxnet/ndarray/ndarray.py)
     arr._node = None
     arr._out_index = 0
-    if grad_req == "null":
-        arr._grad = None
-    elif stype == "row_sparse":
-        # no dense zero buffer: the first backward installs a
-        # RowSparseNDArray grad with memory O(nnz)
-        arr._grad = None
-    else:
-        arr._grad = jnp.zeros(arr.shape, arr.dtype)
+    arr._grad = None
     arr._grad_fresh = False
+
+
+def grad_array(arr):
+    """The variable's gradient array.  A dense variable that takes
+    gradients and has none yet gets zeros here, made at this read and
+    kept (what the reference shows in ``x.grad`` before a backward, and
+    the array a later in-place clip writes through).  ``None`` for
+    ``grad_req='null'`` and for a ``row_sparse`` gradient no backward has
+    installed (O(nnz) memory, never a dense zero buffer)."""
+    if arr._grad is None and arr._grad_req != "null" and \
+            arr._grad_stype != "row_sparse":
+        arr._grad = jnp.zeros(arr.shape, arr.dtype)
+    return arr._grad
+
+
+def grad_bytes(arr):
+    """Device bytes of the gradient array ``arr`` holds now: 0 in the
+    empty state, O(nnz) for a row_sparse one."""
+    g = arr._grad
+    if g is None:
+        return 0
+    handles = g._sync_handles() if hasattr(g, "_sync_handles") else (g,)
+    return sum(h.nbytes for h in handles)
+
+
+def clear_grad(arr):
+    """zero_grad: back to the empty state, which reads as zeros and from
+    which ``grad_req='add'`` accumulates."""
+    arr._grad = None
+    arr._grad_reduced = False   # new accumulation cycle
 
 
 def _accumulate(slot, value):
@@ -474,12 +504,13 @@ def _apply_grad_req(arr, g):
         g = g.astype(arr.dtype)
     if isinstance(g, SparseCotangent):
         from .ndarray.sparse import RowSparseNDArray
-        prev = arr._grad
-        if arr._grad_req == "add" and isinstance(prev, RowSparseNDArray):
+        # a dense variable accumulates densely from its first backward
+        # on (zeros made here); a row_sparse one stays O(nnz)
+        prev = grad_array(arr) if arr._grad_req == "add" else None
+        if isinstance(prev, RowSparseNDArray):
             g = SparseCotangent(prev.indices.data, prev.values.data,
                                 g.shape).merge(g)
-        elif arr._grad_req == "add" and prev is not None:
-            # dense accumulator already exists (attach_grad default)
+        elif prev is not None:
             arr._grad = prev.at[g.indices].add(g.values)
             arr._grad_fresh = True
             arr._grad_reduced = False

@@ -163,7 +163,7 @@ def init_trainer(trainer):
             trainer._update(ignore_stale_grad)
         else:   # skip step, drop stale grads
             for p in trainer._params:
-                if p._data is not None and p._data._grad is not None:
+                if p._data is not None:
                     p._data._grad_fresh = False
         scaler.update_scale(overflow)
 

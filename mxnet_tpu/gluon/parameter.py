@@ -20,6 +20,7 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 
+from .. import _tape
 from ..base import MXNetError
 from ..context import Context, current_context, cpu
 from ..ndarray.ndarray import NDArray, zeros as nd_zeros
@@ -197,13 +198,8 @@ class Parameter:
         return [self._ctx or current_context()]
 
     def zero_grad(self):
-        if self._data is not None and self._data._grad is not None:
-            if self._grad_stype == "row_sparse":
-                self._data._grad = None    # next backward re-installs O(nnz)
-            else:
-                self._data._grad = jnp.zeros(self._data.shape,
-                                             self._data.data.dtype)
-            self._data._grad_reduced = False   # new accumulation cycle
+        if self._data is not None:
+            _tape.clear_grad(self._data)
 
     def set_data(self, data):
         if isinstance(data, NDArray):
@@ -234,9 +230,8 @@ class Parameter:
     def cast(self, dtype):
         self.dtype = dtype
         if self._data is not None:
-            had_grad = self._data._grad is not None
             self._data = self._data.astype(dtype)
-            if had_grad or self._grad_req != "null":
+            if self._grad_req != "null":
                 self._data.attach_grad(self._grad_req,
                                        stype=self._grad_stype)
 

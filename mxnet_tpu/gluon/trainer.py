@@ -16,6 +16,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from .. import _tape
 from ..base import MXNetError
 from ..ndarray.ndarray import NDArray
 from .. import optimizer as opt
@@ -23,6 +24,15 @@ from ..telemetry import tracing as _trace
 from .parameter import ParameterDict, Parameter
 
 __all__ = ["Trainer"]
+
+
+def _stale(param):
+    """No backward has written this parameter's gradient since the last
+    update (or none reached it at all).  A gradient cleared after its
+    backward (``zero_grad``) is not stale: it reads as zeros, made here,
+    and a ``row_sparse`` one that was cleared has nothing to apply."""
+    d = param._data
+    return not d._grad_fresh or _tape.grad_array(d) is None
 
 
 def _fused_adapter(optimizer):
@@ -373,7 +383,7 @@ class Trainer:
         for i, param in enumerate(self._params):
             if param.grad_req == "null" or param._data is None:
                 continue
-            if param._data._grad is None or not param._data._grad_fresh:
+            if _stale(param):
                 if ignore_stale_grad:
                     continue
                 return False      # per-param path raises the right error
@@ -506,7 +516,7 @@ class Trainer:
         for i, param in enumerate(self._params):
             if param.grad_req == "null" or param._data is None:
                 continue
-            if param._data._grad is None or not param._data._grad_fresh:
+            if _stale(param):
                 if ignore_stale_grad:
                     continue
                 return False      # per-param path raises the right error
@@ -562,7 +572,7 @@ class Trainer:
         for i, param in enumerate(self._params):
             if param.grad_req == "null" or param._data is None:
                 continue
-            if param._data._grad is None or not param._data._grad_fresh:
+            if _stale(param):
                 if ignore_stale_grad:
                     continue
                 raise MXNetError(

@@ -273,7 +273,7 @@ class Executor:
         autograd.backward(heads, out_grads)
         for name, arr in self.arg_dict.items():
             if self._req.get(name, "write") != "null" and \
-                    not _is_input_name(name) and arr._grad is not None:
+                    not _is_input_name(name):
                 self.grad_dict[name] = arr.grad
 
     def copy_params_from(self, arg_params, aux_params=None,

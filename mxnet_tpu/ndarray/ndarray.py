@@ -105,8 +105,9 @@ class NDArray:
     design stance).
     """
 
-    __slots__ = ("_data", "_ctx", "_grad", "_grad_req", "_node", "_out_index",
-                 "_grad_fresh", "_grad_reduced", "_grad_of", "_grad_hooks",
+    __slots__ = ("_data", "_ctx", "_grad", "_grad_req", "_grad_stype",
+                 "_node", "_out_index", "_grad_fresh", "_grad_reduced",
+                 "_grad_of", "_grad_hooks",
                  "_io_batch",   # set by DevicePrefetcher alone: its batch
                  "__weakref__")
 
@@ -116,8 +117,11 @@ class NDArray:
     def __init__(self, data, ctx=None):
         self._data = data
         self._ctx = ctx if ctx is not None else current_context()
+        # the gradient array: None until a backward writes one or .grad
+        # is read (attach_grad allocates nothing, see _tape.grad_array)
         self._grad = None
         self._grad_req = "null"
+        self._grad_stype = None
         self._grad_fresh = False
         # True once the cross-worker sum ran for the CURRENT accumulated
         # gradient; re-armed whenever autograd writes fresh gradient data
@@ -178,13 +182,14 @@ class NDArray:
 
     @property
     def grad(self):
-        if self._grad is None:
+        g = _tape.grad_array(self)
+        if g is None:
             return None
-        if isinstance(self._grad, NDArray):
+        if isinstance(g, NDArray):
             # row_sparse grad (sparse_grad=True path): returned directly,
             # stype preserved for the optimizer's lazy update
-            return self._grad
-        out = NDArray(self._grad, self._ctx)
+            return g
+        out = NDArray(g, self._ctx)
         # the wrapper is a live view: in-place mutation of it (clip, scale)
         # writes back to the owner's gradient buffer (see _set_data), so
         # idioms like clip_global_norm([p.grad() ...]) take effect
@@ -456,8 +461,8 @@ class NDArray:
             value = value._data
         elif not isinstance(value, (jnp.ndarray, jax.Array)):
             value = jnp.asarray(value, dtype=self._data.dtype)
-        self._data = self._data.at[key].set(value)
-        self._node = None
+        # through _set_data: ``x.grad[:] = 0`` writes the owner's gradient
+        self._set_data(self._data.at[key].set(value))
 
     def __iter__(self):
         for i in range(self.shape[0]):

@@ -832,6 +832,8 @@ class DataParallelTrainer:
         """Publish per-step metrics after ``k`` steps committed; the
         ambient telemetry step context feeds event records and profiler
         span tags; the health watchdog ticks at the same seam."""
+        if self._mem_gauges_stale:
+            self._publish_memory_gauges()
         if t_step0 is None:
             return
         dt_s = _telem.clock() - t_step0
@@ -841,18 +843,23 @@ class DataParallelTrainer:
         _telem.set_gauge("train.num_update", self._num_update)
         _watchdog.on_step(self._num_update,
                           step_ms=dt_s * 1e3 / max(k, 1))
-        if self._mem_gauges_stale:
-            self._publish_memory_gauges()
 
     def _publish_memory_gauges(self):
-        """One-time (per build) exact byte gauges for the flight
-        recorder's ``memory`` block (ISSUE 15): the device-resident
-        param bytes this trainer owns and its per-chip optimizer-state
-        bytes (``train.zero1_shard_bytes`` when ZeRO-1 shards it, the
-        replicated ``train.opt_state_bytes`` otherwise).  Exact
-        arithmetic on shapes already in hand — no device traffic."""
+        """One-time (per build) exact byte gauges, also carried as
+        arguments of the ``train.step`` root span that is ambient (the
+        first step's): the device-resident param bytes this trainer owns
+        (``train.param_bytes``), its per-chip optimizer-state bytes
+        (``train.state_bytes``; for the flight recorder's ``memory``
+        block (ISSUE 15) also under ``train.zero1_shard_bytes`` when
+        ZeRO-1 shards it, ``train.opt_state_bytes`` otherwise) and the
+        bytes of the gradient arrays the eager tape holds for the
+        network's parameters (``autograd.grad_buffer_bytes``: 0 unless
+        something ran an eager backward or read ``.grad`` — the fused
+        step keeps its gradients inside the program).  Exact arithmetic
+        on shapes already in hand — no device traffic."""
         self._mem_gauges_stale = False
         try:
+            pbytes = sbytes = 0
             if self._param_vals is not None:
                 pbytes = sum(leaf.size * leaf.dtype.itemsize
                              for leaf in jax.tree.leaves(self._param_vals))
@@ -860,7 +867,6 @@ class DataParallelTrainer:
             if self._opt_state is not None:
                 dp = self.mesh.shape.get(AXIS_DP, 1)
                 zero1 = bool(self._zero1 and self._plan is not None)
-                sbytes = 0
                 for leaf in jax.tree.leaves(self._opt_state):
                     nbytes = leaf.size * leaf.dtype.itemsize
                     # ZeRO-1: vector leaves are dp-sharded, scalars
@@ -870,6 +876,14 @@ class DataParallelTrainer:
                 _telem.set_gauge("train.zero1_shard_bytes" if zero1
                                  else "train.opt_state_bytes",
                                  int(sbytes))
+                _telem.set_gauge("train.state_bytes", int(sbytes))
+            gbytes = sum(_tape.grad_bytes(p._data)
+                         for p in self._param_objs or ()
+                         if p._data is not None)
+            _telem.set_gauge("autograd.grad_buffer_bytes", int(gbytes))
+            _trace.annotate(_trace.current(), param_bytes=int(pbytes),
+                            state_bytes=int(sbytes),
+                            grad_buffer_bytes=int(gbytes))
         except Exception:  # noqa: BLE001 — observability never takes
             pass           # a training step down
 
@@ -1609,14 +1623,19 @@ def all_reduce_gradients(params, mesh=None, axis=AXIS_DP, kvstore=None,
     sel_keys, sel_params, grads = [], [], []
     for k, p in zip(keys, params):
         d = getattr(p, "_data", None)
-        if getattr(p, "grad_req", "write") == "null" or d is None or \
-                d._grad is None:
+        if getattr(p, "grad_req", "write") == "null" or d is None:
             continue
         if getattr(d, "_grad_reduced", False):
             continue            # already summed this accumulation cycle
+        # a parameter no backward reached sends zeros (made at this
+        # read), so every worker sends the same keys; a row_sparse one
+        # has nothing to send
+        g = p.grad()
+        if g is None:
+            continue
         sel_keys.append(k)
         sel_params.append(p)
-        grads.append(p.grad())
+        grads.append(g)
     if not sel_keys:
         return params
     if kvstore is not None:

@@ -192,10 +192,10 @@ class OverlapScheduler:
             i = self._order[k]
             p = self._all_params[i]
             d = p._data
-            if d is None or d._grad is None or d._grad_reduced:
+            if d is None or d._grad_req == "null" or d._grad_reduced:
                 continue
             g = p.grad()
-            if isinstance(g, _sp.RowSparseNDArray):
+            if g is None or isinstance(g, _sp.RowSparseNDArray):
                 continue    # row_sparse rides the batched kvstore path
             keys.append(self._all_keys[i])
             grads.append(g)

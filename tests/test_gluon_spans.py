@@ -197,7 +197,12 @@ def test_compile_lands_under_the_span_that_caused_it():
 def test_fused_step_root_is_scoped_and_owns_its_compile():
     _fused_steps(2)
     roots = _named("train.step")
-    assert [r["args"] for r in roots] == [{"step": 1}, {"step": 2}]
+    # the first root also carries the trainer's byte gauges (ISSUE 39)
+    assert set(roots[0]["args"]) == {"step", "param_bytes", "state_bytes",
+                                     "grad_buffer_bytes"}
+    assert roots[0]["args"]["step"] == 1
+    assert roots[0]["args"]["grad_buffer_bytes"] == 0
+    assert roots[1]["args"] == {"step": 2}
     owners = {s["parent"] for s in _named("jit.compile")}
     assert roots[0]["span"] in owners and roots[1]["span"] not in owners
     # the pre-timed phases tile the root from its own start

@@ -147,6 +147,20 @@ def test_decode_parity_bitwise_per_bucket(tie):
     assert eng.stats["compiles_after_warmup"] == 0
 
 
+def test_engine_from_default_initialized_net_holds_no_gradient_arrays():
+    """A net built and initialized with the defaults (``grad_req`` is
+    'write' everywhere) serves without a second array a parameter: the
+    tape allocates a gradient only when a backward or a ``.grad`` read
+    asks for one (ISSUE 39; it used to double the served weights)."""
+    net = _net()
+    params = list(net.collect_params().values())
+    assert all(p.grad_req == "write" for p in params)
+    eng = InferenceEngine(net, max_batch=2, block_size=8, max_context=32)
+    out = _drive(eng, 0, [1, 2, 3, 4, 5], 4)
+    assert len(out) == 5
+    assert all(p._data._grad is None for p in params)
+
+
 def test_prefill_parity_bitwise_per_bucket():
     """Prefill (padded and bucket-exact prompts) reproduces the full
     forward's last-position logits bitwise, and samples its argmax."""
