@@ -16,7 +16,11 @@ grid program takes G (batch x head) rows, a BQ block of queries and
 streams Lk in BK blocks through VMEM with a float32 accumulator: the MXU
 sees G batched (BK, D) x (D, BQ) / (D, BK) x (BK, BQ) matmuls per step.  G
 comes from the shapes alone (:func:`_rows_per_program`): as many rows as
-give a program a few microseconds of work and fit VMEM.  When one BK block
+give a program a few microseconds of work and fit VMEM.  Where K and V have
+fewer rows than Q (grouped-query attention, read in place) a program's G
+rows are query heads of one kv head, which share its K / V block, at a
+square score tile of that rule's size (:func:`_forward_tiling`); the
+backward repeats K / V to the query rows and sums dK / dV back.  When one BK block
 holds all of Lk the body is a plain one-pass softmax.  Otherwise a grid
 step holds as many KV blocks as VMEM has room for
 (:func:`_kv_blocks_per_step`) and walks them in a loop of its own, a block
@@ -112,18 +116,22 @@ def _mask_vmem_bytes(bq, bk):
 
 
 def _program_vmem_bytes(g, bq, bk, d, itemsize, streaming, dv=None,
-                        masked=False):
+                        masked=False, kv_rows=None):
     """VMEM bytes one program of ``g`` rows holds: the double-buffered
     Q/K blocks at ``d`` and V/O blocks at ``dv`` (the head dim on sublanes,
-    padded to the dtype's tile), the log-sum-exp block, the float32 scratch
-    of the streaming body and the (g, bk, bq) score / probability
-    temporaries with the float32 P.V; ``masked``: a selection mask's tile
-    besides."""
+    padded to the dtype's tile) — K and V at ``kv_rows`` rows, ``g`` where
+    every row has its own and 1 where the rows are query heads of one kv
+    head —, the log-sum-exp block, the float32 scratch of the streaming
+    body and the (g, bk, bq) score / probability temporaries with the
+    float32 P.V; ``masked``: a selection mask's tile besides, once a
+    program."""
     dv = d if dv is None else dv
+    kv_rows = g if kv_rows is None else kv_rows
     if masked:
         return _mask_vmem_bytes(bq, bk) + _program_vmem_bytes(
-            g, bq, bk, d, itemsize, streaming, dv)
-    blocks = 2 * g * _padded_head_dims(d, dv, itemsize) * (bq + bk) * itemsize
+            g, bq, bk, d, itemsize, streaming, dv, kv_rows=kv_rows)
+    blocks = 2 * _padded_head_dims(d, dv, itemsize) * (
+        g * bq + kv_rows * bk) * itemsize
     lse = 2 * 8 * -(-g // 8) * bq * 4
     scratch = g * (dv + 2 * 8) * bq * 4 if streaming else 0
     temps = g * bq * (bk * (4 + 4 + itemsize) + dv * 4)
@@ -160,6 +168,31 @@ def _rows_per_program(bh, bq, bk, d, itemsize, streaming, dv=None,
         (bq + bk) * (d + dv) * itemsize)
 
 
+def _forward_tiling(heads, group, lq, lk, bq, bk, d, itemsize, streaming,
+                    dv, masked=False):
+    """``(G, bq, bk)`` of the forward kernel, from the shapes alone.
+    ``group`` query heads share each kv head (``H / Hkv``, read from the
+    operands).  A group of 1 is :func:`_rows_per_program` at the caller's
+    blocks.  A larger one takes query heads of one kv head a program, so
+    that one K / V block and one mask tile serve them all: as many heads of
+    the group as fit VMEM with a square score tile of 128-multiples no
+    larger than that rule's (G, bk, bq): of the tiles of that size the
+    compiler schedules the square's body closest to the MXU's floor, and
+    it leaves VMEM for several KV blocks a grid step (PERF.md §6, PR 40)."""
+    g = _rows_per_program(heads, bq, bk, d, itemsize, streaming, dv, masked)
+    if group == 1:
+        return g, bq, bk
+    tile = g * bq * bk
+    for f in (f for f in range(group, 1, -1) if group % f == 0):
+        side = math.isqrt(tile // f)
+        blocks = _pick_block(lq, side), _pick_block(lk, side)
+        if None not in blocks and _program_vmem_bytes(
+                f, *blocks, d, itemsize, lk > blocks[1], dv, masked,
+                1) <= _VMEM_BUDGET:
+            return (f,) + blocks
+    return 1, bq, bk
+
+
 def _causal_block(i, j, bq, bk):
     """``(live, cut)`` of Q block ``i`` against KV block ``j`` under the
     causal mask (query ``a`` sees key ``b`` where ``a >= b``, both counted
@@ -190,14 +223,19 @@ def _kv_block_fetched(i, j, bq, bk):
     return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
 
 
-def _kv_blocks_per_step(nk, g, bq, bk, d, itemsize, dv, masked=False):
+def _kv_blocks_per_step(nk, g, bq, bk, d, itemsize, dv, masked=False,
+                        kv_rows=None):
     """KV blocks one grid step of the streaming forward holds and walks: the
-    largest divisor of ``nk`` whose K / V blocks, double-buffered, fit what
-    :func:`_program_vmem_bytes` leaves of ``_VMEM_BUDGET`` at the ``g``
-    already chosen.  A grid step costs about half a microsecond whatever it
-    computes; the walk inside one costs nothing a block."""
-    used = _program_vmem_bytes(g, bq, bk, d, itemsize, True, dv, masked)
-    more = 2 * g * _padded_head_dims(d, dv, itemsize) * bk * itemsize \
+    largest divisor of ``nk`` whose K / V blocks (at ``kv_rows`` rows: one
+    where the ``g`` rows are query heads of one kv head) and mask tiles,
+    double-buffered, fit what :func:`_program_vmem_bytes` leaves of
+    ``_VMEM_BUDGET`` at the ``g`` already chosen.  A grid step costs about
+    half a microsecond whatever it computes; the walk inside one costs
+    nothing a block."""
+    kv_rows = g if kv_rows is None else kv_rows
+    used = _program_vmem_bytes(g, bq, bk, d, itemsize, True, dv, masked,
+                               kv_rows)
+    more = 2 * kv_rows * _padded_head_dims(d, dv, itemsize) * bk * itemsize \
         + (2 * bq * bk if masked else 0)
     return max([n for n in range(1, nk + 1) if nk % n == 0 and
                 used + (n - 1) * more <= _VMEM_BUDGET] or [1])
@@ -235,21 +273,30 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
     which here only says which blocks hold nothing); the ``bh`` rows are
     then whole batches of ``bh / batches`` heads, one mask a batch.  Every
     live block is masked by its tile of it and the kernel is
-    ``mxtpu_dsa_attn_fwd``."""
+    ``mxtpu_dsa_attn_fwd``.  K and V may have fewer rows than Q: each of
+    theirs then serves ``bh / rows`` consecutive query rows (grouped-query
+    attention), and a program's rows are query heads of one kv head, which
+    read its K / V block and the mask tile once (:func:`_forward_tiling`:
+    its own G and ``bq``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, lq, d = q.shape
     lk, dv = k.shape[1], v.shape[2]
-    nq, nk = lq // bq, lk // bk
+    group = bh // k.shape[0]
     masked = mask is not None
-    shape = (bq, bk, d, q.dtype.itemsize, nk > 1, dv) + \
-        ((True,) if masked else ())
+    itemsize = q.dtype.itemsize
     # from the rows this call sees: a chip's own, inside _per_batch_shard
     # (a program's rows share a mask tile: they are one batch's heads)
     heads = bh // mask.shape[0] if masked else bh
-    g = _rows_per_program(heads, *shape)
+    g, bq, bk = _forward_tiling(heads, group, lq, lk, bq, bk, d, itemsize,
+                                lk > bk, dv, masked)
+    kv_rows = g if group == 1 else 1
+    nq, nk = lq // bq, lk // bk
+    streaming = nk > 1
+    shape = (bq, bk, d, itemsize, streaming, dv, masked, kv_rows)
     _telem.set_gauge("flash.fwd.rows_per_program", g)
+    _telem.set_gauge("flash.fwd.heads_per_kv_block", g // kv_rows)
     live, cut_blocks = _forward_block_counts(lq, lk, bq, bk, causal)
     _telem.set_gauge("flash.fwd.blocks_live", live)
     _telem.set_gauge("flash.fwd.blocks_masked", cut_blocks)
@@ -257,8 +304,10 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
     # the streaming body: KV blocks a grid step walks, and the Q block's
     # columns (queries: independent of each other) in two halves where the
     # program has few rows to keep the MXU and the vector unit both busy
-    nsub = _kv_blocks_per_step(nk, g, bq, bk, d, q.dtype.itemsize, dv,
-                               *shape[6:]) if nk > 1 else 1
+    # (a program of query heads that share a K / V block gains nothing
+    # from halves of its heads: PERF.md §6, PR 40)
+    nsub = _kv_blocks_per_step(nk, g, *shape[:4], dv, masked,
+                               kv_rows) if streaming else 1
     _telem.set_gauge("flash.fwd.kv_blocks_per_step", nsub)
     halves = 2 if g <= 2 and bq % 256 == 0 else 1
     cols = [slice(c * bq // halves, (c + 1) * bq // halves)
@@ -287,19 +336,30 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
         # interpreter multiplies in float32: the same products, exactly
         return x.astype(jnp.float32) if interpret else x
 
+    def rows(kb, like):
+        # a kv head's block, shared by the query heads of `like`
+        if kb.shape[0] == like.shape[0]:
+            return kb
+        return jnp.broadcast_to(kb, like.shape[:1] + kb.shape[1:])
+
+    def selected(keep):
+        # the (bk, cols) int8 tile of the selection mask as a condition
+        return keep.astype(jnp.int32) != 0
+
     def scores(qb, kb, i, j, mask, col=0, keep=None):
         # of Q block i from its column `col` on against KV block j; `keep`:
-        # the (bk, cols) tile of the selection mask, in the iota's place
+        # the (bk, cols) tile of the selection mask as a condition, in the
+        # iota's place
         if scale_on_q:
             qb = qb * sm_scale
         # (g, d, bk) x (g, d, cols) over d -> (g, bk, cols): keys on sublanes
         s = lax.dot_general(
-            operand(kb), operand(qb), (((1,), (1,)), ((0,), (0,))),
+            operand(rows(kb, qb)), operand(qb), (((1,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         if not scale_on_q:
             s = s * sm_scale
         if keep is not None:
-            s = jnp.where((keep.astype(jnp.int32) != 0)[None], s, _NEG_INF)
+            s = jnp.where(keep[None], s, _NEG_INF)
         elif mask:
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, s.shape[1:], 0)
             qpos = i * bq + col + lax.broadcasted_iota(jnp.int32,
@@ -310,7 +370,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
     def p_dot_v(vb, p):
         # (g, dv, bk) x (g, bk, cols) -> (g, dv, cols)
         return lax.dot_general(
-            operand(vb), operand(p.astype(vb.dtype)),
+            operand(rows(vb, p)), operand(p.astype(vb.dtype)),
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
@@ -324,7 +384,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
         # all of Lk is in the block: nothing to stream, no running state
         o_ref, lse_ref = refs[-2:]
         s = scores(q_ref[...], k_ref[...], pl.program_id(1), 0, causal,
-                   keep=refs[0][0] if masked else None)
+                   keep=selected(refs[0][0]) if masked else None)
         m = jnp.max(s, axis=1, keepdims=True)   # (g, 1, bq)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)   # >= 1: the max's own term
@@ -346,10 +406,13 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
             def block(sub, carry):
                 ks = pl.ds(pl.multiple_of(sub * bk, bk), bk)
                 # every half's QK^T before the first's softmax: the MXU
-                # then has the next product to do under the vector unit
+                # then has the next product to do under the vector unit;
+                # a half's mask tile is widened and compared once for all
+                # of the program's heads
                 tiles = [scores(q_ref[:, :, cs], k_ref[:, :, ks], i,
                                 j * nsub + sub, mask, cs.start,
-                                refs[0][0, ks, cs] if masked else None)
+                                selected(refs[0][0, ks, cs]) if masked
+                                else None)
                          for cs in cols]
                 for cs, s in zip(cols, tiles):
                     m_old = m_i[:, :, cs]
@@ -394,10 +457,11 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
 
     def at_kv(b, i, j):
         # a skipped step asks for the row's last live K / V blocks again,
-        # which are in VMEM already: no copy is issued for it
+        # which are in VMEM already: no copy is issued for it; a program of
+        # query heads reads their kv head's
         if causal:
             j = _kv_block_fetched(i, j, bq, bk * nsub)
-        return (b, 0, j)
+        return (b if group == 1 else b * g // group, 0, j)
 
     # where one row at the caller's blocks does not fit the budget, mosaic's
     # limit is raised by what it is over (nsub is 1 there)
@@ -411,8 +475,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
         grid=(bh // g, nq, nk // nsub),
         in_specs=[
             pl.BlockSpec((g, d, bq), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((g, d, bk * nsub), at_kv),
-            pl.BlockSpec((g, dv, bk * nsub), at_kv),
+            pl.BlockSpec((kv_rows, d, bk * nsub), at_kv),
+            pl.BlockSpec((kv_rows, dv, bk * nsub), at_kv),
         ] + mask_spec,
         out_specs=[
             pl.BlockSpec((g, dv, bq), lambda b, i, j: (b, 0, i)),
@@ -756,7 +820,7 @@ def _use_pallas(lq, lk, d, dv=None):
     return bq, bk
 
 
-def _dp_mesh(q):
+def _dp_mesh(q, *kv):
     """The mesh whose data axis this call's kernels are split over by hand,
     or None: set when the call is being traced into a step that XLA
     partitions over that axis by itself (the trainer's plain dp path: a jit
@@ -767,7 +831,10 @@ def _dp_mesh(q):
     there each chip runs the kernel on its own (batch x heads) rows.  The
     step says how it is split through the ambient mesh
     (``parallel.mesh_scope``); a step that is already inside a shard_map
-    (ZeRO-1) masks it with ``mesh_scope(None)``.  A mesh with a second axis
+    (ZeRO-1) masks it with ``mesh_scope(None)``.  The split keeps a chip's
+    query rows with the kv rows they read where the leading dimensions of
+    q and of ``kv`` (fewer rows: grouped-query attention) all divide by
+    the data axis.  A mesh with a second axis
     of size > 1 is left to fail as before: only the leading dimension is
     known to be splittable.  Read once, where the op is called: the scope
     covers the step's forward trace, and the backward, traced after it has
@@ -777,7 +844,7 @@ def _dp_mesh(q):
     if mesh is None or not isinstance(q, jax.core.Tracer):
         return None
     n = mesh.shape.get(AXIS_DP, 1)
-    if n == 1 or n != mesh.size or q.shape[0] % n:
+    if n == 1 or n != mesh.size or any(x.shape[0] % n for x in (q,) + kv):
         return None
     return mesh
 
@@ -826,8 +893,28 @@ def _forward(q, k, v, mask, causal, sm_scale, mesh):
                     q, k, v, mask)
     else:
         bk = _pick_block(k.shape[1], 256) or k.shape[1]
-        out, lse = _scan_forward(q, k, v, causal, sm_scale, bk, mask)
+        group = q.shape[0] // k.shape[0]
+        out, lse = _scan_forward(q, jnp.repeat(k, group, axis=0),
+                                 jnp.repeat(v, group, axis=0), causal,
+                                 sm_scale, bk, mask)
     return out, (q, k, v, out, lse, mask)
+
+
+def _at_query_rows(backward, group):
+    """``backward`` (q, k, v, ...) -> (dq, dk, dv) at as many K / V rows as
+    query rows, taking K / V at ``1 / group`` of them: each kv row repeated
+    to the ``group`` query rows it serves, dK / dV summed back by the
+    repeat's own transpose."""
+    if group == 1:
+        return backward
+
+    def grouped(q, k, v, *rest):
+        kv, summed = jax.vjp(lambda k, v: (jnp.repeat(k, group, axis=0),
+                                           jnp.repeat(v, group, axis=0)),
+                             k, v)
+        dq, dk, dv = backward(q, *kv, *rest)
+        return (dq,) + summed((dk, dv))
+    return grouped
 
 
 def _flash_bwd(causal, sm_scale, mesh, res, do):
@@ -845,17 +932,22 @@ def _flash_bwd(causal, sm_scale, mesh, res, do):
     # counted while tracing, as the forward's
     name = "flash.bwd" if mask is None else "dsa.attn.bwd"
     _telem.inc(name + (".scan" if blocks is None else ".pallas"))
+    # the backward takes K / V at the query rows (a G = 1 program holds a
+    # row's float32 dQ: the query heads of a kv head do not share one)
+    group = q.shape[0] // k.shape[0]
     if blocks is None:
         bk = _pick_block(lk, 256) or lk
-        return _scan_backward(q, k, v, out, lse, do, causal, sm_scale, bk,
-                              mask)
+        return _at_query_rows(functools.partial(
+            _scan_backward, causal=causal, sm_scale=sm_scale, bk=bk,
+            mask=mask), group)(q, k, v, out, lse, do)
     kernel = functools.partial(
         _pallas_backward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
         bk=blocks[1], interpret=kernel_mode() == "interpret")
     if mask is None:
-        return _per_batch_shard(kernel, mesh)(q, k, v, out, lse, do)
-    return _per_batch_shard(
-        lambda *a: kernel(*a[:6], mask=a[6]), mesh)(
+        return _per_batch_shard(_at_query_rows(kernel, group), mesh)(
+            q, k, v, out, lse, do)
+    return _per_batch_shard(_at_query_rows(
+        lambda *a: kernel(*a[:6], mask=a[6]), group), mesh)(
             q, k, v, out, lse, do, mask)
 
 
@@ -882,12 +974,15 @@ _masked_flash_on.defvjp(_masked_flash_fwd, _masked_flash_bwd)
 
 def masked_flash(q, k, v, mask, sm_scale):
     """``(out, lse)`` of attention over a per-query selection of the causal
-    past: q, k, v (rows, L, d) with the rows whole batches of heads, mask
-    (batches, L, L) int8, keys first, causality included
+    past: q (B·H, L, d) and k, v (B·Hkv, L, d), each the rows of whole
+    batches of heads, query head ``a`` of a batch reading its kv head
+    ``a // (H / Hkv)`` in place (grouped-query attention; ``Hkv = H`` is
+    plain attention), mask (B, L, L) int8, keys first, causality included
     (:func:`_pallas_forward`).  Differentiable in q, k, v by the kernels
-    :func:`flash_attention` has; the log-sum-exp (rows, L) comes without a
-    gradient (the index loss reads it detached)."""
-    return _masked_flash_on(q, k, v, mask, sm_scale, _dp_mesh(q))
+    :func:`flash_attention` has, dK / dV summed over each kv head's query
+    heads; the log-sum-exp (B·H, L) comes without a gradient (the index
+    loss reads it detached)."""
+    return _masked_flash_on(q, k, v, mask, sm_scale, _dp_mesh(q, k))
 
 
 def flash_attention(query, key, value, causal=False, sm_scale=None):

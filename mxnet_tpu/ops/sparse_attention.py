@@ -51,9 +51,12 @@ in float32; they are also the tests' reference):
   times that kernel's products); that is the price of one path — the op
   cannot see whether it is being recomputed, and a flag would be a knob.
 
-K and V are repeated to the query heads before the kernels, as ``llama.py``
-does (gauge ``gqa.kv_repeat``); reading the kv heads in place is a later
-optimisation.
+The kernels read K and V at their own ``Hkv`` heads (gauge
+``gqa.kv_repeat``: the ``H / Hkv`` query heads each kv head serves).  A
+forward program takes query heads of one kv head, which share its K / V
+block and the mask tile (gauge ``flash.fwd.heads_per_kv_block``); the
+backward, a head a program, repeats K / V to the query heads inside its
+rule and sums dK / dV back (``ops/flash_attention.py``).
 """
 from __future__ import annotations
 
@@ -665,10 +668,9 @@ def sparse_gq_attention(q, k, v, qi, ki, w, topk, sm_scale=None,
     with jax.named_scope("dsa.index"), jax.named_scope("dsa.select"):
         mask, _, lse_i = _index_select(qi, ki, w, topk, scale)
     with jax.named_scope("dsa.attention"):
-        rows = (b * h, seq, d)
         out, lse = masked_flash(
-            q.reshape(rows), jnp.repeat(k, h // hkv, axis=1).reshape(rows),
-            jnp.repeat(v, h // hkv, axis=1).reshape(rows), mask, sm_scale)
+            q.reshape(b * h, seq, d), k.reshape(b * hkv, seq, d),
+            v.reshape(b * hkv, seq, d), mask, sm_scale)
     with jax.named_scope("dsa.index_loss"):
         loss = _index_loss_sum(q, k, lse.reshape(b, h, seq), qi, ki, w, mask,
                                lse_i, sm_scale, scale) / seq

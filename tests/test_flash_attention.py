@@ -395,6 +395,73 @@ def test_kv_blocks_per_step_fill_what_the_rows_leave_of_vmem():
     assert mod._kv_blocks_per_step(4, 1, 2048, 2048, 128, 2, 128) == 1
 
 
+@pytest.mark.parametrize("bh,seq,d,dv,want", [
+    # (G, bq, bk, KV blocks a grid step) as the parent (PR 39) chose them
+    pytest.param(1536, 128, 64, 64, (32, 128, 128, 1), id="bert-s128"),
+    pytest.param(384, 512, 64, 64, (4, 512, 512, 1), id="bert-s512"),
+    pytest.param(64, 4096, 192, 128, (2, 512, 512, 4), id="kanana-mla"),
+    pytest.param(32, 8192, 192, 128, (2, 512, 512, 4), id="kimi-mla"),
+])
+def test_a_group_of_one_keeps_the_parents_program(bh, seq, d, dv, want):
+    """Where every row has its own K / V (every call but grouped-query
+    attention) the rule gives the forward program it gave before K / V
+    could be read in place: the same G, blocks and KV blocks a step."""
+    mod = _flash_module()
+    block = mod._pick_block(seq)               # what _use_pallas gives
+    g, bq, bk = mod._forward_tiling(bh, 1, seq, seq, block, block, d, 2,
+                                    seq > block, dv)
+    nsub = mod._kv_blocks_per_step(seq // bk, g, bq, bk, d, 2, dv) \
+        if seq > bk else 1
+    assert (g, bq, bk, nsub) == want
+
+
+def test_the_keye_query_heads_share_a_kv_block_within_the_budget():
+    """The keye cell's masked forward (32 query heads over 4 kv heads,
+    L = 16384, d = 128): a program takes the 8 query heads of one kv head
+    at square 256-blocks — the parent's score tile of 2 x 512 x 512
+    elements — and walks 8 KV blocks a grid step, K / V and the mask tile
+    counted once; all of it within the budget."""
+    mod = _flash_module()
+    args = (16384, 16384, 512, 512, 128, 2, True, 128, True)
+    assert mod._forward_tiling(32, 1, *args) == (2, 512, 512)
+    g, bq, bk = mod._forward_tiling(32, 8, *args)
+    assert (g, bq, bk) == (8, 256, 256)
+    assert g * bq * bk == 2 * 512 * 512
+    nsub = mod._kv_blocks_per_step(16384 // bk, g, bq, bk, 128, 2, 128,
+                                   True, 1)
+    assert nsub == 8
+    used = mod._program_vmem_bytes(g, bq, bk, 128, 2, True, 128, True, 1)
+    more = 2 * mod._padded_head_dims(128, 128, 2) * bk * 2 + 2 * bq * bk
+    assert used + (nsub - 1) * more <= mod._VMEM_BUDGET
+    # the K / V blocks are one row's, not G rows'
+    assert used < mod._program_vmem_bytes(g, bq, bk, 128, 2, True, 128,
+                                          True)
+    # four heads a kv head: four a program
+    assert mod._forward_tiling(32, 4, *args)[0] == 4
+
+
+def test_heads_per_kv_block_gauge_reads_the_group_where_one_is_read():
+    """Set while tracing: 1 for a BERT-shaped call, the group for a masked
+    call whose K / V are at their own heads (the keye shape)."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.kernel_mode import interpret_kernels
+    mod = _flash_module()
+
+    def rows(n, seq, d):
+        return jax.ShapeDtypeStruct((n, seq, d), jnp.bfloat16)
+    with interpret_kernels():
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v), *(
+            jax.ShapeDtypeStruct((128, 12, 128, 64), jnp.bfloat16),) * 3)
+        assert telemetry.value("flash.fwd.heads_per_kv_block") == 1
+        jax.eval_shape(lambda q, k, v, m: mod.masked_flash(
+            q, k, v, m, 128 ** -0.5), rows(32, 16384, 128),
+            rows(4, 16384, 128), rows(4, 16384, 128),
+            jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8))
+        assert telemetry.value("flash.fwd.heads_per_kv_block") == 8
+        assert telemetry.value("flash.fwd.rows_per_program") == 8
+        assert telemetry.value("flash.fwd.kv_blocks_per_step") == 8
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("seq,causal", [(256, False), (512, False),
                                         (768, True)])

@@ -95,13 +95,17 @@ def test_grouped_product_and_both_backward_kernels_at_the_expert_widths(
         assert name in text
 
 
-@pytest.mark.parametrize("part", ["index_select", "attention", "align_loss"])
+@pytest.mark.parametrize("part", ["index_select", "attention",
+                                  "attention-repeated", "align_loss"])
 def test_sparse_attention_kernels_at_the_keye_shape(one_chip, part):
     """The keye cell's four kernels at its own shapes (one sequence of
     16384, 32 / 4 heads of 128, 16 index heads of 64, top-2048): the
     index / select kernel with its (L, 128) scratch of keys, the masked
     streaming flash forward and backward (a row's float32 dQ is 8 MiB:
-    Mosaic's limit is raised), and the alignment loss's value kernel and
+    Mosaic's limit is raised) with K / V at their 4 heads as the op hands
+    them on (the forward's programs of 8 query heads, 256-blocks, 8 KV
+    blocks a grid step) and repeated to the 32 (a group of 1: the parent's
+    program), and the alignment loss's value kernel and
     gradient kernel (the latter with its 4 MiB scratch and the resident
     ``dki`` row): one Mosaic call each, by name, and no scan left."""
     from mxnet_tpu.ops import sparse_attention as sa
@@ -117,13 +121,13 @@ def test_sparse_attention_kernels_at_the_keye_shape(one_chip, part):
         text = _compile(lambda qi, ki, w: sa._index_select(
             qi, ki, w, topk, 1 / 32), *indexer)
         names = ["mxtpu_dsa_index_select"]
-    elif part == "attention":
+    elif part.startswith("attention"):
         def both(q, k, v, mask):
             return jax.grad(lambda q, k, v: jnp.sum(masked_flash(
                 q, k, v, mask, d ** -0.5)[0].astype(f32)),
                 argnums=(0, 1, 2))(q, k, v)
-        rows = shape((b * h, seq, d))
-        text = _compile(both, rows, rows, rows, mask)
+        kv = shape((b * (h if part.endswith("repeated") else hkv), seq, d))
+        text = _compile(both, shape((b * h, seq, d)), kv, kv, mask)
         names = ["mxtpu_dsa_attn_fwd", "mxtpu_dsa_attn_bwd"]
     else:
         def grads(q, k, lse, qi, ki, w, mask, lse_i):

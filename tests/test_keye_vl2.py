@@ -265,6 +265,8 @@ def test_trains_through_the_fused_step_under_amp_with_the_kernels(bf16):
         assert not telemetry.value(name)
     assert telemetry.value("dsa.topk") == 32
     assert telemetry.value("gqa.kv_repeat") == 2
+    # the forward's programs take both query heads of the one kv head
+    assert telemetry.value("flash.fwd.heads_per_kv_block") == 2
     assert telemetry.value("moe.layers") >= 1
 
 

@@ -1,6 +1,8 @@
 """``ops/sparse_attention.py``: the exact k-th largest against ``lax.top_k``,
 the selection with ties and short pasts, and each Pallas kernel (in the
 interpreter) against its XLA form and against a dense masked softmax."""
+import importlib
+
 import numpy as np
 import pytest
 
@@ -289,6 +291,112 @@ def test_masked_flash_rows_share_their_batch_mask():
     np.testing.assert_allclose(
         lse, jax.nn.logsumexp(jnp.where(sel, logits, -jnp.inf), axis=-1),
         rtol=1e-5, atol=1e-5)
+
+
+def _two_masks(seed, b, seq):
+    """(B, L, L) selections, queries first, each batch its own (the
+    diagonal kept), and the same keys first as the kernels take them."""
+    rng = np.random.RandomState(seed)
+    keep = np.tril(rng.rand(b, seq, seq) < 0.3) | np.eye(seq, dtype=bool)
+    return keep, jnp.asarray(keep.transpose(0, 2, 1), jnp.int8)
+
+
+def _grouped_dense(q, k, v, keep, group, d):
+    """out and lse of a masked softmax, query row ``r`` reading kv row
+    ``r // group`` (the rows batch-major, a batch's heads together)."""
+    kr, vr = (jnp.repeat(a, group, axis=0) for a in (k, v))
+    sel = jnp.repeat(jnp.asarray(keep), q.shape[0] // keep.shape[0], axis=0)
+    logits = jnp.where(sel, jnp.einsum("rqd,rkd->rqk", q, kr) * d ** -0.5,
+                       -jnp.inf)
+    p = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("rqk,rkd->rqd", p, vr), jax.nn.logsumexp(logits, -1)
+
+
+@pytest.mark.parametrize("group", [8, 4, 1])
+def test_masked_flash_reads_each_kv_head_in_place(group):
+    """Grouped-query attention with K / V at their own heads — a forward
+    program of the query heads of one kv head, one K / V block and one mask
+    tile for them all — against the same call with K / V repeated to the
+    query heads and against a dense masked softmax: out, the log-sum-exp
+    and the gradients of q, k, v (dK / dV summed over a kv head's query
+    heads).  Two batches with masks of their own; at L = 512 a group of 8
+    or 4 streams two square 256-blocks, a group of 1 (the repeated form's
+    own program) takes one 512-block."""
+    b, h, seq, d = 2, 8, 512, 64
+    hkv = h // group
+    q = _rand(40, b * h, seq, d)
+    k, v = _rand(41, b * hkv, seq, d), _rand(42, b * hkv, seq, d)
+    keep, mask = _two_masks(43, b, seq)
+    weight = jnp.cos(jnp.arange(seq * d, dtype=jnp.float32)).reshape(seq, d)
+
+    def run(kv_rows):
+        def f(q, k, v):
+            out, lse = masked_flash(q, kv_rows(k), kv_rows(v), mask,
+                                    d ** -0.5)
+            return jnp.sum(out * weight), (out, lse)
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    telemetry.reset()
+    with interpret_kernels():
+        grads, (out, lse) = run(lambda x: x)
+        assert telemetry.value("flash.fwd.heads_per_kv_block") == group
+        rep_grads, (rep_out, rep_lse) = run(
+            lambda x: jnp.repeat(x, group, axis=0))
+        assert telemetry.value("flash.fwd.heads_per_kv_block") == 1
+    want_out, want_lse = _grouped_dense(q, k, v, keep, group, d)
+    for got in (out, rep_out):
+        np.testing.assert_allclose(got, want_out, atol=2e-5)
+    for got in (lse, rep_lse):
+        np.testing.assert_allclose(got, want_lse, rtol=1e-5, atol=1e-5)
+    want_grads = jax.grad(lambda q, k, v: jnp.sum(
+        _grouped_dense(q, k, v, keep, group, d)[0] * weight),
+        argnums=(0, 1, 2))(q, k, v)
+    for name, got, rep, want in zip("qkv", grads, rep_grads, want_grads):
+        assert got.shape == want.shape, name
+        np.testing.assert_allclose(got, rep, atol=2e-5, err_msg=name)
+        np.testing.assert_allclose(got, want, atol=1e-4, err_msg=name)
+
+
+def test_a_chip_keeps_its_query_heads_with_their_kv_heads():
+    """Under an ambient dp mesh the kernels go inside a shard_map over the
+    leading dimension of q (B·H rows), of k / v (B·Hkv) and of the mask (B):
+    a chip's query rows are whole batches, so the kv rows they read are the
+    chip's own.  The split result is the unsplit one; where the kv rows do
+    not divide by the data axis nothing is split."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu.parallel import make_mesh, mesh_scope
+    fa = importlib.import_module("mxnet_tpu.ops.flash_attention")
+    b, h, hkv, seq, d = 2, 8, 2, 256, 64
+    q = _rand(50, b * h, seq, d)
+    k, v = _rand(51, b * hkv, seq, d), _rand(52, b * hkv, seq, d)
+    _, mask = _two_masks(53, b, seq)
+    mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+
+    def grads(scope):
+        def loss(q, k, v):
+            with mesh_scope(scope):
+                out, _ = masked_flash(q, k, v, mask, d ** -0.5)
+            return jnp.sum(out ** 2), out
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)
+
+    with interpret_kernels():
+        split = str(jax.make_jaxpr(grads(mesh))(q, k, v))
+        sharded = [jax.device_put(a, NamedSharding(mesh, P("dp")))
+                   for a in (q, k, v)]
+        got, out = jax.jit(grads(mesh))(*sharded)
+        want, want_out = jax.jit(grads(None))(q, k, v)
+    assert split.count("shard_map") == 2
+    np.testing.assert_allclose(out, want_out, atol=1e-6)
+    for a, c in zip(got, want):
+        np.testing.assert_allclose(a, c, atol=1e-5)
+    four, seen = make_mesh({"dp": 4}, devices=jax.devices()[:4]), []
+
+    def probe(q, k):
+        seen.extend([fa._dp_mesh(q), fa._dp_mesh(q, k)])
+        return q
+    with mesh_scope(four):
+        jax.make_jaxpr(probe)(q, k[:2])
+    assert seen == [four, None]         # 16 query rows, 2 kv rows, by 4
 
 
 def test_selected_share_at_the_cell_shape():
