@@ -25,6 +25,9 @@ TARGET_DTYPE_OPS = [
     # and v into the products; the log-decay, beta, every decay factor, the
     # triangular inverse and the carried state stay float32 inside the op
     "kda_attention",
+    # block-diffusion attention: the flash kernels under block rules; the
+    # rotary angles and each query's own noisy block stay float32 inside
+    "block_diffusion_attention",
 ]
 
 # the reference's fp32 blacklist: softmax family, norms, losses, exp/log/pow

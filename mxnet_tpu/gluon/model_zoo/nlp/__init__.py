@@ -14,9 +14,10 @@ from .llama import *  # noqa: F401,F403
 from .deepseek_v3 import *  # noqa: F401,F403
 from .keye_vl2 import *  # noqa: F401,F403
 from .kimi_linear import *  # noqa: F401,F403
+from .sdar_moe import *  # noqa: F401,F403
 
 from . import attention, bert, transformer, language_model, sampler, \
-    llama, deepseek_v3, keye_vl2, kimi_linear  # noqa
+    llama, deepseek_v3, keye_vl2, kimi_linear, sdar_moe  # noqa
 
 _MODELS = {}
 for _m in (bert, transformer, language_model):

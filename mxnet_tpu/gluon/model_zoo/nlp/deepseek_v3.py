@@ -197,7 +197,8 @@ class MoEBlock(HybridBlock):
             norm_topk_prob=cfg.norm_topk_prob,
             scoring_func=self.scoring_func)
         out = F.moe_experts(x, experts, weights, experts_gate, experts_up,
-                            experts_down, expert_offset=cfg.expert_offset)
+                            experts_down, expert_offset=cfg.expert_offset,
+                            fixed_rows=getattr(cfg, "moe_fixed_rows", None))
         if self.shared_experts is None:
             return out
         with jax.named_scope("moe.shared"):

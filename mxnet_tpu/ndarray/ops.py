@@ -3391,19 +3391,21 @@ def moe_router(data, weight, bias=None, top_k=1, routed_scaling_factor=1.0,
 
 @_register
 def moe_experts(data, experts, weights, w_gate, w_up, w_down,
-                expert_offset=0):
+                expert_offset=0, fixed_rows=None):
     """The held experts' part of a dropless SwiGLU expert layer
     (``parallel.moe.dropless_moe_apply``).  data: (..., d); experts, weights:
     (..., k) from ``moe_router`` (``amp`` leaves both as they arrive:
     ``lists.KEEP_DTYPE_ARGS``); w_gate, w_up: (held, d, h); w_down:
-    (held, h, d), the experts ``expert_offset .. expert_offset + held``."""
+    (held, h, d), the experts ``expert_offset .. expert_offset + held``;
+    ``fixed_rows`` a fixed amount of work (``dropless_moe_apply``'s)."""
     from ..parallel.moe import dropless_moe_apply
 
     def fn(d, e, g, wg, wu, wd):
         k = e.shape[-1]
         out = dropless_moe_apply(
             d.reshape(-1, d.shape[-1]), e.reshape(-1, k).astype(jnp.int32),
-            g.reshape(-1, k), wg, wu, wd, expert_offset=expert_offset)
+            g.reshape(-1, k), wg, wu, wd, expert_offset=expert_offset,
+            fixed_rows=fixed_rows)
         return out.reshape(d.shape)
     return apply_nary(fn, [data, experts, weights, w_gate, w_up, w_down],
                       name="moe_experts")
@@ -3508,6 +3510,50 @@ def sparse_gq_attention(q, k, v, q_index, k_index, x_index, w_index,
         fn, [q, k, v, q_index, k_index, x_index, w_index]
         + ([] if positions is None else [positions]), n_out=2,
         name="sparse_gq_attention")
+
+
+@_register
+def block_diffusion_attention(q, k, v, num_heads=1, block_length=4,
+                              rope_theta=10000.0):
+    """Grouped-query attention of a block-diffusion training sequence, the
+    noisy copy beside the clean one (``ops.flash_attention
+    .block_diffusion_attention``: no (2T)^2 mask, the flash kernels under
+    block rules, dead tiles skipped).
+
+    q: (B, 2T, H * d) with ``H = num_heads``; k, v: (B, 2T, Hkv * d), per-head
+    norms applied, not yet rotated; positions 0 .. T - 1 the noisy half,
+    T .. 2T - 1 the clean one.  Both halves take the positions 0 .. T - 1
+    for the rotary embedding (half-split pairs over all of ``d``).  A clean
+    query sees the clean keys of its block and the blocks before it; a
+    noisy query the clean keys of the blocks before its own and the noisy
+    keys of its own block (``block_length`` tokens a block).  Returns (B,
+    2T, H * d)."""
+    from ..ops.flash_attention import block_diffusion_attention as _bd
+    from ..ops.norm_rope import rope_half_split, sectioned_angles
+    from .. import telemetry as _telem
+    h = num_heads
+
+    def fn(qd, kd, vd):
+        b, t2 = qd.shape[0], qd.shape[1]
+        d = qd.shape[2] // h
+        hkv = kd.shape[2] // d
+        _telem.inc("bd.layers")
+        with jax.named_scope("gqa.project"):
+            ang = sectioned_angles(jnp.arange(t2, dtype=jnp.int32) % (t2 // 2),
+                                   d, rope_theta)
+
+            def heads(a, n, rotate=True):       # (B, 2T, n d) -> (B n, 2T, d)
+                a = a.reshape(b, t2, n, d).transpose(0, 2, 1, 3)
+                if rotate:
+                    a = rope_half_split(a.astype(jnp.float32), jnp.cos(ang),
+                                        jnp.sin(ang)).astype(a.dtype)
+                return a.reshape(b * n, t2, d)
+            query, key = heads(qd, h), heads(kd, hkv)
+            value = heads(vd, hkv, rotate=False)
+        out = _bd(query, key, value, block_length, d ** -0.5)
+        return out.reshape(b, h, t2, d).transpose(0, 2, 1, 3).reshape(
+            b, t2, h * d)
+    return apply_nary(fn, [q, k, v], name="block_diffusion_attention")
 
 
 # ======================================================================

@@ -32,7 +32,11 @@ indices (:func:`_causal_extent`), as the backward does: a block that is
 wholly masked is skipped, and a grid step that holds only such blocks asks
 for the row's last live K / V blocks again, so nothing is fetched for it; a
 block the diagonal cuts is masked; a wholly visible one runs without the
-iota, the compare and the select.  Where ``sm_scale`` is a power of two and
+iota, the compare and the select.  ``causal`` may also be a block rule
+``(beta, offset)``, query ``a`` seeing key ``b`` where ``a // beta >= b //
+beta + offset`` (:func:`_sees`): the same walk, skips and fetches, the
+kernels named ``mxtpu_bd_attn_*``; :func:`block_diffusion_attention` is
+built from two such calls.  Where ``sm_scale`` is a power of two and
 the Q block a quarter of the score tile or less it multiplies the
 (G, D, BQ) block of Q and not the (G, BK, BQ) scores: the same numbers, bit
 for bit, from fewer elements.  The fallback is the same algorithm as a
@@ -69,7 +73,7 @@ from jax.sharding import PartitionSpec as P
 from .. import telemetry as _telem
 from .kernel_mode import kernel_mode
 
-__all__ = ["flash_attention", "masked_flash"]
+__all__ = ["flash_attention", "masked_flash", "block_diffusion_attention"]
 
 _NEG_INF = -1e30
 
@@ -193,34 +197,75 @@ def _forward_tiling(heads, group, lq, lk, bq, bk, d, itemsize, streaming,
     return 1, bq, bk
 
 
-def _causal_block(i, j, bq, bk):
+def _plus(x, n):
+    """``x + n``, and ``x`` itself where ``n`` is 0: the token-causal
+    kernels' index arithmetic stays what it was, operation for operation."""
+    return x + n if n else x
+
+
+def _rule(causal):
+    """``(beta, offset)`` of a ``causal`` argument: ``True`` is token-causal,
+    ``(1, 0)``; a pair is the block rule itself (:func:`_sees`)."""
+    return (1, 0) if causal is True else tuple(causal)
+
+
+def _sees(qpos, kpos, beta=1, offset=0):
+    """Query position ``qpos`` sees key position ``kpos`` under the block
+    rule: ``qpos // beta >= kpos // beta + offset`` (``beta`` a power of
+    two).  ``(1, 0)`` is the causal mask, ``qpos >= kpos``; ``(beta, 0)``
+    block-causal, every query seeing its whole block; ``(beta, 1)`` the
+    blocks before its own only, so the first block's queries see nothing."""
+    if beta > 1:
+        shift = beta.bit_length() - 1
+        qpos, kpos = qpos >> shift, kpos >> shift
+    return qpos >= _plus(kpos, offset)
+
+
+def _causal_block(i, j, bq, bk, beta=1, offset=0):
     """``(live, cut)`` of Q block ``i`` against KV block ``j`` under the
-    causal mask (query ``a`` sees key ``b`` where ``a >= b``, both counted
-    from 0): live unless the block's first key comes after its last query,
-    cut where its last key comes after its first query.  Live and not cut
-    is wholly visible.  Grid indices in the backward kernel, plain ints in
-    the counts and the tests."""
-    return (i + 1) * bq > j * bk, (j + 1) * bk - 1 > i * bq
+    block rule :func:`_sees` (``beta`` divides ``bq`` and ``bk``; the
+    causal mask where ``beta`` is 1 and ``offset`` 0: query ``a`` sees key
+    ``b`` where ``a >= b``, both counted from 0): live unless the block's
+    first key comes after what its last query sees, cut where its last key
+    comes after what its first query sees.  Live and not cut is wholly
+    visible.  Grid indices in the backward kernel, plain ints in the counts
+    and the tests."""
+    bq, bk = bq // beta, bk // beta     # in blocks of the rule's length
+    return (i + 1) * bq > _plus(j * bk, offset), \
+        _plus((j + 1) * bk - 1, offset) > i * bq
 
 
-def _causal_extent(i, bq, bk):
+def _causal_extent(i, bq, bk, beta=1, offset=0):
     """``(visible, live)``: :func:`_causal_block` as counts, for the
     forward's inner walk — how many of the KV blocks, from the first on, Q
     block ``i`` sees whole and how many it sees any of (``live`` may pass
-    the last block there is, where Lq is longer than Lk); the blocks between
-    the two are the ones the diagonal cuts.  (Not one written from the
-    other: the divisions cost the backward, which asks once a grid step,
-    0.9 % of its time on the chip.)"""
-    return (i * bq + 1) // bk, ((i + 1) * bq + bk - 1) // bk
+    the last block there is, where Lq is longer than Lk; ``visible`` may be
+    under 0 where the offset hides a whole Q block); the blocks between the
+    two are the ones the diagonal cuts.  (Not one written from the other:
+    the divisions cost the backward, which asks once a grid step, 0.9 % of
+    its time on the chip.)"""
+    bq, bk = bq // beta, bk // beta
+    return _plus(i * bq + 1, -offset) // bk, \
+        _plus((i + 1) * bq + bk - 1, -offset) // bk
 
 
-def _kv_block_fetched(i, j, bq, bk):
+def _kv_block_fetched(i, j, bq, bk, beta=1, offset=0):
     """The K / V block grid step ``(i, j)`` of a causal forward names: its
     own while it is live, then the one that holds the last key Q block ``i``
     sees, which is the row's last live one and in VMEM already, so that no
-    copy is issued for a skipped step.  ``bk`` is what one grid step holds
-    of Lk."""
-    return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+    copy is issued for a skipped step (block 0 where the block sees no key
+    at all).  ``bk`` is what one grid step holds of Lk."""
+    if (beta, offset) == (1, 0):
+        last = (i + 1) * bq - 1
+    else:
+        last = jnp.maximum(((i + 1) * (bq // beta) - offset) * beta - 1, 0)
+    return jnp.minimum(j, last // bk)
+
+
+def _first_live_q_block(j, bq, bk, beta=1, offset=0):
+    """The first Q block that sees any key of KV block ``j``: what the
+    backward's skipped steps name on the Q side."""
+    return _plus(j * (bk // beta), offset) // (bq // beta)
 
 
 def _kv_blocks_per_step(nk, g, bq, bk, d, itemsize, dv, masked=False,
@@ -254,15 +299,26 @@ def _scale_on_q(sm_scale, d, bk):
 def _forward_block_counts(lq, lk, bq, bk, causal):
     """``(live, masked)``: of the ``nq * nk`` (Q block, KV block) pairs of
     one (batch x head) row, those the forward kernel computes and those it
-    masks.  The one-pass body (``nk == 1``) masks every block of a causal
-    call; the streaming one only those the diagonal cuts."""
+    masks, under ``causal``'s rule (:func:`_rule`).  The one-pass body
+    (``nk == 1``) masks every block of a causal call; the streaming one only
+    those the diagonal cuts."""
     nq, nk = lq // bq, lk // bk
     if not causal:
         return nq * nk, 0
-    pairs = [_causal_block(i, j, bq, bk)
+    pairs = [_causal_block(i, j, bq, bk, *_rule(causal))
              for i in range(nq) for j in range(nk)]
     live = sum(lv for lv, _ in pairs)
     return live, live if nk == 1 else sum(lv and ct for lv, ct in pairs)
+
+
+def _kernel_name(direction, masked, rule):
+    """What a kernel is called in a trace: ``mxtpu_dsa_attn_*`` under a
+    selection mask, ``mxtpu_bd_attn_*`` under a block rule other than the
+    causal one, ``mxtpu_flash_*`` otherwise."""
+    if masked:
+        return "mxtpu_dsa_attn_" + direction
+    return ("mxtpu_flash_" if rule == (1, 0) else "mxtpu_bd_attn_") \
+        + direction
 
 
 def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
@@ -277,7 +333,10 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
     theirs then serves ``bh / rows`` consecutive query rows (grouped-query
     attention), and a program's rows are query heads of one kv head, which
     read its K / V block and the mask tile once (:func:`_forward_tiling`:
-    its own G and ``bq``)."""
+    its own G and ``bq``).  ``causal``: False, True, or a block rule
+    ``(beta, offset)`` (:func:`_sees`), whose kernel is
+    ``mxtpu_bd_attn_fwd``; under an offset a query that sees no key gives
+    an output of 0 and a log-sum-exp of -inf."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -285,6 +344,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
     lk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
     masked = mask is not None
+    rule = _rule(causal) if causal else (1, 0)
     itemsize = q.dtype.itemsize
     # from the rows this call sees: a chip's own, inside _per_batch_shard
     # (a program's rows share a mask tile: they are one batch's heads)
@@ -364,7 +424,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, s.shape[1:], 0)
             qpos = i * bq + col + lax.broadcasted_iota(jnp.int32,
                                                        s.shape[1:], 1)
-            s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
+            s = jnp.where(_sees(qpos, kpos, *rule)[None], s, _NEG_INF)
         return s
 
     def p_dot_v(vb, p):
@@ -373,6 +433,18 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
             operand(rows(vb, p)), operand(p.astype(vb.dtype)),
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
+
+    def finish(o_ref, lse_ref, o, lse, m):
+        # o (g, dv, bq) float32; lse and m give (g, 1, bq) when called, in
+        # the order the stores had before there were offsets.  Under an
+        # offset a query whose rule hides every key kept m's -1e30
+        if not rule[1]:
+            o_ref[...] = o.astype(o_ref.dtype)
+            store_lse(lse_ref, lse())
+            return
+        seen = m() > _NEG_INF
+        o_ref[...] = jnp.where(seen, o, 0.0).astype(o_ref.dtype)
+        store_lse(lse_ref, jnp.where(seen, lse(), -jnp.inf))
 
     def store_lse(lse_ref, lse):                # lse (g, 1, bq)
         if lse_rows:
@@ -388,8 +460,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
         m = jnp.max(s, axis=1, keepdims=True)   # (g, 1, bq)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=1, keepdims=True)   # >= 1: the max's own term
-        o_ref[...] = (p_dot_v(v_ref[...], p) / l).astype(o_ref.dtype)
-        store_lse(lse_ref, m + jnp.log(l))
+        finish(o_ref, lse_ref, p_dot_v(v_ref[...], p) / l,
+               lambda: m + jnp.log(l), lambda: m)
 
     def streaming(q_ref, k_ref, v_ref, *refs):
         o_ref, lse_ref, acc, m_i, l_i = refs[-5:]
@@ -433,7 +505,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
             # ones the diagonal cuts masked, the wholly masked not at all
             # (and nothing is fetched for a step that has only those: at_kv)
             visible, live = (jnp.clip(n - j * nsub, 0, nsub)
-                             for n in _causal_extent(i, bq, bk))
+                             for n in _causal_extent(i, bq, bk, *rule))
             if masked:          # no block of a learned selection is whole
                 visible = 0
             else:
@@ -445,8 +517,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
         @pl.when(j == nk // nsub - 1)
         def _fin():
             denom = jnp.maximum(l_i[...], 1e-30)
-            o_ref[...] = (acc[...] / denom).astype(o_ref.dtype)
-            store_lse(lse_ref, m_i[...] + jnp.log(denom))
+            finish(o_ref, lse_ref, acc[...] / denom,
+                   lambda: m_i[...] + jnp.log(denom), lambda: m_i[...])
 
     if lse_rows:
         lse_spec = pl.BlockSpec((g, bq), lambda b, i, j: (b, i))
@@ -460,7 +532,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
         # which are in VMEM already: no copy is issued for it; a program of
         # query heads reads their kv head's
         if causal:
-            j = _kv_block_fetched(i, j, bq, bk * nsub)
+            j = _kv_block_fetched(i, j, bq, bk * nsub, *rule)
         return (b if group == 1 else b * g // group, 0, j)
 
     # where one row at the caller's blocks does not fit the budget, mosaic's
@@ -492,7 +564,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_DEFAULT_LIMIT + over if over > 0 else None),
-        name="mxtpu_dsa_attn_fwd" if masked else "mxtpu_flash_fwd",
+        name=_kernel_name("fwd", masked, rule),
         interpret=interpret,
     )(*(in_hbm(jnp.swapaxes(a, 1, 2)) for a in (q, k, v)),
       *([in_hbm(mask)] if masked else []))
@@ -529,7 +601,8 @@ def _scan_forward(q, k, v, causal, sm_scale, bk, mask=None):
             s = jnp.where(blk[2], s, _NEG_INF)
         elif causal:
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, (lq, bk), 1)
-            s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
+            s = jnp.where(_sees(qpos, kpos, *_rule(causal))[None], s,
+                          _NEG_INF)
         m_new = jnp.maximum(m_i, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_i - m_new)
@@ -547,9 +620,11 @@ def _scan_forward(q, k, v, causal, sm_scale, bk, mask=None):
         step, init, (kb, vb) if mask is None
         else (kb, vb, _mask_blocks(mask, bh, nk, bk)))
     denom = jnp.maximum(l_i, 1e-30)
-    out = (acc / denom).astype(q.dtype)
-    lse = (m_i + jnp.log(denom))[..., 0]
-    return out, lse
+    out, lse = acc / denom, m_i + jnp.log(denom)
+    if causal and _rule(causal)[1]:     # a query that sees no key: 0, -inf
+        seen = m_i > _NEG_INF
+        out, lse = jnp.where(seen, out, 0.0), jnp.where(seen, lse, -jnp.inf)
+    return out.astype(q.dtype), lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -574,7 +649,8 @@ def _scan_backward(q, k, v, out, lse, g, causal, sm_scale, bk, mask=None):
             s = jnp.where(blk[3], s, _NEG_INF)
         elif causal:
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, (lq, bk), 1)
-            s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
+            s = jnp.where(_sees(qpos, kpos, *_rule(causal))[None], s,
+                          _NEG_INF)
         p = jnp.exp(s - lse[..., None])                     # (bh, lq, bk)
         dv_j = jnp.einsum("bqk,bqd->bkd", p, g.astype(jnp.float32))
         dp = jnp.einsum("bqd,bkd->bqk", g.astype(jnp.float32),
@@ -645,7 +721,9 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
     add exact zeros.  ``mask``: the forward's selection mask; every live
     block is then masked by its tile, the kernel is ``mxtpu_dsa_attn_bwd``,
     and where a row's dQ is over the VMEM rule (L = 16384 at d = 128: 8 MiB)
-    Mosaic's limit is raised by what it is over, as the forward's is."""
+    Mosaic's limit is raised by what it is over, as the forward's is.
+    ``causal`` a block rule (:func:`_sees`): the kernel is
+    ``mxtpu_bd_attn_bwd``, and ``lse`` has no -inf (:func:`_flash_bwd`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -654,6 +732,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
     nq, nk = lq // bq, lk // bk
     one = nq == 1 and nk == 1
     masked = mask is not None
+    rule = _rule(causal) if causal else (1, 0)
     shape = (bq, bk, lq, d, q.dtype.itemsize, not one, dv) + \
         ((True,) if masked else ())
     # from the rows this call sees: a chip's own, inside _per_batch_shard
@@ -682,7 +761,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
         elif mask:
             kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
             qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
-            s = jnp.where((qpos >= kpos)[None], s, _NEG_INF)
+            s = jnp.where(_sees(qpos, kpos, *rule)[None], s, _NEG_INF)
         p = jnp.exp(s - lse_ref[...])                      # lse (g, 1, bq)
         delta = jnp.sum(o_ref[...].astype(jnp.float32) *
                         dob.astype(jnp.float32), axis=1, keepdims=True)
@@ -725,7 +804,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
         if causal:
             # wholly masked (its first key after the block's last query):
             # skipped; cut by the diagonal: masked; wholly visible: plain
-            live, cut = _causal_block(i, j, bq, bk)
+            live, cut = _causal_block(i, j, bq, bk, *rule)
             if masked:          # no block of a learned selection is whole
                 pl.when(live)(lambda: step(True))
             else:
@@ -748,7 +827,8 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
         # (the last there is, where Lq ends before this KV block), so
         # nothing is fetched for it
         if causal:
-            i = jnp.minimum(jnp.maximum(i, (j * bk) // bq), nq - 1)
+            i = jnp.minimum(jnp.maximum(
+                i, _first_live_q_block(j, bq, bk, *rule)), nq - 1)
         return (b, 0, i)
 
     def at_kv(b, j, i):
@@ -793,7 +873,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             **({"vmem_limit_bytes": _VMEM_DEFAULT_LIMIT + over}
                if over > 0 else {})),
-        name="mxtpu_dsa_attn_bwd" if masked else "mxtpu_flash_bwd",
+        name=_kernel_name("bwd", masked, rule),
         interpret=interpret,
     )(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
       jnp.swapaxes(out, 1, 2), lse[:, None, :], jnp.swapaxes(do, 1, 2),
@@ -878,9 +958,8 @@ def _forward(q, k, v, mask, causal, sm_scale, mesh):
     (:func:`_pallas_forward`) or None."""
     blocks = _use_pallas(q.shape[1], k.shape[1], q.shape[2], v.shape[2])
     # counted while tracing: one per attention layer of a compiled program
-    # (under a selection mask by the sparse op's own names)
-    name = "flash.fwd" if mask is None else "dsa.attn.fwd"
-    _telem.inc(name + (".scan" if blocks is None else ".pallas"))
+    # (under a selection mask or a block rule by those ops' own names)
+    _telem.inc(_counter("fwd", mask, causal, blocks))
     if blocks is not None:
         kernel = functools.partial(
             _pallas_forward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
@@ -898,6 +977,19 @@ def _forward(q, k, v, mask, causal, sm_scale, mesh):
                                  jnp.repeat(v, group, axis=0), causal,
                                  sm_scale, bk, mask)
     return out, (q, k, v, out, lse, mask)
+
+
+def _counter(direction, mask, causal, blocks):
+    """The counter a traced call adds to: ``flash.<direction>``,
+    ``dsa.attn.<direction>`` under a selection mask, ``bd.attn.<direction>``
+    under a block rule, each ``.pallas`` or the fallback's."""
+    if mask is not None:
+        name, fallback = "dsa.attn.", ".scan"
+    elif causal and _rule(causal) != (1, 0):
+        name, fallback = "bd.attn.", ".xla"
+    else:
+        name, fallback = "flash.", ".scan"
+    return name + direction + (fallback if blocks is None else ".pallas")
 
 
 def _at_query_rows(backward, group):
@@ -930,8 +1022,11 @@ def _flash_bwd(causal, sm_scale, mesh, res, do):
             dv) > _VMEM_BUDGET:
         blocks = None
     # counted while tracing, as the forward's
-    name = "flash.bwd" if mask is None else "dsa.attn.bwd"
-    _telem.inc(name + (".scan" if blocks is None else ".pallas"))
+    _telem.inc(_counter("bwd", mask, causal, blocks))
+    if causal and _rule(causal)[1]:
+        # a query its offset hides every key from has a log-sum-exp of
+        # -inf: as +inf its probabilities come out 0, not exp(inf)
+        lse = jnp.where(jnp.isneginf(lse), jnp.inf, lse)
     # the backward takes K / V at the query rows (a G = 1 program holds a
     # row's float32 dQ: the query heads of a kv head do not share one)
     group = q.shape[0] // k.shape[0]
@@ -983,6 +1078,122 @@ def masked_flash(q, k, v, mask, sm_scale):
     heads; the log-sum-exp (B·H, L) comes without a gradient (the index
     loss reads it detached)."""
     return _masked_flash_on(q, k, v, mask, sm_scale, _dp_mesh(q, k))
+
+
+def _in_block(q, k, v, beta, sm_scale):
+    """``(out, lse)`` of each query over the ``beta`` keys of its own block
+    alone, in float32 over (rows, group, L / beta, beta, beta) score tiles:
+    q (rows * group, L, d), k, v (rows, L, d)."""
+    rows, lk, d = k.shape
+    group, n = q.shape[0] // rows, lk // beta
+    f32 = jnp.float32
+    s = jnp.einsum("rgnqd,rnkd->rgnqk",
+                   q.reshape(rows, group, n, beta, d).astype(f32),
+                   k.reshape(rows, n, beta, d).astype(f32)) * sm_scale
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    total = jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("rgnqk,rnkd->rgnqd", p / total,
+                     v.reshape(rows, n, beta, -1).astype(f32))
+    return out.reshape(q.shape[0], lk, -1), \
+        (m + jnp.log(total)).reshape(q.shape[0], lk)
+
+
+def _in_block_bwd(q, k, v, out, lse, do, beta, sm_scale):
+    """dQ, dK, dV of :func:`_in_block`'s keys under the merged ``out`` and
+    ``lse`` of every key a query sees, float32."""
+    rows, lk, d = k.shape
+    group, n = q.shape[0] // rows, lk // beta
+    f32 = jnp.float32
+
+    def tiles(a, kv=False):
+        shape = (rows, n, beta, -1) if kv else (rows, group, n, beta, -1)
+        return a.reshape(shape).astype(f32)
+    qb, kb, vb, dob = tiles(q), tiles(k, True), tiles(v, True), tiles(do)
+    s = jnp.einsum("rgnqd,rnkd->rgnqk", qb, kb) * sm_scale
+    p = jnp.exp(s - tiles(lse))
+    delta = jnp.sum(tiles(out) * dob, axis=-1, keepdims=True)
+    ds = p * (jnp.einsum("rgnqd,rnkd->rgnqk", dob, vb) - delta)
+    dq = jnp.einsum("rgnqk,rnkd->rgnqd", ds, kb) * sm_scale
+    dk = jnp.einsum("rgnqk,rgnqd->rnkd", ds, qb) * sm_scale
+    dv = jnp.einsum("rgnqk,rgnqd->rnkd", p, dob)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _bd_noisy_on(q, k0, v0, kt, vt, beta, sm_scale, mesh):
+    return _bd_noisy_fwd(q, k0, v0, kt, vt, beta, sm_scale, mesh)[0]
+
+
+def _bd_noisy_fwd(q, k0, v0, kt, vt, beta, sm_scale, mesh):
+    # the clean keys of the blocks before the query's own: the kernel under
+    # the rule (beta, 1); the noisy keys of its own block: XLA; one softmax
+    # over both, merged by their log-sum-exps
+    out_a, res = _forward(q, k0, v0, None, (beta, 1), sm_scale, mesh)
+    out_b, lse_b = _in_block(q, kt, vt, beta, sm_scale)
+    with jax.named_scope("bd.merge"):
+        lse = jnp.logaddexp(res[4], lse_b)
+        out = (jnp.exp(res[4] - lse)[..., None] * out_a.astype(jnp.float32)
+               + jnp.exp(lse_b - lse)[..., None] * out_b).astype(q.dtype)
+    return out, (q, k0, v0, kt, vt, out, lse)
+
+
+def _bd_noisy_bwd(beta, sm_scale, mesh, res, do):
+    # each part's backward under the merged out and lse: the probabilities
+    # it recomputes are then the merged softmax's own
+    q, k0, v0, kt, vt, out, lse = res
+    dq_a, dk0, dv0 = _flash_bwd((beta, 1), sm_scale, mesh,
+                                (q, k0, v0, out, lse, None), do)
+    with jax.named_scope("bd.noisy"):
+        dq_b, dkt, dvt = _in_block_bwd(q, kt, vt, out, lse, do, beta,
+                                       sm_scale)
+    return ((dq_a.astype(jnp.float32) + dq_b).astype(q.dtype), dk0, dv0,
+            dkt.astype(kt.dtype), dvt.astype(vt.dtype))
+
+
+_bd_noisy_on.defvjp(_bd_noisy_fwd, _bd_noisy_bwd)
+
+
+def block_diffusion_attention(q, k, v, block_length, sm_scale):
+    """Attention of a block-diffusion training sequence, the noisy copy
+    ``x_t`` beside the clean ``x_0``: q (B·H, 2L, d), k, v (B·Hkv, 2L, d),
+    each row's first L positions the noisy half and its last L the clean
+    one, both counted from 0 within their half, query head ``a`` of a batch
+    reading kv head ``a // (H / Hkv)`` in place.  With ``beta =
+    block_length`` (a power of two dividing L) and ``blk(a) = a // beta``:
+
+    - a clean query sees the clean keys with ``blk(b) <= blk(a)``
+      (block-causal): the flash kernels under the rule ``(beta, 0)``;
+    - a noisy query sees the clean keys with ``blk(b) < blk(a)`` — the
+      kernels under ``(beta, 1)``, whose first block of queries sees none —
+      and the noisy keys of its own block, ``blk(b) == blk(a)``: ``beta``
+      keys a query, in XLA; one softmax over both, merged by their
+      log-sum-exps, each part's backward taken under the merged result;
+    - a clean query never sees a noisy key.
+
+    No (2L)² mask exists anywhere; dead tiles are skipped as the causal
+    kernels skip them.  Differentiable in q, k, v; returns (B·H, 2L, dv).
+    Gauges ``bd.block_length`` and ``bd.offset_rows_empty`` (the queries of
+    a row that the offset call leaves without a key: one block's)."""
+    lq = q.shape[1] // 2
+    if q.shape[1] % 2 or lq % block_length or \
+            block_length & (block_length - 1):
+        raise ValueError(f"block_diffusion_attention: {q.shape[1]} positions "
+                         f"are not two halves of blocks of {block_length} "
+                         f"(a power of two)")
+    _telem.set_gauge("bd.block_length", block_length)
+    _telem.set_gauge("bd.offset_rows_empty", block_length)
+    mesh = _dp_mesh(q, k)
+
+    def half(a, i):
+        return a[:, i * lq:(i + 1) * lq]
+    with jax.named_scope("bd.clean"):
+        clean = _flash_on(half(q, 1), half(k, 1), half(v, 1),
+                          (block_length, 0), sm_scale, mesh)
+    with jax.named_scope("bd.noisy"):
+        noisy = _bd_noisy_on(half(q, 0), half(k, 1), half(v, 1), half(k, 0),
+                             half(v, 0), block_length, sm_scale, mesh)
+    return jnp.concatenate([noisy, clean], axis=1)
 
 
 def flash_attention(query, key, value, causal=False, sm_scale=None):

@@ -215,18 +215,20 @@ _TILE = 128                     # rows of the grouped product's tile
 _PART = 4                       # buffers to the worst case
 
 
-def window_rows(tokens, top_k, held):
-    """Rows of one buffer: a quarter of the worst case, in whole tiles of
-    the grouped product (the worst case itself where that is smaller)."""
+def window_rows(tokens, top_k, held, rows=None):
+    """Rows of one buffer: ``rows`` where given, else a quarter of the worst
+    case, in whole tiles of the grouped product (the worst case itself where
+    that is smaller)."""
     full = buffer_rows(tokens, top_k, held)
-    return min(full, -(-full // (_PART * _TILE)) * _TILE)
+    want = -(-full // _PART) if rows is None else rows
+    return min(full, -(-want // _TILE) * _TILE)
 
 
-def rung_rows(routed, tokens, top_k, held):
+def rung_rows(routed, tokens, top_k, held, rows=None):
     """The buffer rows a step computes over when ``routed`` rows go to held
     experts: whole buffers of :func:`window_rows`, as many as take them all
     (no count needs more than the worst case and a buffer's rounding)."""
-    rows = window_rows(tokens, top_k, held)
+    rows = window_rows(tokens, top_k, held, rows)
     return -(-routed // rows) * rows
 
 
@@ -366,20 +368,25 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _experts_at(rows, start, x, weights, w_gate, w_up, w_down, plan):
+def _experts_at(rows, start, x, weights, w_gate, w_up, w_down, plan,
+                whole=False):
     """The routed experts over ``rows`` rows (static) of the sorted choices
     from ``start``: every array in here that is d or h wide has ``rows``
-    rows or T.  (T, d) float32, the window's part of every token's sum."""
+    rows or T.  (T, d) float32, the window's part of every token's sum.
+    ``whole``: the products run over every row of the buffer, the last
+    expert's group taking the zero rows past the routed ones."""
     from ..ops.grouped_matmul import grouped_matmul
     with jax.named_scope(f"moe.rung{rows}"):
         window = _window(plan, rows, start)
+        sizes = window.group_sizes
+        if whole:
+            sizes = sizes.at[-1].add(rows - jnp.sum(sizes))
         with jax.named_scope("moe.dispatch"):
             buffer = _dispatch(x, window)
         with jax.named_scope("moe.experts"):
-            gate = grouped_matmul(buffer, w_gate, window.group_sizes)
-            up = grouped_matmul(buffer, w_up, window.group_sizes)
-            out = grouped_matmul(jax.nn.silu(gate) * up, w_down,
-                                 window.group_sizes)
+            gate = grouped_matmul(buffer, w_gate, sizes)
+            up = grouped_matmul(buffer, w_up, sizes)
+            out = grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes)
         with jax.named_scope("moe.combine"):
             return _combine(out, weights, window)
 
@@ -402,25 +409,24 @@ def _over_windows(plan, rows, body, sums):
 # ``kernels`` is the cache's key for what ``ops.kernel_mode`` said when the
 # trace was made.
 
-@functools.partial(jax.jit, static_argnames="kernels")
-def _forward(x, weights, w_gate, w_up, w_down, plan, *, kernels):
-    rows = window_rows(*weights.shape, w_gate.shape[0])
+@functools.partial(jax.jit, static_argnames=("rows", "whole", "kernels"))
+def _forward(x, weights, w_gate, w_up, w_down, plan, *, rows, whole,
+             kernels):
     return _over_windows(
         plan, rows,
         lambda start: _experts_at(rows, start, x, weights, w_gate, w_up,
-                                  w_down, plan),
+                                  w_down, plan, whole),
         jnp.zeros(x.shape, jnp.float32)).astype(x.dtype)
 
 
-@functools.partial(jax.jit, static_argnames="kernels")
-def _backward(g, *operands, kernels):
+@functools.partial(jax.jit, static_argnames=("rows", "whole", "kernels"))
+def _backward(g, *operands, rows, whole, kernels):
     *floats, plan = operands
     x, weights, *stacks = floats
-    rows = window_rows(*weights.shape, stacks[0].shape[0])
 
     def body(start):
         _, vjp = jax.vjp(lambda *floats: _experts_at(
-            rows, start, *floats, plan), *floats)
+            rows, start, *floats, plan, whole), *floats)
         return list(vjp(g.astype(jnp.float32)))
 
     # The token-side sums run in float32 from buffer to buffer.  The three
@@ -437,25 +443,26 @@ def _backward(g, *operands, kernels):
     return tuple(d.astype(f.dtype) for d, f in zip(sums, floats))
 
 
-@jax.custom_vjp
-def _routed_experts(x, weights, w_gate, w_up, w_down, plan):
-    """The sum of :func:`_experts_at` over the buffers the routed count
-    needs, one loop a direction.  Neither loop is differentiated (its trip
-    count is data): the forward rule keeps the operands alone, and the
-    backward rule's loop computes each buffer again and applies its VJP,
-    with float32 sums between buffers in both."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed_experts(rows, whole, x, weights, w_gate, w_up, w_down, plan):
+    """The sum of :func:`_experts_at` over the buffers of ``rows`` rows the
+    routed count needs, one loop a direction.  Neither loop is
+    differentiated (its trip count is data): the forward rule keeps the
+    operands alone, and the backward rule's loop computes each buffer again
+    and applies its VJP, with float32 sums between buffers in both."""
     from ..ops.kernel_mode import kernel_mode
-    return _forward(x, weights, w_gate, w_up, w_down, plan,
-                    kernels=kernel_mode())
+    return _forward(x, weights, w_gate, w_up, w_down, plan, rows=rows,
+                    whole=whole, kernels=kernel_mode())
 
 
-def _routed_experts_fwd(*operands):
-    return _routed_experts(*operands), operands
+def _routed_experts_fwd(rows, whole, *operands):
+    return _routed_experts(rows, whole, *operands), operands
 
 
-def _routed_experts_bwd(operands, g):
+def _routed_experts_bwd(rows, whole, operands, g):
     from ..ops.kernel_mode import kernel_mode
-    return (*_backward(g, *operands, kernels=kernel_mode()), None)
+    return (*_backward(g, *operands, rows=rows, whole=whole,
+                       kernels=kernel_mode()), None)
 
 
 _routed_experts.defvjp(_routed_experts_fwd, _routed_experts_bwd)
@@ -485,7 +492,7 @@ def _make_plan(experts, held, expert_offset):
 
 
 def dropless_moe_apply(x, experts, weights, w_gate, w_up, w_down, *,
-                       expert_offset=0):
+                       expert_offset=0, fixed_rows=None):
     """The routed experts' part of a SwiGLU expert layer, for the experts
     held here.
 
@@ -507,13 +514,21 @@ def dropless_moe_apply(x, experts, weights, w_gate, w_up, w_down, *,
     compiler drops it: 12 grouped products a layer, as before); a model
     without ``remat`` runs the three forward products a second time in its
     backward pass and holds none of the layer's buffers in between.
+
+    ``fixed_rows`` makes the work a fixed amount: buffers of that many rows
+    (whole tiles, at most the worst case) in place of the quarter, and the
+    products over every row of each, the rows past the routed ones as
+    zeros.  A step whose routed rows fit one buffer then does the same work
+    whatever the routing, at the price of the products over those zeros.
     """
     tokens, k = experts.shape
     held = w_gate.shape[0]
+    rows = window_rows(tokens, k, held, fixed_rows)
     _telem.inc("moe.layers")
     _telem.set_gauge("moe.experts_held", held)
     _telem.set_gauge("moe.rows_buffer", buffer_rows(tokens, k, held))
-    _telem.set_gauge("moe.rows_ladder", window_rows(tokens, k, held))
+    _telem.set_gauge("moe.rows_ladder", rows)
     _telem.set_gauge("moe.top_k", k)
-    return _routed_experts(x, weights, w_gate, w_up, w_down,
+    return _routed_experts(rows, fixed_rows is not None, x, weights, w_gate,
+                           w_up, w_down,
                            _make_plan(experts, held, expert_offset))

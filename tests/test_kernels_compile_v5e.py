@@ -195,3 +195,29 @@ def test_scan_kernels_at_the_kimi_shape(one_chip):
     assert names == ["mxtpu_kda_bwd", "mxtpu_kda_fwd"]
     assert not re.search(r"mxtpu_(flash|gmm)", text)
     assert " while(" not in text
+
+
+def test_block_diffusion_kernels_at_the_sdar_shape(one_chip):
+    """The SDAR cell's attention: a training row of 2 x 8192 positions, 32
+    query / 4 key-value heads of 128, blocks of 4 — the clean half's
+    block-causal call and the noisy half's offset call, each a forward and
+    a backward kernel by the block rule's own names, the 8 query heads of a
+    kv head reading it in place, and no scan left."""
+    from mxnet_tpu.ops.flash_attention import block_diffusion_attention
+
+    def shape(rows):
+        return jax.ShapeDtypeStruct((rows, 2 * 8192, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: block_diffusion_attention(
+            *a, 4, 128 ** -0.5).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+    text = _compile(grads, shape(32), shape(4), shape(4))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = sorted(re.search(r'op_name="[^"]*(mxtpu_\w+)', ln).group(1)
+                   for ln in calls)
+    assert names == ["mxtpu_bd_attn_bwd"] * 2 + ["mxtpu_bd_attn_fwd"] * 2
+    assert "mxtpu_flash" not in text
+    assert " while(" not in text

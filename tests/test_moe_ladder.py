@@ -4,6 +4,7 @@ routed rows need, up to the worst case — against the worst-case formulation
 it had before, kept here as the plain reference; the rows a routed count
 runs through; what the layer's program holds with and without ``remat``."""
 import functools
+import importlib
 import re
 
 import jax
@@ -115,6 +116,35 @@ def test_every_fill_agrees_with_the_worst_case_buffer(routed, rows):
         assert not any(float(jnp.abs(a).max()) for a in got)
 
 
+@pytest.mark.parametrize("fixed,routed,rows", [
+    (512, 600, 1024),               # two buffers of half the worst case
+    (300, 10, 384),                 # rounded up to whole tiles
+    (4096, 700, FULL)])             # never past the worst case
+def test_a_fixed_amount_of_work(monkeypatch, fixed, routed, rows):
+    """``fixed_rows``: buffers of that many rows, and the products over
+    every row of each whatever is routed; the layer still agrees with the
+    worst-case buffer, the zero rows adding nothing."""
+    gmm = importlib.import_module("mxnet_tpu.ops.grouped_matmul")
+    assert moe.rung_rows(routed, T, K, HELD, fixed) == rows
+    seen = []
+    plain = gmm.grouped_matmul
+
+    def counting(lhs, rhs, group_sizes):
+        jax.debug.callback(lambda n: seen.append(int(n)),
+                           jnp.sum(group_sizes))
+        return plain(lhs, rhs, group_sizes)
+    monkeypatch.setattr(gmm, "grouped_matmul", counting)
+    experts, floats, g = _operands(routed, seed=routed + 1)
+    layer = functools.partial(moe.dropless_moe_apply, fixed_rows=fixed)
+    got = _value_and_grads(layer, experts, floats, g)
+    buffer = moe.window_rows(T, K, HELD, fixed)
+    assert telemetry.value("moe.rows_ladder") == buffer
+    assert seen and set(seen) == {buffer}
+    want = _value_and_grads(_worst_case, experts, floats, g)
+    for name, a, b in zip(NAMES, got, want):
+        np.testing.assert_allclose(a, b, err_msg=name, **TOL)
+
+
 def test_dropless_at_the_worst_case_against_a_layer_without_a_buffer():
     """Every token sends all its choices here, the worst case to its last
     row: four buffers take them all, and the result is what a dense loop
@@ -140,6 +170,20 @@ def test_the_kernels_under_a_buffer(routed):
     experts, floats, g = _operands(routed, seed=7, d=128, h=128)
     with interpret_kernels():
         got = _value_and_grads(moe.dropless_moe_apply, experts, floats, g)
+    want = _value_and_grads(_worst_case, experts, floats, g)
+    for name, a, b in zip(NAMES, got, want):
+        np.testing.assert_allclose(a, b, err_msg=name, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("routed", [129, 600])
+def test_the_kernels_over_a_fixed_buffer(routed):
+    """``mxtpu_gmm`` and its backward kernels over every row of buffers of
+    512, the last expert's group holding the zero rows: one buffer, and
+    two."""
+    experts, floats, g = _operands(routed, seed=7, d=128, h=128)
+    layer = functools.partial(moe.dropless_moe_apply, fixed_rows=512)
+    with interpret_kernels():
+        got = _value_and_grads(layer, experts, floats, g)
     want = _value_and_grads(_worst_case, experts, floats, g)
     for name, a, b in zip(NAMES, got, want):
         np.testing.assert_allclose(a, b, err_msg=name, rtol=1e-4, atol=1e-4)
@@ -197,6 +241,16 @@ def test_buffers_of_small_and_ragged_layers(shape, buffer):
     full = moe.buffer_rows(*shape)
     assert moe.window_rows(*shape) == moe.rung_rows(1, *shape) == buffer
     assert full <= moe.rung_rows(full, *shape) < full + buffer
+
+
+def test_the_sdar_cell_computes_one_fixed_buffer():
+    """The SDAR cell's ``moe_fixed_rows`` (65536, half the worst case): the
+    most a layer routed by the end of a window over twelve seeds (52222)
+    takes one buffer, where the quarter (32768) takes two."""
+    shape = (16384, 8, 16)
+    assert 2 * moe.window_rows(*shape, 65536) == moe.buffer_rows(*shape)
+    assert moe.rung_rows(52222, *shape) == 2 * 32768
+    assert moe.rung_rows(52222, *shape, 65536) == 65536
 
 
 def _plan_of(experts, floats):
