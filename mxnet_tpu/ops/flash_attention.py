@@ -19,10 +19,9 @@ comes from the shapes alone (:func:`_rows_per_program`): as many rows as
 give a program a few microseconds of work and fit VMEM.  Where K and V have
 fewer rows than Q (grouped-query attention, read in place) a program's G
 rows are query heads of one kv head, which share its K / V block, at a
-square score tile of that rule's size (:func:`_forward_tiling`); the
-backward repeats K / V to the query rows and sums dK / dV back.  When one BK block
-holds all of Lk the body is a plain one-pass softmax.  Otherwise a grid
-step holds as many KV blocks as VMEM has room for
+square score tile of that rule's size (:func:`_forward_tiling`).  When one
+BK block holds all of Lk the body is a plain one-pass softmax.  Otherwise a
+grid step holds as many KV blocks as VMEM has room for
 (:func:`_kv_blocks_per_step`) and walks them in a loop of its own, a block
 at a time through the same online softmax (a grid step costs about half a
 microsecond whatever it computes); with few rows to a program the Q
@@ -55,9 +54,15 @@ where a block holds all of L; otherwise the Q blocks are walked inside each
 KV block, dK / dV accumulate over the inner walk, the row's dQ in a float32
 VMEM scratch over the outer one, and causal blocks that are wholly masked
 are skipped.  G is the forward's rule over the backward's own VMEM
-arithmetic (:func:`_backward_rows_per_program`).  Everywhere else, and
-where one row's dQ does not fit VMEM, it is the float32 scan
-(:func:`_scan_backward`), which is also the tests' reference.
+arithmetic (:func:`_backward_rows_per_program`).  Where K and V have fewer
+rows than Q a program takes every query head of one kv head instead, and
+the loops turn round: Q blocks outer, KV blocks inner, the kv head's dK /
+dV summed over its query heads in a float32 VMEM scratch of the whole row
+and the Q block's dQ over the inner walk (:func:`_grouped_backward_blocks`;
+where that does not fit VMEM, K / V are repeated to the query rows and
+dK / dV summed back).  Everywhere else, and where one row's dQ does not
+fit VMEM, it is the float32 scan (:func:`_scan_backward`), which is also
+the tests' reference.
 """
 from __future__ import annotations
 
@@ -321,6 +326,14 @@ def _kernel_name(direction, masked, rule):
         + direction
 
 
+def _shared(kb, like):
+    """A kv head's block inside a kernel, shared by the query heads of
+    ``like``: broadcast to their rows where it has fewer."""
+    if kb.shape[0] == like.shape[0]:
+        return kb
+    return jnp.broadcast_to(kb, like.shape[:1] + kb.shape[1:])
+
+
 def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
                     mask=None):
     """``mask``: a selection of keys a query, (batches, Lk, Lq) int8 with
@@ -396,12 +409,6 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
         # interpreter multiplies in float32: the same products, exactly
         return x.astype(jnp.float32) if interpret else x
 
-    def rows(kb, like):
-        # a kv head's block, shared by the query heads of `like`
-        if kb.shape[0] == like.shape[0]:
-            return kb
-        return jnp.broadcast_to(kb, like.shape[:1] + kb.shape[1:])
-
     def selected(keep):
         # the (bk, cols) int8 tile of the selection mask as a condition
         return keep.astype(jnp.int32) != 0
@@ -414,7 +421,8 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
             qb = qb * sm_scale
         # (g, d, bk) x (g, d, cols) over d -> (g, bk, cols): keys on sublanes
         s = lax.dot_general(
-            operand(rows(kb, qb)), operand(qb), (((1,), (1,)), ((0,), (0,))),
+            operand(_shared(kb, qb)), operand(qb),
+            (((1,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         if not scale_on_q:
             s = s * sm_scale
@@ -430,7 +438,7 @@ def _pallas_forward(q, k, v, causal, sm_scale, bq, bk, interpret=False,
     def p_dot_v(vb, p):
         # (g, dv, bk) x (g, bk, cols) -> (g, dv, cols)
         return lax.dot_general(
-            operand(rows(vb, p)), operand(p.astype(vb.dtype)),
+            operand(_shared(vb, p)), operand(p.astype(vb.dtype)),
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
 
@@ -706,6 +714,48 @@ def _backward_rows_per_program(bh, bq, bk, lq, d, itemsize, streaming,
         2 * (bq + bk) * (d + dv) * itemsize)
 
 
+# What a backward program of a kv head's query heads may take of VMEM with
+# its limit raised: the v5e has 128 MiB, and XLA keeps some for itself.
+_VMEM_GROUPED_CEILING = 96 * 2 ** 20
+
+
+def _grouped_backward_vmem_bytes(g, bq, bk, lk, d, itemsize, dv, masked):
+    """VMEM bytes of a backward program that takes the ``g`` query heads of
+    one kv head (:func:`_grouped_backward_blocks`): the double-buffered
+    Q / dQ and O / dO blocks of ``g`` heads and the K / dK and V / dV blocks
+    of one, the log-sum-exp block, the float32 scratch — the Q block's dQ
+    and delta, the kv head's whole-row dK and dV — and
+    :func:`_backward_vmem_bytes`'s temporaries at ``g`` heads."""
+    blocks = 4 * _padded_head_dims(d, dv, itemsize) * (g * bq + bk) * itemsize
+    lse = 2 * g * 8 * bq * 4
+    scratch = (g * (d + 8) * bq + (d + dv) * lk) * 4
+    temps = g * (bk * bq * (4 + 4 + 2 * itemsize) + 8 * bq * 4
+                 + ((d + dv) * bk + d * bq) * 4)
+    return blocks + lse + scratch + temps + (
+        _mask_vmem_bytes(bq, bk) if masked else 0)
+
+
+def _grouped_backward_blocks(group, lq, lk, bq, bk, d, itemsize, dv,
+                             masked):
+    """``(bq, bk)`` of a backward program that takes all ``group`` query
+    heads of one kv head (K / V at fewer rows than Q), or None where none
+    fits ``_VMEM_GROUPED_CEILING`` (its float32 dK / dV hold the kv head's
+    whole row): square blocks of the caller's side, halved until the
+    program fits.  The body has no walk of its own, so a grid step's fixed
+    cost is paid once a tile: on a TPU v5e the whole backward rule at 32
+    query heads over 4 kv heads, L = 16384, d = 128 takes 34.1 ms a call at
+    (512, 512), 35.2 at (256, 512), 37.3 at (256, 256) and 43.8 with K / V
+    repeated (PERF.md §6)."""
+    side = max(bq, bk)
+    while side >= 128:
+        bq, bk = _pick_block(lq, side), _pick_block(lk, side)
+        if _grouped_backward_vmem_bytes(group, bq, bk, lk, d, itemsize, dv,
+                                        masked) <= _VMEM_GROUPED_CEILING:
+            return bq, bk
+        side //= 2
+    return None
+
+
 def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
                      interpret=False, mask=None):
     """dQ, dK, dV of the kernel's forward, operands as (rows, D, L) and the
@@ -723,23 +773,39 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
     and where a row's dQ is over the VMEM rule (L = 16384 at d = 128: 8 MiB)
     Mosaic's limit is raised by what it is over, as the forward's is.
     ``causal`` a block rule (:func:`_sees`): the kernel is
-    ``mxtpu_bd_attn_bwd``, and ``lse`` has no -inf (:func:`_flash_bwd`)."""
+    ``mxtpu_bd_attn_bwd``, and ``lse`` has no -inf (:func:`_flash_bwd`).
+
+    K and V may have fewer rows than Q (grouped-query attention, read in
+    place): a program then takes the ``group`` query heads of one kv head
+    at :func:`_grouped_backward_blocks`' blocks, which share its K / V block
+    and the mask tile, and the loops turn the other way round — the Q
+    blocks outer, the KV blocks inner — so that what is held whole in VMEM
+    is the kv head's float32 dK / dV row, summed over its query heads and
+    every Q block and written out on the last Q block's walk, while dQ and
+    ``delta`` are the current Q block's alone."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, lq, d = q.shape
     lk, dv = k.shape[1], v.shape[2]
-    nq, nk = lq // bq, lk // bk
-    one = nq == 1 and nk == 1
+    group = bh // k.shape[0]
     masked = mask is not None
+    itemsize = q.dtype.itemsize
+    if group > 1:
+        bq, bk = _grouped_backward_blocks(group, lq, lk, bq, bk, d, itemsize,
+                                          dv, masked)
+    nq, nk = lq // bq, lk // bk
+    one = nq == 1 and nk == 1 and group == 1
     rule = _rule(causal) if causal else (1, 0)
-    shape = (bq, bk, lq, d, q.dtype.itemsize, not one, dv) + \
+    shape = (bq, bk, lq, d, itemsize, not one, dv) + \
         ((True,) if masked else ())
     # from the rows this call sees: a chip's own, inside _per_batch_shard
     # (a program's rows share a mask tile: they are one batch's heads)
     heads = bh // mask.shape[0] if masked else bh
-    g = _backward_rows_per_program(heads, *shape)
+    g = _backward_rows_per_program(heads, *shape) if group == 1 else group
+    kv_rows = g if group == 1 else 1
     _telem.set_gauge("flash.bwd.rows_per_program", g)
+    _telem.set_gauge("flash.bwd.heads_per_kv_block", g // kv_rows)
 
     def dot(a, b, contract):
         # batched over the g rows; float32 in the interpreter, as the forward
@@ -748,13 +814,19 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
         return lax.dot_general(a, b, (contract, ((0,), (0,))),
                                preferred_element_type=jnp.float32)
 
+    def per_kv(x):
+        # a kv head's dK / dV: the sum of its query heads'
+        return x if kv_rows == g else jnp.sum(x, axis=0, keepdims=True)
+
     def products(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, i, j, mask,
-                 keep=None):
+                 keep=None, delta=None):
         """The block's dV, dK and dQ in float32, dK and dQ still without
-        ``sm_scale`` (applied to the small results, not to the scores)."""
+        ``sm_scale`` (applied to the small results, not to the scores);
+        ``delta``: the Q block's rowsum(O dO) where it is held, else it is
+        computed here."""
         qb, kb, vb, dob = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
         # (g, d, bk) x (g, d, bq) over d -> (g, bk, bq)
-        s = dot(kb, qb, ((1,), (1,))) * sm_scale
+        s = dot(_shared(kb, qb), qb, ((1,), (1,))) * sm_scale
         if keep is not None:
             s = jnp.where((keep[0].astype(jnp.int32) != 0)[None], s,
                           _NEG_INF)
@@ -763,13 +835,28 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
             qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
             s = jnp.where(_sees(qpos, kpos, *rule)[None], s, _NEG_INF)
         p = jnp.exp(s - lse_ref[...])                      # lse (g, 1, bq)
-        delta = jnp.sum(o_ref[...].astype(jnp.float32) *
-                        dob.astype(jnp.float32), axis=1, keepdims=True)
-        ds = p * (dot(vb, dob, ((1,), (1,))) - delta)
+        if delta is None:
+            delta = jnp.sum(o_ref[...].astype(jnp.float32) *
+                            dob.astype(jnp.float32), axis=1, keepdims=True)
+        ds = p * (dot(_shared(vb, dob), dob, ((1,), (1,))) - delta)
         p, ds = p.astype(qb.dtype), ds.astype(qb.dtype)
-        return (dot(dob, p, ((2,), (2,))),                 # (g, dv, bk)
-                dot(qb, ds, ((2,), (2,))),                 # (g, d, bk)
-                dot(kb, ds, ((2,), (1,))))                 # (g, d, bq)
+        return (per_kv(dot(dob, p, ((2,), (2,)))),        # (kv, dv, bk)
+                per_kv(dot(qb, ds, ((2,), (2,)))),        # (kv, d, bk)
+                dot(_shared(kb, ds), ds, ((2,), (1,))))       # (g, d, bq)
+
+    def walk(step, i, j):
+        # one (Q block i, KV block j) pair of the causal walk, or all
+        if causal:
+            # wholly masked (its first key after the block's last query):
+            # skipped; cut by the diagonal: masked; wholly visible: plain
+            live, cut = _causal_block(i, j, bq, bk, *rule)
+            if masked:          # no block of a learned selection is whole
+                pl.when(live)(lambda: step(True))
+            else:
+                pl.when(live & cut)(lambda: step(True))
+                pl.when(live & jnp.logical_not(cut))(lambda: step(False))
+        else:
+            step(False)
 
     def one_pass(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *refs):
         dq_ref, dk_ref, dv_ref = refs[-3:]
@@ -801,17 +888,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
             dk_acc[...] += dkb
             dq_acc[i] += dqb
 
-        if causal:
-            # wholly masked (its first key after the block's last query):
-            # skipped; cut by the diagonal: masked; wholly visible: plain
-            live, cut = _causal_block(i, j, bq, bk, *rule)
-            if masked:          # no block of a learned selection is whole
-                pl.when(live)(lambda: step(True))
-            else:
-                pl.when(live & cut)(lambda: step(True))
-                pl.when(live & jnp.logical_not(cut))(lambda: step(False))
-        else:
-            step(False)
+        walk(step, i, j)
 
         @pl.when(i == nq - 1)
         def _fin_kv():
@@ -822,53 +899,125 @@ def _pallas_backward(q, k, v, out, lse, do, causal, sm_scale, bq, bk,
         def _fin_q():
             dq_ref[...] = (dq_acc[i] * sm_scale).astype(dq_ref.dtype)
 
-    def at_q(b, j, i):
-        # a skipped block asks for the first live one's Q-side blocks again
-        # (the last there is, where Lq ends before this KV block), so
-        # nothing is fetched for it
-        if causal:
-            i = jnp.minimum(jnp.maximum(
-                i, _first_live_q_block(j, bq, bk, *rule)), nq - 1)
-        return (b, 0, i)
+    def q_outer(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, *refs):
+        # a kv head's query heads: Q block i outer, KV block j inner; the
+        # kv head's dK / dV rows whole in VMEM, the Q block's dQ and delta
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, delta = refs[-7:]
+        i = pl.program_id(1)                    # the Q block, outer
+        j = pl.program_id(2)                    # the KV block, inner
 
-    def at_kv(b, j, i):
-        return (b, 0, j)
+        @pl.when(j == 0)
+        def _init_q():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+            delta[...] = jnp.sum(o_ref[...].astype(jnp.float32) *
+                                 do_ref[...].astype(jnp.float32), axis=1,
+                                 keepdims=True)
 
-    def at_dq(b, j, i):
-        # parked on block 0 until the last KV block, when each Q block's sum
-        # is complete and is written out once
-        return (b, 0, jnp.where(j == nk - 1, i, 0))
+        @pl.when(i == 0)
+        def _init_kv():
+            dk_acc[j] = jnp.zeros(dk_acc.shape[1:], dk_acc.dtype)
+            dv_acc[j] = jnp.zeros(dv_acc.shape[1:], dv_acc.dtype)
 
-    over = _backward_vmem_bytes(g, *shape) - _VMEM_BUDGET if masked else 0
-    mask_spec = [pl.BlockSpec(
-        (1, bk, bq), lambda b, j, i: (b * g // heads, j, at_q(b, j, i)[2]))] \
-        if masked else []
+        def step(mask):
+            dvb, dkb, dqb = products(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                                     do_ref, i, j, mask,
+                                     refs[0] if masked else None, delta[...])
+            dv_acc[j] += dvb[0]
+            dk_acc[j] += dkb[0]
+            dq_acc[...] += dqb
+
+        walk(step, i, j)
+
+        @pl.when(i == nq - 1)
+        def _fin_kv():
+            dv_ref[...] = dv_acc[j][None].astype(dv_ref.dtype)
+            dk_ref[...] = (dk_acc[j][None] * sm_scale).astype(dk_ref.dtype)
+
+        @pl.when(j == nk - 1)
+        def _fin_q():
+            dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+    if group == 1:
+        def at_q(b, j, i):
+            # a skipped block asks for the first live one's Q-side blocks
+            # again (the last there is, where Lq ends before this KV block),
+            # so nothing is fetched for it
+            if causal:
+                i = jnp.minimum(jnp.maximum(
+                    i, _first_live_q_block(j, bq, bk, *rule)), nq - 1)
+            return (b, 0, i)
+
+        def at_kv(b, j, i):
+            return (b, 0, j)
+
+        def at_dq(b, j, i):
+            # parked on block 0 until the last KV block, when each Q block's
+            # sum is complete and is written out once
+            return (b, 0, jnp.where(j == nk - 1, i, 0))
+
+        def at_mask(b, j, i):
+            return (b * g // heads, j, at_q(b, j, i)[2])
+        at_dkv = at_kv
+        grid = (bh // g, nk, nq)
+        scratch = [] if one else [
+            pltpu.VMEM((nq, g, d, bq), jnp.float32),
+            pltpu.VMEM((g, d, bk), jnp.float32),
+            pltpu.VMEM((g, dv, bk), jnp.float32),
+        ]
+        over = _backward_vmem_bytes(g, *shape) - _VMEM_BUDGET if masked \
+            else 0
+    else:
+        def at_q(b, i, j):
+            return (b, 0, i)
+
+        def at_kv(b, i, j):
+            # a skipped step asks for the Q block's last live K / V blocks
+            # again, which are in VMEM already: no copy is issued for it
+            if causal:
+                j = _kv_block_fetched(i, j, bq, bk, *rule)
+            return (b, 0, j)
+
+        def at_dkv(b, i, j):
+            # parked on block 0 until the last Q block, when each KV block's
+            # sum is complete and is written out once
+            return (b, 0, jnp.where(i == nq - 1, j, 0))
+
+        def at_mask(b, i, j):
+            return (b * g // heads, at_kv(b, i, j)[2], i)
+        at_dq = at_q
+        grid = (bh // g, nq, nk)
+        scratch = [
+            pltpu.VMEM((g, d, bq), jnp.float32),
+            pltpu.VMEM((nk, d, bk), jnp.float32),
+            pltpu.VMEM((nk, dv, bk), jnp.float32),
+            pltpu.VMEM((g, 1, bq), jnp.float32),
+        ]
+        over = _grouped_backward_vmem_bytes(g, bq, bk, lk, d, itemsize, dv,
+                                            masked) - _VMEM_BUDGET
+
+    mask_spec = [pl.BlockSpec((1, bk, bq), at_mask)] if masked else []
     dq_t, dk_t, dv_t = pl.pallas_call(
-        one_pass if one else streaming,
-        grid=(bh // g, nk, nq),
+        q_outer if group > 1 else one_pass if one else streaming,
+        grid=grid,
         in_specs=[
             pl.BlockSpec((g, d, bq), at_q),
-            pl.BlockSpec((g, d, bk), at_kv),
-            pl.BlockSpec((g, dv, bk), at_kv),
+            pl.BlockSpec((kv_rows, d, bk), at_kv),
+            pl.BlockSpec((kv_rows, dv, bk), at_kv),
             pl.BlockSpec((g, dv, bq), at_q),
             pl.BlockSpec((g, 1, bq), at_q),
             pl.BlockSpec((g, dv, bq), at_q),
         ] + mask_spec,
         out_specs=[
             pl.BlockSpec((g, d, bq), at_dq),
-            pl.BlockSpec((g, d, bk), at_kv),
-            pl.BlockSpec((g, dv, bk), at_kv),
+            pl.BlockSpec((kv_rows, d, bk), at_dkv),
+            pl.BlockSpec((kv_rows, dv, bk), at_dkv),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, d, lq), q.dtype),
-            jax.ShapeDtypeStruct((bh, d, lk), k.dtype),
-            jax.ShapeDtypeStruct((bh, dv, lk), v.dtype),
+            jax.ShapeDtypeStruct((bh // group, d, lk), k.dtype),
+            jax.ShapeDtypeStruct((bh // group, dv, lk), v.dtype),
         ],
-        scratch_shapes=[] if one else [
-            pltpu.VMEM((nq, g, d, bq), jnp.float32),
-            pltpu.VMEM((g, d, bk), jnp.float32),
-            pltpu.VMEM((g, dv, bk), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             **({"vmem_limit_bytes": _VMEM_DEFAULT_LIMIT + over}
@@ -1013,13 +1162,20 @@ def _flash_bwd(causal, sm_scale, mesh, res, do):
     q, k, v, out, lse, mask = res
     lq, lk, d, dv = q.shape[1], k.shape[1], q.shape[2], v.shape[2]
     blocks = _use_pallas(lq, lk, d, dv)
+    # K / V at fewer rows than Q: the kernel reads them in place where a
+    # program of a kv head's query heads fits VMEM (its dK / dV rows whole);
+    # otherwise the backward takes K / V repeated to the query rows
+    group = q.shape[0] // k.shape[0]
+    in_place = blocks is not None and group > 1 and \
+        _grouped_backward_blocks(group, lq, lk, *blocks, d,
+                                 q.dtype.itemsize, dv, mask is not None)
     # a streamed row's float32 dQ is held whole in VMEM: where one row is
     # over the budget (L = 16384 at d = 192) the scan runs; under a
     # selection mask the kernel runs with Mosaic's limit raised instead
     # (the scan at L = 16384 holds (rows, L, bk) float32 scores)
-    if mask is None and blocks is not None and _backward_vmem_bytes(
-            1, *blocks, lq, d, q.dtype.itemsize, (lq, lk) != blocks,
-            dv) > _VMEM_BUDGET:
+    if mask is None and blocks is not None and not in_place and \
+            _backward_vmem_bytes(1, *blocks, lq, d, q.dtype.itemsize,
+                                 (lq, lk) != blocks, dv) > _VMEM_BUDGET:
         blocks = None
     # counted while tracing, as the forward's
     _telem.inc(_counter("bwd", mask, causal, blocks))
@@ -1027,9 +1183,6 @@ def _flash_bwd(causal, sm_scale, mesh, res, do):
         # a query its offset hides every key from has a log-sum-exp of
         # -inf: as +inf its probabilities come out 0, not exp(inf)
         lse = jnp.where(jnp.isneginf(lse), jnp.inf, lse)
-    # the backward takes K / V at the query rows (a G = 1 program holds a
-    # row's float32 dQ: the query heads of a kv head do not share one)
-    group = q.shape[0] // k.shape[0]
     if blocks is None:
         bk = _pick_block(lk, 256) or lk
         return _at_query_rows(functools.partial(
@@ -1038,6 +1191,7 @@ def _flash_bwd(causal, sm_scale, mesh, res, do):
     kernel = functools.partial(
         _pallas_backward, causal=causal, sm_scale=sm_scale, bq=blocks[0],
         bk=blocks[1], interpret=kernel_mode() == "interpret")
+    group = 1 if in_place else group
     if mask is None:
         return _per_batch_shard(_at_query_rows(kernel, group), mesh)(
             q, k, v, out, lse, do)
@@ -1210,7 +1364,10 @@ def flash_attention(query, key, value, causal=False, sm_scale=None):
     fallback with identical semantics.  Counters ``flash.fwd.pallas`` /
     ``flash.fwd.scan`` and ``flash.bwd.pallas`` / ``flash.bwd.scan`` say
     which was traced, gauges ``flash.fwd.rows_per_program`` and
-    ``flash.bwd.rows_per_program`` the last G, ``flash.fwd.blocks_live`` /
+    ``flash.bwd.rows_per_program`` the last G,
+    ``flash.fwd.heads_per_kv_block`` and ``flash.bwd.heads_per_kv_block``
+    the query heads one K / V block serves in a program (1 where every row
+    has its own), ``flash.fwd.blocks_live`` /
     ``flash.fwd.blocks_masked`` the (Q block, KV block) pairs a row of the
     last forward kernel computes and those it masks,
     ``flash.fwd.kv_blocks_per_step`` the KV blocks one of its grid steps

@@ -54,9 +54,10 @@ in float32; they are also the tests' reference):
 The kernels read K and V at their own ``Hkv`` heads (gauge
 ``gqa.kv_repeat``: the ``H / Hkv`` query heads each kv head serves).  A
 forward program takes query heads of one kv head, which share its K / V
-block and the mask tile (gauge ``flash.fwd.heads_per_kv_block``); the
-backward, a head a program, repeats K / V to the query heads inside its
-rule and sums dK / dV back (``ops/flash_attention.py``).
+block and the mask tile (gauge ``flash.fwd.heads_per_kv_block``); a
+backward program takes all of a kv head's query heads, Q blocks outer, and
+sums their dK / dV in VMEM (gauge ``flash.bwd.heads_per_kv_block``;
+``ops/flash_attention.py``).
 """
 from __future__ import annotations
 

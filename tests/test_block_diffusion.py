@@ -130,6 +130,52 @@ def test_kernels_under_the_block_rule_match_a_dense_masked_softmax(
         assert not np.asarray(got[0])[:, :beta * offset].any()
 
 
+@pytest.mark.parametrize("offset", [0, 1])
+def test_grouped_backward_reads_each_kv_head_in_place(offset):
+    """The backward kernel under the block rule with K / V at their own
+    heads (2 kv heads, 4 query heads each): a program of a kv head's query
+    heads, Q blocks of 512 outer over two KV blocks, dK / dV summed over the
+    heads in VMEM — against the same backward with K / V repeated to the
+    query heads (dK / dV summed back) and against the dense masked softmax.
+    Under the offset the first query block's first 4 queries see no key:
+    their log-sum-exp is -inf and their gradients 0."""
+    rows, group, lq, beta, d = 2, 4, 1024, 4, 64
+    rng = np.random.RandomState(7 + offset)
+    q, do = (jnp.asarray(rng.randn(rows * group, lq, d), jnp.float32)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(rows, lq, d), jnp.float32)
+            for _ in range(2))
+    kr, vr = (jnp.repeat(a, group, axis=0) for a in (k, v))
+    mask = _rule_mask(lq, lq, beta, offset)
+    scale = 0.125
+    out, lse = mod._scan_forward(q, kr, vr, (beta, offset), scale, 128)
+    assert np.isneginf(np.asarray(lse)).any() == bool(offset)
+    telemetry.reset()
+    with interpret_kernels():
+        got = mod._flash_bwd((beta, offset), scale, None,
+                             (q, k, v, out, lse, None), do)
+        assert telemetry.value("flash.bwd.heads_per_kv_block") == group
+        assert telemetry.value("flash.bwd.rows_per_program") == group
+        rep = mod._flash_bwd((beta, offset), scale, None,
+                             (q, kr, vr, out, lse, None), do)
+        assert telemetry.value("flash.bwd.heads_per_kv_block") == 1
+    assert telemetry.value("bd.attn.bwd.pallas") == 2
+    rep = (rep[0],) + tuple(a.reshape(rows, group, lq, d).sum(1)
+                            for a in rep[1:])
+    _, vjp = jax.vjp(lambda q, k, v: _dense(
+        q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
+        mask, scale)[0], q, k, v)
+    for name, a, b, c in zip("qkv", got, rep, vjp(do)):
+        assert a.shape == c.shape, name
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5, err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=1e-4,
+                                   atol=1e-4, err_msg=name)
+    if offset:
+        assert not np.asarray(got[0])[:, :beta * offset].any()
+
+
 def _bd_mask(length, beta):
     """The (2L, 2L) mask, the noisy half first."""
     blk = np.arange(length) // beta

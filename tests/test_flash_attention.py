@@ -395,17 +395,71 @@ def test_kv_blocks_per_step_fill_what_the_rows_leave_of_vmem():
     assert mod._kv_blocks_per_step(4, 1, 2048, 2048, 128, 2, 128) == 1
 
 
-@pytest.mark.parametrize("bh,seq,d,dv,want", [
+def _kernel_calls(jaxpr):
+    """The params of every ``pallas_call`` in ``jaxpr``, nested ones too."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn.params)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    found += _kernel_calls(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    found += _kernel_calls(sub)
+    return found
+
+
+# what each group-1 shape traced before the backward could read K / V in
+# place, forward then backward: the kernel's grid and blocks, and a hash of
+# its jaxpr, grid mapping and compiler parameters (the text without source
+# locations, from which Mosaic's module is lowered)
+_PARENTS_KERNELS = {
+    "bert-s128": [
+        ((48, 1, 1), [(32, 64, 128)] * 4 + [(32, 128)], "8bd08293699501b5"),
+        ((96, 1, 1), [(16, 64, 128)] * 4 + [(16, 1, 128)]
+         + [(16, 64, 128)] * 4, "39f9112012260790")],
+    "bert-s512": [
+        ((96, 1, 1), [(4, 64, 512)] * 4 + [(4, 8, 512)], "0c2e75e448465df3"),
+        ((128, 1, 1), [(3, 64, 512)] * 4 + [(3, 1, 512)]
+         + [(3, 64, 512)] * 4, "2598f49dc3b99c35")],
+    "kanana-mla": [
+        ((32, 8, 2), [(2, 192, 512), (2, 192, 2048), (2, 128, 2048),
+                      (2, 128, 512), (2, 8, 512)], "f9445e1922d1fdc1"),
+        ((64, 8, 8), [(1, 192, 512), (1, 192, 512), (1, 128, 512),
+                      (1, 128, 512), (1, 1, 512), (1, 128, 512),
+                      (1, 192, 512), (1, 192, 512), (1, 128, 512)],
+         "926287c5acbe381c")],
+    "kimi-mla": [
+        ((16, 16, 4), [(2, 192, 512), (2, 192, 2048), (2, 128, 2048),
+                       (2, 128, 512), (2, 8, 512)], "5e8b9e7dbc31905e"),
+        ((32, 16, 16), [(1, 192, 512), (1, 192, 512), (1, 128, 512),
+                        (1, 128, 512), (1, 1, 512), (1, 128, 512),
+                        (1, 192, 512), (1, 192, 512), (1, 128, 512)],
+         "21105af07da86bd0")],
+}
+
+
+@pytest.mark.parametrize("bh,seq,d,dv,causal,want", [
     # (G, bq, bk, KV blocks a grid step) as the parent (PR 39) chose them
-    pytest.param(1536, 128, 64, 64, (32, 128, 128, 1), id="bert-s128"),
-    pytest.param(384, 512, 64, 64, (4, 512, 512, 1), id="bert-s512"),
-    pytest.param(64, 4096, 192, 128, (2, 512, 512, 4), id="kanana-mla"),
-    pytest.param(32, 8192, 192, 128, (2, 512, 512, 4), id="kimi-mla"),
+    pytest.param(1536, 128, 64, 64, False, (32, 128, 128, 1),
+                 id="bert-s128"),
+    pytest.param(384, 512, 64, 64, False, (4, 512, 512, 1), id="bert-s512"),
+    pytest.param(64, 4096, 192, 128, True, (2, 512, 512, 4),
+                 id="kanana-mla"),
+    pytest.param(32, 8192, 192, 128, True, (2, 512, 512, 4), id="kimi-mla"),
 ])
-def test_a_group_of_one_keeps_the_parents_program(bh, seq, d, dv, want):
+def test_a_group_of_one_keeps_the_parents_program(request, bh, seq, d, dv,
+                                                  causal, want):
     """Where every row has its own K / V (every call but grouped-query
     attention) the rule gives the forward program it gave before K / V
-    could be read in place: the same G, blocks and KV blocks a step."""
+    could be read in place: the same G, blocks and KV blocks a step; and
+    both kernels a differentiated call traces for the chip are the
+    parent's, forward and backward — grid, blocks, and the kernel's jaxpr
+    with its grid mapping and compiler parameters, hashed."""
+    import hashlib
+    from unittest import mock
     mod = _flash_module()
     block = mod._pick_block(seq)               # what _use_pallas gives
     g, bq, bk = mod._forward_tiling(bh, 1, seq, seq, block, block, d, 2,
@@ -413,6 +467,28 @@ def test_a_group_of_one_keeps_the_parents_program(bh, seq, d, dv, want):
     nsub = mod._kv_blocks_per_step(seq // bk, g, bq, bk, d, 2, dv) \
         if seq > bk else 1
     assert (g, bq, bk, nsub) == want
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: mod._flash(*a, causal, d ** -0.5).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    def rows(x):
+        return jax.ShapeDtypeStruct((bh, seq, x), jnp.bfloat16)
+    # traced as for the chip (nothing is lowered: no chip needed)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        calls = _kernel_calls(jax.make_jaxpr(grads)(rows(d), rows(d),
+                                                    rows(dv)).jaxpr)
+    got = []
+    for p in calls:
+        gm = p["grid_mapping"]
+        text = str(p["jaxpr"]) + str(gm) + str(p["compiler_params"])
+        got.append((tuple(gm.grid),
+                    [tuple(getattr(x, "block_size", x) for x in b.block_shape)
+                     for b in gm.block_mappings],
+                    hashlib.sha256(text.encode()).hexdigest()[:16]))
+    assert [str(p["name"]) for p in calls] == ["mxtpu_flash_fwd",
+                                               "mxtpu_flash_bwd"]
+    assert got == _PARENTS_KERNELS[request.node.callspec.id]
 
 
 def test_the_keye_query_heads_share_a_kv_block_within_the_budget():
@@ -741,3 +817,52 @@ def test_backward_keeps_the_scan_where_a_row_is_over_the_budget(monkeypatch):
         trace()
     assert telemetry.value("flash.fwd.pallas") == fwd0 + 1
     assert telemetry.value("flash.bwd.scan") == scan0 + 1
+
+
+def test_grouped_backward_blocks_at_the_cell_shapes():
+    """A backward program of a kv head's query heads takes the caller's
+    512-blocks at the keye shape (8 heads a kv head, L = 16384, d = 128,
+    masked) and the SDAR one (L = 8192, unmasked), the kv head's float32
+    dK / dV rows included, within the ceiling; a group of 16 at L = 16384
+    halves them; a row so long that its dK / dV alone are over the ceiling
+    has none."""
+    mod = _flash_module()
+    blocks = mod._grouped_backward_blocks
+    keye = (16384, 16384, 512, 512, 128, 2, 128, True)
+    assert blocks(8, *keye) == (512, 512)
+    assert mod._grouped_backward_vmem_bytes(
+        8, 512, 512, 16384, 128, 2, 128, True) <= mod._VMEM_GROUPED_CEILING
+    assert blocks(8, 8192, 8192, 512, 512, 128, 2, 128, False) == (512, 512)
+    assert blocks(16, *keye) == (256, 256)
+    assert blocks(8, 2 ** 18, 2 ** 18, 512, 512, 128, 2, 128, True) is None
+
+
+def test_grouped_backward_repeats_kv_where_a_program_is_over_the_ceiling(
+        monkeypatch):
+    """Where no program of a kv head's query heads fits, the backward takes
+    K / V repeated to the query rows (the parent's program, a head a row)
+    and sums dK / dV back: the same gradients as in place."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.kernel_mode import interpret_kernels
+    mod = _flash_module()
+    rng = np.random.RandomState(3)
+    q, do = (jnp.asarray(rng.randn(8, 256, 64), jnp.float32)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(2, 256, 64), jnp.float32)
+            for _ in range(2))
+    out, lse = mod._scan_forward(q, jnp.repeat(k, 4, axis=0),
+                                 jnp.repeat(v, 4, axis=0), True, 0.125, 128)
+
+    def grads():
+        return mod._flash_bwd(True, 0.125, None, (q, k, v, out, lse, None),
+                              do)
+    with interpret_kernels():
+        in_place = grads()
+        assert telemetry.value("flash.bwd.heads_per_kv_block") == 4
+        monkeypatch.setattr(mod, "_VMEM_GROUPED_CEILING", 2 ** 20)
+        repeated = grads()
+        assert telemetry.value("flash.bwd.heads_per_kv_block") == 1
+    for a, b in zip(in_place, repeated):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)

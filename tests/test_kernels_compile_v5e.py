@@ -101,13 +101,16 @@ def test_sparse_attention_kernels_at_the_keye_shape(one_chip, part):
     """The keye cell's four kernels at its own shapes (one sequence of
     16384, 32 / 4 heads of 128, 16 index heads of 64, top-2048): the
     index / select kernel with its (L, 128) scratch of keys, the masked
-    streaming flash forward and backward (a row's float32 dQ is 8 MiB:
-    Mosaic's limit is raised) with K / V at their 4 heads as the op hands
-    them on (the forward's programs of 8 query heads, 256-blocks, 8 KV
-    blocks a grid step) and repeated to the 32 (a group of 1: the parent's
-    program), and the alignment loss's value kernel and
+    streaming flash forward and backward with K / V at their 4 heads as the
+    op hands them on (the forward's programs of 8 query heads, 256-blocks,
+    8 KV blocks a grid step; the backward's of the same 8 heads at
+    512-blocks, Q blocks outer, the kv head's float32 dK / dV rows, 16 MiB,
+    in VMEM: Mosaic's limit is raised) and repeated to the 32 (a group of
+    1: the parent's programs, the backward's holding a row's 8 MiB float32
+    dQ), and the alignment loss's value kernel and
     gradient kernel (the latter with its 4 MiB scratch and the resident
     ``dki`` row): one Mosaic call each, by name, and no scan left."""
+    from mxnet_tpu import telemetry
     from mxnet_tpu.ops import sparse_attention as sa
     from mxnet_tpu.ops.flash_attention import masked_flash
     b, h, hkv, seq, d, hi, di, topk = 1, 32, 4, 16384, 128, 16, 64, 2048
@@ -129,6 +132,8 @@ def test_sparse_attention_kernels_at_the_keye_shape(one_chip, part):
         kv = shape((b * (h if part.endswith("repeated") else hkv), seq, d))
         text = _compile(both, shape((b * h, seq, d)), kv, kv, mask)
         names = ["mxtpu_dsa_attn_fwd", "mxtpu_dsa_attn_bwd"]
+        group = 1 if part.endswith("repeated") else h // hkv
+        assert telemetry.value("flash.bwd.heads_per_kv_block") == group
     else:
         def grads(q, k, lse, qi, ki, w, mask, lse_i):
             return jax.value_and_grad(
@@ -202,7 +207,10 @@ def test_block_diffusion_kernels_at_the_sdar_shape(one_chip):
     query / 4 key-value heads of 128, blocks of 4 — the clean half's
     block-causal call and the noisy half's offset call, each a forward and
     a backward kernel by the block rule's own names, the 8 query heads of a
-    kv head reading it in place, and no scan left."""
+    kv head reading it in place in both (the backward's programs at
+    512-blocks, Q blocks outer, the kv head's float32 dK / dV rows in
+    VMEM), and no scan left."""
+    from mxnet_tpu import telemetry
     from mxnet_tpu.ops.flash_attention import block_diffusion_attention
 
     def shape(rows):
@@ -219,5 +227,7 @@ def test_block_diffusion_kernels_at_the_sdar_shape(one_chip):
     names = sorted(re.search(r'op_name="[^"]*(mxtpu_\w+)', ln).group(1)
                    for ln in calls)
     assert names == ["mxtpu_bd_attn_bwd"] * 2 + ["mxtpu_bd_attn_fwd"] * 2
+    assert telemetry.value("flash.fwd.heads_per_kv_block") == 8
+    assert telemetry.value("flash.bwd.heads_per_kv_block") == 8
     assert "mxtpu_flash" not in text
     assert " while(" not in text

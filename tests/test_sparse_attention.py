@@ -316,13 +316,16 @@ def _grouped_dense(q, k, v, keep, group, d):
 def test_masked_flash_reads_each_kv_head_in_place(group):
     """Grouped-query attention with K / V at their own heads — a forward
     program of the query heads of one kv head, one K / V block and one mask
-    tile for them all — against the same call with K / V repeated to the
-    query heads and against a dense masked softmax: out, the log-sum-exp
-    and the gradients of q, k, v (dK / dV summed over a kv head's query
-    heads).  Two batches with masks of their own; at L = 512 a group of 8
-    or 4 streams two square 256-blocks, a group of 1 (the repeated form's
-    own program) takes one 512-block."""
-    b, h, seq, d = 2, 8, 512, 64
+    tile for them all, and a backward program of all of them, Q blocks
+    outer, the kv head's dK / dV summed in VMEM — against the same call with
+    K / V repeated to the query heads and against a dense masked softmax:
+    out, the log-sum-exp and the gradients of q, k, v (dK / dV summed over
+    a kv head's query heads).  Two batches with masks of their own; at
+    L = 1024 the backward of a group of 8 or 4 walks two Q blocks of 512
+    over two KV blocks (one dead step a kv head, the dK / dV of the first
+    KV block written out on the last Q block's walk), a group of 1 takes
+    the parent's program."""
+    b, h, seq, d = 2, 8, 1024, 64
     hkv = h // group
     q = _rand(40, b * h, seq, d)
     k, v = _rand(41, b * hkv, seq, d), _rand(42, b * hkv, seq, d)
@@ -340,9 +343,13 @@ def test_masked_flash_reads_each_kv_head_in_place(group):
     with interpret_kernels():
         grads, (out, lse) = run(lambda x: x)
         assert telemetry.value("flash.fwd.heads_per_kv_block") == group
+        assert telemetry.value("flash.bwd.heads_per_kv_block") == group
+        assert telemetry.value("flash.bwd.rows_per_program") == (
+            group if group > 1 else 1)
         rep_grads, (rep_out, rep_lse) = run(
             lambda x: jnp.repeat(x, group, axis=0))
         assert telemetry.value("flash.fwd.heads_per_kv_block") == 1
+        assert telemetry.value("flash.bwd.heads_per_kv_block") == 1
     want_out, want_lse = _grouped_dense(q, k, v, keep, group, d)
     for got in (out, rep_out):
         np.testing.assert_allclose(got, want_out, atol=2e-5)
