@@ -30,10 +30,9 @@
    buffers (``window_rows``) as the rows need (``rung_rows``) — one in
    most steps, four at the worst case, float32 sums between them: no
    routing is dropped or clipped at any fill, and no array of the layer
-   has the worst case's rows.  The token-side sums are taken in the row
-   domain too (a gather into token order, a sum over neighbours, a gather
-   of T), so a buffer's traffic is about ``5 rows + 2 T`` rows of d where
-   the worst-case form moved ``7 R``.
+   has the worst case's rows.  The token-side sums (the combine, and the
+   dispatch's transpose) read each token's routed rows where they lie in
+   the buffer (``ops.moe_sum_rows``): no copy of the buffer in token order.
    The loops are not differentiated through (their trip count is data):
    the layer is one custom VJP that keeps its operands alone, with a loop
    of its own in each rule; the backward rule's loop computes each buffer
@@ -245,14 +244,9 @@ class _Plan(NamedTuple):
 
 class _Window(NamedTuple):
     """``rows`` consecutive rows of the sorted choices, from ``start``: what
-    one pass through the experts works on.  Rows are in sorted order; slots
-    hold the same choices in token order."""
+    one pass through the experts works on."""
     choice_of_row: jax.Array    # (rows,)
     row_is_routed: jax.Array    # (rows,)
-    choice_of_slot: jax.Array   # (rows,)
-    row_of_slot: jax.Array      # (rows,) the permutation between the two
-    last_slot: jax.Array        # (T,) of each token's last choice in here
-    token_is_here: jax.Array    # (T,) whether it has one
     row_of_choice: jax.Array    # (T, k) counted from ``start``
     choice_is_here: jax.Array   # (T, k)
     group_sizes: jax.Array      # (held,) the rows of each expert in here
@@ -263,18 +257,8 @@ class _Window(NamedTuple):
 
 
 def _window(plan, rows, start):
-    tokens, k = plan.row_of_choice.shape
-    choices = jnp.arange(tokens * k, dtype=jnp.int32)
     row_of_choice = plan.row_of_choice - start
     here = plan.choice_is_held & (row_of_choice >= 0) & (row_of_choice < rows)
-    row_of_choice = jnp.where(here, row_of_choice, 0)
-    # the choices in here are in token order already: a running count packs
-    # them to the front (the others behind them, so that the packing is a
-    # permutation)
-    slots = jnp.cumsum(here.reshape(-1), dtype=jnp.int32)
-    choice_of_slot = jnp.zeros(tokens * k, jnp.int32).at[
-        jnp.where(here.reshape(-1), slots - 1, slots[-1] + choices - slots)
-    ].set(choices, unique_indices=True)[:rows]
     ends = jnp.cumsum(plan.group_sizes)
     return _Window(
         # (the last window may reach past the last choice, and dynamic_slice
@@ -282,9 +266,7 @@ def _window(plan, rows, start):
         lax.dynamic_slice(jnp.pad(plan.choice_of_row, (0, rows)),
                           (start,), (rows,)),
         start + jnp.arange(rows, dtype=jnp.int32) < plan.routed,
-        choice_of_slot, row_of_choice.reshape(-1)[choice_of_slot],
-        jnp.maximum(slots.reshape(tokens, k)[:, -1] - 1, 0),
-        jnp.any(here, axis=1), row_of_choice, here,
+        jnp.where(here, row_of_choice, 0), here,
         jnp.clip(ends, start, start + rows)
         - jnp.clip(ends - plan.group_sizes, start, start + rows))
 
@@ -297,31 +279,13 @@ def _gather_rows(v, window):
 
 
 def _sum_rows(rows, window, scale=None):
-    """``out[t] = sum of scale[slot] * rows[row of the slot]`` over the
-    slots of token ``t``, float32; zeros for a token with no choice in the
-    window.  One gather brings the rows into token order, where a token's
-    rows are neighbours; each slot then adds the up to ``k - 1`` slots
-    before it that carry its token (shifted slices, one fusion), and the
-    last slot of each token's run is the token's sum: a gather of T."""
-    k, n = window.top_k, rows.shape[0]
-    # k - 1 slots of no token in front, by way of the indices, so that every
-    # shift below is a slice of what the gather wrote.  A slot in use looks
-    # back at slots in use only and no other is read at the end, so neither
-    # needs a mask.
-    front = jnp.zeros(k - 1, jnp.int32)
-    token = jnp.concatenate([front - 1, window.choice_of_slot // k])
-    ordered = rows[jnp.concatenate([front, window.row_of_slot])]
-    if scale is not None:
-        scale = jnp.concatenate([front.astype(scale.dtype), scale])
-
-    def shifted(s):
-        term = ordered[s:s + n].astype(jnp.float32)
-        if scale is not None:
-            term = term * scale[s:s + n, None]
-        return jnp.where((token[s:s + n] == token[k - 1:])[:, None], term, 0)
-
-    runs = sum(shifted(s) for s in range(k))
-    return jnp.where(window.token_is_here[:, None], runs[window.last_slot], 0)
+    """``out[t] = sum_j scale[t, j] * rows[row_of_choice[t, j]]`` over the
+    choices of token ``t`` in the window, float32, from 0 and in ascending
+    ``j``; zeros for a token with no choice in the window.  Each routed row
+    is read once where it lies (``ops.moe_sum_rows``)."""
+    from ..ops.moe_sum_rows import sum_rows
+    return sum_rows(rows, window.row_of_choice, window.choice_is_here, scale,
+                    jnp.sum(window.row_is_routed, dtype=jnp.int32))
 
 
 @jax.custom_vjp
@@ -342,8 +306,7 @@ _dispatch.defvjp(lambda x, window: (_gather_rows(x, window), window),
 def _combine(buffer, weights, window):
     """``out[t] = sum_j weights[t, j] * buffer[row_of_choice[t, j]]`` over
     the choices in the window, float32."""
-    scale = weights.reshape(-1)[window.choice_of_slot].astype(jnp.float32)
-    return _sum_rows(buffer, window, scale)
+    return _sum_rows(buffer, window, weights.astype(jnp.float32))
 
 
 def _combine_fwd(buffer, weights, window):

@@ -231,3 +231,37 @@ def test_block_diffusion_kernels_at_the_sdar_shape(one_chip):
     assert telemetry.value("flash.bwd.heads_per_kv_block") == 8
     assert "mxtpu_flash" not in text
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("tokens,k,d,rows", [
+    pytest.param(8192, 6, 2048, 12288, id="kanana"),
+    pytest.param(16384, 8, 2048, 32768, id="keye"),
+    pytest.param(8192, 8, 2304, 16384, id="kimi"),
+    pytest.param(16384, 8, 2048, 65536, id="sdar"),
+])
+def test_token_side_sum_at_the_four_expert_cells(one_chip, tokens, k, d,
+                                                 rows):
+    """The expert layer's token-side sum at each cell's (tokens, top_k,
+    hidden, buffer rows): the combine (weighted) and the dispatch's
+    transpose, each the copy of the routed rows into slabs and the sum,
+    by name, and no gather of rows left."""
+    from mxnet_tpu.ops.moe_sum_rows import sum_rows
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(buffer, row_of_choice, here, weights, routed):
+        return (sum_rows(buffer, row_of_choice, here, weights, routed),
+                sum_rows(buffer, row_of_choice, here, None, routed))
+    text = _compile(both, shaped((rows, d), jnp.bfloat16),
+                    shaped((tokens, k), jnp.int32),
+                    shaped((tokens, k), jnp.bool_),
+                    shaped((tokens, k), jnp.float32),
+                    shaped((), jnp.int32))
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = sorted(re.search(r'op_name="[^"]*(mxtpu_\w+)', ln).group(1)
+                   for ln in calls)
+    assert names == ["mxtpu_moe_sum_rows"] * 2 + \
+        ["mxtpu_moe_sum_rows_slabs"] * 2
+    assert not re.search(rf"= bf16\[\d+,{d}\]\S* (gather|fusion)\(", text)
