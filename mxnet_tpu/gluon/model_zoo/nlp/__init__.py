@@ -11,13 +11,14 @@ from .transformer import *  # noqa: F401,F403
 from .language_model import *  # noqa: F401,F403
 from .sampler import *  # noqa: F401,F403
 from .llama import *  # noqa: F401,F403
+from .decoder import *  # noqa: F401,F403
 from .deepseek_v3 import *  # noqa: F401,F403
 from .keye_vl2 import *  # noqa: F401,F403
 from .kimi_linear import *  # noqa: F401,F403
 from .sdar_moe import *  # noqa: F401,F403
 
 from . import attention, bert, transformer, language_model, sampler, \
-    llama, deepseek_v3, keye_vl2, kimi_linear, sdar_moe  # noqa
+    llama, decoder, deepseek_v3, keye_vl2, kimi_linear, sdar_moe  # noqa
 
 _MODELS = {}
 for _m in (bert, transformer, language_model):

@@ -27,7 +27,7 @@ T) positions (temporal, height, width; for text all three the token's index):
 - ``y = RMSNorm(h)``; ``g = softmax(y W_r)`` over all ``num_experts`` in
   float32; the ``num_experts_per_tok`` largest, divided by their sum
   (``norm_topk_prob``); ``h += sum_{e chosen and held} g_e E_e(y)``, ``E_e``
-  SwiGLU of width ``moe_intermediate_size``: ``deepseek_v3.MoEBlock`` with a
+  SwiGLU of width ``moe_intermediate_size``: ``decoder.MoEBlock`` with a
   softmax router and no shared expert, holding ``experts_held`` of the
   experts from ``expert_offset`` on.
 
@@ -44,82 +44,64 @@ as published), ``L_I`` (its sparse-training stage), ``q_chunk_size`` /
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+
 import jax
 
 from ....base import MXNetError
 from ....initializer import Normal
-from ... import nn
 from ...block import HybridBlock
-from .deepseek_v3 import MoEBlock
+from .decoder import DecoderLM, MoEBlock, dense, expert_share, head_norm
 from .llama import RMSNorm
 
 __all__ = ["KeyeVL2Config", "SparseIndexer", "SparseGQAttention",
-           "KeyeVL2Layer", "KeyeVL2Model", "KeyeVL2ForCausalLM",
-           "causal_lm_loss", "keye_vl2_30b_a3b", "keye_vl2_tiny"]
+           "KeyeVL2Layer", "KeyeVL2LM", "causal_lm_loss", "keye_vl2_30b_a3b",
+           "keye_vl2_tiny"]
 
 
+@dataclasses.dataclass
 class KeyeVL2Config:
     """Sizes under the names of the published ``config.json`` (``sa_config``
-    flattened to ``indexer_*`` and ``topk``).  ``n_routed_experts``,
-    ``n_shared_experts``, ``scoring_func`` and ``routed_scaling_factor`` are
-    what ``deepseek_v3.MoEBlock`` reads.  ``embedding_initializer_range`` is
-    the embedding's own standard deviation (``initializer_range`` where
+    flattened to ``indexer_*`` and ``topk``).  ``embedding_initializer_range``
+    is the embedding's own standard deviation (``initializer_range`` where
     None)."""
 
-    def __init__(self, vocab_size=151936, hidden_size=2048,
-                 moe_intermediate_size=768, num_hidden_layers=48,
-                 num_attention_heads=32, num_key_value_heads=4, head_dim=128,
-                 num_experts=128, num_experts_per_tok=8, norm_topk_prob=True,
-                 rope_theta=10000000.0, mrope_section=(16, 24, 24),
-                 rms_norm_eps=1e-6, indexer_num_heads=16, indexer_head_dim=64,
-                 topk=2048, experts_held=None, expert_offset=0,
-                 initializer_range=0.02, embedding_initializer_range=None):
-        self.vocab_size = vocab_size
-        self.hidden_size = hidden_size
-        self.moe_intermediate_size = moe_intermediate_size
-        self.num_hidden_layers = num_hidden_layers
-        self.num_attention_heads = num_attention_heads
-        self.num_key_value_heads = num_key_value_heads
-        self.head_dim = head_dim
-        self.n_routed_experts = self.num_experts = num_experts
-        self.num_experts_per_tok = num_experts_per_tok
-        self.norm_topk_prob = norm_topk_prob
-        self.rope_theta = rope_theta
-        self.mrope_section = tuple(mrope_section)
-        self.rms_norm_eps = rms_norm_eps
-        self.indexer_num_heads = indexer_num_heads
-        self.indexer_head_dim = indexer_head_dim
-        self.topk = topk
-        self.experts_held = num_experts if experts_held is None \
-            else experts_held
-        self.expert_offset = expert_offset
-        self.initializer_range = initializer_range
-        self.embedding_initializer_range = initializer_range \
-            if embedding_initializer_range is None \
-            else embedding_initializer_range
-        self.n_shared_experts = 0
-        self.scoring_func = "softmax"
-        self.routed_scaling_factor = 1.0
-        if num_attention_heads % num_key_value_heads:
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    moe_intermediate_size: int = 768
+    num_hidden_layers: int = 48
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    num_experts: int = 128
+    num_experts_per_tok: int = 8
+    norm_topk_prob: bool = True
+    rope_theta: float = 10000000.0
+    mrope_section: tuple = (16, 24, 24)
+    rms_norm_eps: float = 1e-6
+    indexer_num_heads: int = 16
+    indexer_head_dim: int = 64
+    topk: int = 2048
+    experts_held: int | None = None
+    expert_offset: int = 0
+    initializer_range: float = 0.02
+    embedding_initializer_range: float | None = None
+
+    def __post_init__(self):
+        self.mrope_section = tuple(self.mrope_section)
+        self.experts_held = expert_share(
+            self.num_experts, self.num_experts_per_tok, self.experts_held,
+            self.expert_offset)
+        if self.num_attention_heads % self.num_key_value_heads:
             raise MXNetError("num_key_value_heads must divide "
                              "num_attention_heads")
-        if head_dim % 2 or indexer_head_dim % 2 or \
-                sum(self.mrope_section) != head_dim // 2:
+        d = self.head_dim
+        if d % 2 or self.indexer_head_dim % 2 or \
+                sum(self.mrope_section) != d // 2:
             raise MXNetError(
                 f"mrope_section {self.mrope_section} does not divide the "
-                f"{head_dim // 2} rotary frequencies of head_dim {head_dim}")
-        if num_experts_per_tok > num_experts:
-            raise MXNetError("num_experts_per_tok exceeds num_experts")
-        if not (0 <= expert_offset and self.experts_held >= 1 and
-                expert_offset + self.experts_held <= num_experts):
-            raise MXNetError(
-                f"experts {expert_offset}..{expert_offset + self.experts_held}"
-                f" are not among the {num_experts} experts")
-
-
-def _dense(units, cfg, name):
-    return nn.Dense(units, use_bias=False, flatten=False, prefix=name + "_",
-                    weight_initializer=Normal(cfg.initializer_range))
+                f"{d // 2} rotary frequencies of head_dim {d}")
 
 
 class SparseIndexer(HybridBlock):
@@ -130,9 +112,10 @@ class SparseIndexer(HybridBlock):
     def __init__(self, cfg, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
-            self.wq_proj = _dense(
-                cfg.indexer_num_heads * cfg.indexer_head_dim, cfg, "wq_proj")
-            self.wk_proj = _dense(cfg.indexer_head_dim, cfg, "wk_proj")
+            self.wq_proj = dense(cfg.indexer_num_heads * cfg.indexer_head_dim,
+                                 cfg.initializer_range, "wq_proj")
+            self.wk_proj = dense(cfg.indexer_head_dim, cfg.initializer_range,
+                                 "wk_proj")
             self.weights_proj = self.params.get(
                 "weights_proj_weight",
                 shape=(cfg.indexer_num_heads, cfg.hidden_size),
@@ -152,26 +135,23 @@ class SparseGQAttention(HybridBlock):
         self.cfg = cfg
         h, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
             cfg.head_dim
+        std = cfg.initializer_range
         with self.name_scope():
-            self.q_proj = _dense(h * d, cfg, "q_proj")
-            self.k_proj = _dense(hkv * d, cfg, "k_proj")
-            self.v_proj = _dense(hkv * d, cfg, "v_proj")
-            self.o_proj = _dense(cfg.hidden_size, cfg, "o_proj")
+            self.q_proj = dense(h * d, std, "q_proj")
+            self.k_proj = dense(hkv * d, std, "k_proj")
+            self.v_proj = dense(hkv * d, std, "v_proj")
+            self.o_proj = dense(cfg.hidden_size, std, "o_proj")
             self.q_norm = RMSNorm(d, cfg.rms_norm_eps, prefix="q_norm_")
             self.k_norm = RMSNorm(d, cfg.rms_norm_eps, prefix="k_norm_")
-
-    def _head_norm(self, F, norm, a, heads):
-        # (B, T, heads * d): the norm over each head's d
-        return F.reshape(norm(F.reshape(a, (0, 0, heads, -1))), (0, 0, -1))
 
     def hybrid_forward(self, F, x, q_index, k_index, x_index, w_index,
                        positions=None):
         cfg = self.cfg
         with jax.named_scope("gqa.project"):
-            q = self._head_norm(F, self.q_norm, self.q_proj(x),
-                                cfg.num_attention_heads)
-            k = self._head_norm(F, self.k_norm, self.k_proj(x),
-                                cfg.num_key_value_heads)
+            q = head_norm(F, self.q_norm, self.q_proj(x),
+                          cfg.num_attention_heads)
+            k = head_norm(F, self.k_norm, self.k_proj(x),
+                          cfg.num_key_value_heads)
             v = self.v_proj(x)
         out, index_loss = F.sparse_gq_attention(
             q, k, v, q_index, k_index, x_index, w_index, positions,
@@ -182,9 +162,11 @@ class SparseGQAttention(HybridBlock):
 
 
 class KeyeVL2Layer(HybridBlock):
-    """``(h, index_loss_so_far[, positions]) -> (h, index_loss_so_far)``."""
+    """``(h, index_loss_so_far[, positions]) -> (h, index_loss_so_far)``:
+    the pre-norm pair of ``decoder.DecoderLayer`` with the indexer beside
+    the attention."""
 
-    def __init__(self, cfg, **kwargs):
+    def __init__(self, cfg, moe, **kwargs):
         super().__init__(**kwargs)
         with self.name_scope():
             self.input_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
@@ -193,58 +175,28 @@ class KeyeVL2Layer(HybridBlock):
             self.attention = SparseGQAttention(cfg, prefix="attn_")
             self.post_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
                                      prefix="post_norm_")
-            self.moe = MoEBlock(cfg, prefix="moe_")
+            self.mlp = moe()
 
     def hybrid_forward(self, F, x, index_loss, positions=None):
         normed = self.input_norm(x)
         attn, layer_loss = self.attention(normed, *self.indexer(normed),
                                           positions)
         x = x + attn
-        return x + self.moe(self.post_norm(x)), index_loss + layer_loss
+        return x + self.mlp(self.post_norm(x)), index_loss + layer_loss
 
 
-class KeyeVL2Model(HybridBlock):
-    def __init__(self, cfg, **kwargs):
-        super().__init__(**kwargs)
-        self.cfg = cfg
-        with self.name_scope():
-            self.embed = nn.Embedding(
-                cfg.vocab_size, cfg.hidden_size, prefix="embed_",
-                weight_initializer=Normal(cfg.embedding_initializer_range))
-            self.layers = nn.HybridSequential(prefix="")
-            for i in range(cfg.num_hidden_layers):
-                self.layers.add(KeyeVL2Layer(cfg, prefix=f"layer{i}_"))
-            self.norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
-                                prefix="norm_")
-
-    def hybrid_forward(self, F, tokens, positions=None):
-        x = self.embed(tokens)
-        index_loss = F.zeros((tokens.shape[0],), dtype="float32")
-        for layer in self.layers:
-            x, index_loss = layer(x, index_loss, positions)
-        return self.norm(x), index_loss
-
-    def remat(self, active=True):
-        """Per-layer ``jax.checkpoint``, as ``DeepseekV3Model.remat``."""
-        for layer in self.layers:
-            layer.hybridize(active, remat=active)
-
-
-class KeyeVL2ForCausalLM(HybridBlock):
+class KeyeVL2LM(DecoderLM):
     """tokens (B, T) [, positions (3, B, T)] -> ``(logits (B, T,
-    vocab_size), index_loss (B,))`` over the rows of the vocabulary held
-    here; the head is not tied to the embedding."""
-
-    def __init__(self, cfg, **kwargs):
-        super().__init__(**kwargs)
-        self.cfg = cfg
-        with self.name_scope():
-            self.model = KeyeVL2Model(cfg, prefix="model_")
-            self.lm_head = _dense(cfg.vocab_size, cfg, "lm_head")
+    vocab_size), index_loss (B,))``: the decoder, its layers carrying the
+    indexer's alignment loss."""
 
     def hybrid_forward(self, F, tokens, positions=None):
-        hidden, index_loss = self.model(tokens, positions)
-        return self.lm_head(hidden), index_loss
+        model = self.model
+        x = model.embed(tokens)
+        index_loss = F.zeros((tokens.shape[0],), dtype="float32")
+        for layer in model.layers:
+            x, index_loss = layer(x, index_loss, positions)
+        return self.lm_head(model.norm(x)), index_loss
 
 
 def causal_lm_loss():
@@ -260,11 +212,28 @@ def causal_lm_loss():
     return loss
 
 
+def _network(cfg):
+    moe = functools.partial(
+        MoEBlock, hidden_size=cfg.hidden_size,
+        expert_width=cfg.moe_intermediate_size, num_experts=cfg.num_experts,
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
+        top_k=cfg.num_experts_per_tok, norm_topk_prob=cfg.norm_topk_prob,
+        scoring_func="softmax", initializer_range=cfg.initializer_range,
+        prefix="moe_")
+    return KeyeVL2LM(
+        [functools.partial(KeyeVL2Layer, cfg, moe)
+         for _ in range(cfg.num_hidden_layers)],
+        vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
+        rms_norm_eps=cfg.rms_norm_eps,
+        initializer_range=cfg.initializer_range,
+        embedding_initializer_range=cfg.embedding_initializer_range)
+
+
 def keye_vl2_30b_a3b(**overrides):
     """Kwai-Keye/Keye-VL-2.0-30B-A3B's language model at its published sizes
     (pass ``experts_held``, ``vocab_size`` and ``num_hidden_layers`` for one
     chip's share)."""
-    return KeyeVL2ForCausalLM(KeyeVL2Config(**overrides))
+    return _network(KeyeVL2Config(**overrides))
 
 
 def keye_vl2_tiny(**overrides):
@@ -276,4 +245,4 @@ def keye_vl2_tiny(**overrides):
               num_experts_per_tok=2, mrope_section=(2, 3, 3),
               indexer_num_heads=2, indexer_head_dim=8, topk=8)
     kw.update(overrides)
-    return KeyeVL2ForCausalLM(KeyeVL2Config(**kw))
+    return _network(KeyeVL2Config(**kw))

@@ -29,14 +29,14 @@ Mixer(RMSNorm(h)); h += FFN(RMSNorm(h))``.  The source counts its layers from
   head's ``d`` with one learned weight of ``d``, a sigmoid gate through a
   second low-rank pair.
 
-**Latent attention** is ``deepseek_v3.MLAAttention`` with ``mla_use_nope``:
-the 64 "rope" dims of q and the shared key stay, nothing is rotated.  **The
-feed-forward** is a dense SwiGLU in the first ``first_k_dense_replace`` layers
-and ``deepseek_v3.MoEBlock`` in the others (sigmoid scores over all
-``num_experts``, the ``num_experts_per_token`` largest renormalised and scaled,
-one shared expert), holding ``experts_held`` of the experts from
-``expert_offset`` on; ``vocab_size`` is the rows of the embedding and the head
-held here.
+**Latent attention** is ``deepseek_v3.MLAAttention`` with ``use_nope`` (the
+configuration's ``mla_use_nope``): the 64 "rope" dims of q and the shared key
+stay, nothing is rotated.  **The feed-forward** is a dense SwiGLU in the first
+``first_k_dense_replace`` layers and ``decoder.MoEBlock`` in the others
+(sigmoid scores over all ``num_experts``, the ``num_experts_per_token``
+largest renormalised and scaled, one shared expert), holding ``experts_held``
+of the experts from ``expert_offset`` on; ``vocab_size`` is the rows of the
+embedding and the head held here.
 
 Assumed where the published ``config.json`` has no key (the benchmark's
 configuration file lists the same), each as the published architecture's
@@ -48,8 +48,9 @@ scale; the decay's parametrisation and its draws (``exp(A_log)`` uniform on
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
-import types
 
 import jax
 import jax.numpy as jnp
@@ -57,94 +58,70 @@ import jax.numpy as jnp
 from .... import random as _rnd
 from ....base import MXNetError
 from ....initializer import Initializer, Normal
-from ... import nn
 from ...block import HybridBlock
-from .deepseek_v3 import MLAAttention, MoEBlock, _dense
-from .llama import LlamaMLP, RMSNorm
+from .decoder import DecoderLM, DecoderLayer, MoEBlock, dense, \
+    expert_share, swiglu
+from .deepseek_v3 import MLAAttention
+from .llama import RMSNorm
 
-__all__ = ["KimiLinearConfig", "KimiDeltaAttention", "KimiLinearLayer",
-           "KimiLinearModel", "KimiLinearForCausalLM", "kimi_linear_48b_a3b",
+__all__ = ["KimiLinearConfig", "KimiDeltaAttention", "kimi_linear_48b_a3b",
            "kimi_linear_tiny"]
 
 _PUBLISHED_FULL = (4, 8, 12, 16, 20, 24, 27)
 
 
+@dataclasses.dataclass
 class KimiLinearConfig:
     """Sizes under the names of the published ``config.json``
     (``linear_attn_config`` flattened to ``kda_*``, ``short_conv_kernel_size``
-    and the two layer lists).  ``n_routed_experts``, ``n_shared_experts``,
-    ``num_experts_per_tok``, ``norm_topk_prob`` and ``scoring_func`` are the
-    same sizes under the names ``deepseek_v3.MoEBlock`` reads."""
+    and the two layer lists)."""
 
-    def __init__(self, vocab_size=163840, hidden_size=2304,
-                 intermediate_size=9216, moe_intermediate_size=1024,
-                 num_hidden_layers=27, first_k_dense_replace=1,
-                 num_attention_heads=32, kv_lora_rank=512,
-                 qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-                 mla_use_nope=True, kda_num_heads=32, kda_head_dim=128,
-                 short_conv_kernel_size=4, kda_layers=None,
-                 full_attn_layers=_PUBLISHED_FULL, num_experts=256,
-                 num_experts_per_token=8, num_shared_experts=1,
-                 routed_scaling_factor=2.446, moe_renormalize=True,
-                 rope_theta=10000.0, rms_norm_eps=1e-5, experts_held=None,
-                 expert_offset=0, initializer_range=0.02,
-                 embedding_initializer_range=None):
-        self.vocab_size = vocab_size
-        self.hidden_size = hidden_size
-        self.intermediate_size = intermediate_size
-        self.moe_intermediate_size = moe_intermediate_size
-        self.num_hidden_layers = num_hidden_layers
-        self.first_k_dense_replace = first_k_dense_replace
-        self.num_attention_heads = num_attention_heads
-        self.kv_lora_rank = kv_lora_rank
-        self.qk_nope_head_dim = qk_nope_head_dim
-        self.qk_rope_head_dim = qk_rope_head_dim
-        self.v_head_dim = v_head_dim
-        self.mla_use_nope = mla_use_nope
-        self.kda_num_heads = kda_num_heads
-        self.kda_head_dim = kda_head_dim
-        self.short_conv_kernel_size = short_conv_kernel_size
-        layers = range(1, num_hidden_layers + 1)
+    vocab_size: int = 163840
+    hidden_size: int = 2304
+    intermediate_size: int = 9216
+    moe_intermediate_size: int = 1024
+    num_hidden_layers: int = 27
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    mla_use_nope: bool = True
+    kda_num_heads: int = 32
+    kda_head_dim: int = 128
+    short_conv_kernel_size: int = 4
+    kda_layers: tuple | None = None
+    full_attn_layers: tuple = _PUBLISHED_FULL
+    num_experts: int = 256
+    num_experts_per_token: int = 8
+    num_shared_experts: int = 1
+    routed_scaling_factor: float = 2.446
+    moe_renormalize: bool = True
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-5
+    experts_held: int | None = None
+    expert_offset: int = 0
+    initializer_range: float = 0.02
+    embedding_initializer_range: float | None = None
+
+    def __post_init__(self):
+        n = self.num_hidden_layers
         self.full_attn_layers = tuple(
-            i for i in full_attn_layers if i <= num_hidden_layers)
+            i for i in self.full_attn_layers if i <= n)
         self.kda_layers = tuple(
-            i for i in layers if i not in self.full_attn_layers) \
-            if kda_layers is None else tuple(
-                i for i in kda_layers if i <= num_hidden_layers)
-        self.n_routed_experts = self.num_experts = num_experts
-        self.num_experts_per_tok = self.num_experts_per_token = \
-            num_experts_per_token
-        self.n_shared_experts = self.num_shared_experts = num_shared_experts
-        self.routed_scaling_factor = routed_scaling_factor
-        self.norm_topk_prob = self.moe_renormalize = moe_renormalize
-        self.scoring_func = "sigmoid"
-        self.rope_theta = rope_theta
-        self.rms_norm_eps = rms_norm_eps
-        self.experts_held = num_experts if experts_held is None \
-            else experts_held
-        self.expert_offset = expert_offset
-        self.initializer_range = initializer_range
-        self.embedding_initializer_range = initializer_range \
-            if embedding_initializer_range is None \
-            else embedding_initializer_range
-        if sorted(self.kda_layers + self.full_attn_layers) != list(layers):
+            i for i in range(1, n + 1) if i not in self.full_attn_layers) \
+            if self.kda_layers is None else tuple(
+                i for i in self.kda_layers if i <= n)
+        self.experts_held = expert_share(
+            self.num_experts, self.num_experts_per_token, self.experts_held,
+            self.expert_offset)
+        if sorted(self.kda_layers + self.full_attn_layers) != \
+                list(range(1, n + 1)):
             raise MXNetError(
                 f"kda_layers {self.kda_layers} and full_attn_layers "
                 f"{self.full_attn_layers} do not give each of the layers 1.."
-                f"{num_hidden_layers} one mixer")
-        if num_experts_per_token > num_experts:
-            raise MXNetError("num_experts_per_token exceeds num_experts")
-        if not (0 <= expert_offset and self.experts_held >= 1 and
-                expert_offset + self.experts_held <= num_experts):
-            raise MXNetError(
-                f"experts {expert_offset}..{expert_offset + self.experts_held}"
-                f" are not among the {num_experts} experts")
-
-    def mlp(self, width):
-        """What ``LlamaMLP`` reads of a configuration, at this width."""
-        return types.SimpleNamespace(hidden_size=self.hidden_size,
-                                     intermediate_size=width,
-                                     tensor_parallel=False)
+                f"{n} one mixer")
 
 
 class _LogUniform(Initializer):
@@ -175,11 +152,12 @@ class KimiDeltaAttention(HybridBlock):
         super().__init__(**kwargs)
         self.cfg = cfg
         h, d = cfg.kda_num_heads, cfg.kda_head_dim
-        init = Normal(cfg.initializer_range)
+        std = cfg.initializer_range
+        init = Normal(std)
         with self.name_scope():
-            self.q_proj = _dense(h * d, cfg, "q_proj")
-            self.k_proj = _dense(h * d, cfg, "k_proj")
-            self.v_proj = _dense(h * d, cfg, "v_proj")
+            self.q_proj = dense(h * d, std, "q_proj")
+            self.k_proj = dense(h * d, std, "k_proj")
+            self.v_proj = dense(h * d, std, "v_proj")
             self.q_conv = self.params.get(
                 "q_conv_weight", init=init,
                 shape=(h * d, cfg.short_conv_kernel_size))
@@ -189,11 +167,11 @@ class KimiDeltaAttention(HybridBlock):
             self.v_conv = self.params.get(
                 "v_conv_weight", init=init,
                 shape=(h * d, cfg.short_conv_kernel_size))
-            self.f_a_proj = _dense(d, cfg, "f_a_proj")
-            self.f_b_proj = _dense(h * d, cfg, "f_b_proj")
-            self.b_proj = _dense(h, cfg, "b_proj")
-            self.g_a_proj = _dense(d, cfg, "g_a_proj")
-            self.g_b_proj = _dense(h * d, cfg, "g_b_proj")
+            self.f_a_proj = dense(d, std, "f_a_proj")
+            self.f_b_proj = dense(h * d, std, "f_b_proj")
+            self.b_proj = dense(h, std, "b_proj")
+            self.g_a_proj = dense(d, std, "g_a_proj")
+            self.g_b_proj = dense(h * d, std, "g_b_proj")
             self.a_log = self.params.get(
                 "A_log", shape=(h,),
                 init=_LogUniform(1.0, 16.0, jnp.log, False))
@@ -203,7 +181,7 @@ class KimiDeltaAttention(HybridBlock):
                                  lambda dt: dt + jnp.log(-jnp.expm1(-dt)),
                                  True))
             self.o_norm = RMSNorm(d, cfg.rms_norm_eps, prefix="o_norm_")
-            self.o_proj = _dense(cfg.hidden_size, cfg, "o_proj")
+            self.o_proj = dense(cfg.hidden_size, std, "o_proj")
 
     def hybrid_forward(self, F, x, q_conv, k_conv, v_conv, a_log, dt_bias):
         cfg = self.cfg
@@ -223,74 +201,40 @@ class KimiDeltaAttention(HybridBlock):
             return self.o_proj(o)
 
 
-class KimiLinearLayer(HybridBlock):
-    def __init__(self, cfg, kda, dense, **kwargs):
-        super().__init__(**kwargs)
-        with self.name_scope():
-            self.input_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
-                                      prefix="input_norm_")
-            self.attention = (KimiDeltaAttention if kda else MLAAttention)(
-                cfg, prefix="attn_")
-            self.post_norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
-                                     prefix="post_norm_")
-            self.mlp = LlamaMLP(cfg.mlp(cfg.intermediate_size),
-                                prefix="mlp_") if dense \
-                else MoEBlock(cfg, prefix="moe_")
-
-    def hybrid_forward(self, F, x):
-        x = x + self.attention(self.input_norm(x))
-        return x + self.mlp(self.post_norm(x))
-
-
-class KimiLinearModel(HybridBlock):
-    def __init__(self, cfg, **kwargs):
-        super().__init__(**kwargs)
-        self.cfg = cfg
-        with self.name_scope():
-            self.embed = nn.Embedding(
-                cfg.vocab_size, cfg.hidden_size, prefix="embed_",
-                weight_initializer=Normal(cfg.embedding_initializer_range))
-            self.layers = nn.HybridSequential(prefix="")
-            for i in range(cfg.num_hidden_layers):
-                self.layers.add(KimiLinearLayer(
-                    cfg, kda=i + 1 in cfg.kda_layers,
-                    dense=i < cfg.first_k_dense_replace,
-                    prefix=f"layer{i}_"))
-            self.norm = RMSNorm(cfg.hidden_size, cfg.rms_norm_eps,
-                                prefix="norm_")
-
-    def hybrid_forward(self, F, tokens):
-        x = self.embed(tokens)
-        for layer in self.layers:
-            x = layer(x)
-        return self.norm(x)
-
-    def remat(self, active=True):
-        """Per-layer ``jax.checkpoint``, as ``DeepseekV3Model.remat``."""
-        for layer in self.layers:
-            layer.hybridize(active, remat=active)
-
-
-class KimiLinearForCausalLM(HybridBlock):
-    """tokens (B, T) -> logits (B, T, vocab_size) over the rows of the
-    vocabulary held here; the head is not tied to the embedding."""
-
-    def __init__(self, cfg, **kwargs):
-        super().__init__(**kwargs)
-        self.cfg = cfg
-        with self.name_scope():
-            self.model = KimiLinearModel(cfg, prefix="model_")
-            self.lm_head = _dense(cfg.vocab_size, cfg, "lm_head")
-
-    def hybrid_forward(self, F, tokens):
-        return self.lm_head(self.model(tokens))
+def _network(cfg):
+    """The decoder with each layer's mixer from the two lists (counted from
+    1), a dense SwiGLU in the first ``first_k_dense_replace`` layers and the
+    experts in the others."""
+    kda = functools.partial(KimiDeltaAttention, cfg, prefix="attn_")
+    mla = functools.partial(MLAAttention, cfg, use_nope=cfg.mla_use_nope,
+                            prefix="attn_")
+    mlp = functools.partial(swiglu, cfg.hidden_size, cfg.intermediate_size,
+                            "mlp_")
+    moe = functools.partial(
+        MoEBlock, hidden_size=cfg.hidden_size,
+        expert_width=cfg.moe_intermediate_size, num_experts=cfg.num_experts,
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
+        top_k=cfg.num_experts_per_token,
+        shared_experts=cfg.num_shared_experts,
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        norm_topk_prob=cfg.moe_renormalize, scoring_func="sigmoid",
+        initializer_range=cfg.initializer_range, prefix="moe_")
+    return DecoderLM(
+        [functools.partial(DecoderLayer, cfg.hidden_size, cfg.rms_norm_eps,
+                           kda if i + 1 in cfg.kda_layers else mla,
+                           mlp if i < cfg.first_k_dense_replace else moe)
+         for i in range(cfg.num_hidden_layers)],
+        vocab_size=cfg.vocab_size, hidden_size=cfg.hidden_size,
+        rms_norm_eps=cfg.rms_norm_eps,
+        initializer_range=cfg.initializer_range,
+        embedding_initializer_range=cfg.embedding_initializer_range)
 
 
 def kimi_linear_48b_a3b(**overrides):
     """moonshotai/Kimi-Linear-48B-A3B-Instruct at its published sizes (49.1 B
     parameters: pass ``experts_held``, ``expert_offset``, ``vocab_size`` and
     ``num_hidden_layers`` for one chip's share)."""
-    return KimiLinearForCausalLM(KimiLinearConfig(**overrides))
+    return _network(KimiLinearConfig(**overrides))
 
 
 def kimi_linear_tiny(**overrides):
@@ -303,4 +247,4 @@ def kimi_linear_tiny(**overrides):
               kda_head_dim=16, full_attn_layers=(3,), num_experts=8,
               num_experts_per_token=2)
     kw.update(overrides)
-    return KimiLinearForCausalLM(KimiLinearConfig(**kw))
+    return _network(KimiLinearConfig(**kw))

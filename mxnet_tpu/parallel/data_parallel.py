@@ -158,7 +158,6 @@ class DataParallelTrainer:
         self._param_vals = None   # device-resident, sharded; owned by us
         self._opt_state = None
         self._jitted = None
-        self._jitted_indexed = None
         self._jit_accum_cache = {}
         self._jit_multi_cache = {}
         self._jit_zero1_cache = {}
@@ -252,7 +251,7 @@ class DataParallelTrainer:
 
     def _make_loss_of(self, manual=False):
         """The traced fwd+loss closure — ONE source for every step
-        variant (plain, indexed, accumulating), replicated or sharded.
+        variant (plain, accumulating, multi-step), replicated or sharded.
 
         While it traces, the ambient mesh says how the step is split:
         this trainer's mesh when XLA partitions the step itself (the
@@ -294,26 +293,16 @@ class DataParallelTrainer:
                 new_state.append(ns)
         return new_params, new_state
 
-    def _step_body(self):
-        """The fused fwd/bwd/reduce/update body shared by the *batch and
-        indexed-epoch jit entry points (single source — the step paths
-        can never diverge)."""
+    def _build(self):
+        """The fused fwd/bwd/reduce/update step of :meth:`step`."""
         loss_of = self._make_loss_of()
 
-        def body(param_vals, opt_state, lr, key, inputs, label):
+        def train_step(param_vals, opt_state, lr, key, *batch):
             loss, grads = jax.value_and_grad(loss_of)(
-                list(param_vals), key, inputs, label)
+                list(param_vals), key, list(batch[:-1]), batch[-1])
             new_params, new_state = self._apply_updates(
                 param_vals, grads, opt_state, lr)
             return new_params, new_state, loss
-        return body
-
-    def _build(self):
-        body = self._step_body()
-
-        def train_step(param_vals, opt_state, lr, key, *batch):
-            return body(param_vals, opt_state, lr, key,
-                        list(batch[:-1]), batch[-1])
 
         donate = (0, 1) if self._donate else ()
         self._jitted = jax.jit(train_step, donate_argnums=donate)
@@ -482,20 +471,6 @@ class DataParallelTrainer:
             self._trace_step_phases(tt1, tt2, tt3)
         return NDArray(loss)
 
-    def _build_indexed(self):
-        body = self._step_body()
-
-        def train_step(param_vals, opt_state, lr, key, superdata,
-                       superlabel, i):
-            data = jax.lax.dynamic_index_in_dim(superdata, i, 0,
-                                                keepdims=False)
-            label_b = jax.lax.dynamic_index_in_dim(superlabel, i, 0,
-                                                   keepdims=False)
-            return body(param_vals, opt_state, lr, key, [data], label_b)
-
-        donate = (0, 1) if self._donate else ()
-        self._jitted_indexed = jax.jit(train_step, donate_argnums=donate)
-
     # -- ZeRO-1 sharded gradient sync (the bucketed RS+AG pipeline) -----
     def _zero1_active(self):
         """Resolve (once) whether the sharded pipeline runs: needs
@@ -584,7 +559,7 @@ class DataParallelTrainer:
         """Bucketed reduce-scatter -> 1/N optimizer update -> all-gather.
         Runs INSIDE shard_map ('dp' bound); ``grads`` are this chip's
         LOCAL gradients, ``opt_local`` the local 1/dp state shards.  ONE
-        source for plain/accum/indexed sharded steps.
+        source for plain/accum/multi sharded steps.
 
         ``comm_mode`` exists for the with-vs-without-overlap probe
         (:meth:`overlap_probe`):
@@ -686,24 +661,12 @@ class DataParallelTrainer:
                 return pv, st, losses
         else:
             def local_body(param_vals, opt_local, lr, key, *batch):
-                if kind == "indexed":
-                    superdata, superlabel, i = batch
-                    data = lax.dynamic_index_in_dim(superdata, i, 0,
-                                                    keepdims=False)
-                    label = lax.dynamic_index_in_dim(superlabel, i, 0,
-                                                     keepdims=False)
-                    ins = [data]
-                else:
-                    ins, label = list(batch[:-1]), batch[-1]
-                return local_step(param_vals, opt_local, lr, key, ins,
-                                  label)
+                return local_step(param_vals, opt_local, lr, key,
+                                  list(batch[:-1]), batch[-1])
 
         pspecs = [P()] * len(self._param_vals)
         sspecs = self._zero1_state_spec_tree()
-        if kind == "indexed":
-            dspec, lspec = inputs[0], inputs[1]   # prebuilt epoch specs
-            batch_specs = (dspec, lspec, P())
-        elif kind == "multi":
+        if kind == "multi":
             # per-step batches stacked on a leading replicated K axis;
             # the within-batch sharding follows the same _eff_bax rule
             batch_specs = tuple(
@@ -1051,46 +1014,6 @@ class DataParallelTrainer:
             self._trace_step_phases(tt1, tt2, tt3)
         return NDArray(losses)
 
-    def put_epoch(self, superdata, superlabel):
-        """Upload an epoch of batches to device once: superdata
-        (n_batches, B, ...), superlabel (n_batches, B, ...). Returns an
-        opaque handle for :meth:`step_indexed`.
-
-        Device-resident epoch feeding: per step only a scalar index
-        crosses host->device; the batch select is an in-graph
-        ``dynamic_index``. This is the TPU analog of the reference's
-        PrefetcherIter keeping decoded batches pinned
-        (src/io/iter_prefetcher.h).
-        """
-        if self._pp_active():
-            raise MXNetError(
-                "put_epoch/step_indexed are flat-mesh entry points; "
-                "with a pp axis use step()/step_accum()/step_multi()")
-        mesh = self.mesh
-        sd = jnp.asarray(superdata.data if isinstance(superdata, NDArray)
-                         else superdata)
-        sl = jnp.asarray(superlabel.data if isinstance(superlabel, NDArray)
-                         else superlabel)
-        def epoch_spec(a, is_label=False):
-            # leading epoch axis replicated; the within-batch sharding
-            # follows the same _eff_bax rule as step()/step_accum()
-            if a.ndim < 2:
-                raise MXNetError(
-                    f"put_epoch expects super-arrays with a leading epoch "
-                    f"axis, i.e. (n_batches, batch, ...) with ndim >= 2; "
-                    f"got shape {tuple(a.shape)}. Stack per-step batches "
-                    f"along a new axis 0 before calling put_epoch.")
-            inner = [None] * (a.ndim - 1)
-            inner[self._eff_bax(a.ndim - 1, is_label)] = AXIS_DP
-            return P(*([None] + inner))
-
-        spec_d = epoch_spec(sd)
-        spec_l = epoch_spec(sl, is_label=True)
-        # caller owns the handle; dropping it frees the device buffers
-        return (jax.device_put(sd, NamedSharding(mesh, spec_d)),
-                jax.device_put(sl, NamedSharding(mesh, spec_l)),
-                (spec_d, spec_l))
-
     def _ensure_device_state(self, params):
         """Params stay resident on device across steps (VERDICT r1 weak
         #6: re-device_put per step put a host round on the timed path).
@@ -1136,37 +1059,6 @@ class DataParallelTrainer:
                                  self._rule_init(v))
                     for v in self._param_vals]
 
-    def step_indexed(self, epoch_handle, i):
-        """One fused train step on batch ``i`` of a resident epoch
-        (see :meth:`put_epoch`)."""
-        t_step = self._step_entry()
-        superdata, superlabel = epoch_handle[0], epoch_handle[1]
-        if self._param_objs is None:
-            # probe batch only for deferred-shape resolution on first call
-            self._collect(NDArray(superdata[0]))
-        params = self._param_objs
-        if self._zero1_active():
-            self._zero1_ensure_plan([superdata[0], superlabel[0]])
-        self._ensure_device_state(params)
-        if self._zero1_active():
-            spec_d, spec_l = epoch_handle[2]
-            jitted = self._get_zero1_jit("indexed", (spec_d, spec_l))
-        else:
-            if self._jitted_indexed is None:
-                self._build_indexed()
-            jitted = self._jitted_indexed
-        key = _rnd.next_key()
-        lr = jnp.asarray(self.learning_rate, jnp.float32)
-        new_params, self._opt_state, loss = self._dispatch(
-            jitted, self._param_vals, self._opt_state, lr, key,
-            superdata, superlabel, jnp.asarray(i, jnp.int32))
-        self._num_update += 1
-        self._param_vals = list(new_params)
-        for p, v in zip(params, new_params):
-            p._data._set_data(v)
-        self._record_step(1, t_step)
-        return NDArray(loss)
-
     # -- elastic membership (mx.elastic, ISSUE 8) -----------------------
     def rebuild(self, mesh):
         """Adopt a new mesh **in place** — the trainer half of an
@@ -1205,7 +1097,6 @@ class DataParallelTrainer:
         self._zero1 = None
         self._plan = None
         self._jitted = None
-        self._jitted_indexed = None
         self._jit_accum_cache = {}
         self._jit_multi_cache = {}
         self._jit_zero1_cache = {}

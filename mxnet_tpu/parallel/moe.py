@@ -43,7 +43,7 @@
    of the layer's buffers between the passes.
    On one chip it runs without its exchange; nothing here stands in for
    absent chips.  The Gluon block around it is
-   ``gluon.model_zoo.nlp.deepseek_v3.MoEBlock``.
+   ``gluon.model_zoo.nlp.decoder.MoEBlock``.
 """
 from __future__ import annotations
 

@@ -458,22 +458,3 @@ def test_amp_zero1_accum_interaction():
         assert l3 < l1, (l1, l3)
     finally:
         amp._deinit_for_tests()   # restore default precision policy
-
-
-@needs8
-def test_put_epoch_rejects_rank1_superarray():
-    """A super-array without the leading epoch axis must raise a clear
-    MXNetError, not an IndexError from the sharding-spec internals."""
-    from mxnet_tpu.base import MXNetError
-    from mxnet_tpu.parallel.data_parallel import DataParallelTrainer
-    net = gluon.nn.Dense(2)
-    net.initialize()
-    net(nd.zeros((2, 3)))
-    mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
-    tr = DataParallelTrainer(net, gluon.loss.L2Loss(), "sgd",
-                             {"learning_rate": 0.1}, mesh=mesh)
-    good = nd.zeros((3, 2, 3))
-    with pytest.raises(MXNetError, match="leading epoch axis"):
-        tr.put_epoch(nd.zeros((6,)), nd.zeros((6,)))
-    with pytest.raises(MXNetError, match="leading epoch axis"):
-        tr.put_epoch(good, nd.zeros((6,)))

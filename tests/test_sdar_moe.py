@@ -174,17 +174,6 @@ def test_expert_shares_add_up_to_the_uncut_layer(net, held):
     np.testing.assert_allclose(total, whole, atol=2e-6)
 
 
-def test_block_holds_a_share_of_the_experts():
-    shared = _net(experts_held=2, expert_offset=4)
-    shared(mx.nd.array(np.asarray(_batch()[0]), dtype="int32"))
-    shapes = {n[len(shared.prefix):]: p.shape
-              for n, p in shared.collect_params().items()}
-    assert shapes["model_layer0_moe_experts_gate_weight"] == (2, 64, 32)
-    assert shapes["model_layer0_moe_router_weight"] == (8, 64)
-    assert not any("e_score_correction_bias" in n or "shared" in n
-                   for n in shapes)
-
-
 @pytest.fixture
 def bf16():
     amp.init(target_dtype="bfloat16")
